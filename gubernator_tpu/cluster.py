@@ -14,6 +14,8 @@ from __future__ import annotations
 import random
 from typing import List, Optional, Sequence
 
+import jax
+
 from gubernator_tpu.api.types import PeerInfo
 from gubernator_tpu.service.config import BehaviorConfig, DaemonConfig
 from gubernator_tpu.service.daemon import Daemon
@@ -36,16 +38,20 @@ class Cluster:
         cache_size: int = 8192,
         **daemon_conf,
     ) -> "Cluster":
-        """Extra keyword args pass through to every DaemonConfig —
-        e.g. ``overload=True, intake_limit=64`` arms the overload
-        control plane mesh-wide (tools/jobs/45_overload_soak.py)."""
+        """Daemon i's table lives on ``jax.devices()[i % n]``: on a
+        four-chip host four daemons hold four chips. Extra keyword args
+        pass through to every DaemonConfig — e.g. ``overload=True,
+        intake_limit=64`` arms the overload control plane mesh-wide
+        (tools/jobs/45_overload_soak.py)."""
         c = cls()
         dcs = list(datacenters) if datacenters else [DATACENTER_NONE] * count
-        for dc in dcs:
+        devices = jax.devices()
+        for i, dc in enumerate(dcs):
             conf = DaemonConfig(
                 data_center=dc,
                 cache_size=cache_size,
                 behaviors=behaviors or BehaviorConfig(),
+                device=devices[i % len(devices)],
                 **daemon_conf,
             )
             c.daemons.append(await Daemon.spawn(conf))

@@ -18,9 +18,7 @@ def main() -> None:
     args = p.parse_args()
 
     from gubernator_tpu.utils.compilecache import enable_compile_cache
-    from gubernator_tpu.utils.platform import honor_env_platforms
 
-    honor_env_platforms()
     enable_compile_cache()
 
     from gubernator_tpu.cluster import Cluster

@@ -19,12 +19,10 @@ def main() -> None:
     args = parser.parse_args()
 
     from gubernator_tpu.utils.compilecache import enable_compile_cache
-    from gubernator_tpu.utils.platform import honor_env_platforms
 
-    honor_env_platforms()
     # Persistent XLA cache: a restarted daemon deserializes its decide
-    # kernels instead of recompiling (~123s cold on TPU) — serving within
-    # seconds of exec, like the reference's Go daemon.
+    # kernels instead of recompiling — serving within seconds of exec,
+    # like the reference's Go daemon.
     enable_compile_cache()
 
     from gubernator_tpu.service.daemon import Daemon

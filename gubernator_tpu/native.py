@@ -1,6 +1,7 @@
 """Native batch key hasher: build-on-demand C++ via ctypes.
 
-Loads native/_guberhash.so (building it with g++ on first use) and
+Loads the library built from native/guberhash.cc (utils/nativebuild.py
+builds it with g++ on first use, named by the source's hash) and
 exposes single and batch 128-bit hashing. The in-process table identity
 hash is swappable (it never crosses process boundaries — peers route by
 fnv1 over strings and all wire/state formats carry string keys), so when
@@ -13,48 +14,34 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
-import threading
 from typing import List, Optional, Tuple
 
 import numpy as np
 
-from gubernator_tpu.utils import lockorder
+from gubernator_tpu.utils import lockorder, nativebuild
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
 _SRC = os.path.join(_NATIVE_DIR, "guberhash.cc")
-_SO = os.path.join(_NATIVE_DIR, "_guberhash.so")
 
 _lock = lockorder.make_lock("native.load")
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
-
-
-def _build() -> bool:
-    try:
-        subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-o", _SO, _SRC],
-            check=True,
-            capture_output=True,
-            timeout=120,
-        )
-        return True
-    except Exception:
-        return False
+# Why load() returned None, for the daemon's start-up WARNING.
+unavailable_reason = ""
 
 
 def load() -> Optional[ctypes.CDLL]:
     """The native library, building it on first use; None if unavailable."""
-    global _lib, _tried
+    global _lib, _tried, unavailable_reason
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) or os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-            if not os.path.exists(_SRC) or not _build():
-                return None
+        so, unavailable_reason = nativebuild.build_library(_SRC)
+        if so is None:
+            return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
             lib.guber_hash128.argtypes = [
                 ctypes.c_char_p,
                 ctypes.c_int,
@@ -71,7 +58,8 @@ def load() -> Optional[ctypes.CDLL]:
                 np.ctypeslib.ndpointer(np.int32),
             ]
             _lib = lib
-        except OSError:
+        except OSError as e:
+            unavailable_reason = f"{so}: {e}"
             _lib = None
         return _lib
 

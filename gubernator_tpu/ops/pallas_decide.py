@@ -653,11 +653,11 @@ def _build_pallas_call(
     if paged:
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))  # page_map
     in_specs.extend(blk() for _ in _VMEM_COLS)
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))  # data
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))  # data
     data_index = len(in_specs) - 1
 
     out_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),  # data (aliased)
+        pl.BlockSpec(memory_space=pl.ANY),  # data (aliased)
         blk(),  # status (i32)
         blk(),  # limit
         blk(),  # remaining

@@ -31,7 +31,6 @@ from gubernator_tpu.ops.kernels import (
 )
 from gubernator_tpu.ops.layout import DecideOutput, RequestBatch, SlotTable
 from gubernator_tpu.utils import lockorder, transfer
-from gubernator_tpu.utils.jaxcompat import shard_map
 
 AXIS = "owners"
 
@@ -116,7 +115,7 @@ def make_sharded_decide(
         out = jax.tree.map(lambda x: jax.lax.psum(x, AXIS), out)
         return table, out
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_decide,
         mesh=mesh,
         in_specs=(P(AXIS), P(), P()),
@@ -149,7 +148,7 @@ def make_sharded_inject(
         )
         return table, jax.lax.psum(ehi, AXIS), jax.lax.psum(elo, AXIS)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_inject,
         mesh=mesh,
         in_specs=(P(AXIS), P(), P()),
@@ -286,7 +285,7 @@ def _make_mesh_paged_kernels(
         )
         return data, jax.tree.map(lambda x: jax.lax.psum(x, AXIS), out)
 
-    _sharded_decide = shard_map(
+    _sharded_decide = jax.shard_map(
         _local_decide,
         mesh=mesh,
         in_specs=(P(AXIS), P(), P()),
@@ -306,7 +305,7 @@ def _make_mesh_paged_kernels(
         )
         return data, jax.lax.psum(ehi, AXIS), jax.lax.psum(elo, AXIS)
 
-    _sharded_inject = shard_map(
+    _sharded_inject = jax.shard_map(
         _local_inject,
         mesh=mesh,
         in_specs=(P(AXIS), P(), P()),

@@ -81,8 +81,7 @@ def tune_cache_path() -> str:
     override = os.environ.get("GUBER_PALLAS_TUNE_CACHE", "").strip()
     if override:
         return override
-    base = os.environ.get("GUBER_COMPILE_CACHE") or compilecache.DEFAULT_DIR
-    return os.path.join(base, "pallas_tune.json")
+    return os.path.join(compilecache.cache_dir(), "pallas_tune.json")
 
 
 def device_key(layout: str, paged: bool) -> str:

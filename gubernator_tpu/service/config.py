@@ -239,6 +239,11 @@ class DaemonConfig:
 
     behaviors: BehaviorConfig = dataclasses.field(default_factory=BehaviorConfig)
     engine: Optional[EngineConfig] = None
+    # The jax device this daemon's table lives on (None: the process
+    # default). Set by hosts of several daemons in one process — the
+    # in-process cluster puts daemon i on chip i — never from the
+    # environment: one daemon process owns one chip anyway.
+    device: Optional[object] = None
 
     # Static peer list (the in-process cluster fixture and tests use this;
     # discovery pools feed the same set_peers path)
@@ -452,6 +457,7 @@ class DaemonConfig:
             # Daemons serve the columnar edge; sized kernel buckets
             # compile in the background at boot.
             fast_buckets=True,
+            device=self.device,
             layout=self.table_layout,
             hotkeys_k=self.hotkeys_k,
             stage_metadata=self.stage_metadata,

@@ -30,6 +30,24 @@ from gubernator_tpu.utils import net
 log = logging.getLogger("gubernator.daemon")
 
 
+def _warn_missing_native() -> None:
+    """One WARNING when a native library did not build or load: the
+    process then hashes with Python xxh3 and/or serves every call
+    through the protobuf object path — it keeps working, slower, and
+    nothing else would say so."""
+    from gubernator_tpu import native, wire
+
+    for name, mod, effect in (
+        ("guberhash", native, "key hashing falls back to Python xxh3"),
+        ("wirepath", wire, "the columnar wire path is off"),
+    ):
+        if not mod.available():
+            log.warning(
+                "native library %s unavailable (%s): %s",
+                name, mod.unavailable_reason, effect,
+            )
+
+
 class Daemon:
     def __init__(self, conf: DaemonConfig):
         self.conf = conf
@@ -62,12 +80,24 @@ class Daemon:
         from gubernator_tpu.utils import faults
 
         faults.configure_from_env()
+        _warn_missing_native()
         if conf.global_mode == "ici":
             from gubernator_tpu.runtime.ici_engine import IciEngine, IciEngineConfig
 
             self.engine = IciEngine(conf.ici or IciEngineConfig())
         else:
             self.engine = DeviceEngine(conf.engine_config())
+
+        from gubernator_tpu.utils import devicemem
+
+        # JAX falls back to CPU by itself; say once what this daemon got.
+        pd = devicemem.process_devices()
+        log.info(
+            "device: platform=%s device_kind=%s device_count=%d "
+            "engine_devices=%s",
+            pd["platform"], pd["device_kind"], pd["device_count"],
+            [str(d) for d in self.engine.devices],
+        )
 
         # Persistence plugins (reference gubernator.go:138-148)
         if conf.store is not None:

@@ -871,15 +871,17 @@ class V1Service:
 
     def device_debug_info(self) -> dict:
         """/debug/device payload (docs/monitoring.md "Device
-        resources"): per-subsystem HBM attribution + headroom, the
-        host<->device transfer ledger, compile telemetry with retrace
-        attribution, and profiler capture stats. Host-side reads only —
-        allocator stats, histogram summaries, bounded ring copies — so
-        scraping it never dispatches device work (GL009)."""
+        resources"): the platform, device kind and device count JAX
+        initialised, per-subsystem HBM attribution + headroom with one
+        row per device the engine spans, the host<->device transfer
+        ledger, compile telemetry with retrace attribution, and
+        profiler capture stats. Host-side reads only — allocator stats,
+        histogram summaries, bounded ring copies — so scraping it never
+        dispatches device work (GL009)."""
         from gubernator_tpu.runtime import telemetry as _rt
-        from gubernator_tpu.utils import compilecache
+        from gubernator_tpu.utils import compilecache, devicemem
 
-        info: dict = {"v": 1}
+        info: dict = {"v": 1, **devicemem.process_devices()}
         if hasattr(self.engine, "device_memory"):
             info["memory"] = self.engine.device_memory()
         em = getattr(self.engine, "metrics", None)

@@ -9,7 +9,8 @@ tier, census buffers, pipeline in-flight ring, snapshot staging — and
 sizes them from bytes_per_slot x capacity) and the second from the
 backend's real per-device allocator stats when they exist.
 
-Two sources, ONE schema (tests/test_device_observatory.py pins parity):
+Two sources, ONE schema (tests/test_device_observatory.py pins parity),
+summed over every device the engine spans with one row per device:
 
 - "device": jax `device.memory_stats()` — real allocator numbers
   (TPU/GPU backends). bytes_in_use/bytes_limit come from the device;
@@ -42,6 +43,20 @@ SCHEMA_VERSION = 1
 ESTIMATED_CAPACITY_BYTES = 16 << 30
 
 
+def process_devices() -> dict:
+    """What JAX initialised in this process, as JAX reports it. JAX
+    falls back to CPU by itself when no accelerator initialises; this
+    is where a daemon says which one it got."""
+    import jax
+
+    devs = jax.devices()
+    return {
+        "platform": devs[0].platform,
+        "device_kind": devs[0].device_kind,
+        "device_count": len(devs),
+    }
+
+
 def device_stats(device=None) -> Optional[dict]:
     """Raw allocator stats for `device` (default: the first jax device),
     or None when unavailable — jax absent, no devices, or a backend
@@ -58,36 +73,61 @@ def device_stats(device=None) -> Optional[dict]:
     return dict(stats)
 
 
+def _device_row(device, stats: Optional[dict]) -> dict:
+    """One `devices` row: identity plus the device's own allocator
+    numbers (None when its backend reports none)."""
+    in_use = int(stats.get("bytes_in_use", 0)) if stats else None
+    return {
+        "id": getattr(device, "id", None),
+        "platform": getattr(device, "platform", None),
+        "device_kind": getattr(device, "device_kind", None),
+        "bytes_in_use": in_use,
+        "peak_bytes_in_use": (
+            int(stats.get("peak_bytes_in_use", in_use)) if stats else None
+        ),
+        "bytes_limit": (
+            int(
+                stats.get("bytes_limit", 0)
+                or stats.get("bytes_reservable_limit", 0)
+                or 0
+            )
+            if stats
+            else None
+        ),
+    }
+
+
 def snapshot(
     subsystems: Optional[dict] = None,
-    device=None,
+    devices=None,
     capacity_bytes: Optional[int] = None,
 ) -> dict:
-    """One device-memory accounting snapshot.
+    """One device-memory accounting snapshot over `devices` — every
+    device the engine's tables span (default: the first jax device).
 
     `subsystems` maps subsystem name -> estimated resident bytes (static
     geometry, computed once by the engine at init). The returned dict
     has the SAME keys whether backed by real device stats or the
-    estimated fallback; only `source` distinguishes them."""
+    estimated fallback; only `source` distinguishes them. The totals sum
+    over the devices; `devices` carries one row per device (identity +
+    its own allocator numbers, None where the backend reports none) so
+    a table that landed on the wrong chip, or all on one, is visible."""
     subs = {k: int(v) for k, v in (subsystems or {}).items()}
     accounted = sum(subs.values())
-    stats = device_stats(device)
-    if stats is not None:
+    devs = list(devices) if devices else [None]
+    rows = [_device_row(d, device_stats(d)) for d in devs]
+    if all(r["bytes_in_use"] is not None for r in rows):
         source = "device"
-        in_use = int(stats.get("bytes_in_use", 0))
-        limit = int(
-            stats.get("bytes_limit", 0)
-            or stats.get("bytes_reservable_limit", 0)
-            or 0
-        )
-        peak = int(stats.get("peak_bytes_in_use", in_use))
+        in_use = sum(r["bytes_in_use"] for r in rows)
+        peak = sum(r["peak_bytes_in_use"] for r in rows)
+        limit = sum(r["bytes_limit"] for r in rows)
     else:
         source = "estimated"
         in_use = accounted
         limit = 0
         peak = in_use
     if limit <= 0:
-        limit = int(capacity_bytes or ESTIMATED_CAPACITY_BYTES)
+        limit = int(capacity_bytes or ESTIMATED_CAPACITY_BYTES * len(devs))
     headroom = max(limit - in_use, 0)
     return {
         "v": SCHEMA_VERSION,
@@ -100,4 +140,5 @@ def snapshot(
         "subsystems": subs,
         "accounted_bytes": accounted,
         "unattributed_bytes": max(in_use - accounted, 0),
+        "devices": rows,
     }

@@ -2,7 +2,8 @@
 
 The Python protobuf round trip costs ~10µs per request item; at the
 north-star request rates that is the entire budget. This module loads
-native/_wirepath.so (built on demand like the batch hasher) and exposes:
+the library built from native/wirepath.cc (on demand, like the batch
+hasher — utils/nativebuild.py) and exposes:
 
 - parse_requests(data) -> RequestColumns | None: one pass over a
   GetRateLimitsReq's bytes into numpy columns + concatenated
@@ -21,50 +22,35 @@ from __future__ import annotations
 import ctypes
 import dataclasses
 import os
-import subprocess
-import threading
 from typing import Optional
 
 import numpy as np
 
-from gubernator_tpu.utils import lockorder
+from gubernator_tpu.utils import lockorder, nativebuild
 
 _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native"
 )
 _SRC = os.path.join(_NATIVE_DIR, "wirepath.cc")
-_SO = os.path.join(_NATIVE_DIR, "_wirepath.so")
 
 _lock = lockorder.make_lock("wire.load")
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
-
-
-def _build() -> bool:
-    try:
-        subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-o", _SO, _SRC],
-            check=True,
-            capture_output=True,
-            timeout=120,
-        )
-        return True
-    except Exception:
-        return False
+# Why load() returned None, for the daemon's start-up WARNING.
+unavailable_reason = ""
 
 
 def load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, unavailable_reason
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) or os.path.getmtime(_SO) < os.path.getmtime(_SRC):
-            if not os.path.exists(_SRC) or not _build():
-                return None
+        so, unavailable_reason = nativebuild.build_library(_SRC)
+        if so is None:
+            return None
         try:
-            lib = ctypes.CDLL(_SO)
-            u8 = ctypes.POINTER(ctypes.c_uint8)
+            lib = ctypes.CDLL(so)
             lib.guber_count_requests.argtypes = [
                 ctypes.c_char_p, ctypes.c_int,
                 ctypes.POINTER(ctypes.c_int64),
@@ -121,8 +107,8 @@ def load() -> Optional[ctypes.CDLL]:
                     np.ctypeslib.ndpointer(np.uint64),
                 ]
             _lib = lib
-            _ = u8
-        except OSError:
+        except OSError as e:
+            unavailable_reason = f"{so}: {e}"
             _lib = None
         return _lib
 

@@ -8,7 +8,8 @@
 // boundaries (peers route by fnv1 over strings; wire/state carry string
 // keys), so the in-process hash choice is free.
 //
-// Build: g++ -O3 -shared -fPIC -o _guberhash.so guberhash.cc
+// Built on first use by gubernator_tpu/utils/nativebuild.py:
+// g++ -O3 -shared -fPIC -o _guberhash.<source-hash>.so guberhash.cc
 
 #include <cstdint>
 #include <cstring>

@@ -10,7 +10,8 @@
 // created_at=10; RateLimitResp status=1 limit=2 remaining=3
 // reset_time=4 error=5).
 //
-// Build: g++ -O3 -shared -fPIC -o _wirepath.so wirepath.cc
+// Built on first use by gubernator_tpu/utils/nativebuild.py:
+// g++ -O3 -shared -fPIC -o _wirepath.<source-hash>.so wirepath.cc
 
 #include <cstdint>
 #include <cstring>

@@ -53,6 +53,7 @@ def test_healthcheck_timeout_flag_applies(monkeypatch):
 # ---- EdgeClient timeout: configured, counted -------------------------------
 
 
+@pytest.mark.deadline(30)
 def test_edge_client_timeout_sourced_and_counted():
     from gubernator_tpu.service.edge import (
         METHOD_HEALTH_CHECK,
@@ -62,12 +63,16 @@ def test_edge_client_timeout_sourced_and_counted():
 
     async def main():
         # A server that accepts frames and never answers: the stall case.
+        # It closes its writer once the client goes away — on Python
+        # 3.12 Server.wait_closed() waits for every connection.
         async def black_hole(reader, writer):
             try:
                 while await reader.read(4096):
                     pass
             except ConnectionResetError:
                 pass
+            finally:
+                writer.close()
 
         server = await asyncio.start_server(black_hole, "127.0.0.1", 0)
         port = server.sockets[0].getsockname()[1]

@@ -995,31 +995,3 @@ def test_pallas_wavescan_matches_scans(layout, mode, monkeypatch):
         assert int(scan.adm_limit) == int(adm["limit_sum"]), tag
         assert int(scan.census_live) == int(cen["live"]), tag
         assert int(scan.census_waste) == int(cen["waste"]), tag
-
-
-@pytest.mark.pallas
-@pytest.mark.parametrize("layout", PALLAS_LAYOUTS)
-def test_pallas_mosaic_block_shapes(layout, monkeypatch):
-    """TPU-only: the mosaic lowering must stay bit-exact with the
-    reference program across the autotuner's candidate lane tiles.
-    Skips cleanly off-TPU (the mosaic compiler needs real hardware)."""
-    if jax.default_backend() != "tpu":
-        pytest.skip("mosaic lowering requires a TPU backend")
-    monkeypatch.setenv("GUBER_KERNEL", "xla")
-    K = get_kernels(layout)
-    rng = np.random.default_rng(31)
-    b = _pallas_reqs(rng, NOW)
-    for block in (128, 256, 512):
-        monkeypatch.setenv("GUBER_PALLAS_INTERPRET", "0")
-        monkeypatch.setenv("GUBER_PALLAS_BLOCK", str(block))
-        tm, om = _pd.decide_flat(
-            K.create(NUM_GROUPS, WAYS), b, jnp.int64(NOW),
-            layout=layout, ways=WAYS,
-        )
-        monkeypatch.setenv("GUBER_PALLAS_INTERPRET", "1")
-        ti, oi = _pd.decide_flat(
-            K.create(NUM_GROUPS, WAYS), b, jnp.int64(NOW),
-            layout=layout, ways=WAYS,
-        )
-        _assert_outs_match(om, oi, f"mosaic/{layout}/b{block}")
-        _assert_tables_match(tm, ti, f"mosaic/{layout}/b{block}")

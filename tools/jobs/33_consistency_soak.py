@@ -1,4 +1,3 @@
-# TIMEOUT: 1800
 """Consistency soak (docs/monitoring.md "Consistency"): drive GLOBAL
 traffic through a 3-daemon mesh from non-owner replicas, then measure
 the eventual-consistency window the observatory instruments —
@@ -6,14 +5,12 @@ end-to-end propagation lag p50/p99 at each replica, per-leg counts,
 and a full divergence-audit pass from every owner which must come back
 clean (zero divergence, zero max staleness) once traffic quiesces.
 
-Prints one `RESULT {json}` line like the other jobs (picked up by
-tools/tpu_runner.py / utils/ledger.py).
+Prints one `RESULT {json}` line like the other jobs.
 """
+import os
 import re, sys, json, time
 
-sys.path.insert(0, "/root/repo")
-for _m in [k for k in list(sys.modules) if k == "bench" or k.startswith("gubernator_tpu")]:
-    del sys.modules[_m]
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 
 
 def run() -> dict:

@@ -1,4 +1,3 @@
-# TIMEOUT: 1800
 """Table-census capacity planner (docs/monitoring.md "Table census"):
 soak a DeviceEngine with a skewed keyspace — a small always-hot set, a
 warm working set, and a stream of one-shot short-window tail keys —
@@ -9,14 +8,12 @@ HBM expired residents waste, how fast slots churn (insert / evict /
 recycle rates from the ledger), and how skew concentrates occupancy
 across heatmap regions.
 
-Prints one `RESULT {json}` line like the other jobs (picked up by
-tools/tpu_runner.py / utils/ledger.py).
+Prints one `RESULT {json}` line like the other jobs.
 """
+import os
 import sys, json
 
-sys.path.insert(0, "/root/repo")
-for _m in [k for k in list(sys.modules) if k == "bench" or k.startswith("gubernator_tpu")]:
-    del sys.modules[_m]
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 
 
 def run() -> dict:

@@ -1,4 +1,3 @@
-# TIMEOUT: 1800
 """Device-resource observatory soak (docs/monitoring.md "Device
 resources"): drive a DeviceEngine through the serving, snapshot/restore
 and readthrough-inject paths, then report what the run actually cost in
@@ -9,14 +8,12 @@ retrace attribution. The punchline numbers: HBM headroom after a full
 warm-up, and sustainable d2h serve bandwidth (the demux readback is the
 serving path's host<->device bottleneck).
 
-Prints one `RESULT {json}` line like the other jobs (picked up by
-tools/tpu_runner.py / utils/ledger.py).
+Prints one `RESULT {json}` line like the other jobs.
 """
+import os
 import sys, json
 
-sys.path.insert(0, "/root/repo")
-for _m in [k for k in list(sys.modules) if k == "bench" or k.startswith("gubernator_tpu")]:
-    del sys.modules[_m]
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 
 
 def run() -> dict:

@@ -1,4 +1,3 @@
-# TIMEOUT: 1800
 """Paged-table capacity wall study (docs/architecture.md "Paged table"):
 the same Zipf-skewed trace through (a) a flat all-resident engine — the
 oracle and latency baseline — and (b) a paged engine whose logical
@@ -16,14 +15,12 @@ page budget (PageBudgetError otherwise), so the trace is served in
 8-request calls against a 12-frame budget — worst case 8 distinct
 pages per wave, with 4 frames of slack for the demoter.
 
-Prints one `RESULT {json}` line (ledgered + auto-gated by
-tools/tpu_runner.py).
+Prints one `RESULT {json}` line.
 """
+import os
 import sys, json, time
 
-sys.path.insert(0, "/root/repo")
-for _m in [k for k in list(sys.modules) if k == "bench" or k.startswith("gubernator_tpu")]:
-    del sys.modules[_m]
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 
 
 def run() -> dict:

@@ -1,4 +1,3 @@
-# TIMEOUT: 1800
 """Cooperative-lease soak (docs/architecture.md "Cooperative leases"):
 the same Zipf-skewed single-check trace against a 3-daemon mesh twice —
 (a) a plain client, every check a gRPC round trip (plus peer forwarding
@@ -12,14 +11,12 @@ returning its slices, the fleet-wide over-admission stays bounded by
 the outstanding ledger, and the expiry sweep drives
 `gubernator_lease_outstanding_hits` back to 0 (`healed`).
 
-Prints one `RESULT {json}` line (ledgered + auto-gated by
-tools/tpu_runner.py).
+Prints one `RESULT {json}` line.
 """
+import os
 import re, sys, json, time
 
-sys.path.insert(0, "/root/repo")
-for _m in [k for k in list(sys.modules) if k == "bench" or k.startswith("gubernator_tpu")]:
-    del sys.modules[_m]
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 
 
 def run() -> dict:

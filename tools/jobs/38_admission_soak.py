@@ -1,4 +1,3 @@
-# TIMEOUT: 1800
 """Admission-observatory soak (docs/monitoring.md "Admission"): measured
 fleet enforcement error under chaos — partition + leases + paged table
 all on, per ISSUE 14.
@@ -23,13 +22,12 @@ is live) serves one keyspace owned by a single daemon. The drill:
 
 Acceptance evidence (ISSUE 14): `partition.within_bound`,
 `healed.excess_zero`, `healed.bound_zero`. Prints one `RESULT {json}`
-line (ledgered + auto-gated by tools/tpu_runner.py).
+line.
 """
+import os
 import sys, json, time
 
-sys.path.insert(0, "/root/repo")
-for _m in [k for k in list(sys.modules) if k == "bench" or k.startswith("gubernator_tpu")]:
-    del sys.modules[_m]
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 
 
 def run() -> dict:

@@ -1,16 +1,13 @@
-# TIMEOUT: 3600
 # Unified-core mesh scaling (ISSUE 15): the same seeded trace through
 # MeshEngine at mesh width 1 and IciEngine's owner-sharded tier at every
 # power-of-two width up to the full device count — decisions/s vs chips,
-# the measurement the engine unification exists for. On TPU the device
-# claim is held by THIS process, so every cell runs in-process
-# (bench_mesh_ab falls through from the fresh-process CPU path); per-cell
-# rows and the mesh/single-chip ratio row are ledgered as they land, and
-# the runner's auto-gate appends the GATE verdict for the freshest row.
+# the measurement the engine unification exists for. On TPU the chips
+# are held by THIS process, so every cell runs in-process (the
+# fresh-process isolation is for the CPU path); per-cell rows and the
+# mesh/single-chip ratio row are ledgered as they land.
+import os
 import sys, json
-sys.path.insert(0, "/root/repo")
-for _m in [k for k in list(sys.modules) if k == "bench" or k.startswith("gubernator_tpu")]:
-    del sys.modules[_m]
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 import bench
 import jax
 

@@ -1,4 +1,3 @@
-# TIMEOUT: 1800
 """Chaos soak (staged for the cluster harness): the ISSUE-3 acceptance
 criterion as a measured job. With one of three daemons hard-killed
 under sustained mixed (forwarded + GLOBAL) traffic, p99 latency for
@@ -7,14 +6,12 @@ keys owned by SURVIVING peers must stay within 2x the healthy baseline
 of burning 5 serial timeouts per request — and aggregated GLOBAL hit
 totals must reconcile across a fault-injected transient partition.
 
-Prints one `RESULT {json}` line like the other jobs (picked up by
-tools/tpu_runner.py / utils/ledger.py).
+Prints one `RESULT {json}` line like the other jobs.
 """
+import os
 import sys, json, time
 
-sys.path.insert(0, "/root/repo")
-for _m in [k for k in list(sys.modules) if k == "bench" or k.startswith("gubernator_tpu")]:
-    del sys.modules[_m]
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 
 
 def percentile(xs, q):

@@ -1,4 +1,3 @@
-# TIMEOUT: 1800
 """Rolling-restart soak (ISSUE-5 acceptance): restart a 3-daemon
 cluster one node at a time UNDER LOAD and assert zero counter resets
 and zero failed in-flight requests with GUBER_HANDOVER on.
@@ -13,15 +12,13 @@ survivors' routing flip (bounded by worker concurrency, NOT by key
 count: a counter RESET would lose hundreds of hits per key and trips
 the per-key bound immediately).
 
-Prints one `RESULT {json}` line like the other jobs (picked up by
-tools/tpu_runner.py / utils/ledger.py).
+Prints one `RESULT {json}` line like the other jobs.
 """
+import os
 import json
 import sys
 
-sys.path.insert(0, "/root/repo")
-for _m in [k for k in list(sys.modules) if k == "bench" or k.startswith("gubernator_tpu")]:
-    del sys.modules[_m]
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 
 LIMIT = 10_000_000
 N_KEYS = 120

@@ -1,17 +1,12 @@
-# TIMEOUT: 3600
 # Pallas-vs-XLA decide backend A/B (ISSUE 16): the same seeded Zipf
 # trace through GUBER_KERNEL=xla and GUBER_KERNEL=pallas cells at
-# identical geometry/layout, for both pallas-eligible layouts. On the
-# TPU runner the pallas cells run the mosaic lowering (the fused
-# one-HBM-pass kernel this job exists to measure); each cell's raw row
-# and the pallas/xla ratio row are ledgered as they land, and the
-# runner's auto-gate appends the GATE verdict for the freshest row
-# (utils/ledger.gate — a pallas throughput regression fails the job's
-# verdict on the next run).
+# identical geometry/layout, for both pallas-eligible layouts. On a
+# TPU the pallas cells run the mosaic lowering (the fused one-HBM-pass
+# kernel this job exists to measure); each cell's raw row and the
+# pallas/xla ratio row are ledgered as they land.
+import os
 import sys, json
-sys.path.insert(0, "/root/repo")
-for _m in [k for k in list(sys.modules) if k == "bench" or k.startswith("gubernator_tpu")]:
-    del sys.modules[_m]
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 import bench
 
 r = None

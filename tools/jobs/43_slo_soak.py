@@ -1,4 +1,3 @@
-# TIMEOUT: 1800
 """SLO-observatory soak (docs/monitoring.md "SLOs & burn rates"): drive
 the admission-accuracy SLO through a full burn-rate alert cycle with a
 real fault, per ISSUE 17.
@@ -31,13 +30,12 @@ window. The drill:
 
 Acceptance evidence (ISSUE 17): `fired`, `fired_within_window`,
 `fleet_budget_visible`, `cleared`, `budget_stopped_burning`. Prints one
-`RESULT {json}` line (ledgered + auto-gated by tools/tpu_runner.py).
+`RESULT {json}` line.
 """
+import os
 import sys, json, time
 
-sys.path.insert(0, "/root/repo")
-for _m in [k for k in list(sys.modules) if k == "bench" or k.startswith("gubernator_tpu")]:
-    del sys.modules[_m]
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 
 
 def run() -> dict:

@@ -1,4 +1,3 @@
-# TIMEOUT: 1800
 """Crash soak: the standby-replication acceptance drill
 (docs/robustness.md "Standby replication & crash recovery").
 
@@ -17,11 +16,10 @@ digest exchange reporting zero mismatched regions (convergence).
 Prints one `RESULT {json}` line and appends it to the benchmark ledger
 (mode=crash_soak) with the auto-gate verdict as a `GATE {json}` line.
 """
+import os
 import sys, json, time, random
 
-sys.path.insert(0, "/root/repo")
-for _m in [k for k in list(sys.modules) if k == "bench" or k.startswith("gubernator_tpu")]:
-    del sys.modules[_m]
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 
 
 def run() -> dict:

@@ -1,4 +1,3 @@
-# TIMEOUT: 1800
 """Overload soak: the DoS-flood + retry-storm acceptance drill
 (docs/robustness.md "Overload control & brownout").
 
@@ -28,11 +27,10 @@ The GATE asserts the paper-grade overload contract:
 Prints one `RESULT {json}` line and appends it to the benchmark ledger
 (mode=overload_soak) with the auto-gate verdict as a `GATE {json}` line.
 """
+import os
 import sys, json, time
 
-sys.path.insert(0, "/root/repo")
-for _m in [k for k in list(sys.modules) if k == "bench" or k.startswith("gubernator_tpu")]:
-    del sys.modules[_m]
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 
 
 def run() -> dict:
