@@ -171,8 +171,33 @@ class Log2Histogram:
             )
         return _HistChild(self, tuple(str(v) for v in values))
 
+    def declare(self, *values) -> _HistChild:
+        """labels() that also exposes the series at 0 from now on: a
+        reader that takes deltas between two scrapes gets nothing for a
+        series that is absent from the first."""
+        child = self.labels(*values)
+        with self._lock:
+            self._series.setdefault(
+                child._key, [[0] * (self.n_buckets + 1), 0.0, {}]
+            )
+        return child
+
     def observe(self, value: float, trace_id: str = "") -> None:
         self._observe((), value, trace_id)
+
+    def observe_many(self, items) -> None:
+        """observe() for [(label values, float value)] under one lock
+        hold: a flush hands over its stages at once."""
+        rows = [(key, v, self._bucket_index(v)) for key, v in items]
+        with self._lock:
+            for key, v, i in rows:
+                s = self._series.get(key)
+                if s is None:
+                    s = self._series[key] = [
+                        [0] * (self.n_buckets + 1), 0.0, {}
+                    ]
+                s[0][i] += 1
+                s[1] += v
 
     def _bucket_index(self, value: float) -> int:
         if value <= self.scale:
@@ -584,6 +609,39 @@ raceguard.guarded_by(HotKeySketch, {
 # tier instantiates exactly these via EngineMetrics, Metrics exposes them
 # through register_renderable, and tools/check_metrics_names.py audits
 # the names against docs/monitoring.md without importing jax).
+# The flush stages tracing.stage() times (docs/monitoring.md "Tracing
+# the pipeline"), and every `stage` value of the engine's histogram.
+FLUSH_STAGES = (
+    "hash", "waves", "keydict", "lock_wait", "dispatch", "readback", "post",
+)
+ENGINE_STAGES = (
+    "intake", "assemble", "inflight_wait", "device_sync", "resolve",
+) + FLUSH_STAGES
+
+# The stages of one GetRateLimits call by the path that served it
+# (docs/monitoring.md "Tracing the pipeline"): they partition the
+# handler's time. PeersV1 calls observe the same stages under
+# path="peer_columnar" / "peer_object".
+CALL_STAGES = {
+    "columnar": ("executor_wait", "parse", "engine", "build", "loop_return"),
+    "mixed": ("executor_wait", "parse", "engine", "build", "loop_return",
+              "route", "engine_wait"),
+    "object": ("columnar_attempt", "pb_decode", "route", "engine_wait",
+               "pb_encode"),
+}
+CALL_STAGES["peer_columnar"] = CALL_STAGES["columnar"]
+CALL_STAGES["peer_object"] = CALL_STAGES["object"]
+# Why a call left the columnar path: edge_calls{path,reason}.
+EDGE_REASONS = {
+    "columnar": ("",),
+    "mixed": ("ring", "gregorian"),
+    "object": ("waves", "slow_item", "gregorian", "ring", "forward_only",
+               "disabled", "error"),
+}
+EDGE_REASONS["peer_columnar"] = EDGE_REASONS["columnar"]
+EDGE_REASONS["peer_object"] = EDGE_REASONS["object"]
+
+
 def engine_histograms() -> dict:
     us, cnt = 1e-6, 1.0
     return {
@@ -658,7 +716,12 @@ def engine_histograms() -> dict:
             "launch), inflight_wait (dispatched, waiting for the "
             "completion stage), device_sync (host materialization of "
             "device results), resolve (telemetry + write-behind + "
-            "future resolution).",
+            "future resolution). Inside assemble: hash, waves, keydict. "
+            "Inside device_sync on the columnar path (after assemble on "
+            "the object path): lock_wait (engine lock + collective "
+            "guard), dispatch (the wave launches under the lock), "
+            "readback (the blocking read). post is what follows the "
+            "read.",
             scale=us, n_buckets=24, labelnames=("stage",),
         ),
         "transfer_duration": Log2Histogram(
@@ -1008,6 +1071,49 @@ class Metrics:
             "gubernator_grpc_request_duration",
             "The timings of gRPC requests in seconds.",
             ["method"],
+            registry=r,
+        )
+        # One timeline per call (docs/monitoring.md "Tracing the
+        # pipeline"): the stages of a GetRateLimits / GetPeerRateLimits
+        # handler, observed at its exit under the path that served it.
+        self.call_stage_duration = Log2Histogram(
+            "gubernator_call_stage_duration",
+            "Wall seconds one call spent in each stage of its handler, "
+            "by the path that served it; the stages of a call add up "
+            "to its gubernator_grpc_request_duration observation.",
+            scale=1e-6, n_buckets=24, labelnames=("path", "stage"),
+        )
+        self.register_renderable(self.call_stage_duration)
+        self.edge_calls = counter(
+            "gubernator_edge_calls",
+            "GetRateLimits / GetPeerRateLimits calls by the path that "
+            "served them and, off the columnar path, why; counted "
+            "where gubernator_grpc_request_duration is observed.",
+            ["path", "reason"],
+        )
+        # (path, stage) -> histogram child, resolved once; every child
+        # is exposed at 0 from start-up.
+        self.call_stages = {
+            (path, stage): self.call_stage_duration.declare(path, stage)
+            for path, stages in CALL_STAGES.items()
+            for stage in stages
+        }
+        for path, reasons in EDGE_REASONS.items():
+            for reason in reasons:
+                self.edge_calls.labels(path, reason).inc(0)
+        self.engine_busy_seconds = counter(
+            "gubernator_engine_busy_seconds",
+            "Seconds in which at least one flush was between asking "
+            "for the engine lock and the end of its readback: host "
+            "time in which the engine had work outstanding for the "
+            "device, not device time (a blocking read of an idle "
+            "device counts in full).",
+        )
+        self.engine_clock_seconds = Gauge(
+            "gubernator_engine_clock_seconds",
+            "The clock gubernator_engine_busy_seconds is read on "
+            "(perf_counter at scrape); the ratio of their deltas is the "
+            "share of the time the engine had work outstanding.",
             registry=r,
         )
 
@@ -1576,6 +1682,10 @@ def engine_sync(engine):
         m.command_counter.set(em.requests)
         m.worker_queue_length.set(engine.queue_depth())
         m.engine_cold_compiles.set(getattr(em, "cold_compiles", 0))
+        if hasattr(em, "busy_clock"):
+            busy, clock = em.busy_clock()
+            m.engine_busy_seconds.set(busy)
+            m.engine_clock_seconds.set(clock)
         if hasattr(engine, "table_census"):
             c = engine.table_census()
             m.cache_size.set(c["live"])

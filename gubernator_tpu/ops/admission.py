@@ -144,8 +144,10 @@ def make_admission(
     def impl(table, now):
         if stacked:
             table = jax.tree.map(lambda x: x[0], table)
-        wide = RK.to_wide(table)
-        return _admission_wide(wide, now, n_buckets=n_buckets)
+        with jax.named_scope("admission.layout_in"):  # profile metadata
+            wide = RK.to_wide(table)
+        with jax.named_scope("admission.scan"):
+            return _admission_wide(wide, now, n_buckets=n_buckets)
 
     return jax.jit(impl)
 

@@ -201,15 +201,19 @@ def make_census(
     def impl(table, now):
         if stacked:
             table = jax.tree.map(lambda x: x[0], table)
-        wide = RK.to_wide(table)
-        return _census_wide(
-            wide,
-            now,
-            ways=ways,
-            heatmap_width=heatmap_width,
-            thresholds=tuple(thresholds),
-            n_buckets=n_buckets,
-        )
+        # Scope names are profile metadata (docs/monitoring.md
+        # "/debug/profile"); the outputs are unchanged.
+        with jax.named_scope("census.layout_in"):
+            wide = RK.to_wide(table)
+        with jax.named_scope("census.scan"):
+            return _census_wide(
+                wide,
+                now,
+                ways=ways,
+                heatmap_width=heatmap_width,
+                thresholds=tuple(thresholds),
+                n_buckets=n_buckets,
+            )
 
     return jax.jit(impl)
 

@@ -723,6 +723,7 @@ def make_sync_step(
                    left for the next tick (identical on every device; 0
                    when unbounded), groups merged this tick (identical
                    on every device; G when unbounded)]."""
-        return sharded(state, jnp.asarray(now, I64))
+        with jax.named_scope("ici.tick"):  # profile metadata only
+            return sharded(state, jnp.asarray(now, I64))
 
     return sync_fn

@@ -28,6 +28,7 @@ granularity also guarantees scatter-disjointness inside each wave.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import queue
 import threading
 import time
@@ -39,6 +40,11 @@ import numpy as np
 
 from gubernator_tpu.utils import lockorder
 from gubernator_tpu.utils import raceguard
+from gubernator_tpu.metrics import (
+    ENGINE_STAGES,
+    FLUSH_STAGES,
+    engine_histograms,
+)
 from gubernator_tpu.api.keys import group_of, key_hash128, key_hash128_batch
 from gubernator_tpu.api.types import (
     Behavior,
@@ -190,7 +196,6 @@ class EngineMetrics:
     metrics.wire_engine_telemetry()."""
 
     def __init__(self):
-        from gubernator_tpu.metrics import engine_histograms
         from gubernator_tpu.runtime.telemetry import (
             FlightRecorder,
             install_compile_listener,
@@ -213,14 +218,19 @@ class EngineMetrics:
             setattr(self, attr, h)
         self._histograms = tuple(hists.values())
         # Pre-resolved stage children (labels() lookups are per-flush
-        # hot-path cost; see observe_stages).
+        # hot-path cost).
         self._stage = {
-            s: self.stage_duration.labels(s)
-            for s in (
-                "intake", "assemble", "dispatch", "inflight_wait",
-                "device_sync", "resolve",
-            )
+            s: self.stage_duration.labels(s) for s in ENGINE_STAGES
         }
+        for s in FLUSH_STAGES:
+            self.stage_duration.declare(s)
+        # Engine occupancy: the union of the intervals in which at least
+        # one flush is between asking for the engine lock and the end of
+        # its readback (busy_enter / busy_exit).
+        self._busy_lock = lockorder.make_lock("engine.metrics.busy")
+        self._busy_n = 0
+        self._busy_t0 = 0.0
+        self._busy_s = 0.0
         self.recorder = FlightRecorder()
         install_compile_listener()
 
@@ -229,6 +239,26 @@ class EngineMetrics:
 
     def observe_stage(self, stage: str, dur: float) -> None:
         self._stage[stage].observe(dur)
+
+    def busy_enter(self) -> None:
+        with self._busy_lock:
+            if self._busy_n == 0:
+                self._busy_t0 = time.perf_counter()
+            self._busy_n += 1
+
+    def busy_exit(self) -> None:
+        with self._busy_lock:
+            self._busy_n -= 1
+            if self._busy_n == 0:
+                self._busy_s += time.perf_counter() - self._busy_t0
+
+    def busy_clock(self) -> tuple:
+        """(busy seconds so far, the clock they are read on), taken at
+        one instant: an interval still open counts up to now."""
+        with self._busy_lock:
+            now = time.perf_counter()
+            open_s = now - self._busy_t0 if self._busy_n else 0.0
+            return self._busy_s + open_s, now
 
     def observe_transfer(self, direction: str, purpose: str,
                          n_bytes: int, dur: float) -> None:
@@ -298,6 +328,35 @@ class EngineMetrics:
             self.collective_tick.observe(dev)
 
 
+class FlushStages:
+    """The tracing.stage() sink of one flush. A stage that closes only
+    leaves its interval here; publish(), after the flush's read, hands
+    them to the engine's stage histogram in one go and fills `us`,
+    which the flight recorder keeps with the flush's record."""
+
+    __slots__ = ("em", "ids", "us", "_rows")
+
+    def __init__(self, em: EngineMetrics, flush: int, call: int):
+        self.em = em
+        self.ids = {"flush": flush, "call": call}
+        # every key from the start: the record shares this dict, and a
+        # /debug/engine dump may walk it while publish() fills it in
+        self.us: Dict[str, int] = dict.fromkeys(FLUSH_STAGES, 0)
+        self._rows: list = []
+
+    def add(self, label: str, t0_ns: int, t1_ns: int) -> None:
+        self._rows.append((label, t1_ns - t0_ns))
+
+    def publish(self) -> None:
+        rows, self._rows = self._rows, []
+        us = self.us
+        for label, wall_ns in rows:
+            us[label] += wall_ns // 1000
+        self.em.stage_duration.observe_many(
+            [((label,), wall_ns * 1e-9) for label, wall_ns in rows]
+        )
+
+
 class _Slot:
     """Lock-free result slot for bulk submissions: Future.set_result costs
     ~12µs in lock/notify overhead per item; bulk callers only need the
@@ -306,11 +365,13 @@ class _Slot:
 
     `span` (the caller's request span, captured once per bulk) and
     `t_enq` (enqueue stamp for GUBER_STAGE_METADATA) are observability
-    side-channels — both stay None on the knob-off path. `deadline_ms`
+    side-channels — both stay None on the knob-off path. `call` is the
+    caller's call sequence number (0: none), so the flush that serves
+    the member can name a call in its record. `deadline_ms`
     (absolute epoch ms, GUBER_OVERLOAD only) lets the pump drop the
     member at pickup when the caller already gave up."""
 
-    __slots__ = ("value", "_done", "span", "t_enq", "deadline_ms")
+    __slots__ = ("value", "_done", "span", "t_enq", "deadline_ms", "call")
 
     def __init__(self):
         self.value = None
@@ -318,6 +379,7 @@ class _Slot:
         self.span = None
         self.t_enq = None
         self.deadline_ms = None
+        self.call = 0
 
     def set_result(self, v) -> None:
         self.value = v
@@ -353,6 +415,7 @@ class _FlushTicket:
         "span",         # flush OTel span (dispatch->completion lifecycle)
         "otel_ctx",     # dispatch-time trace context for _complete
         "trace_id",     # sampled trace id hex ('' when unsampled/off)
+        "stages",       # FlushStages sink (dispatch -> completion)
     )
 
     def __init__(self, **kw):
@@ -443,7 +506,7 @@ class EngineBase:
         self._draining = False
         # Flush-ticket sequence (pump-thread only; the drain pass runs
         # on the same thread): the /debug/engine <-> trace join key.
-        self._ticket_seq = 0
+        self._ticket_seq = itertools.count(1)
         self._stage_md = bool(getattr(self.cfg, "stage_metadata", False))
         hk = getattr(self.metrics, "hotkeys", None)
         if hk is not None:
@@ -703,10 +766,10 @@ class EngineBase:
     # -- flush-span lifecycle (docs/monitoring.md "Tracing the pipeline") ----
 
     def _flush_seq(self) -> int:
-        """Next ticket sequence. Pump-thread only (the drain pass runs
-        on the pump thread too), so a plain increment suffices."""
-        self._ticket_seq += 1
-        return self._ticket_seq
+        """Next flush sequence: the pump's tickets and the columnar
+        flushes of the serving threads draw from one counter, so a
+        flush's id names it in /debug/engine and in a capture alike."""
+        return next(self._ticket_seq)
 
     def _start_flush_span(self, flush_items, seq: int, **attributes):
         """Start the per-ticket flush span (ends at completion, possibly
@@ -793,10 +856,14 @@ class EngineBase:
         self._queue.put((req, fut, t_enq))
         return fut
 
-    def check_bulk(self, reqs: Sequence[RateLimitReq]) -> "Future[List[RateLimitResp]]":
+    def check_bulk(
+        self, reqs: Sequence[RateLimitReq], call: int = 0
+    ) -> "Future[List[RateLimitResp]]":
         """Bulk check: ONE queue entry and ONE Future for N requests
         (amortizes pump wakeups and future overhead; the natural fit for
-        the batched GetRateLimits API). Resolves in request order."""
+        the batched GetRateLimits API). Resolves in request order.
+        `call` is the caller's call sequence number (tracing.CallRecord),
+        carried to the flush record."""
         t_in = time.perf_counter()
         out: Future = Future()
         if not self._running:
@@ -815,6 +882,7 @@ class EngineBase:
         for req in reqs:
             slot = _Slot()
             slot.span = rs
+            slot.call = call
             slots.append(slot)
             err = validate_request(req)
             if err is not None:
@@ -2314,16 +2382,23 @@ class MeshEngine(EngineBase):
         now = self.now_fn()
         cfg = self.cfg
         B = cfg.batch_size
+        seq = self._flush_seq()
+        # The flush names the first call it serves (a pump flush may
+        # coalesce several).
+        fs = FlushStages(self.metrics, seq, next(
+            (c for c in (getattr(f, "call", 0) for _, f in items) if c), 0
+        ))
 
         # One native batch-hash call for the whole flush (assembler hot
         # loop; gubernator_tpu.native), then one-shot tolist conversions
         # — per-item numpy scalar boxing dominated the assembler loop.
-        hashes = key_hash128_batch(
-            [req.hash_key() for req, _ in items], cfg.num_groups
-        )
-        hi_l, lo_l, grp_l = (
-            hashes[0].tolist(), hashes[1].tolist(), hashes[2].tolist()
-        )
+        with tracing.stage("flush.hash", fs, fs.ids):
+            hashes = key_hash128_batch(
+                [req.hash_key() for req, _ in items], cfg.num_groups
+            )
+            hi_l, lo_l, grp_l = (
+                hashes[0].tolist(), hashes[1].tolist(), hashes[2].tolist()
+            )
 
         # Store read-through happens per WAVE inside the execution loop
         # below, driven by a table-residency probe — the table, not host
@@ -2334,144 +2409,145 @@ class MeshEngine(EngineBase):
         # residency) are prefetched HERE; the per-wave probe catches the
         # rare remainder (displaced keys) with a direct fetch.
         prefetched: Dict[Tuple[int, int], object] = {}
-        if self.store is not None and cfg.keep_key_strings:
-            with self._keys_lock:
-                need = []
-                seen = set()
-                for i, (req, _) in enumerate(items):
-                    k = (hi_l[i], lo_l[i])
-                    if k not in self._key_strings and k not in seen:
-                        seen.add(k)
-                        need.append((req, k))
-            for req, k in need:
-                try:
-                    snap = self.store.get(req)
-                except Exception:
-                    snap = None  # store outage == cache miss, not a crash
-                if snap is not None:
-                    prefetched[k] = snap
+        with tracing.stage("flush.keydict", fs, fs.ids):
+            if self.store is not None and cfg.keep_key_strings:
+                with self._keys_lock:
+                    need = []
+                    seen = set()
+                    for i, (req, _) in enumerate(items):
+                        k = (hi_l[i], lo_l[i])
+                        if k not in self._key_strings and k not in seen:
+                            seen.add(k)
+                            need.append((req, k))
+                for req, k in need:
+                    try:
+                        snap = self.store.get(req)
+                    except Exception:
+                        snap = None  # store outage == cache miss, not a crash
+                    if snap is not None:
+                        prefetched[k] = snap
 
-        if cfg.keep_key_strings:
-            self._maybe_prune_key_strings()
+            if cfg.keep_key_strings:
+                self._maybe_prune_key_strings()
 
-        asm = _WaveAssembler(RequestBatch.zeros, B)
-        placements: List[Optional[tuple]] = []
-        wave_rows: List[list] = []  # per-wave (req, hi, lo, grp) for bulk fill
-        wave_lanes: List[list] = []
-        GREG = int(Behavior.DURATION_IS_GREGORIAN)
-        GLOBAL = int(Behavior.GLOBAL)
-        keep = cfg.keep_key_strings
-        rt = self._rtier
-        # GLOBAL replica routing (replica topologies): keys re-hash into
-        # the replica keyspace, waves assemble per (home, slot) so the
-        # round-robin home device rides the wave batch, and placements
-        # carry an "r" tag so _complete demuxes from the replica outputs.
-        r_asm = _WaveAssembler(RequestBatch.zeros, B) if rt is not None else None
-        replica_homes: List[np.ndarray] = []
+        with tracing.stage("flush.waves", fs, fs.ids):
+            asm = _WaveAssembler(RequestBatch.zeros, B)
+            placements: List[Optional[tuple]] = []
+            wave_rows: List[list] = []  # per-wave (req, hi, lo, grp) for bulk fill
+            wave_lanes: List[list] = []
+            GREG = int(Behavior.DURATION_IS_GREGORIAN)
+            GLOBAL = int(Behavior.GLOBAL)
+            keep = cfg.keep_key_strings
+            rt = self._rtier
+            # GLOBAL replica routing (replica topologies): keys re-hash into
+            # the replica keyspace, waves assemble per (home, slot) so the
+            # round-robin home device rides the wave batch, and placements
+            # carry an "r" tag so _complete demuxes from the replica outputs.
+            r_asm = _WaveAssembler(RequestBatch.zeros, B) if rt is not None else None
+            replica_homes: List[np.ndarray] = []
 
-        carry: List[Tuple[RateLimitReq, object]] = []
-        new_strings: Dict[Tuple[int, int], str] = {}
-        for i, (req, fut) in enumerate(items):
-            hi, lo = hi_l[i], lo_l[i]
-            if keep:
-                new_strings[(hi, lo)] = req.hash_key()
-            if rt is not None and (req.behavior & GLOBAL):
-                slot = group_of(lo, rt.num_rgroups)
-                home = self._home_rr % self.topo.n_dev
-                placed = r_asm.place((home, slot), cfg.max_waves)
+            carry: List[Tuple[RateLimitReq, object]] = []
+            new_strings: Dict[Tuple[int, int], str] = {}
+            for i, (req, fut) in enumerate(items):
+                hi, lo = hi_l[i], lo_l[i]
+                if keep:
+                    new_strings[(hi, lo)] = req.hash_key()
+                if rt is not None and (req.behavior & GLOBAL):
+                    slot = group_of(lo, rt.num_rgroups)
+                    home = self._home_rr % self.topo.n_dev
+                    placed = r_asm.place((home, slot), cfg.max_waves)
+                    if placed is None:
+                        carry.append((req, fut))
+                        placements.append("carry")
+                        continue
+                    self._home_rr += 1
+                    wb, w, lane = placed
+                    try:
+                        encode_one(wb, lane, req, now, rt.num_rgroups, key=(hi, lo))
+                    except EncodeError as e:
+                        fut.set_result(RateLimitResp(error=str(e)))
+                        placements.append(None)
+                        continue
+                    while len(replica_homes) < len(r_asm.waves):
+                        replica_homes.append(np.zeros(B, dtype=np.int64))
+                    replica_homes[w][lane] = home
+                    r_asm.commit(w, (home, slot))
+                    placements.append(("r", w, lane, hi, lo))
+                    continue
+                grp = grp_l[i]
+                placed = asm.place(grp, cfg.max_waves)
                 if placed is None:
+                    # Wave cap reached for this group: defer to the next flush
+                    # (the pump re-presents carried items first, preserving
+                    # per-key arrival order).
                     carry.append((req, fut))
                     placements.append("carry")
                     continue
-                self._home_rr += 1
                 wb, w, lane = placed
-                try:
-                    encode_one(wb, lane, req, now, rt.num_rgroups, key=(hi, lo))
-                except EncodeError as e:
-                    fut.set_result(RateLimitResp(error=str(e)))
-                    placements.append(None)
-                    continue
-                while len(replica_homes) < len(r_asm.waves):
-                    replica_homes.append(np.zeros(B, dtype=np.int64))
-                replica_homes[w][lane] = home
-                r_asm.commit(w, (home, slot))
-                placements.append(("r", w, lane, hi, lo))
-                continue
-            grp = grp_l[i]
-            placed = asm.place(grp, cfg.max_waves)
-            if placed is None:
-                # Wave cap reached for this group: defer to the next flush
-                # (the pump re-presents carried items first, preserving
-                # per-key arrival order).
-                carry.append((req, fut))
-                placements.append("carry")
-                continue
-            wb, w, lane = placed
-            if req.behavior & GREG:
-                # calendar resolution stays per-item (rare path)
-                try:
-                    encode_one(wb, lane, req, now, cfg.num_groups, key=(hi, lo))
-                except EncodeError as e:
-                    fut.set_result(RateLimitResp(error=str(e)))
-                    placements.append(None)
-                    continue
-            else:
-                while len(wave_rows) < len(asm.waves):
-                    wave_rows.append([])
-                    wave_lanes.append([])
-                wave_rows[w].append((req, hi, lo, grp))
-                wave_lanes[w].append(lane)
-            asm.commit(w, grp)
-            placements.append(("s", w, lane, hi, lo))
+                if req.behavior & GREG:
+                    # calendar resolution stays per-item (rare path)
+                    try:
+                        encode_one(wb, lane, req, now, cfg.num_groups, key=(hi, lo))
+                    except EncodeError as e:
+                        fut.set_result(RateLimitResp(error=str(e)))
+                        placements.append(None)
+                        continue
+                else:
+                    while len(wave_rows) < len(asm.waves):
+                        wave_rows.append([])
+                        wave_lanes.append([])
+                    wave_rows[w].append((req, hi, lo, grp))
+                    wave_lanes[w].append(lane)
+                asm.commit(w, grp)
+                placements.append(("s", w, lane, hi, lo))
 
-        if new_strings:
-            with self._keys_lock:
-                self._key_strings.update(new_strings)
+            if new_strings:
+                with self._keys_lock:
+                    self._key_strings.update(new_strings)
 
-        for w, rows in enumerate(wave_rows):
-            if rows:
-                encode_rows(asm.waves[w], wave_lanes[w], rows, now)
-        waves = asm.waves
+            for w, rows in enumerate(wave_rows):
+                if rows:
+                    encode_rows(asm.waves[w], wave_lanes[w], rows, now)
+            waves = asm.waves
 
-        # Bucket each wave's device width to its occupancy (the kernel's
-        # cost is per-LANE: a NO_BATCHING single-request flush must not
-        # pay a batch_size-wide kernel). Lane indices are arrival ranks,
-        # so every occupied lane survives the narrowing; only ALREADY-
-        # WARM shapes are used — same policy as the columnar path. With
-        # a store, flushes stay batch_size-wide (warm_store_path pins
-        # that width for probe/inject/gather).
-        if self.store is None:
-            warm = self._warm_shapes  # immutable snapshot
-            for w in range(len(waves)):
-                fill, Bn = asm.fill(w), B
-                for s in warm:
-                    if s >= fill and s < Bn:
-                        Bn = s
-                if Bn < B:
-                    waves[w] = jax.tree.map(lambda a: a[:Bn], waves[w])
+            # Bucket each wave's device width to its occupancy (the kernel's
+            # cost is per-LANE: a NO_BATCHING single-request flush must not
+            # pay a batch_size-wide kernel). Lane indices are arrival ranks,
+            # so every occupied lane survives the narrowing; only ALREADY-
+            # WARM shapes are used — same policy as the columnar path. With
+            # a store, flushes stay batch_size-wide (warm_store_path pins
+            # that width for probe/inject/gather).
+            if self.store is None:
+                warm = self._warm_shapes  # immutable snapshot
+                for w in range(len(waves)):
+                    fill, Bn = asm.fill(w), B
+                    for s in warm:
+                        if s >= fill and s < Bn:
+                            Bn = s
+                    if Bn < B:
+                        waves[w] = jax.tree.map(lambda a: a[:Bn], waves[w])
 
-        # Execute waves sequentially against the (donated) table. With a
-        # Store attached, each wave runs the reference's exact per-request
-        # sequence at wave granularity (algorithms.go:45-51):
-        #   probe (cache lookup) -> Store.Get for misses -> insert -> decide
-        # and then gathers its touched rows from the intermediate table so
-        # write-behind persists the value the caller observed even if a
-        # later wave displaces the slot (OnChange runs within the request,
-        # algorithms.go:149-153).
-        wave_lane_req: List[Dict[int, tuple]] = [dict() for _ in waves]
-        if self.store is not None:
-            for i, place in enumerate(placements):
-                if isinstance(place, tuple) and place[0] == "s":
-                    wave_lane_req[place[1]][place[2]] = (
-                        items[i][0], place[3], place[4],
-                    )
-        # Per-ticket flush span: starts here, rides the ticket across
-        # the pipeline boundary, ends when _complete finishes (the
-        # completion thread re-attaches its context — see
-        # _complete_ticket). Request spans link to it and back.
-        r_waves = r_asm.waves if r_asm is not None else []
-        n_waves = len(waves) + len(r_waves)
-        seq = self._flush_seq()
+            # Execute waves sequentially against the (donated) table. With a
+            # Store attached, each wave runs the reference's exact per-request
+            # sequence at wave granularity (algorithms.go:45-51):
+            #   probe (cache lookup) -> Store.Get for misses -> insert -> decide
+            # and then gathers its touched rows from the intermediate table so
+            # write-behind persists the value the caller observed even if a
+            # later wave displaces the slot (OnChange runs within the request,
+            # algorithms.go:149-153).
+            wave_lane_req: List[Dict[int, tuple]] = [dict() for _ in waves]
+            if self.store is not None:
+                for i, place in enumerate(placements):
+                    if isinstance(place, tuple) and place[0] == "s":
+                        wave_lane_req[place[1]][place[2]] = (
+                            items[i][0], place[3], place[4],
+                        )
+            # Per-ticket flush span: starts here, rides the ticket across
+            # the pipeline boundary, ends when _complete finishes (the
+            # completion thread re-attaches its context — see
+            # _complete_ticket). Request spans link to it and back.
+            r_waves = r_asm.waves if r_asm is not None else []
+            n_waves = len(waves) + len(r_waves)
         fspan = self._start_flush_span(
             items, seq, path="object", layout=cfg.layout,
             items=len(items), waves=n_waves,
@@ -2489,7 +2565,7 @@ class MeshEngine(EngineBase):
                 fspan
             ):
                 outs, r_outs, wave_rows_host, events = self._execute_waves(
-                    waves, wave_lane_req, now, prefetched,
+                    waves, wave_lane_req, now, prefetched, fs,
                     r_waves=r_waves, r_homes=replica_homes,
                 )
         except Exception as e:
@@ -2505,6 +2581,7 @@ class MeshEngine(EngineBase):
             t0=t0, t_dev=t_dev, seq=seq, span=fspan,
             otel_ctx=tracing.context_of(fspan),
             trace_id=tracing.trace_id_of(fspan),
+            stages=fs,
         )
 
     def _complete(self, t: _FlushTicket) -> None:
@@ -2512,126 +2589,138 @@ class MeshEngine(EngineBase):
         (one host sync per wave), feed telemetry, run write-behind, and
         resolve the futures — in FIFO dispatch order when pipelined."""
         cfg = self.cfg
+        fs = t.stages
         t_c0 = time.perf_counter()
         # The np.asarray syncs live in _materialize_out (the sanctioned
         # completion-stage readback). Sharded ("s") and replica ("r")
         # outputs materialize side by side; placements tag which list a
         # lane demuxes from.
-        host = {
-            "s": [_materialize_out(o) for o in t.outs],
-            "r": [_materialize_out(o) for o in t.r_outs],
-        }
-        t_sync = time.perf_counter()
-        dev_s = t_sync - t.t_dev
-        # Transfer ledger: the serve-path d2h readback. Duration is the
-        # blocking sync (copy + any pending compute it waited on).
-        _transfer.record(
-            self.metrics, "d2h", "serve", _transfer.nbytes(host),
-            t_sync - t_c0,
-        )
-
-        if cfg.keep_key_strings:
-            self._drop_displaced_strings(t.events)
-        tot = [
-            sum(h[i] for hs in host.values() for h in hs)
-            for i in (4, 5, 6, 7)
-        ]
-        dur = time.perf_counter() - t.t0
-        em = self.metrics
-        trace_id = (t.trace_id or "") if cfg.exemplars else ""
-        em.observe(tot[0], tot[1], tot[2], tot[3], t.waves, t.served, dur)
-        em.observe_flush(
-            "object", t.served, t.waves, dur, dev_s, trace_id,
-            collective=self.topo.n_dev > 1,
-        )
-        em.observe_stage("assemble", t.t_dev - t.t0)
-        em.observe_stage("dispatch", t.t_disp_end - t.t_dev)
-        em.observe_stage("inflight_wait", max(t_c0 - t.t_disp_end, 0.0))
-        em.observe_stage("device_sync", t_sync - t_c0)
-        em.recorder.record(
-            path="object", layout=cfg.layout, n=t.served, waves=t.waves,
-            carry=t.carry_n, widths=t.widths,
-            dur_us=int(dur * 1e6), dev_us=int(dev_s * 1e6),
-            ticket=t.seq, trace_id=t.trace_id or "",
-        )
-
-        # Write-behind BEFORE resolving futures, so a caller that observed
-        # its response can rely on the store reflecting it (the reference's
-        # OnChange runs within the request, algorithms.go:149-153).
-        if self.store is not None:
-            self._store_write_behind(t.items, t.placements, t.outs, t.rows)
-
-        # GUBER_STAGE_METADATA: the flush-level stage times every served
-        # item shares, built once; each response appends its own queue
-        # wait (resolve time is unknowable before resolution and is
-        # reported as the flush-level histogram only).
-        stage_base = None
-        if self._stage_md:
-            stage_base = (
-                f"assemble={int((t.t_dev - t.t0) * 1e6)}"
-                f",dispatch={int((t.t_disp_end - t.t_dev) * 1e6)}"
-                f",inflight_wait={int(max(t_c0 - t.t_disp_end, 0.0) * 1e6)}"
-                f",device_sync={int((t_sync - t_c0) * 1e6)}"
-            )
-        hk = em.hotkeys if em.hotkeys.k > 0 else None
-        hk_agg: Dict[Tuple[int, int], list] = {}
-        # Standby dirty harvest rides the same demux loop as the hotkey
-        # aggregation: zero extra passes, None when tracking is off.
-        with raceguard.racy_read(
-            "_dirty",
-            reason="None-gate only; _note_dirty re-checks under the lock",
-        ):
-            dirty_agg: Optional[list] = (
-                [] if self._dirty is not None else None
-            )
-        OVER = 1  # api.types.Status.OVER_LIMIT
-        for (req, fut), place in zip(t.items, t.placements):
-            if place is None or place == "carry":
-                continue  # resolved (encode error) or deferred
-            path, w, lane = place[0], place[1], place[2]
-            hw = host[path][w]
-            st, rem, rst, lim = hw[0], hw[1], hw[2], hw[3]
-            status = int(st[lane])  # guberlint: allow-host-sync -- numpy demux of already-materialized rows
-            if dirty_agg is not None:
-                dirty_agg.append((req.hash_key(), max(int(req.hits), 0)))
-            if hk is not None:
-                k = (place[3], place[4])
-                ent = hk_agg.get(k)
-                if ent is None:
-                    hk_agg[k] = [
-                        max(int(req.hits), 0), int(status == OVER),
-                        req.hash_key(),
-                    ]
-                else:
-                    ent[0] += max(int(req.hits), 0)
-                    ent[1] += int(status == OVER)
-            md = None
-            if stage_base is not None:
-                t_enq = getattr(fut, "t_enq", None)
-                md = {
-                    "stage_breakdown_us": (
-                        f"queue={int((t.t0 - t_enq) * 1e6)},{stage_base}"
-                        if t_enq is not None
-                        else stage_base
-                    )
+        try:
+            with tracing.stage("flush.readback", fs, fs.ids):
+                host = {
+                    "s": [_materialize_out(o) for o in t.outs],
+                    "r": [_materialize_out(o) for o in t.r_outs],
                 }
-            fut.set_result(
-                RateLimitResp(
-                    status=status,
-                    limit=int(lim[lane]),  # guberlint: allow-host-sync -- numpy demux of already-materialized rows
-                    remaining=int(rem[lane]),  # guberlint: allow-host-sync -- numpy demux of already-materialized rows
-                    reset_time=int(rst[lane]),  # guberlint: allow-host-sync -- numpy demux of already-materialized rows
-                    **({"metadata": md} if md else {}),
+        finally:
+            self.metrics.busy_exit()  # entered in _execute_waves
+        t_sync = time.perf_counter()
+        with tracing.stage("flush.post", fs, fs.ids):
+            dev_s = t_sync - t.t_dev
+            # Transfer ledger: the serve-path d2h readback. Duration is the
+            # blocking sync (copy + any pending compute it waited on).
+            _transfer.record(
+                self.metrics, "d2h", "serve", _transfer.nbytes(host),
+                t_sync - t_c0,
+            )
+
+            if cfg.keep_key_strings:
+                self._drop_displaced_strings(t.events)
+            tot = [
+                sum(h[i] for hs in host.values() for h in hs)
+                for i in (4, 5, 6, 7)
+            ]
+            dur = time.perf_counter() - t.t0
+            em = self.metrics
+            trace_id = (t.trace_id or "") if cfg.exemplars else ""
+            em.observe(tot[0], tot[1], tot[2], tot[3], t.waves, t.served, dur)
+            em.observe_flush(
+                "object", t.served, t.waves, dur, dev_s, trace_id,
+                collective=self.topo.n_dev > 1,
+            )
+            em.observe_stage("assemble", t.t_dev - t.t0)
+            # `dispatch` (the launches under the lock) and `lock_wait` were
+            # observed by _execute_waves; together they are the interval
+            # t_dev..t_disp_end this line observed as `dispatch` before.
+            em.observe_stage("inflight_wait", max(t_c0 - t.t_disp_end, 0.0))
+            em.observe_stage("device_sync", t_sync - t_c0)
+            # The record shares fs.us, which publish() fills in below.
+            em.recorder.record(
+                path="object", layout=cfg.layout, n=t.served, waves=t.waves,
+                carry=t.carry_n, widths=t.widths,
+                dur_us=int(dur * 1e6), dev_us=int(dev_s * 1e6),
+                ticket=t.seq, trace_id=t.trace_id or "",
+                call=fs.ids["call"], stages_us=fs.us,
+            )
+
+            # Write-behind BEFORE resolving futures, so a caller that observed
+            # its response can rely on the store reflecting it (the reference's
+            # OnChange runs within the request, algorithms.go:149-153).
+            if self.store is not None:
+                self._store_write_behind(t.items, t.placements, t.outs, t.rows)
+
+            # GUBER_STAGE_METADATA: the flush-level stage times every served
+            # item shares, built once; each response appends its own queue
+            # wait (resolve time is unknowable before resolution and is
+            # reported as the flush-level histogram only).
+            stage_base = None
+            if self._stage_md:
+                stage_base = (
+                    f"assemble={int((t.t_dev - t.t0) * 1e6)}"
+                    f",dispatch={int((t.t_disp_end - t.t_dev) * 1e6)}"
+                    f",inflight_wait={int(max(t_c0 - t.t_disp_end, 0.0) * 1e6)}"
+                    f",device_sync={int((t_sync - t_c0) * 1e6)}"
                 )
-            )
-        if hk is not None and hk_agg:
-            hk.update(
-                [(k, v[0], v[1], v[2]) for k, v in hk_agg.items()]
-            )
-        if dirty_agg:
-            self._note_dirty(dirty_agg)
-        em.observe_stage("resolve", time.perf_counter() - t_sync)
-        self._observe_overlap(t)
+            hk = em.hotkeys if em.hotkeys.k > 0 else None
+            hk_agg: Dict[Tuple[int, int], list] = {}
+            # Standby dirty harvest rides the same demux loop as the hotkey
+            # aggregation: zero extra passes, None when tracking is off.
+            with raceguard.racy_read(
+                "_dirty",
+                reason="None-gate only; _note_dirty re-checks under the lock",
+            ):
+                dirty_agg: Optional[list] = (
+                    [] if self._dirty is not None else None
+                )
+            OVER = 1  # api.types.Status.OVER_LIMIT
+            for (req, fut), place in zip(t.items, t.placements):
+                if place is None or place == "carry":
+                    continue  # resolved (encode error) or deferred
+                path, w, lane = place[0], place[1], place[2]
+                hw = host[path][w]
+                st, rem, rst, lim = hw[0], hw[1], hw[2], hw[3]
+                status = int(st[lane])  # guberlint: allow-host-sync -- numpy demux of already-materialized rows
+                if dirty_agg is not None:
+                    dirty_agg.append((req.hash_key(), max(int(req.hits), 0)))
+                if hk is not None:
+                    k = (place[3], place[4])
+                    ent = hk_agg.get(k)
+                    if ent is None:
+                        hk_agg[k] = [
+                            max(int(req.hits), 0), int(status == OVER),
+                            req.hash_key(),
+                        ]
+                    else:
+                        ent[0] += max(int(req.hits), 0)
+                        ent[1] += int(status == OVER)
+                md = None
+                if stage_base is not None:
+                    t_enq = getattr(fut, "t_enq", None)
+                    md = {
+                        "stage_breakdown_us": (
+                            f"queue={int((t.t0 - t_enq) * 1e6)},{stage_base}"
+                            if t_enq is not None
+                            else stage_base
+                        )
+                    }
+                fut.set_result(
+                    RateLimitResp(
+                        status=status,
+                        limit=int(lim[lane]),  # guberlint: allow-host-sync -- numpy demux of already-materialized rows
+                        remaining=int(rem[lane]),  # guberlint: allow-host-sync -- numpy demux of already-materialized rows
+                        reset_time=int(rst[lane]),  # guberlint: allow-host-sync -- numpy demux of already-materialized rows
+                        **({"metadata": md} if md else {}),
+                    )
+                )
+            if hk is not None and hk_agg:
+                hk.update(
+                    [(k, v[0], v[1], v[2]) for k, v in hk_agg.items()]
+                )
+            if dirty_agg:
+                self._note_dirty(dirty_agg)
+            em.observe_stage("resolve", time.perf_counter() - t_sync)
+            self._observe_overlap(t)
+        fs.publish()
+
 
     @staticmethod
     def _snapshot_from_row(r, lane: int, key: str):
@@ -2659,6 +2748,7 @@ class MeshEngine(EngineBase):
         now: Optional[int] = None,
         select: Optional[np.ndarray] = None,
         hashes: Optional[tuple] = None,
+        call=tracing.NO_CALL,
     ):
         """Vectorized decide over wire columns: no per-item Python objects
         anywhere — hashing, wave/lane assignment, encoding, and response
@@ -2684,6 +2774,8 @@ class MeshEngine(EngineBase):
         locally-owned lanes go columnar while the rest forward), with
         `hashes` = (hi, lo, grp) precomputed over the FULL batch so key
         bytes need no re-slicing. Results align with `select`'s order.
+        `call` is the caller's tracing.CallRecord: its sequence number
+        names this flush's stages in the flight recorder and a capture.
         """
         from gubernator_tpu import native as _native
 
@@ -2694,11 +2786,13 @@ class MeshEngine(EngineBase):
         t_start = time.perf_counter()
         if now is None:
             now = self.now_fn()
+        fs = FlushStages(self.metrics, self._flush_seq(), call.seq)
 
         if hashes is None:
-            hi, lo, grp = _native.hash128_batch_raw(
-                cols.key_data.tobytes(), cols.key_offsets, cfg.num_groups
-            )
+            with tracing.stage("flush.hash", fs, fs.ids):
+                hi, lo, grp = _native.hash128_batch_raw(
+                    cols.key_data.tobytes(), cols.key_offsets, cfg.num_groups
+                )
         else:
             hi, lo, grp = hashes
         if self._rtier is not None:
@@ -2707,36 +2801,37 @@ class MeshEngine(EngineBase):
             # (routes_global_internally — the caller does NOT filter
             # GLOBAL out for this engine).
             return self._check_columns_replica_split(
-                cols, now, select, (hi, lo, grp), t_start
+                cols, now, select, (hi, lo, grp), t_start, fs
             )
         # Key strings resolve through the ORIGINAL columns (select drops
         # key_offsets); the store path decodes every key, the store-less
         # path only never-seen ones (record_columnar_keys).
         orig_cols, sel_map = cols, None
-        if select is not None:
-            if len(select) == 0:
-                return None
-            hi, lo, grp = hi[select], lo[select], grp[select]
-            cols = _select_columns(cols, select)
-            sel_map = select
+        if select is not None and len(select) == 0:
+            return None
+        with tracing.stage("flush.waves", fs, fs.ids):
+            if select is not None:
+                hi, lo, grp = hi[select], lo[select], grp[select]
+                cols = _select_columns(cols, select)
+                sel_map = select
+            asm = _assemble_column_waves(
+                cols, hi, lo, grp, now, cfg.batch_size, cfg.max_waves,
+                # Width bucketing uses only ALREADY-WARM shapes (batch_size
+                # always is). With a store, only batch_size-wide store-path
+                # kernels are warmed (warm_store_path); narrower buckets
+                # would cold-compile probe/inject/gather under the lock.
+                width_candidates=self._warm_shapes if store is None else (),
+            )
+        if asm is None:
+            fs.publish()  # the refused attempt's hash and waves
+            return None
         n = cols.n
+        wb, wave, lane, ix, W, B = asm
 
         def key_str(j: int) -> str:
             return orig_cols.key_string(
                 int(sel_map[j]) if sel_map is not None else j
             )
-
-        asm = _assemble_column_waves(
-            cols, hi, lo, grp, now, cfg.batch_size, cfg.max_waves,
-            # Width bucketing uses only ALREADY-WARM shapes (batch_size
-            # always is). With a store, only batch_size-wide store-path
-            # kernels are warmed (warm_store_path); narrower buckets
-            # would cold-compile probe/inject/gather under the lock.
-            width_candidates=self._warm_shapes if store is None else (),
-        )
-        if asm is None:
-            return None
-        wb, wave, lane, ix, W, B = asm
 
         # Store path pre-work (the columnar twin of _process's read-through
         # plumbing): request objects are built LAZILY, only for miss lanes;
@@ -2744,80 +2839,82 @@ class MeshEngine(EngineBase):
         # never-seen keys prefetch OUTSIDE the device lock.
         prefetched: Dict[Tuple[int, int], object] = {}
         strs = None
-        if store is not None:
-            from gubernator_tpu import wire as _wire
+        with tracing.stage("flush.keydict", fs, fs.ids):
+            if store is not None:
+                from gubernator_tpu import wire as _wire
 
-            if sel_map is None:
-                strs = cols.key_strings_all()
-            else:
-                strs = [key_str(j) for j in range(n)]
+                if sel_map is None:
+                    strs = cols.key_strings_all()
+                else:
+                    strs = [key_str(j) for j in range(n)]
 
-            def req_of(j: int) -> RateLimitReq:
-                i = int(sel_map[j]) if sel_map is not None else j
-                return _wire.req_from_columns(orig_cols, i)
+                def req_of(j: int) -> RateLimitReq:
+                    i = int(sel_map[j]) if sel_map is not None else j
+                    return _wire.req_from_columns(orig_cols, i)
 
-            # One-shot tolist conversions: per-item numpy scalar boxing
-            # (int(hi[j]) etc.) dominated this path's host cost.
-            hi_l, lo_l = hi.tolist(), lo.tolist()
-            wave_l, lane_l = wave.tolist(), lane.tolist()
-            keys_l = list(zip(hi_l, lo_l))
-            keep = cfg.keep_key_strings
-            if keep:
-                # Prefetch never-seen keys OUTSIDE the lock (the dict is
-                # a superset of table residency, as in _process). Without
-                # the dictionary there is no never-seen predicate: rely
-                # on the in-lock per-wave probe alone rather than issuing
-                # a blocking store.get for every key of every flush.
-                need = []
-                seen = set()
+                # One-shot tolist conversions: per-item numpy scalar boxing
+                # (int(hi[j]) etc.) dominated this path's host cost.
+                hi_l, lo_l = hi.tolist(), lo.tolist()
+                wave_l, lane_l = wave.tolist(), lane.tolist()
+                keys_l = list(zip(hi_l, lo_l))
+                keep = cfg.keep_key_strings
+                if keep:
+                    # Prefetch never-seen keys OUTSIDE the lock (the dict is
+                    # a superset of table residency, as in _process). Without
+                    # the dictionary there is no never-seen predicate: rely
+                    # on the in-lock per-wave probe alone rather than issuing
+                    # a blocking store.get for every key of every flush.
+                    need = []
+                    seen = set()
+                    with self._keys_lock:
+                        for j, k in enumerate(keys_l):
+                            if k not in self._key_strings and k not in seen:
+                                seen.add(k)
+                                need.append((j, k))
+                        self._key_strings.update(zip(keys_l, strs))
+                    for j, k in need:
+                        try:
+                            snap = store.get(req_of(j))
+                        except Exception:
+                            snap = None  # store outage == cache miss
+                        if snap is not None:
+                            prefetched[k] = snap
+                    self._maybe_prune_key_strings()
+                # item indices per wave (for the lazy lane_req dicts)
+                by_wave = [[] for _ in range(W)]
+                for j, w_ in enumerate(wave_l):
+                    by_wave[w_].append(j)
+            elif cfg.keep_key_strings and cfg.record_columnar_keys:
+                # Store-less columnar edge: keep the key-string dictionary
+                # complete so handover/Loader snapshots are routable
+                # (docs/robustness.md "Rolling restarts & handover" — an
+                # anonymous row cannot be ring-placed at its new owner).
+                # Cost discipline: a bulk (hi, lo) membership probe, and
+                # string decodes ONLY for never-seen keys — steady-state
+                # traffic pays dict lookups, not Python string builds.
+                keys_l = list(zip(hi.tolist(), lo.tolist()))
                 with self._keys_lock:
-                    for j, k in enumerate(keys_l):
-                        if k not in self._key_strings and k not in seen:
-                            seen.add(k)
-                            need.append((j, k))
-                    self._key_strings.update(zip(keys_l, strs))
-                for j, k in need:
-                    try:
-                        snap = store.get(req_of(j))
-                    except Exception:
-                        snap = None  # store outage == cache miss
-                    if snap is not None:
-                        prefetched[k] = snap
-                self._maybe_prune_key_strings()
-            # item indices per wave (for the lazy lane_req dicts)
-            by_wave = [[] for _ in range(W)]
-            for j, w_ in enumerate(wave_l):
-                by_wave[w_].append(j)
-        elif cfg.keep_key_strings and cfg.record_columnar_keys:
-            # Store-less columnar edge: keep the key-string dictionary
-            # complete so handover/Loader snapshots are routable
-            # (docs/robustness.md "Rolling restarts & handover" — an
-            # anonymous row cannot be ring-placed at its new owner).
-            # Cost discipline: a bulk (hi, lo) membership probe, and
-            # string decodes ONLY for never-seen keys — steady-state
-            # traffic pays dict lookups, not Python string builds.
-            keys_l = list(zip(hi.tolist(), lo.tolist()))
-            with self._keys_lock:
-                miss = [
-                    (j, k)
-                    for j, k in enumerate(keys_l)
-                    if k not in self._key_strings
-                ]
-            if miss:
-                decoded = [(k, key_str(j)) for j, k in miss]
-                with self._keys_lock:
-                    self._key_strings.update(decoded)
-                self._maybe_prune_key_strings()
+                    miss = [
+                        (j, k)
+                        for j, k in enumerate(keys_l)
+                        if k not in self._key_strings
+                    ]
+                if miss:
+                    decoded = [(k, key_str(j)) for j, k in miss]
+                    with self._keys_lock:
+                        self._key_strings.update(decoded)
+                    self._maybe_prune_key_strings()
 
-        wave_slices = [jax.tree.map(lambda a, w=w: a[w], wb) for w in range(W)]
-        lane_reqs: List[Dict[int, tuple]] = [{} for _ in range(W)]
-        resolver = None
-        if store is not None:
-            resolver = req_of
-            for w in range(W):
-                lane_reqs[w] = {
-                    lane_l[j]: (j, hi_l[j], lo_l[j]) for j in by_wave[w]
-                }
+        with tracing.stage("flush.waves", fs, fs.ids):
+            wave_slices = [jax.tree.map(lambda a, w=w: a[w], wb) for w in range(W)]
+            lane_reqs: List[Dict[int, tuple]] = [{} for _ in range(W)]
+            resolver = None
+            if store is not None:
+                resolver = req_of
+                for w in range(W):
+                    lane_reqs[w] = {
+                        lane_l[j]: (j, hi_l[j], lo_l[j]) for j in by_wave[w]
+                    }
         _telemetry.set_shape_hint(f"{cfg.layout}:columnar:{W}x{B}")
         t_dev = time.perf_counter()
         with _telemetry.serving_scope(self.metrics), tracing.span(
@@ -2825,58 +2922,70 @@ class MeshEngine(EngineBase):
             layout=cfg.layout,
         ) as fspan:
             outs, _r_outs, wave_rows_host, events = self._execute_waves(
-                wave_slices, lane_reqs, now, prefetched,
+                wave_slices, lane_reqs, now, prefetched, fs,
                 req_resolver=resolver,
             )
 
-            with _transfer.account(self.metrics, "d2h", "serve") as tx:
-                status, r_limit, remaining, reset_time = (
-                    _stack_wave_outputs(outs)
-                )
-                tx.add((status, r_limit, remaining, reset_time))
+            try:
+                with tracing.stage(
+                    "flush.readback", fs, fs.ids
+                ), _transfer.account(self.metrics, "d2h", "serve") as tx:
+                    status, r_limit, remaining, reset_time = (
+                        _stack_wave_outputs(outs)
+                    )
+                    tx.add((status, r_limit, remaining, reset_time))
+            finally:
+                self.metrics.busy_exit()  # entered in _execute_waves
         dev_s = time.perf_counter() - t_dev
         flush_trace_id = tracing.trace_id_of(fspan)
 
-        if store is not None:
-            # Write-behind from the per-wave gathered rows (last-op-wins
-            # per key, request order) + key-dictionary hygiene — same
-            # semantics as the object path's flush.
-            self._store_write_behind_core(
-                list(zip(strs, wave_l, lane_l, hi_l, lo_l)),
-                outs, wave_rows_host,
+        with tracing.stage("flush.post", fs, fs.ids):
+            if store is not None:
+                # Write-behind from the per-wave gathered rows
+                # (last-op-wins per key, request order) + key-dictionary
+                # hygiene — same semantics as the object path's flush.
+                self._store_write_behind_core(
+                    list(zip(strs, wave_l, lane_l, hi_l, lo_l)),
+                    outs, wave_rows_host,
+                )
+                if cfg.keep_key_strings:
+                    self._drop_displaced_strings(events)
+
+            tot_hits, tot_miss, tot_evic, tot_over = _wave_totals(outs)
+            dur = time.perf_counter() - t_start
+            em = self.metrics
+            em.observe(tot_hits, tot_miss, tot_evic, tot_over, W, n, dur)
+            em.observe_flush(
+                "columnar", n, W, dur, dev_s,
+                flush_trace_id if cfg.exemplars else "",
+                collective=self.topo.n_dev > 1,
             )
-            if cfg.keep_key_strings:
-                self._drop_displaced_strings(events)
+            em.observe_stage("assemble", t_dev - t_start)
+            em.observe_stage("device_sync", dev_s)
+            em.recorder.record(
+                path="columnar", layout=cfg.layout, n=n, waves=W, carry=0,
+                widths=[B] * W, dur_us=int(dur * 1e6),
+                dev_us=int(dev_s * 1e6), trace_id=flush_trace_id,
+                ticket=fs.ids["flush"], call=fs.ids["call"],
+                stages_us=fs.us,
+            )
+            st_req = status[ix]
+            if em.hotkeys.k > 0:
+                _note_hotkeys_columnar(em.hotkeys, hi, lo, cols.hits, st_req)
+            with raceguard.racy_read(
+                "_dirty",
+                reason="None-gate only; _note_dirty re-checks under the lock",
+            ):
+                track_dirty = self._dirty is not None
+            if track_dirty:
+                self._note_dirty_columnar(hi, lo, cols.hits)
+            out = (st_req, r_limit[ix], remaining[ix], reset_time[ix])
+        fs.publish()
+        return out
 
-        tot_hits, tot_miss, tot_evic, tot_over = _wave_totals(outs)
-        dur = time.perf_counter() - t_start
-        em = self.metrics
-        em.observe(tot_hits, tot_miss, tot_evic, tot_over, W, n, dur)
-        em.observe_flush(
-            "columnar", n, W, dur, dev_s,
-            flush_trace_id if cfg.exemplars else "",
-            collective=self.topo.n_dev > 1,
-        )
-        em.observe_stage("assemble", t_dev - t_start)
-        em.observe_stage("device_sync", dev_s)
-        em.recorder.record(
-            path="columnar", layout=cfg.layout, n=n, waves=W, carry=0,
-            widths=[B] * W, dur_us=int(dur * 1e6), dev_us=int(dev_s * 1e6),
-            trace_id=flush_trace_id,
-        )
-        st_req = status[ix]
-        if em.hotkeys.k > 0:
-            _note_hotkeys_columnar(em.hotkeys, hi, lo, cols.hits, st_req)
-        with raceguard.racy_read(
-            "_dirty",
-            reason="None-gate only; _note_dirty re-checks under the lock",
-        ):
-            track_dirty = self._dirty is not None
-        if track_dirty:
-            self._note_dirty_columnar(hi, lo, cols.hits)
-        return (st_req, r_limit[ix], remaining[ix], reset_time[ix])
-
-    def _check_columns_replica_split(self, cols, now, select, hashes, t_start):
+    def _check_columns_replica_split(
+        self, cols, now, select, hashes, t_start, fs
+    ):
         """Columnar serving for replica topologies — the multi-chip
         daemon's fast edge. Non-GLOBAL items feed the owner-sharded SPMD
         decide (shared wave assembler, one collective call per wave);
@@ -2889,12 +2998,95 @@ class MeshEngine(EngineBase):
         cfg = self.cfg
         rt = self._rtier
         hi, lo, grp = hashes
+        if select is not None and len(select) == 0:
+            return None
+        with tracing.stage("flush.waves", fs, fs.ids):
+            asm = self._assemble_replica_split(cols, now, select, hi, lo, grp)
+        if asm is None:
+            fs.publish()
+            return None
+        (cols, hi, lo, s_asm, r_asm, ng_idx, g_idx, wave_slices, r_slices,
+         r_homes) = asm
+        n = cols.n
+
+        _telemetry.set_shape_hint(
+            f"{cfg.layout}:mesh-columnar:B{cfg.batch_size}"
+        )
+        t_dev = time.perf_counter()
+        with _telemetry.serving_scope(self.metrics), tracing.span(
+            "engine.flush", level="DEBUG", path="columnar", items=n,
+            layout=cfg.layout,
+        ) as fspan:
+            # _execute_waves supplies the lock, the collective guard,
+            # page residency (paged mesh), and unified recovery.
+            s_outs, r_outs, _rows, _events = self._execute_waves(
+                wave_slices, [{} for _ in wave_slices], now, {}, fs,
+                r_waves=r_slices, r_homes=r_homes,
+            )
+
+        status = np.zeros(n, np.int64)
+        r_limit = np.zeros(n, np.int64)
+        remaining = np.zeros(n, np.int64)
+        reset_time = np.zeros(n, np.int64)
+        waves_total = 0
+        tots = [0, 0, 0, 0]
+        try:
+            with tracing.stage(
+                "flush.readback", fs, fs.ids
+            ), _transfer.account(self.metrics, "d2h", "serve") as tx:
+                for outs, asm, idx in (
+                    (s_outs, s_asm, ng_idx), (r_outs, r_asm, g_idx),
+                ):
+                    if asm is None:
+                        continue
+                    st, li, re, rst = _stack_wave_outputs(outs)
+                    tx.add((st, li, re, rst))
+                    ix = asm[3]
+                    status[idx] = st[ix]
+                    r_limit[idx] = li[ix]
+                    remaining[idx] = re[ix]
+                    reset_time[idx] = rst[ix]
+                    waves_total += asm[4]
+                    for j, v in enumerate(_wave_totals(outs)):
+                        tots[j] += v
+        finally:
+            self.metrics.busy_exit()  # entered in _execute_waves
+        dev_s = time.perf_counter() - t_dev
+        with tracing.stage("flush.post", fs, fs.ids):
+            dur = time.perf_counter() - t_start
+            flush_trace_id = tracing.trace_id_of(fspan)
+            em = self.metrics
+            em.observe(
+                tots[0], tots[1], tots[2], tots[3], waves_total, n, dur
+            )
+            em.observe_flush(
+                "columnar", n, waves_total, dur, dev_s,
+                flush_trace_id if cfg.exemplars else "",
+                collective=self.topo.n_dev > 1,
+            )
+            em.observe_stage("assemble", t_dev - t_start)
+            em.observe_stage("device_sync", dev_s)
+            em.recorder.record(
+                path="columnar", layout=cfg.layout, n=n, waves=waves_total,
+                carry=0, widths=[cfg.batch_size] * waves_total,
+                dur_us=int(dur * 1e6), dev_us=int(dev_s * 1e6),
+                trace_id=flush_trace_id, ticket=fs.ids["flush"],
+                call=fs.ids["call"], stages_us=fs.us,
+            )
+            if em.hotkeys.k > 0:
+                _note_hotkeys_columnar(em.hotkeys, hi, lo, cols.hits, status)
+        fs.publish()
+        return (status, r_limit, remaining, reset_time)
+
+    def _assemble_replica_split(self, cols, now, select, hi, lo, grp):
+        """The host assembly of _check_columns_replica_split: the
+        sharded and the replica waves and their per-wave slices, or None
+        where the batch needs the object path."""
+        cfg = self.cfg
+        rt = self._rtier
         if select is not None:
-            if len(select) == 0:
-                return None
             hi, lo, grp = hi[select], lo[select], grp[select]
             cols = _select_columns(cols, select)
-        n = cols.n
         g_mask = (np.asarray(cols.behavior) & int(Behavior.GLOBAL)) != 0  # guberlint: allow-host-sync -- wire columns are host numpy (wire.parse_requests output), no device readback
         ng_idx = np.nonzero(~g_mask)[0]
         g_idx = np.nonzero(g_mask)[0]
@@ -2952,68 +3144,11 @@ class MeshEngine(EngineBase):
                 for w in range(r_asm[4])
             ]
             r_homes = [homes_wb[w] for w in range(r_asm[4])]
-
-        _telemetry.set_shape_hint(
-            f"{cfg.layout}:mesh-columnar:B{cfg.batch_size}"
-        )
-        t_dev = time.perf_counter()
-        with _telemetry.serving_scope(self.metrics), tracing.span(
-            "engine.flush", level="DEBUG", path="columnar", items=n,
-            layout=cfg.layout,
-        ) as fspan:
-            # _execute_waves supplies the lock, the collective guard,
-            # page residency (paged mesh), and unified recovery.
-            s_outs, r_outs, _rows, _events = self._execute_waves(
-                wave_slices, [{} for _ in wave_slices], now, {},
-                r_waves=r_slices, r_homes=r_homes,
-            )
-
-        status = np.zeros(n, np.int64)
-        r_limit = np.zeros(n, np.int64)
-        remaining = np.zeros(n, np.int64)
-        reset_time = np.zeros(n, np.int64)
-        waves_total = 0
-        tots = [0, 0, 0, 0]
-        with _transfer.account(self.metrics, "d2h", "serve") as tx:
-            for outs, asm, idx in (
-                (s_outs, s_asm, ng_idx), (r_outs, r_asm, g_idx),
-            ):
-                if asm is None:
-                    continue
-                st, li, re, rst = _stack_wave_outputs(outs)
-                tx.add((st, li, re, rst))
-                ix = asm[3]
-                status[idx] = st[ix]
-                r_limit[idx] = li[ix]
-                remaining[idx] = re[ix]
-                reset_time[idx] = rst[ix]
-                waves_total += asm[4]
-                for j, v in enumerate(_wave_totals(outs)):
-                    tots[j] += v
-        dev_s = time.perf_counter() - t_dev
-        dur = time.perf_counter() - t_start
-        flush_trace_id = tracing.trace_id_of(fspan)
-        em = self.metrics
-        em.observe(tots[0], tots[1], tots[2], tots[3], waves_total, n, dur)
-        em.observe_flush(
-            "columnar", n, waves_total, dur, dev_s,
-            flush_trace_id if cfg.exemplars else "",
-            collective=self.topo.n_dev > 1,
-        )
-        em.observe_stage("assemble", t_dev - t_start)
-        em.observe_stage("device_sync", dev_s)
-        em.recorder.record(
-            path="columnar", layout=cfg.layout, n=n, waves=waves_total,
-            carry=0, widths=[cfg.batch_size] * waves_total,
-            dur_us=int(dur * 1e6), dev_us=int(dev_s * 1e6),
-            trace_id=flush_trace_id,
-        )
-        if em.hotkeys.k > 0:
-            _note_hotkeys_columnar(em.hotkeys, hi, lo, cols.hits, status)
-        return (status, r_limit, remaining, reset_time)
+        return (cols, hi, lo, s_asm, r_asm, ng_idx, g_idx, wave_slices,
+                r_slices, r_homes)
 
     def _execute_waves(
-        self, waves, lane_reqs, now, prefetched, req_resolver=None,
+        self, waves, lane_reqs, now, prefetched, fs, req_resolver=None,
         r_waves=(), r_homes=(),
     ):
         """Run decide over scatter-disjoint waves under the device lock,
@@ -3029,6 +3164,13 @@ class MeshEngine(EngineBase):
         per-lane home devices (replica topologies only), decided against
         the replica tier after the sharded waves. Returns
         (outs, r_outs, wave_rows_host, events).
+
+        `fs` (FlushStages) takes the two stages every path shares:
+        `flush.lock_wait` (waiting for the engine lock and the
+        collective guard) and `flush.dispatch` (the launches under the
+        lock). Asking for the lock opens the engine's busy interval;
+        the caller closes it (EngineMetrics.busy_exit) when its readback
+        ends; a failure here closes it before it propagates.
 
         All dispatches run under the topology's collective guard (inside
         the table lock): on a mesh, concurrent multi-device programs
@@ -3057,7 +3199,21 @@ class MeshEngine(EngineBase):
             # BEFORE device dispatch — this is the one choke point both
             # the object and columnar paths flow through.
             self._note_shard_decisions(waves)
+        # Under the lock every microsecond is serial for all callers, so
+        # it holds the stages' two clock reads and nothing else of them:
+        # the intervals go to `fs` after the release. Only a capture or
+        # a DEBUG SDK (`live`, None otherwise) opens spans in there.
+        n_dispatch = len(waves) + len(r_waves)
+        self.metrics.busy_enter()
+        live = tracing.open_live("flush.lock_wait", fs.ids)
+        t_wait = time.perf_counter_ns()
         with self._lock, self.topo.dispatch_guard():
+            t_in = time.perf_counter_ns()
+            if live is not None:
+                tracing.close_live(live)
+                live = tracing.open_live(
+                    "flush.dispatch", dict(fs.ids, waves=n_dispatch)
+                )
             table = self.table
             rstate = rt.state if rt is not None else None
             try:
@@ -3105,6 +3261,9 @@ class MeshEngine(EngineBase):
                 if rt is not None:
                     rt.state = rstate
             except Exception as e:
+                self.metrics.busy_exit()  # no readback will follow
+                if live is not None:
+                    tracing.close_live(live)
                 self.table = table
                 if rt is not None:
                     rt.state = rstate
@@ -3112,6 +3271,11 @@ class MeshEngine(EngineBase):
                 if (outs or r_outs) and not rebuilt:
                     raise TableCommittedError(str(e)) from e
                 raise
+            t_out = time.perf_counter_ns()
+            if live is not None:
+                tracing.close_live(live)
+        fs.add("lock_wait", t_wait, t_in)
+        fs.add("dispatch", t_in, t_out)
         return outs, r_outs, wave_rows_host, events
 
     def _drop_displaced_strings(self, events) -> None:

@@ -37,6 +37,7 @@ import numpy as np
 from gubernator_tpu import wire
 from gubernator_tpu.api.types import Behavior
 from gubernator_tpu.parallel import hash_ring
+from gubernator_tpu.utils import tracing
 
 MAX_BATCH_SIZE = 1000
 
@@ -93,8 +94,32 @@ def enabled(svc) -> bool:
     )
 
 
-def try_serve(svc, data: bytes, peer_call: bool):
-    """Serve one call's raw request bytes columnar-fast.
+class _Phases:
+    """try_serve's stages of the call's timeline, one open at a time:
+    call.parse -> call.engine -> call.build (docs/monitoring.md
+    "Tracing the pipeline")."""
+
+    __slots__ = ("call", "open")
+
+    def __init__(self, call):
+        self.call = call
+        self.open = None
+
+    def enter(self, name: str) -> None:
+        self.close()
+        self.open = tracing.stage(name, self.call, self.call.ids)
+        self.open.__enter__()
+
+    def close(self) -> None:
+        if self.open is not None:
+            self.open.__exit__(None, None, None)
+            self.open = None
+
+
+def try_serve(svc, data: bytes, peer_call: bool, call=tracing.NO_CALL):
+    """Serve one call's raw request bytes columnar-fast, on a serving
+    executor thread. `call` (tracing.CallRecord) takes the stages and,
+    where the call leaves the columnar path, the reason.
 
     Returns:
     - bytes — the complete response (all items served columnar);
@@ -116,10 +141,32 @@ def try_serve(svc, data: bytes, peer_call: bool):
     the flag unstripped and decide through their replica tier; items
     carrying trace metadata keep the object path.
     """
+    call.mark("executor_wait")
+    # Until _try_serve says which path serves the call: it raised.
+    call.served("object", "error")
+    phases = _Phases(call)
+    # The request span's context does not follow the call onto this
+    # thread by itself (run_in_executor copies no context).
+    ctx = tracing.attached(call.otel_ctx) if call.otel_ctx else None
+    if ctx is not None:
+        ctx.__enter__()
+    phases.enter("call.parse")
+    try:
+        return _try_serve(svc, data, peer_call, call, phases)
+    finally:
+        phases.close()
+        if ctx is not None:
+            ctx.__exit__(None, None, None)
+
+
+def _try_serve(svc, data: bytes, peer_call: bool, call, phases):
+    """try_serve's body; `phases` moves the call's timeline on."""
     cols = wire.parse_requests(data)
     if cols is None or cols.n == 0 or cols.n > MAX_BATCH_SIZE:
+        call.served("object", "error")
         return None
     if cols.slow.any():
+        call.served("object", "slow_item")
         return None
     # DURATION_IS_GREGORIAN needs host-side calendar math the columnar
     # decide doesn't carry — but those ITEMS ride the mixed return's
@@ -129,6 +176,7 @@ def try_serve(svc, data: bytes, peer_call: bool):
     greg = (cols.behavior & _SLOW_BEHAVIOR) != 0
     has_greg = bool(greg.any())
     if has_greg and (peer_call or bool(greg.all())):
+        call.served("object", "gregorian")
         return None
     if not peer_call and getattr(svc, "force_global", False):
         # GUBER_FORCE_GLOBAL: every V1 item becomes GLOBAL (the same OR
@@ -152,8 +200,10 @@ def try_serve(svc, data: bytes, peer_call: bool):
     if np.any(cols.name_lens == 0) or np.any(
         key_lens - cols.name_lens - 1 == 0
     ):
+        call.served("object", "slow_item")
         return None
     local = None
+    forwards = False  # some items go to the peer that owns them
     g_owned = g_mask  # standalone daemon: owner of everything
     owner_addrs = None
     ring_mask = None
@@ -162,6 +212,7 @@ def try_serve(svc, data: bytes, peer_call: bool):
         if picker is not None and picker.peers():
             variant = _RING_VARIANT.get(getattr(picker, "hash_fn", None))
             if variant is None:
+                call.served("object", "ring")
                 return None
             ring_h = wire.fnv1_batch(cols.key_data, cols.key_offsets, variant)
             mask = np.asarray(picker.local_mask(ring_h), dtype=bool)
@@ -171,6 +222,7 @@ def try_serve(svc, data: bytes, peer_call: bool):
                 # owned or not (reference gubernator.go:395-421); only
                 # non-GLOBAL peer-owned items forward.
                 if not hasattr(picker, "owner_spans"):
+                    call.served("object", "ring")
                     return None
                 g_owned = g_mask & mask
                 owner_addrs = (picker, ring_h)  # spans built post-decide
@@ -179,6 +231,7 @@ def try_serve(svc, data: bytes, peer_call: bool):
                 serve = mask
             if not serve.all():
                 local = serve
+                forwards = True
     if has_greg:
         # Gregorian lanes leave the columnar set and come back spliced
         # through merge_mixed, decided by the object path.
@@ -308,15 +361,20 @@ def try_serve(svc, data: bytes, peer_call: bool):
         # a failure AFTER waves committed to a surviving table raises
         # TableCommittedError, which must propagate (a silent fallback
         # would re-apply every committed hit).
+        phases.enter("call.engine")
         try:
-            out = svc.engine.check_columns(cols, now=now)
+            out = svc.engine.check_columns(cols, now=now, call=call)
         except _committed_error():
             raise
         # guberlint: allow-swallow -- fallback to the object path IS the handling (byte-equivalence fuzzed); TableCommittedError re-raised above
         except Exception:
+            call.served("object", "error")
             return None
         if out is None:
+            call.served("object", "waves")
             return None
+        phases.enter("call.build")
+        call.served("columnar")
         count_metrics(np.ones(cols.n, dtype=bool))
         record_provenance(out, np.arange(cols.n))
         if has_global or mr_queue:
@@ -328,7 +386,9 @@ def try_serve(svc, data: bytes, peer_call: bool):
             return wire.build_responses_md(*out, odata, ooffs)
         return wire.build_responses(*out)
     if not local.any():
-        return None  # nothing local to decide: pure forwarding batch
+        # nothing local to decide: pure forwarding batch
+        call.served("object", "forward_only")
+        return None
     # Mixed ownership: decide the local subset columnar now (with the
     # identity hashes computed once over the full batch); hand the
     # peer-owned subset back as objects for the forwarding path. The
@@ -343,17 +403,24 @@ def try_serve(svc, data: bytes, peer_call: bool):
         cols.key_data.tobytes(), cols.key_offsets,
         svc.engine.cfg.num_groups,
     )
+    phases.enter("call.engine")
     try:
         out = svc.engine.check_columns(
-            cols, now=now, select=local_pos, hashes=hashes
+            cols, now=now, select=local_pos, hashes=hashes, call=call
         )
     except _committed_error():
         raise
     # guberlint: allow-swallow -- fallback to the object path IS the handling (byte-equivalence fuzzed); TableCommittedError re-raised above
     except Exception:
+        call.served("object", "error")
         return None
     if out is None:
+        call.served("object", "waves")
         return None
+    phases.enter("call.build")
+    # Mixed: some items forward to their owner ("ring"), or Gregorian
+    # lanes splice through the object path.
+    call.served("mixed", "ring" if forwards else "gregorian")
     count_metrics(local)
     record_provenance(out, local_pos)
     md = None

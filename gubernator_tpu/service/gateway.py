@@ -14,7 +14,9 @@ Device-tier debug surface (docs/monitoring.md; no reference analog):
 - GET /debug/profile?seconds=N — on-demand jax.profiler capture to a
   temp dir (one capture at a time process-wide; 503 when busy or when
   the profiler is unavailable). Works on CPU too — the XLA profiler is
-  backend-agnostic.
+  backend-agnostic. The capture holds the program's own rpc.*, call.*
+  and flush.* spans in plane /host:CPU; `python=1` adds Python frames
+  (and slows the server it traces).
 - GET /debug/slo — the SLO observatory: per-SLO multi-window burn
   rates, alert states, remaining error budgets, and the self-watchdog's
   per-loop heartbeat table (docs/monitoring.md "SLOs & burn rates").
@@ -27,6 +29,7 @@ without client certs.
 from __future__ import annotations
 
 import asyncio
+import functools
 import json
 
 from aiohttp import web
@@ -69,9 +72,12 @@ def add_debug_routes(app: web.Application, svc: V1Service) -> None:
                 status=503,
                 headers={"Retry-After": str(int(seconds) or 1)},
             )
+        python = request.query.get("python", "0") in ("1", "true")
         try:
             out = await asyncio.get_running_loop().run_in_executor(
-                None, _profiler.capture, seconds
+                None, functools.partial(
+                    _profiler.capture, seconds, python=python
+                )
             )
         except Exception as e:
             return web.json_response(

@@ -10,6 +10,7 @@ import grpc
 
 from gubernator_tpu.service import pb
 from gubernator_tpu.service.server import ApiError, V1Service
+from gubernator_tpu.utils import tracing
 
 _GRPC_CODES = {
     "OUT_OF_RANGE": grpc.StatusCode.OUT_OF_RANGE,
@@ -19,30 +20,58 @@ _GRPC_CODES = {
 
 
 @contextlib.asynccontextmanager
-async def _instrumented(metrics, method: str):
+async def _instrumented(metrics, method: str, call=None):
     """Per-RPC duration + success/failed counters (the reference's
     GRPCStatsHandler role, grpc_stats.go:41-131). Counts every outcome:
-    any exception — ApiError-driven aborts included — is 'failed'."""
-    t0 = time.perf_counter()
+    any exception — ApiError-driven aborts included — is 'failed'.
+
+    `call` (a tracing.CallRecord, GetRateLimits / GetPeerRateLimits) is
+    the call's timeline: its root runs from the clock read that opens
+    the duration to the one that closes it, so its stages add up to
+    the duration observed here; the request span `rpc.<Method>` (INFO)
+    covers the columnar and the object path alike."""
+    t0 = time.perf_counter_ns()
+    root = None
+    if call is not None:
+        call.begin(t0)
+        tracing.rpc_mark("rpc.begin", call.ids)
+        root = tracing.start_span(
+            "rpc." + method.rpartition("/")[2], level="INFO", call=call.seq
+        )
+        call.otel_ctx = tracing.context_of(root)
+    err = None
     try:
-        yield
+        if root is None:  # no SDK: nothing to make current
+            yield
+        else:
+            with tracing.use_span_ctx(root):
+                yield
         metrics.grpc_request_counts.labels(method, "success").inc()
-    except BaseException:
+    except BaseException as e:
+        err = e
         metrics.grpc_request_counts.labels(method, "failed").inc()
         raise
     finally:
-        metrics.grpc_request_duration.labels(method).observe(time.perf_counter() - t0)
+        t1 = time.perf_counter_ns()
+        metrics.grpc_request_duration.labels(method).observe((t1 - t0) * 1e-9)
+        if call is not None:
+            metrics.edge_calls.labels(call.finish(t1), call.reason).inc()
+            tracing.rpc_mark("rpc.end", call.ids)
+            tracing.end_span(root, error=err)
 
 
 async def _abort(context, e: ApiError):
     await context.abort(_GRPC_CODES.get(e.grpc_code, grpc.StatusCode.INTERNAL), str(e))
 
 
-async def serve_get_rate_limits_bytes(svc: V1Service, request_bytes) -> bytes:
+async def serve_get_rate_limits_bytes(
+    svc: V1Service, request_bytes, call=tracing.NO_CALL
+) -> bytes:
     """The V1/GetRateLimits serving core over raw wire bytes, shared by
     the gRPC servicer and the edge-tier listener (service/edge.py) so
     both transports have identical semantics. Raises ApiError for
-    whole-call failures (the caller maps it to its transport's status)."""
+    whole-call failures (the caller maps it to its transport's status).
+    `call` is the gRPC handler's timeline (tracing.CallRecord)."""
     from gubernator_tpu.service import fastpath
 
     if fastpath.enabled(svc):
@@ -50,11 +79,13 @@ async def serve_get_rate_limits_bytes(svc: V1Service, request_bytes) -> bytes:
         # kernel runs (the C parse and the jitted decide release
         # the GIL, so calls genuinely overlap).
         res = await asyncio.get_running_loop().run_in_executor(
-            None, fastpath.try_serve, svc, request_bytes, False
+            None, fastpath.try_serve, svc, request_bytes, False, call
         )
         if isinstance(res, bytes):
+            call.mark("loop_return")
             return res
         if res is not None:  # mixed ownership: forward the rest
+            call.mark("loop_return")
             _, n, local_pos, local_out, nl_reqs, md = res
             # Local hits are already committed — a forwarding
             # failure must degrade the REMOTE items to per-item
@@ -63,20 +94,26 @@ async def serve_get_rate_limits_bytes(svc: V1Service, request_bytes) -> bytes:
             from gubernator_tpu.api.types import RateLimitResp
 
             try:
-                nl_resps = await svc.get_rate_limits(nl_reqs)
+                nl_resps = await svc.get_rate_limits(nl_reqs, call=call)
             except Exception as e:
                 nl_resps = [RateLimitResp(error=str(e)) for _ in nl_reqs]
-            return fastpath.merge_mixed(n, local_pos, local_out, nl_resps, md)
-    try:
-        request = pb.pb.GetRateLimitsReq.FromString(request_bytes)
-    except Exception:
-        raise ApiError("malformed request", grpc_code="INVALID_ARGUMENT")
-    reqs = [pb.req_from_pb(r) for r in request.requests]
-    out = await svc.get_rate_limits(reqs)
-    resp = pb.pb.GetRateLimitsResp()
-    for r in out:
-        resp.responses.append(pb.resp_to_pb(r))
-    return resp.SerializeToString()
+            with tracing.stage("call.build", call, call.ids):
+                return fastpath.merge_mixed(
+                    n, local_pos, local_out, nl_resps, md
+                )
+        call.attempt_refused()
+    with tracing.stage("call.pb_decode", call, call.ids):
+        try:
+            request = pb.pb.GetRateLimitsReq.FromString(request_bytes)
+        except Exception:
+            raise ApiError("malformed request", grpc_code="INVALID_ARGUMENT")
+        reqs = [pb.req_from_pb(r) for r in request.requests]
+    out = await svc.get_rate_limits(reqs, call=call)
+    with tracing.stage("call.pb_encode", call, call.ids):
+        resp = pb.pb.GetRateLimitsResp()
+        for r in out:
+            resp.responses.append(pb.resp_to_pb(r))
+        return resp.SerializeToString()
 
 
 async def serve_lease_bytes(svc: V1Service, request_bytes, context) -> bytes:
@@ -115,9 +152,13 @@ class V1Servicer:
         self.svc = svc
 
     async def GetRateLimits(self, request_bytes, context):
-        async with _instrumented(self.svc.metrics, "/pb.gubernator.V1/GetRateLimits"):
+        m = self.svc.metrics
+        call = tracing.CallRecord(m.call_stages)
+        async with _instrumented(m, "/pb.gubernator.V1/GetRateLimits", call):
             try:
-                return await serve_get_rate_limits_bytes(self.svc, request_bytes)
+                return await serve_get_rate_limits_bytes(
+                    self.svc, request_bytes, call
+                )
             except ApiError as e:
                 await _abort(context, e)
 
@@ -142,8 +183,10 @@ class PeersV1Servicer:
         self._fast = fastpath
 
     async def GetPeerRateLimits(self, request_bytes, context):
+        m = self.svc.metrics
+        call = tracing.CallRecord(m.call_stages, kind="peer_")
         async with _instrumented(
-            self.svc.metrics, "/pb.gubernator.PeersV1/GetPeerRateLimits"
+            m, "/pb.gubernator.PeersV1/GetPeerRateLimits", call
         ):
             # Forwarded batches are owned by construction — the owner-side
             # hot path (SURVEY.md §3.2) skips the ring check. The response
@@ -152,27 +195,37 @@ class PeersV1Servicer:
             # serves both.
             if self._fast.enabled(self.svc):
                 raw = await asyncio.get_running_loop().run_in_executor(
-                    None, self._fast.try_serve, self.svc, request_bytes, True
+                    None, self._fast.try_serve, self.svc, request_bytes,
+                    True, call,
                 )
                 if isinstance(raw, bytes):  # peer calls are never "mixed"
+                    call.mark("loop_return")
                     return raw
-            try:
-                request = pb.peers_pb.GetPeerRateLimitsReq.FromString(
-                    request_bytes
-                )
-            except Exception:
+                call.attempt_refused()
+            reqs = None
+            with tracing.stage("call.pb_decode", call, call.ids):
+                try:
+                    request = pb.peers_pb.GetPeerRateLimitsReq.FromString(
+                        request_bytes
+                    )
+                # guberlint: allow-swallow -- not swallowed: reqs stays None and the call is aborted INVALID_ARGUMENT right below, outside the stage (a stage's body holds no await)
+                except Exception:
+                    pass
+                else:
+                    reqs = [pb.req_from_pb(r) for r in request.requests]
+            if reqs is None:
                 await context.abort(
                     grpc.StatusCode.INVALID_ARGUMENT, "malformed request"
                 )
-            reqs = [pb.req_from_pb(r) for r in request.requests]
             try:
-                out = await self.svc.get_peer_rate_limits(reqs)
+                out = await self.svc.get_peer_rate_limits(reqs, call=call)
             except ApiError as e:
                 await _abort(context, e)
-            resp = pb.peers_pb.GetPeerRateLimitsResp()
-            for r in out:
-                resp.rate_limits.append(pb.resp_to_pb(r))
-            return resp.SerializeToString()
+            with tracing.stage("call.pb_encode", call, call.ids):
+                resp = pb.peers_pb.GetPeerRateLimitsResp()
+                for r in out:
+                    resp.rate_limits.append(pb.resp_to_pb(r))
+                return resp.SerializeToString()
 
     async def UpdatePeerGlobals(self, request, context):
         async with _instrumented(

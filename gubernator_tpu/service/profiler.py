@@ -32,6 +32,7 @@ import threading
 import time
 
 from gubernator_tpu.utils import lockorder
+from gubernator_tpu.utils import tracing
 
 log = logging.getLogger("gubernator_tpu.profiler")
 
@@ -78,12 +79,22 @@ def rotate(keep: int, root: str | None = None) -> int:
 
 
 def capture(
-    seconds: float, keep: int = DEFAULT_KEEP, root: str | None = None
+    seconds: float, keep: int = DEFAULT_KEEP, root: str | None = None,
+    python: bool = False,
 ) -> dict:
     """Blocking profiler capture (callers run it in an executor or the
     sampler thread) into a fresh dir under the rotating parent.
-    Caller must hold PROFILE_GUARD."""
+    Caller must hold PROFILE_GUARD.
+
+    The Python tracer is off unless `python`: the profiler's default
+    hooks every Python call on every thread, and this server's host path
+    is Python, so the default capture slows what it measures. The host
+    tracer stays on: tracing.stage()'s spans and the runtime's own
+    events land in plane /host:CPU either way."""
     import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 1 if python else 0
 
     root = root or trace_root()
     os.makedirs(root, exist_ok=True)
@@ -91,11 +102,18 @@ def capture(
     # handle the rotation would then have to special-case.
     trace_dir = os.path.join(root, f"capture-{time.time_ns():020d}")
     os.makedirs(trace_dir, exist_ok=True)
-    jax.profiler.start_trace(trace_dir)
+    t0 = time.perf_counter()
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    # From here tracing.stage() puts its spans on this timeline.
+    tracing.set_capturing(True)
+    t_started = time.perf_counter()
     try:
         time.sleep(seconds)
     finally:
+        tracing.set_capturing(False)
+        t_stop = time.perf_counter()
         jax.profiler.stop_trace()
+    t_stopped = time.perf_counter()
     files, nbytes = _dir_stats(trace_dir)
     rotated = rotate(keep, root)
     return {
@@ -103,6 +121,9 @@ def capture(
         "seconds": seconds,
         "files": files,
         "bytes": nbytes,
+        "python": python,
+        "start_s": t_started - t0,
+        "stop_s": t_stopped - t_stop,
         "rotated_out": rotated,
         "keep": keep,
     }

@@ -136,7 +136,12 @@ class V1Service:
 
     # ---- V1.GetRateLimits (reference gubernator.go:183-309) ----------------
 
-    async def get_rate_limits(self, reqs: Sequence[RateLimitReq]) -> List[RateLimitResp]:
+    async def get_rate_limits(
+        self, reqs: Sequence[RateLimitReq], call=tracing.NO_CALL
+    ) -> List[RateLimitResp]:
+        """`call` (tracing.CallRecord, gRPC handlers only) takes this
+        path's two stages: `route` is everything here but the awaits on
+        engine futures, which are `engine_wait`."""
         m = self.metrics
         if len(reqs) > MAX_BATCH_SIZE:
             m.check_error_counter.labels("Request too large").inc()
@@ -154,14 +159,17 @@ class V1Service:
             with tracing.span(
                 "V1Instance.GetRateLimits", level="INFO", items=len(reqs)
             ):
-                return await self._get_rate_limits(reqs)
+                return await self._get_rate_limits(reqs, call)
         finally:
+            call.mark("route")
             m.concurrent_checks.dec()
             m.func_duration.labels("V1Instance.GetRateLimits").observe(
                 time.perf_counter() - t0
             )
 
-    async def _get_rate_limits(self, reqs: Sequence[RateLimitReq]) -> List[RateLimitResp]:
+    async def _get_rate_limits(
+        self, reqs: Sequence[RateLimitReq], call
+    ) -> List[RateLimitResp]:
         m = self.metrics
         now = self.now_fn()
         n = len(reqs)
@@ -223,16 +231,20 @@ class V1Service:
                 if strip:
                     r2.behavior &= ~Behavior.GLOBAL
                 bulk_reqs.append(r2)
-            global_fut = self.engine.check_bulk(bulk_reqs)
+            global_fut = self.engine.check_bulk(bulk_reqs, call=call.seq)
 
         local_fut = None
         if local_items:
-            local_fut = self.engine.check_bulk([r for _, r in local_items])
+            local_fut = self.engine.check_bulk(
+                [r for _, r in local_items], call=call.seq
+            )
 
         stage_md = bool(getattr(self.engine.cfg, "stage_metadata", False))
         if global_fut is not None:
             try:
+                call.mark("route")
                 results = await asyncio.wrap_future(global_fut)
+                call.mark("engine_wait")
                 for (i, req, owner), resp in zip(global_items, results):
                     if self.global_mgr is not None:
                         self.global_mgr.queue_hit(req)
@@ -263,7 +275,9 @@ class V1Service:
 
         if local_fut is not None:
             try:
+                call.mark("route")
                 results = await asyncio.wrap_future(local_fut)
+                call.mark("engine_wait")
                 for (i, req), resp in zip(local_items, results):
                     responses[i] = resp
                     if resp.error:
@@ -471,7 +485,16 @@ class V1Service:
     # ---- PeersV1.GetPeerRateLimits (reference gubernator.go:462-539) -------
 
     async def get_peer_rate_limits(
-        self, reqs: Sequence[RateLimitReq]
+        self, reqs: Sequence[RateLimitReq], call=tracing.NO_CALL
+    ) -> List[RateLimitResp]:
+        """`call`: as in get_rate_limits."""
+        try:
+            return await self._get_peer_rate_limits(reqs, call)
+        finally:
+            call.mark("route")
+
+    async def _get_peer_rate_limits(
+        self, reqs: Sequence[RateLimitReq], call
     ) -> List[RateLimitResp]:
         if len(reqs) > MAX_BATCH_SIZE:
             self.metrics.check_error_counter.labels("Request too large").inc()
@@ -505,7 +528,11 @@ class V1Service:
                 req.created_at = self.now_fn()
         t_apply = time.perf_counter()
         try:
-            results = await asyncio.wrap_future(self.engine.check_bulk(list(reqs)))
+            call.mark("route")
+            results = await asyncio.wrap_future(
+                self.engine.check_bulk(list(reqs), call=call.seq)
+            )
+            call.mark("engine_wait")
         except Exception as e:
             return [RateLimitResp(error=str(e)) for _ in reqs]
         if has_global:

@@ -11,11 +11,17 @@ model here:
   configure an exporter, docs/tracing.md:10-41).
 - propagate_inject/extract move W3C traceparent through the request's
   metadata dict, so spans stitch across the peer-forwarding hop.
+- stage() times one layer boundary of a call for three readers at once:
+  a /metrics histogram (always), the profiler's own timeline while a
+  capture runs (set_capturing), and an OTel child span at DEBUG
+  (docs/monitoring.md "Tracing the pipeline").
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
+import time
 from typing import Dict, Optional
 
 try:
@@ -236,3 +242,220 @@ def attached(ctx):
         yield
     finally:
         _otel_context.detach(token)
+
+
+# ---------------------------------------------------------------------------
+# Stage timing: one site, three readers (docs/monitoring.md "Tracing the
+# pipeline"). A stage's sink decides which /metrics series the interval
+# lands in: EngineMetrics' FlushStages observes per flush as it goes, a
+# CallRecord holds a call's stages until its handler knows which path
+# served it.
+
+_CALL_SEQ = itertools.count(1)
+
+# True between the profiler's start_trace and stop_trace
+# (service/profiler.capture sets it; one capture runs at a time).
+_capturing = False
+
+
+def set_capturing(on: bool) -> None:
+    global _capturing
+    _capturing = on
+
+
+def capturing() -> bool:
+    return _capturing
+
+
+def _annotation(name: str, attrs: dict):
+    """A jax.profiler.TraceAnnotation: a span in plane /host:CPU of the
+    running capture, on the device planes' clock and on the line of the
+    thread that opens it. Built only while a capture runs."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **attrs)
+
+
+def _debug_spans() -> bool:
+    return _OTEL and _LEVEL >= 2
+
+
+def open_live(name: str, attrs: dict):
+    """Open the two readers of a stage that are there only sometimes:
+    the capture's span and the SDK's DEBUG span. None, the usual case,
+    when neither is on; else what close_live() takes."""
+    if not _capturing and not _debug_spans():
+        return None
+    live = []
+    if _capturing:
+        live.append(_annotation(name, attrs))
+    if _debug_spans():
+        live.append(span(name, level="DEBUG", **attrs))
+    for cm in live:
+        cm.__enter__()
+    return live
+
+
+def close_live(live, *exc) -> None:
+    for cm in reversed(live):
+        cm.__exit__(*(exc or (None, None, None)))
+
+
+class stage:
+    """`with stage("flush.hash", sink, ids):` times the body on the wall
+    clock and hands the interval to `sink.add` under the name's last
+    part. The body must stay on one thread and hold no `await`: the
+    profiler nests spans per thread. A site that cannot afford the
+    object (under the engine lock) takes the clock marks itself and
+    uses open_live/close_live."""
+
+    __slots__ = ("name", "sink", "attrs", "_t0", "_live")
+
+    def __init__(self, name: str, sink, attrs: Optional[dict] = None):
+        self.name = name
+        self.sink = sink
+        self.attrs = attrs or {}
+
+    def __enter__(self):
+        self._live = open_live(self.name, self.attrs)
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
+        if self._live is not None:
+            close_live(self._live, *exc)
+        self.sink.add(self.name.rpartition(".")[2], self._t0, t1)
+        return False
+
+
+def _interval_span(name: str, t0_ns: int, t1_ns: int, ctx, attrs: dict):
+    """An OTel span for an interval that is already over (a wait between
+    threads has no body to wrap), placed by the exporter's clock."""
+    off = time.time_ns() - time.perf_counter_ns()
+    try:
+        s = _TRACER.start_span(name, context=ctx, start_time=t0_ns + off)
+        if s.is_recording():
+            for k, v in attrs.items():
+                s.set_attribute(k, v)
+        s.end(end_time=t1_ns + off)
+    except Exception:
+        pass
+
+
+class CallRecord:
+    """One RPC's timeline: made at handler entry, handed to the fast
+    edge, the service and the engine, observed at handler exit when the
+    path that served the call is known. Its stages partition the
+    handler's time: each ends where the next begins (`add` and `mark`
+    both close the interval since the previous boundary), and the
+    return to the handler's exit belongs to the last one.
+
+    `sink` maps (path, stage) to a histogram child; `kind` prefixes the
+    path label (PeersV1 calls)."""
+
+    __slots__ = (
+        "seq", "ids", "kind", "path", "reason", "otel_ctx", "t0", "cursor",
+        "_sink", "_stages", "_last",
+    )
+
+    def __init__(self, sink, kind: str = ""):
+        self.seq = next(_CALL_SEQ)
+        self.ids = {"call": self.seq}
+        self.kind = kind
+        # Until try_serve says otherwise: the fast edge is off
+        # (fastpath.enabled), and the object path serves the call.
+        self.path = "object"
+        self.reason = "disabled"
+        self.otel_ctx = None
+        self.t0 = self.cursor = 0
+        self._sink = sink
+        self._stages: Dict[str, int] = {}
+        self._last = ""
+
+    def begin(self, t_ns: int) -> None:
+        self.t0 = self.cursor = t_ns
+
+    def attempt_refused(self) -> None:
+        """The columnar attempt was refused (`served` says why): to the
+        object path that now serves the call the attempt is one stage,
+        from the handler's entry, so the stages so far are forgotten."""
+        self._stages.clear()
+        self.cursor = self.t0
+        self.mark("columnar_attempt")
+
+    def add(self, label: str, t0_ns: int, t1_ns: int) -> None:
+        """Close the stage `label` at t1_ns. It runs from the previous
+        boundary, not from t0_ns: the clock reads between two stages
+        belong to the later one, so the stages add up to the handler's
+        time exactly."""
+        self._stages[label] = (
+            self._stages.get(label, 0) + t1_ns - self.cursor
+        )
+        self.cursor = t1_ns
+        self._last = label
+
+    def mark(self, label: str) -> None:
+        """Close a stage that is a wait between threads or spans an
+        `await`: histogram and OTel span, no profiler span (the gap
+        between two spans with this call's id is the wait)."""
+        t1 = time.perf_counter_ns()
+        if _debug_spans():
+            _interval_span(
+                f"call.{label}", self.cursor, t1, self.otel_ctx, self.ids
+            )
+        self.add(label, t1, t1)
+
+    def served(self, path: str, reason: str = "") -> None:
+        self.path, self.reason = path, reason
+
+    def finish(self, t_ns: int) -> str:
+        """Observe the stages under the serving path; returns the path
+        label. The tail (the return to the handler's exit) folds into
+        the last stage."""
+        path = self.kind + self.path
+        if self._last:
+            self._stages[self._last] += t_ns - self.cursor
+            self.cursor = t_ns
+        for label, wall_ns in self._stages.items():
+            child = self._sink.get((path, label))
+            if child is not None:
+                child.observe(wall_ns * 1e-9)
+        return path
+
+    def stages_ns(self) -> Dict[str, int]:
+        return dict(self._stages)
+
+
+class _NoCall:
+    """Stands in where a caller hands no record (tests and tools that
+    call try_serve or the engine directly): same methods, no series."""
+
+    seq = 0
+    ids: dict = {}
+    otel_ctx = None
+
+    def add(self, label, t0_ns, t1_ns) -> None:
+        pass
+
+    def mark(self, label) -> None:
+        pass
+
+    def attempt_refused(self) -> None:
+        pass
+
+    def served(self, path, reason="") -> None:
+        pass
+
+
+NO_CALL = _NoCall()
+
+
+def rpc_mark(name: str, ids: dict) -> None:
+    """`rpc.begin` / `rpc.end`: the root of a call in a capture. The
+    handler is a coroutine, and coroutines interleave on the loop's
+    thread where the profiler nests spans, so the root is two short
+    marks carrying the call's id rather than one span."""
+    if _capturing:
+        with _annotation(name, ids):
+            pass

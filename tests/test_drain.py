@@ -168,10 +168,10 @@ def test_daemon_drain_zero_failed_inflight(daemon, loop_thread):
         started = 0
         orig_serve = grpc_service.serve_get_rate_limits_bytes
 
-        async def counting_serve(svc, data):
+        async def counting_serve(svc, data, *call):
             nonlocal started
             started += 1
-            return await orig_serve(svc, data)
+            return await orig_serve(svc, data, *call)
 
         grpc_service.serve_get_rate_limits_bytes = counting_serve
         try:

@@ -496,3 +496,48 @@ def test_no_sdk_path_attaches_nothing(engine):
     assert not resp.error
     recs = engine.metrics.recorder.snapshot()
     assert recs[-1].get("trace_id") == ""
+
+
+# ---------------------------------------------------------------------------
+# tracing.stage(): the OTel reader of a call's timeline
+
+
+def test_stage_and_mark_open_debug_children_of_the_request_span(spans):
+    rec = tracing.CallRecord({})
+    root = tracing.start_span("rpc.GetRateLimits", level="INFO", call=rec.seq)
+    rec.otel_ctx = tracing.context_of(root)
+    rec.begin(time.perf_counter_ns())
+    done = threading.Event()
+
+    def executor_thread():  # as fastpath.try_serve runs: another thread
+        with tracing.attached(rec.otel_ctx):
+            rec.mark("executor_wait")
+            with tracing.stage("call.parse", rec, rec.ids):
+                with tracing.stage("flush.hash", rec, {"flush": 9, **rec.ids}):
+                    pass
+        done.set()
+
+    threading.Thread(target=executor_thread).start()
+    assert done.wait(10)
+    tracing.end_span(root)
+    got = {s.name: s for s in spans()}
+    assert {"rpc.GetRateLimits", "call.executor_wait", "call.parse",
+            "flush.hash"} <= set(got)
+    root_key = _ctx_key(got["rpc.GetRateLimits"])
+    assert _parent_key(got["call.executor_wait"]) == root_key
+    assert _parent_key(got["call.parse"]) == root_key
+    assert _parent_key(got["flush.hash"]) == _ctx_key(got["call.parse"])
+    assert got["flush.hash"].attributes["flush"] == 9
+    assert got["call.parse"].attributes["call"] == rec.seq
+    assert set(rec.stages_ns()) == {"executor_wait", "parse", "hash"}
+
+
+def test_stage_opens_no_span_at_info(spans):
+    tracing.set_trace_level("INFO")
+    rec = tracing.CallRecord({})
+    rec.begin(time.perf_counter_ns())
+    rec.mark("executor_wait")
+    with tracing.stage("call.parse", rec, rec.ids):
+        pass
+    assert [s.name for s in spans()] == []
+    assert set(rec.stages_ns()) == {"executor_wait", "parse"}

@@ -1,0 +1,198 @@
+#!/usr/bin/env python
+"""Name the device's idle time in a /debug/profile capture.
+
+    JAX_PLATFORMS=cpu python tools/profile_gaps.py <trace dir or .xplane.pb>
+
+A capture holds the device planes and, in plane /host:CPU on the same
+clock, the program's own spans (docs/monitoring.md "Tracing the
+pipeline"): `rpc.begin` / `rpc.end` marks, `call.*` and `flush.*`, each
+carrying the `call` and `flush` ids. Seventeen threads have spans open
+at once, so "which host span covers the gap" has no single answer. The
+rule here:
+
+- a gap on a device plane ends when a device program starts;
+- that program was launched by the flush whose `flush.dispatch` span
+  last began before it;
+- the gap is divided along that flush's own call: the parts of the gap
+  its call spent in `executor_wait` (from `rpc.begin` to the call's
+  first span on another thread), `parse`, `hash`, `waves`, `keydict`,
+  `lock_wait` and `dispatch`, and, before `rpc.begin`, "call not yet in
+  the server";
+- what none of these covers goes to another flush's `readback` or
+  `post` where one was open (a pipelined pump waits for the flush before
+  last to be read before it launches the next), and is "unattributed"
+  otherwise (the runtime's own queue after the launch, a wait no span
+  times, a gap that ends with the capture).
+
+Prints seconds per name and per device plane; the names add up to the
+plane's idle time. Reads the file with jax.profiler.ProfileData on the
+CPU backend and never touches a chip.
+"""
+
+from __future__ import annotations
+
+import bisect
+import glob
+import os
+import sys
+
+MODULES_LINE = "XLA Modules"
+OPS_LINE = "XLA Ops"
+NOT_YET = "call not yet in the server"
+UNATTRIBUTED = "unattributed"
+OTHER = {"flush.readback": "another flush: readback",
+         "flush.post": "another flush: post"}
+# Innermost first: where two of a call's intervals overlap (a pump
+# flush beside its call's own thread) the gap goes to the earlier name.
+STAGES = ("dispatch", "lock_wait", "keydict", "waves", "hash", "parse",
+          "executor_wait")
+ORDER = (NOT_YET, "executor_wait", "parse", "hash", "waves", "keydict",
+         "lock_wait", "dispatch", *OTHER.values(), UNATTRIBUTED)
+
+
+def find_trace(path: str) -> str:
+    if os.path.isfile(path):
+        return path
+    found = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                             recursive=True))
+    if not found:
+        raise FileNotFoundError(f"no .xplane.pb under {path}")
+    return found[-1]
+
+
+def busy_gaps(programs, t_lo: float, t_hi: float) -> list:
+    """[(gap start, gap end, a program starts at its end)] between the
+    busy intervals of `programs` = [(start, end)], window edges included."""
+    out = []
+    end = t_lo
+    for a, b in sorted(programs):
+        if a > end:
+            out.append((end, a, True))
+        end = max(end, b)
+    if t_hi > end:
+        out.append((end, t_hi, False))
+    return out
+
+
+def call_intervals(call_spans: list, flush_spans: list) -> list:
+    """The named intervals of one call for one of its flushes:
+    [(start, end, name)]. `call_spans` are the call's own spans,
+    `flush_spans` those of the flush; both [(start, end, span name)]."""
+    out = []
+    begin = min((a for a, _, n in call_spans if n == "rpc.begin"), default=None)
+    if begin is not None:
+        out.append((float("-inf"), begin, NOT_YET))
+        first = min((a for a, _, n in call_spans
+                     if a >= begin and n.startswith("call.")), default=None)
+        if first is not None:
+            out.append((begin, first, "executor_wait"))
+    for a, b, n in call_spans + flush_spans:
+        label = n.rpartition(".")[2]
+        if label in STAGES and n.split(".")[0] in ("call", "flush"):
+            out.append((a, b, label))
+    return out
+
+
+def divide(gap: tuple, intervals: list) -> dict:
+    """Seconds of `gap` = (start, end) per name of `intervals`; each
+    instant goes to the first of STAGES (then NOT_YET, then OTHER) that
+    covers it."""
+    g0, g1 = gap
+    cuts = sorted({g0, g1, *(t for a, b, _ in intervals for t in (a, b)
+                             if g0 < t < g1)})
+    rank = {name: i for i, name in enumerate(
+        STAGES + (NOT_YET,) + tuple(OTHER.values()))}
+    out: dict = {}
+    for a, b in zip(cuts, cuts[1:]):
+        mid = (a + b) / 2
+        names = [n for s, e, n in intervals if s <= mid < e]
+        name = min(names, key=rank.__getitem__) if names else UNATTRIBUTED
+        out[name] = out.get(name, 0.0) + (b - a)
+    return out
+
+
+def attribute_plane(programs: list, spans: list, t_lo: float, t_hi: float) -> dict:
+    """`programs`: [(start, end)] of one device plane. `spans`:
+    [(start, end, name, call id, flush id)] of the host plane. Returns
+    {name: idle seconds}; the values add up to the plane's idle time."""
+    dispatches = sorted((a, fl) for a, _, n, _, fl in spans
+                        if n == "flush.dispatch")
+    starts = [a for a, _ in dispatches]
+    by_flush: dict = {}
+    by_call: dict = {}
+    flush_call: dict = {}
+    for a, b, n, call, fl in spans:
+        if n.startswith("flush."):
+            by_flush.setdefault(fl, []).append((a, b, n))
+            flush_call[fl] = call
+        else:
+            by_call.setdefault(call, []).append((a, b, n))
+    totals: dict = {}
+    for g0, g1, launched in busy_gaps(programs, t_lo, t_hi):
+        parts = {UNATTRIBUTED: g1 - g0}
+        i = bisect.bisect_right(starts, g1) - 1
+        if launched and i >= 0:
+            fl = dispatches[i][1]
+            others = [(a, b, OTHER[n]) for a, b, n, _, f in spans
+                      if n in OTHER and f != fl and a < g1 and b > g0]
+            parts = divide((g0, g1), others + call_intervals(
+                by_call.get(flush_call.get(fl), []), by_flush.get(fl, [])))
+        for name, secs in parts.items():
+            totals[name] = totals.get(name, 0.0) + secs
+    return totals
+
+
+def read_trace(path: str) -> tuple:
+    """({device plane: [(start, end)] of its programs}, host spans)."""
+    from jax.profiler import ProfileData
+
+    planes: dict = {}
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:") and not plane.name.startswith(
+                "/device:CUSTOM"):
+            lines = {ln.name: ln for ln in plane.lines}
+            line = lines.get(MODULES_LINE) or lines.get(OPS_LINE)
+            if line is not None:
+                planes[plane.name] = [
+                    (e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9)
+                    for e in line.events if e.duration_ns > 0]
+        elif plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(("rpc.", "call.", "flush.")):
+                        st = dict(e.stats)
+                        a = e.start_ns * 1e-9
+                        spans.append((a, a + e.duration_ns * 1e-9, e.name,
+                                      int(st.get("call", 0)),
+                                      int(st.get("flush", 0))))
+    return planes, spans
+
+
+def main(argv: list) -> int:
+    if len(argv) != 2:
+        print(__doc__.split("\n\n")[1], file=sys.stderr)
+        return 2
+    planes, spans = read_trace(find_trace(argv[1]))
+    names = sorted({n for _, _, n, _, _ in spans})
+    print(f"host spans: {len(spans)} of {len(names)} names: {' '.join(names)}")
+    if not planes:
+        print("no device plane in this capture (a CPU backend has none)")
+        return 0
+    every = [t for evs in planes.values() for ab in evs for t in ab]
+    t_lo, t_hi = min(every), max(every)
+    for name in sorted(planes):
+        totals = attribute_plane(planes[name], spans, t_lo, t_hi)
+        idle = sum(totals.values())
+        print(f"{name}: window {t_hi - t_lo:.6f} s, idle {idle:.6f} s "
+              f"({100 * idle / (t_hi - t_lo):.2f} %), "
+              f"programs {len(planes[name])}")
+        for stage in ORDER:
+            if stage in totals:
+                print(f"  {stage:<28} {totals[stage]:>10.6f} s "
+                      f"{100 * totals[stage] / idle:>6.2f} % of idle")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv))
