@@ -558,31 +558,34 @@ def bench_ici(layout: str = "fused") -> dict:
     state = ici.create_ici_state(mesh, num_slots, WAYS, layout=layout)
     scan_fn = ici.make_replica_decide_scan(mesh, num_slots, WAYS, layout=layout)
 
-    def stack_steps():
-        bs = []
-        for _ in range(S):
-            b = _make_zipf_batch(rng, B, 500_000, num_groups, NOW)
-            b.behavior[: b.active.sum()] |= int(Behavior.GLOBAL)
-            bs.append(b)
-        return jax.tree.map(lambda *xs: np.stack(xs), *bs), int(
-            sum(b.active.sum() for b in bs)
-        )
+    from gubernator_tpu.ops.layout import WaveOperand
 
-    stacked, active = stack_steps()
-    homes = rng.integers(0, n_dev, (S, B)).astype(np.int64)
-    nows = np.arange(NOW, NOW + S, dtype=np.int64)
+    def stack_steps(groups, now_of):
+        """S zipf GLOBAL steps as stacked wave operands (each step's
+        batch, random per-lane home device and `now` in one array)."""
+        ops, active = [], 0
+        for i in range(S):
+            b = _make_zipf_batch(rng, B, 500_000, groups, now_of(i))
+            b.behavior[: b.active.sum()] |= int(Behavior.GLOBAL)
+            active += int(b.active.sum())
+            ops.append(WaveOperand.of(
+                b, now_of(i), rng.integers(0, n_dev, B)
+            ).buf)
+        return np.stack(ops), active
+
+    stacked, active = stack_steps(num_groups, lambda i: NOW + i)
 
     t0 = time.perf_counter()
-    state, outs = scan_fn(state, stacked, homes, nows)
-    jax.block_until_ready(outs.status)
+    state, outs = scan_fn(state, stacked)
+    jax.block_until_ready(outs)
     print(f"[bench] replica decide_scan compiled+warm in "
           f"{time.perf_counter() - t0:.1f}s ({layout}, {n_dev} device(s))",
           flush=True)
     CHUNKS = 6
     t0 = time.perf_counter()
     for _ in range(CHUNKS):
-        state, outs = scan_fn(state, stacked, homes, nows)
-    jax.block_until_ready(outs.status)
+        state, outs = scan_fn(state, stacked)
+    jax.block_until_ready(outs)
     dt = time.perf_counter() - t0
     tput = CHUNKS * active / dt
     print(f"[bench] replica decide THROUGHPUT {tput:.0f} decisions/s",
@@ -608,18 +611,9 @@ def bench_ici(layout: str = "fused") -> dict:
         traffic = ici.make_replica_decide_scan(mesh, sz, WAYS, layout=layout)
 
         def one_traffic(st, tick_i):
-            bs = []
-            for s in range(S):
-                b = _make_zipf_batch(
-                    rng, B, 500_000, n_groups_sz, NOW + tick_i
-                )
-                b.behavior[: b.active.sum()] |= int(Behavior.GLOBAL)
-                bs.append(b)
-            stacked_b = jax.tree.map(lambda *xs: np.stack(xs), *bs)
-            hm = rng.integers(0, n_dev, (S, B)).astype(np.int64)
-            nw = np.full(S, NOW + tick_i, dtype=np.int64)
-            st, o = traffic(st, stacked_b, hm, nw)
-            jax.block_until_ready(o.status)
+            stacked_b, _n = stack_steps(n_groups_sz, lambda i: NOW + tick_i)
+            st, o = traffic(st, stacked_b)
+            jax.block_until_ready(o)
             return st
 
         for vname, msg in variants:

@@ -97,6 +97,9 @@ class _BareCounter:
     def set(self, value: float) -> None:
         _BareChild(self, ()).set(value)
 
+    def sample_names(self) -> list:
+        return [self.name]
+
     def render_lines(self) -> list:
         out = [f"# HELP {self.name} {self.doc}", f"# TYPE {self.name} counter"]
         with self._lock:
@@ -640,6 +643,25 @@ EDGE_REASONS = {
 }
 EDGE_REASONS["peer_columnar"] = EDGE_REASONS["columnar"]
 EDGE_REASONS["peer_object"] = EDGE_REASONS["object"]
+
+
+def engine_wave_transfers() -> _BareCounter:
+    """The engine-owned counter of a wave's crossings of the host-device
+    boundary. The engine adds to it where it observes
+    gubernator_engine_flush_waves, and wire_engine_telemetry exposes it
+    on the very next lines, so a scrape reads the two as one: their
+    ratio is the arrays a wave costs each way."""
+    c = _BareCounter(
+        "gubernator_engine_wave_transfers",
+        "Arrays that crossed the host-device boundary for serving "
+        "waves: operands uploaded (h2d) and outputs read (d2h). One of "
+        "each a wave; over gubernator_engine_flush_waves_sum it is the "
+        "arrays a wave costs each way.",
+        ["direction"],
+    )
+    for direction in ("h2d", "d2h"):
+        c.labels(direction).inc(0)
+    return c
 
 
 def engine_histograms() -> dict:
@@ -1797,6 +1819,8 @@ def wire_engine_telemetry(metrics: "Metrics", engine) -> None:
     em = engine.metrics
     for h in getattr(em, "histograms", lambda: ())():
         metrics.register_renderable(h)
+        if h is getattr(em, "flush_waves", None):
+            metrics.register_renderable(em.wave_transfers)
     metrics.add_sync(engine_sync(engine))
 
 
@@ -1808,4 +1832,5 @@ def catalog_names() -> set:
     imported."""
     names = Metrics().sample_family_names()
     names |= {h.name for h in engine_histograms().values()}
+    names.add(engine_wave_transfers().name)
     return names

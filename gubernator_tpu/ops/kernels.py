@@ -21,7 +21,10 @@ layouts.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
+
+import jax
 
 # The registry every layout-selection surface validates against
 # (EngineConfig.layout, GUBER_TABLE_LAYOUT / GUBER_ICI_LAYOUT, bench.py
@@ -42,7 +45,11 @@ from gubernator_tpu.ops.decide import (
     probe_exists as _wpe,
 )
 from gubernator_tpu.ops.inject import inject as _wi
-from gubernator_tpu.ops.layout import SlotTable
+from gubernator_tpu.ops.layout import (
+    SlotTable,
+    pack_output,
+    unpack_operand,
+)
 
 # Decide-program backends (GUBER_KERNEL). "xla" is the grown fleet of
 # per-layout XLA programs; "pallas" routes the narrow/fused decide hot
@@ -77,6 +84,10 @@ class Kernels(NamedTuple):
     to_wide: object  # table -> SlotTable
     from_wide: object  # SlotTable -> table
     bytes_per_slot: int = 83  # resident table bytes per slot
+    # What an engine launches: (table, operand, ways, with_store) ->
+    # (table, output vector). One uploaded operand in, one array out
+    # (ops/layout.py WaveOperand / split_output).
+    decide_packed: object = None
 
 
 def _wide_decide(table, batch, now, ways, with_store=False):
@@ -198,18 +209,65 @@ def _pallas(layout: str, base: Kernels) -> Kernels:
 
 def get_kernels(layout: str) -> Kernels:
     if layout == "wide":
-        return _WIDE
-    if layout == "packed":
-        return _packed()
-    if layout == "fused":
+        base = _WIDE
+    elif layout == "packed":
+        base = _packed()
+    elif layout == "fused":
         base = _fused()
     elif layout == "narrow":
         base = _narrow()
     else:
         raise ValueError(f"unknown table layout: {layout!r}")
-    if kernel_backend() == "pallas":
-        return _pallas(layout, base)
-    return base
+    if layout in ("fused", "narrow") and kernel_backend() == "pallas":
+        base = _pallas(layout, base)
+    return base._replace(decide_packed=packed_decide(layout))
+
+
+def program_variant(layout: str, lanes: int, paged: bool = False):
+    """What besides shapes selects the decide program: "xla", or the
+    Pallas route's lowering and lane tile, which it resolves when it is
+    traced. A static argument of the packed entries, so that a change
+    of either traces a program of its own."""
+    if layout not in ("fused", "narrow") or kernel_backend() != "pallas":
+        return "xla"
+    from gubernator_tpu.ops import pallas_decide as _pd
+
+    return ("pallas", _pd.pallas_mode(), _pd.choose_block(layout, paged, lanes))
+
+
+@functools.lru_cache(maxsize=None)
+def _packed_program(layout: str):
+    """The jitted packed entry of `layout`, one per process (the jit
+    cache lives on it): unpack the operand, run the layout's raw decide,
+    pack the output. Named after the layout so a profile shows the
+    program under the name it always had (`jit_decide_fused`)."""
+
+    def entry(table, operand, ways, with_store, variant):
+        batch, _home, now = unpack_operand(operand)
+        table, out = get_raw_kernels(layout).decide(table, batch, now, ways)
+        return table, pack_output(out, with_store)
+
+    entry.__name__ = entry.__qualname__ = f"decide_{layout}"
+    return jax.jit(
+        entry,
+        static_argnames=("ways", "with_store", "variant"),
+        donate_argnums=(0,),
+    )
+
+
+def packed_decide(layout: str):
+    """(table, operand, ways, with_store=False) -> (table, output
+    vector): the launch of one wave whose only operand beside the table
+    is the uploaded (OPERAND_ROWS, B) int64 array."""
+    program = _packed_program(layout)
+
+    def decide_packed(table, operand, ways, with_store=False):
+        return program(
+            table, operand, ways=ways, with_store=bool(with_store),
+            variant=program_variant(layout, operand.shape[-1]),
+        )
+
+    return decide_packed
 
 
 class RawKernels(NamedTuple):

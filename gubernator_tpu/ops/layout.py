@@ -29,7 +29,8 @@ device-resident packing (ops/kernels.py to_wide/from_wide).
 
 from __future__ import annotations
 
-from typing import NamedTuple
+import sys
+from typing import NamedTuple, Optional
 
 import jax.numpy as jnp
 import numpy as np
@@ -118,23 +119,164 @@ class RequestBatch(NamedTuple):
 
     @staticmethod
     def zeros(b: int) -> "RequestBatch":
-        i64 = lambda: np.zeros((b,), dtype=np.int64)  # noqa: E731
-        return RequestBatch(
-            key_hi=i64(),
-            key_lo=i64(),
-            group=np.zeros((b,), dtype=np.int32),
-            algo=np.zeros((b,), dtype=np.int8),
-            behavior=np.zeros((b,), dtype=np.int32),
-            hits=i64(),
-            limit=i64(),
-            duration=i64(),
-            rate_num=i64(),
-            eff_duration=i64(),
-            greg_expire=i64(),
-            burst=i64(),
-            created_at=i64(),
-            active=np.zeros((b,), dtype=bool),
-        )
+        """Host batch whose fields are views of one WaveOperand buffer."""
+        return WaveOperand.zeros(b).batch
+
+
+# ---- the decide program's interface: one operand in, one array out ----------
+#
+# A wave crosses the host-device boundary once each way. The host builds
+# ONE int64 buffer of OPERAND_ROWS x B (WaveOperand below; RequestBatch's
+# host fields are views of it), uploads it, and the program unpacks it
+# to a RequestBatch inside the jit (unpack_operand) and packs its
+# DecideOutput to one int64 vector (pack_output) that the host reads in
+# one go (split_output). The per-field structs live only inside the
+# compiled program.
+#
+# Rows: ten int64 fields, then two shared words (group | behavior << 32;
+# algo | active << 8), the replica tier's per-lane home device, and `now`
+# (lane 0).
+_OP_I64 = (
+    "key_hi", "key_lo", "hits", "limit", "duration", "rate_num",
+    "eff_duration", "greg_expire", "burst", "created_at",
+)
+OP_GROUP_BEHAVIOR = len(_OP_I64)
+OP_ALGO_ACTIVE = OP_GROUP_BEHAVIOR + 1
+OP_HOME = OP_ALGO_ACTIVE + 1
+OP_NOW = OP_HOME + 1
+OPERAND_ROWS = OP_NOW + 1
+
+# Where a narrow field sits inside its shared int64 word, as an element
+# offset of the word's int32 / int8 view.
+_LITTLE = sys.byteorder == "little"
+_GROUP_AT, _BEHAVIOR_AT = (0, 1) if _LITTLE else (1, 0)
+_ALGO_AT, _ACTIVE_AT = (0, 1) if _LITTLE else (7, 6)
+
+# Output vector: OUT_LANE_ROWS (or OUT_STORE_ROWS with_store) rows of B
+# lanes, then the four totals.
+OUT_STATUS, OUT_LIMIT, OUT_REMAINING, OUT_RESET_TIME = range(4)
+OUT_SLOT, OUT_EVICTED_HI, OUT_EVICTED_LO, OUT_FREED = range(4, 8)
+OUT_LANE_ROWS = 4
+OUT_STORE_ROWS = 8
+OUT_TOTALS = 4  # hits, misses, unexpired_evictions, over_limit
+
+
+class WaveOperand:
+    """The host side of one wave (buf (OPERAND_ROWS, B)) or of W stacked
+    waves (buf (W, OPERAND_ROWS, B)): the buffer that is uploaded, its
+    RequestBatch fields as views with their own dtypes, and the replica
+    tier's `home` row."""
+
+    __slots__ = ("buf", "_batch")
+
+    def __init__(self, buf: np.ndarray):
+        self.buf = buf
+        self._batch = None
+
+    @property
+    def batch(self) -> RequestBatch:
+        """The buffer's fields as views (built on first use: a wave
+        sliced off a stacked operand only to be uploaded never needs
+        them)."""
+        if self._batch is None:
+            buf = self.buf
+            gb = buf[..., OP_GROUP_BEHAVIOR, :].view(np.int32)
+            aa = buf[..., OP_ALGO_ACTIVE, :]
+            self._batch = RequestBatch(
+                group=gb[..., _GROUP_AT::2],
+                behavior=gb[..., _BEHAVIOR_AT::2],
+                algo=aa.view(np.int8)[..., _ALGO_AT::8],
+                active=aa.view(np.bool_)[..., _ACTIVE_AT::8],
+                **{f: buf[..., i, :] for i, f in enumerate(_OP_I64)},
+            )
+        return self._batch
+
+    @property
+    def home(self) -> np.ndarray:
+        return self.buf[..., OP_HOME, :]
+
+    @staticmethod
+    def zeros(b: int, waves: Optional[int] = None) -> "WaveOperand":
+        shape = (OPERAND_ROWS, b) if waves is None else (waves, OPERAND_ROWS, b)
+        return WaveOperand(np.zeros(shape, dtype=np.int64))
+
+    @staticmethod
+    def of(batch: RequestBatch, now: int, home=None) -> "WaveOperand":
+        """Operand holding a copy of `batch` (any RequestBatch of host
+        arrays), stamped: the tests' and tools' way in."""
+        op = WaveOperand.zeros(batch.key_hi.shape[-1])
+        for dst, src in zip(op.batch, batch):
+            dst[...] = src
+        if home is not None:
+            op.home[...] = home
+        return op.stamp(now)
+
+    @property
+    def lanes(self) -> int:
+        return self.buf.shape[-1]
+
+    def wave(self, w: int) -> "WaveOperand":
+        return WaveOperand(self.buf[w])
+
+    def narrowed(self, lanes: int) -> "WaveOperand":
+        """The first `lanes` lanes as an operand of its own."""
+        return WaveOperand(np.ascontiguousarray(self.buf[..., :lanes]))
+
+    def stamp(self, now: int) -> "WaveOperand":
+        self.buf[..., OP_NOW, 0] = now
+        return self
+
+
+def unpack_operand(operand):
+    """(RequestBatch, home, now) from one uploaded operand, inside the
+    jit: THE unpack every layout, the paged kernels, the mesh and the
+    replica tier share. `operand` is (OPERAND_ROWS, B) int64."""
+    gb = operand[OP_GROUP_BEHAVIOR]
+    aa = operand[OP_ALGO_ACTIVE]
+    batch = RequestBatch(
+        group=gb.astype(jnp.int32),  # the low half (the cast wraps)
+        behavior=(gb >> 32).astype(jnp.int32),
+        algo=aa.astype(jnp.int8),
+        active=((aa >> 8) & 0xFF) != 0,
+        **{f: operand[i] for i, f in enumerate(_OP_I64)},
+    )
+    return batch, operand[OP_HOME], operand[OP_NOW, 0]
+
+
+def pack_output(out: "DecideOutput", with_store: bool):
+    """One int64 vector from a DecideOutput, inside the jit: the four
+    answer rows (and slot, evicted_hi/lo, freed when `with_store`), then
+    the four totals."""
+    rows = [out.status, out.limit, out.remaining, out.reset_time]
+    if with_store:
+        rows += [out.slot, out.evicted_hi, out.evicted_lo, out.freed]
+    lanes = jnp.stack([r.astype(jnp.int64) for r in rows]).reshape(-1)
+    totals = jnp.stack(
+        [out.hits, out.misses, out.unexpired_evictions, out.over_limit]
+    ).astype(jnp.int64)
+    return jnp.concatenate([lanes, totals])
+
+
+def split_output(vec: np.ndarray, with_store: bool = False):
+    """(rows (R, B), totals (4,)) views of one wave's output vector on
+    the host; rows index by OUT_*."""
+    r = OUT_STORE_ROWS if with_store else OUT_LANE_ROWS
+    return vec[:-OUT_TOTALS].reshape(r, -1), vec[-OUT_TOTALS:]
+
+
+def output_struct(vec, with_store: bool = False) -> "DecideOutput":
+    """A DecideOutput of host arrays from one output vector (tests and
+    tools; fields a store-less vector lacks are None)."""
+    rows, tot = split_output(np.asarray(vec), with_store)  # guberlint: allow-host-sync -- tests/tools helper, never on the serving path (the engine reads in _read_waves)
+    extra = (
+        (rows[OUT_SLOT], rows[OUT_EVICTED_HI], rows[OUT_EVICTED_LO],
+         rows[OUT_FREED] != 0)
+        if with_store else (None,) * 4
+    )
+    return DecideOutput(
+        rows[OUT_STATUS].astype(np.int8), rows[OUT_LIMIT],
+        rows[OUT_REMAINING], rows[OUT_RESET_TIME], *extra, *tot,
+    )
 
 
 class DecideOutput(NamedTuple):
@@ -161,3 +303,18 @@ class DecideOutput(NamedTuple):
     misses: jnp.ndarray
     unexpired_evictions: jnp.ndarray
     over_limit: jnp.ndarray
+
+
+def batch_entry(packed):
+    """A packed program under the RequestBatch signature, for tests and
+    tools: entry(state, batch, now) or entry(state, batch, home, now)
+    packs the host batch into one WaveOperand, launches `packed(state,
+    operand)` and returns (state, DecideOutput of host arrays)."""
+
+    def entry(state, batch, *home_now):
+        *home, now = home_now
+        op = WaveOperand.of(batch, int(now), home[0] if home else None)
+        state, vec = packed(state, op.buf)
+        return state, output_struct(vec)
+
+    return entry

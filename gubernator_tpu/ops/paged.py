@@ -54,11 +54,12 @@ import jax.numpy as jnp
 
 from gubernator_tpu.ops.kernels import (
     BYTES_PER_SLOT,
+    program_variant,
     get_kernels,
     get_raw_kernels,
     kernel_backend,
 )
-from gubernator_tpu.ops.layout import SlotTable
+from gubernator_tpu.ops.layout import SlotTable, pack_output, unpack_operand
 
 
 class PagedTable(NamedTuple):
@@ -105,6 +106,9 @@ class PagedKernels(NamedTuple):
     layout: str
     create: object  # () -> PagedTable (empty map, zeroed physical table)
     decide: object  # (pt, batch, now, ways, with_store) -> (pt, out)
+    # (pt, operand, ways, with_store) -> (pt, output vector): what an
+    # engine launches (ops/kernels.py Kernels.decide_packed)
+    decide_packed: object
     decide_scan: object  # (pt, batches, nows, ways, with_store)
     inject: object  # (pt, items, now, ways) -> (pt, ehi, elo)
     probe_exists: object  # (pt, hi, lo, group, now, ways) -> bool[B]
@@ -185,13 +189,19 @@ def make_paged_kernels(
                 pt, batches, nows, layout=layout, ways=ways, gpp=gpp
             )
 
+        def _raw_decide(pt, batch, now):
+            return _pd.raw_decide_paged(
+                pt, batch, now, layout=layout, ways=ways, gpp=gpp
+            )
+
     else:
 
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def _decide(pt, batch, now):
+        def _raw_decide(pt, batch, now):
             b = batch._replace(group=_xlate(pt.page_map, batch.group))
             data, out = raw.decide(pt.data, b, now, ways)
             return PagedTable(data, pt.page_map), out
+
+        _decide = jax.jit(_raw_decide, donate_argnums=(0,))
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def _decide_scan(pt, batches, nows):
@@ -205,6 +215,14 @@ def make_paged_kernels(
 
             data, outs = jax.lax.scan(step, pt.data, (batches, nows))
             return PagedTable(data, pm), outs
+
+    @functools.partial(
+        jax.jit, static_argnames=("with_store", "variant"), donate_argnums=(0,)
+    )
+    def _decide_packed(pt, operand, with_store, variant):
+        batch, _home, now = unpack_operand(operand)
+        pt, out = _raw_decide(pt, batch, now)
+        return pt, pack_output(out, with_store)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def _inject(pt, items, now):
@@ -277,6 +295,12 @@ def make_paged_kernels(
         create=_create,
         decide=lambda t, b, now, ways_=ways, with_store=False: _decide(
             t, b, now
+        ),
+        decide_packed=lambda t, op, ways_=ways, with_store=False: (
+            _decide_packed(
+                t, op, with_store=bool(with_store),
+                variant=program_variant(layout, op.shape[-1], paged=True),
+            )
         ),
         decide_scan=lambda t, bs, ns, ways_=ways, with_store=False: (
             _decide_scan(t, bs, ns)

@@ -936,3 +936,17 @@ def raw_decide_flat(table, batch, now, *, layout: str, ways: int):
         ways=ways, gpp=0, block_b=blk, mode=mode,
     )
     return type(table)(data), out
+
+
+def raw_decide_paged(pt, batch, now, *, layout: str, ways: int, gpp: int):
+    """UNJITTED paged decide, the page-map translation folded into the
+    kernel: what the paged packed entry (ops/paged.py) traces."""
+    _check_layout(layout)
+    mode = pallas_mode()
+    blk = choose_block(layout, True, batch.key_hi.shape[0])
+    data, out, _scan = _wave(
+        layout, pt.data.data, pt.page_map, batch, now,
+        ways=ways, gpp=gpp, block_b=blk, mode=mode,
+    )
+    inner = type(pt.data)(data)
+    return type(pt)(inner, pt.page_map), out

@@ -46,7 +46,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from gubernator_tpu.api.types import Behavior
 from gubernator_tpu.models.bucket import FIXED_SHIFT
 from gubernator_tpu.ops.kernels import get_raw_kernels
-from gubernator_tpu.ops.layout import RequestBatch, SlotTable
+from gubernator_tpu.ops.layout import (
+    SlotTable,
+    pack_output,
+    unpack_operand,
+)
 from gubernator_tpu.utils import transfer
 
 AXIS = "owners"
@@ -145,42 +149,43 @@ def _replica_step(RK, ways, groups_per, num_slots, dev, tbl, pending,
 def make_replica_decide(
     mesh: Mesh, num_slots: int, ways: int = 1, layout: str = DEFAULT_LAYOUT
 ):
-    """decide(state, batch, home, now): lane i is answered by device
-    home[i]'s replica (the node the request arrived at); non-owned GLOBAL
-    hits are accumulated into that device's pending deltas at the slot
-    decide() placed the key in (way choice is per-device)."""
+    """decide(state, operand): lane i is answered by device home[i]'s
+    replica (the node the request arrived at); non-owned GLOBAL hits are
+    accumulated into that device's pending deltas at the slot decide()
+    placed the key in (way choice is per-device). `operand` is the one
+    uploaded wave array (ops/layout.py WaveOperand: the batch, its
+    `home` row and `now`); the answer is one output vector, psum-merged
+    (ops/layout.py split_output)."""
     n_dev = mesh.devices.size
     num_groups = num_slots // ways
     groups_per = num_groups // n_dev
     RK = get_raw_kernels(layout)
 
-    def local(state: IciState, batch: RequestBatch, home, now):
+    def local(state: IciState, operand):
+        batch, home, now = unpack_operand(operand)
         dev = jax.lax.axis_index(AXIS).astype(I64)
         tbl, pending, out = _replica_step(
             RK, ways, groups_per, num_slots, dev,
             _squeeze(state.table), state.pending[0], batch, home, now,
         )
-        out = jax.tree.map(lambda x: jax.lax.psum(x, AXIS), out)
         return (
             IciState(
                 table=_unsqueeze(tbl), pending=pending[None],
                 tick=state.tick,
             ),
-            out,
+            jax.lax.psum(pack_output(out, False), AXIS),
         )
 
     sharded = jax.shard_map(
         local,
         mesh=mesh,
-        in_specs=(P(AXIS), P(), P(), P()),
+        in_specs=(P(AXIS), P()),
         out_specs=(P(AXIS), P()),
     )
 
     @functools.partial(jax.jit, donate_argnums=(0,))
-    def decide_fn(state: IciState, batch: RequestBatch, home, now):
-        return sharded(
-            state, batch, jnp.asarray(home, I64), jnp.asarray(now, I64)
-        )
+    def decide_fn(state: IciState, operand):
+        return sharded(state, operand)
 
     return decide_fn
 
@@ -188,8 +193,9 @@ def make_replica_decide(
 def make_replica_decide_scan(
     mesh: Mesh, num_slots: int, ways: int = 1, layout: str = DEFAULT_LAYOUT
 ):
-    """Scan variant: decide(state, batches, homes, nows) where every
-    input is stacked (S, ...) — S replica decide steps in ONE dispatch.
+    """Scan variant: decide(state, operands) where `operands` is S
+    stacked wave operands (S, OPERAND_ROWS, B) — S replica decide steps
+    in ONE dispatch, answered by (S, ...) stacked output vectors.
     Benchmarks need this to keep per-dispatch host overhead out of the
     device step time the same way decide_scan does for the single-chip
     kernel (bench.py kernel mode)."""
@@ -198,45 +204,41 @@ def make_replica_decide_scan(
     groups_per = num_groups // n_dev
     RK = get_raw_kernels(layout)
 
-    def local(state: IciState, batches: RequestBatch, homes, nows):
+    def local(state: IciState, operands):
         dev = jax.lax.axis_index(AXIS).astype(I64)
 
-        def step(carry, xs):
+        def step(carry, operand):
             tbl, pending = carry
-            b, home, now = xs
+            b, home, now = unpack_operand(operand)
             tbl, pending, out = _replica_step(
                 RK, ways, groups_per, num_slots, dev,
                 tbl, pending, b, home, now,
             )
-            return (tbl, pending), out
+            return (tbl, pending), pack_output(out, False)
 
         (tbl, pending), outs = jax.lax.scan(
-            step, (_squeeze(state.table), state.pending[0]),
-            (batches, homes, nows),
+            step, (_squeeze(state.table), state.pending[0]), operands
         )
-        # One collective per output leaf on the stacked (S, B) results,
-        # instead of one per scan step.
-        outs = jax.tree.map(lambda x: jax.lax.psum(x, AXIS), outs)
+        # One collective on the stacked (S, ...) vectors, instead of one
+        # per scan step.
         return (
             IciState(
                 table=_unsqueeze(tbl), pending=pending[None],
                 tick=state.tick,
             ),
-            outs,
+            jax.lax.psum(outs, AXIS),
         )
 
     sharded = jax.shard_map(
         local,
         mesh=mesh,
-        in_specs=(P(AXIS), P(), P(), P()),
+        in_specs=(P(AXIS), P()),
         out_specs=(P(AXIS), P()),
     )
 
     @functools.partial(jax.jit, donate_argnums=(0,))
-    def scan_fn(state: IciState, batches: RequestBatch, homes, nows):
-        return sharded(
-            state, batches, jnp.asarray(homes, I64), jnp.asarray(nows, I64)
-        )
+    def scan_fn(state: IciState, operands):
+        return sharded(state, operands)
 
     return scan_fn
 

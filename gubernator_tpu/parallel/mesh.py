@@ -29,7 +29,11 @@ from gubernator_tpu.ops.kernels import (
     get_kernels,
     get_raw_kernels,
 )
-from gubernator_tpu.ops.layout import DecideOutput, RequestBatch, SlotTable
+from gubernator_tpu.ops.layout import (
+    SlotTable,
+    pack_output,
+    unpack_operand,
+)
 from gubernator_tpu.utils import lockorder, transfer
 
 AXIS = "owners"
@@ -98,36 +102,52 @@ def create_sharded_table(
     return transfer.put_tree(table, sharding, metrics=metrics)
 
 
+def _sharded_packed_decide(mesh: Mesh, groups_per: int, decide, xlate=None):
+    """The packed launch over a sharded table: (table, operand,
+    with_store) -> (table', output vector), operand and output
+    replicated. The one operand is unpacked (ops/layout.py); each shard
+    masks the batch to the lanes it owns, runs `decide(table, batch,
+    now)` on its slice and packs its output; inactive lanes and foreign totals
+    are zeros, so ONE psum of the packed vector gives every lane its
+    single authoritative answer. `xlate(table, group)` (paged) maps
+    logical to physical groups, replicated, before the ownership mask;
+    `table` is then the PagedTable and only its data is sharded."""
+
+    def local(data, batch, now, with_store):
+        data, out = decide(data, _mask_to_local(groups_per, batch), now)
+        return data, jax.lax.psum(pack_output(out, with_store), AXIS)
+
+    @functools.partial(
+        jax.jit, static_argnames=("with_store",), donate_argnums=(0,)
+    )
+    def decide_fn(table, operand, with_store=False):
+        batch, _home, now = unpack_operand(operand)
+        sharded = jax.shard_map(
+            functools.partial(local, with_store=with_store),
+            mesh=mesh,
+            in_specs=(P(AXIS), P(), P()),
+            out_specs=(P(AXIS), P()),
+        )
+        if xlate is None:
+            return sharded(table, batch, now)
+        b = batch._replace(group=xlate(table.page_map, batch.group))
+        data, out = sharded(table.data, b, now)
+        return type(table)(data, table.page_map), out
+
+    return decide_fn
+
+
 def make_sharded_decide(
     mesh: Mesh, num_groups: int, ways: int = 8, layout: str = DEFAULT_LAYOUT
 ):
-    """Builds decide(table, batch, now) -> (table', DecideOutput) where the
-    table is sharded over `mesh` and the batch is replicated."""
-    n_dev = mesh.devices.size
-    groups_per = num_groups // n_dev
+    """Builds decide(table, operand, with_store=False) -> (table', output
+    vector) where the table is sharded over `mesh` and the one operand
+    (ops/layout.py WaveOperand) is replicated."""
     RK = get_raw_kernels(layout)
-
-    def local_decide(table, batch: RequestBatch, now):
-        local_batch = _mask_to_local(groups_per, batch)
-        table, out = RK.decide(table, local_batch, now, ways)
-        # Inactive lanes produce zeros, so a psum over owners yields each
-        # lane's single authoritative answer; scalar metrics sum naturally.
-        out = jax.tree.map(lambda x: jax.lax.psum(x, AXIS), out)
-        return table, out
-
-    sharded = jax.shard_map(
-        local_decide,
-        mesh=mesh,
-        in_specs=(P(AXIS), P(), P()),
-        out_specs=(P(AXIS), P()),
+    return _sharded_packed_decide(
+        mesh, num_groups // mesh.devices.size,
+        lambda t, b, now: RK.decide(t, b, now, ways),
     )
-
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def decide_fn(table, batch: RequestBatch, now):
-        now = jnp.asarray(now, dtype=jnp.int64)
-        return sharded(table, batch, now)
-
-    return decide_fn
 
 
 def make_sharded_inject(
@@ -167,6 +187,13 @@ def _no_scan(*_a, **_k):
     raise NotImplementedError(
         "the mesh tier serves wave-at-a-time SPMD programs; there is no "
         "decide_scan path (bench the single-chip engine for scan shapes)"
+    )
+
+
+def _packed_only(*_a, **_k):
+    raise NotImplementedError(
+        "the mesh tier launches the packed entry only (decide_packed: one "
+        "uploaded operand in, one array out)"
     )
 
 
@@ -222,8 +249,9 @@ def make_mesh_kernels(
         return Kernels(
             layout=layout,
             create=_create,
-            decide=lambda t, b, now, ways_=ways, with_store=False: decide_fn(
-                t, b, now
+            decide=_packed_only,
+            decide_packed=lambda t, op, ways_=ways, with_store=False: (
+                decide_fn(t, op, with_store=bool(with_store))
             ),
             decide_scan=_no_scan,
             inject=lambda t, i, now, ways_=ways: inject_fn(t, i, now),
@@ -279,25 +307,11 @@ def _make_mesh_paged_kernels(
         phys = jnp.where(pp >= 0, pp * gpp + g % gpp, sentinel)
         return phys.astype(group.dtype)
 
-    def _local_decide(data, batch, now):
-        data, out = raw.decide(
-            data, _mask_to_local(groups_per, batch), now, ways
-        )
-        return data, jax.tree.map(lambda x: jax.lax.psum(x, AXIS), out)
-
-    _sharded_decide = jax.shard_map(
-        _local_decide,
-        mesh=mesh,
-        in_specs=(P(AXIS), P(), P()),
-        out_specs=(P(AXIS), P()),
+    _decide_packed = _sharded_packed_decide(
+        mesh, groups_per,
+        lambda d, b, now: raw.decide(d, b, now, ways),
+        xlate=_xlate,
     )
-
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def _decide(pt, batch, now):
-        now = jnp.asarray(now, dtype=jnp.int64)
-        b = batch._replace(group=_xlate(pt.page_map, batch.group))
-        data, out = _sharded_decide(pt.data, b, now)
-        return PagedTable(data, pt.page_map), out
 
     def _local_inject(data, items, now):
         data, ehi, elo = raw.inject(
@@ -395,8 +409,9 @@ def _make_mesh_paged_kernels(
     return PagedKernels(
         layout=layout,
         create=_create,
-        decide=lambda t, b, now, ways_=ways, with_store=False: _decide(
-            t, b, now
+        decide=_packed_only,
+        decide_packed=lambda t, op, ways_=ways, with_store=False: (
+            _decide_packed(t, op, with_store=bool(with_store))
         ),
         decide_scan=_no_scan,
         inject=lambda t, i, now, ways_=ways: _inject(t, i, now),

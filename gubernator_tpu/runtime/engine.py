@@ -44,6 +44,7 @@ from gubernator_tpu.metrics import (
     ENGINE_STAGES,
     FLUSH_STAGES,
     engine_histograms,
+    engine_wave_transfers,
 )
 from gubernator_tpu.api.keys import group_of, key_hash128, key_hash128_batch
 from gubernator_tpu.api.types import (
@@ -54,7 +55,20 @@ from gubernator_tpu.api.types import (
     validate_request,
 )
 from gubernator_tpu.ops.encode import EncodeError, encode_one, encode_rows
-from gubernator_tpu.ops.layout import RequestBatch, SlotTable
+from gubernator_tpu.ops.layout import (
+    OUT_EVICTED_HI,
+    OUT_EVICTED_LO,
+    OUT_FREED,
+    OUT_LIMIT,
+    OUT_REMAINING,
+    OUT_RESET_TIME,
+    OUT_SLOT,
+    OUT_STATUS,
+    OUT_TOTALS,
+    SlotTable,
+    WaveOperand,
+    split_output,
+)
 from gubernator_tpu.ops.kernels import (
     get_admission,
     get_census,
@@ -217,6 +231,11 @@ class EngineMetrics:
         for attr, h in hists.items():
             setattr(self, attr, h)
         self._histograms = tuple(hists.values())
+        # Arrays that crossed the host-device boundary for serving
+        # waves: operands uploaded, outputs read; one of each a wave.
+        self.wave_transfers = engine_wave_transfers()
+        self._wave_h2d = self.wave_transfers.labels("h2d")
+        self._wave_d2h = self.wave_transfers.labels("d2h")
         # Pre-resolved stage children (labels() lookups are per-flush
         # hot-path cost).
         self._stage = {
@@ -236,6 +255,14 @@ class EngineMetrics:
 
     def histograms(self) -> tuple:
         return self._histograms
+
+    @property
+    def wave_h2d(self) -> int:
+        return int(self._wave_h2d.get())
+
+    @property
+    def wave_d2h(self) -> int:
+        return int(self._wave_d2h.get())
 
     def observe_stage(self, stage: str, dur: float) -> None:
         self._stage[stage].observe(dur)
@@ -311,7 +338,7 @@ class EngineMetrics:
 
     def observe_flush(self, path: str, n: int, waves: int, dur: float,
                       dev: float, trace_id: str = "",
-                      collective: bool = False) -> None:
+                      collective: bool = False, transfers=(0, 0)) -> None:
         """One flush's distribution samples (per FLUSH, not per
         request). A non-empty trace_id attaches an OpenMetrics exemplar
         to the latency buckets this flush lands in, so a p99 spike in
@@ -319,11 +346,15 @@ class EngineMetrics:
         topologies) additionally lands the device time in the
         collective-tick histogram: on a sharded decide the psum merge
         rendezvouses every shard, so this distribution is the
-        shard-skew amplifier the SLO layer watches."""
+        shard-skew amplifier the SLO layer watches. `transfers` =
+        (operands uploaded, outputs read) for the flush's waves, counted
+        beside the waves themselves so a scrape sees both or neither."""
         self.flush_duration.labels(path).observe(dur, trace_id)
         self.device_sync.labels(path).observe(dev, trace_id)
         self.batch_width.labels(path).observe(n)
         self.flush_waves.observe(waves)
+        self._wave_h2d.inc(transfers[0])
+        self._wave_d2h.inc(transfers[1])
         if collective:
             self.collective_tick.observe(dev)
 
@@ -334,11 +365,15 @@ class FlushStages:
     them to the engine's stage histogram in one go and fills `us`,
     which the flight recorder keeps with the flush's record."""
 
-    __slots__ = ("em", "ids", "us", "_rows")
+    __slots__ = ("em", "ids", "us", "_rows", "h2d", "d2h")
 
     def __init__(self, em: EngineMetrics, flush: int, call: int):
         self.em = em
         self.ids = {"flush": flush, "call": call}
+        # wave operands uploaded / wave outputs read by this flush
+        # (EngineMetrics.observe_flush counts them beside its waves)
+        self.h2d = 0
+        self.d2h = 0
         # every key from the start: the record shares this dict, and a
         # /debug/engine dump may walk it while publish() fills it in
         self.us: Dict[str, int] = dict.fromkeys(FLUSH_STAGES, 0)
@@ -399,8 +434,8 @@ class _FlushTicket:
     __slots__ = (
         "items",        # [(req, future-like)] — the flush's intake
         "placements",   # per-item routing (engine-specific)
-        "outs",         # per-wave DecideOutputs (device arrays)
-        "r_outs",       # ici replica-tier outputs (device arrays)
+        "outs",         # per-wave output vectors (device arrays; host with a store)
+        "r_outs",       # ici replica-tier output vectors (device arrays)
         "rows",         # store path: materialized per-wave gathered rows
         "events",       # store path: ('d'|'i', key) displacement events
         "served",       # items answered by this flush (excludes carry)
@@ -423,20 +458,24 @@ class _FlushTicket:
             setattr(self, k, kw.get(k))
 
 
-def _materialize_out(o) -> tuple:
-    """One wave's DecideOutputs pulled to host — THE completion-stage
-    flush-boundary readback (pipelined engines run it off the pump
-    thread, so the device never waits on host encode)."""
-    return (
-        np.asarray(o.status),  # guberlint: allow-host-sync -- completion-stage flush-boundary readback
-        np.asarray(o.remaining),  # guberlint: allow-host-sync -- completion-stage flush-boundary readback
-        np.asarray(o.reset_time),  # guberlint: allow-host-sync -- completion-stage flush-boundary readback
-        np.asarray(o.limit),  # guberlint: allow-host-sync -- completion-stage flush-boundary readback
-        int(o.hits),
-        int(o.misses),
-        int(o.unexpired_evictions),
-        int(o.over_limit),
-    )
+def _read_waves(outs, fs: FlushStages, with_store: bool = False):
+    """THE completion-stage flush-boundary readback, shared by every
+    path: ONE blocking read a wave (pipelined engines run it off the
+    pump thread, so the device never waits on host encode), sliced on
+    the host. Returns ([rows (R, B) per wave, indexed by OUT_*], [hits,
+    misses, unexpired_evictions, over_limit] summed over the waves). A
+    wave the store path already read under the lock arrives as its host
+    vector and is not read again."""
+    rows = []
+    totals = np.zeros(OUT_TOTALS, np.int64)
+    for o in outs:
+        if not isinstance(o, np.ndarray):
+            o = np.asarray(o)  # guberlint: allow-host-sync -- completion-stage flush-boundary readback
+            fs.d2h += 1
+        r, t = split_output(o, with_store)
+        rows.append(r)
+        totals += t
+    return rows, totals.tolist()
 
 
 class _WaveAssembler:
@@ -1605,6 +1644,9 @@ class MeshEngine(EngineBase):
         # host-DRAM cold tier (one frame pool + cold tier PER SHARD on
         # a mesh) — while flat binds the full-size table directly.
         self.K, self._pager = self.topo.build_kernels(config, self.metrics)
+        # Where a wave's operand is uploaded to (None: the default
+        # device; the configured device; replicated over the mesh).
+        self._operand_sharding = self.topo.operand_sharding(config)
         # Decide backend provenance (GUBER_KERNEL, resolved by the
         # topology's registry build) + the Pallas lane tile. Tuning runs
         # HERE — before _warmup compiles the decide program — so the
@@ -1854,11 +1896,11 @@ class MeshEngine(EngineBase):
                 scratch = self._place(
                     lambda: self.K.create(cfg.num_groups, cfg.ways)
                 )
-                scratch, out = self.K.decide(
-                    scratch, RequestBatch.zeros(B), self.now_fn(),
+                scratch, out = self.K.decide_packed(
+                    scratch, self._warm_operand(B, self.now_fn()),
                     cfg.ways, self.store is not None,
                 )
-                np.asarray(out.status)
+                np.asarray(out)
                 del scratch
             except Exception:
                 # A width that does not compile or does not fit on the
@@ -1949,13 +1991,15 @@ class MeshEngine(EngineBase):
         from gubernator_tpu.ops.inject import InjectBatch
 
         now = self.now_fn()
-        wb = RequestBatch.zeros(self.cfg.batch_size)
+        # The serving form of every launch: one uploaded operand in, one
+        # output vector out (ops/layout.py).
+        op = self._warm_operand(self.cfg.batch_size, now)
         with self.topo.dispatch_guard():
             with _transfer.account(self.metrics, "d2h", "warmup") as tx:
-                table, out = self.K.decide(
-                    self.table, wb, now, self.cfg.ways, self.store is not None
+                table, out = self.K.decide_packed(
+                    self.table, op, self.cfg.ways, self.store is not None
                 )
-                tx.add(np.asarray(out.status))
+                tx.add(np.asarray(out))
                 table, _, _ = self.K.inject(
                     table, InjectBatch.zeros(self.cfg.batch_size), now,
                     self.cfg.ways,
@@ -1992,10 +2036,9 @@ class MeshEngine(EngineBase):
                 # variants), and the stacked census/admission scans —
                 # the first GLOBAL request or sync tick must dispatch
                 # warm programs.
-                home = np.zeros(self.cfg.batch_size, np.int64)
                 with _transfer.account(self.metrics, "d2h", "warmup") as tx:
-                    rt.state, r_out = rt.decide(rt.state, wb, home, now)
-                    tx.add(np.asarray(r_out.status))  # guberlint: allow-host-sync -- warmup: compile the replica decide program before serving
+                    rt.state, r_out = rt.decide(rt.state, op)
+                    tx.add(np.asarray(r_out))  # guberlint: allow-host-sync -- warmup: compile the replica decide program before serving
                     rt.state, diag = rt.sync(rt.state, now)
                     tx.add(np.asarray(diag))  # guberlint: allow-host-sync -- warmup: compile the sync tick before the cadence thread runs it
                     if rt.sync_full is not None:
@@ -2014,6 +2057,29 @@ class MeshEngine(EngineBase):
         oracle in _census_scan), the table itself otherwise."""
         return table.data if self._pager is not None else table
 
+    def _warm_operand(self, lanes: int, now: int):
+        """An empty wave's operand on the device, placed as _upload
+        places a serving one (the jit cache keys on it), accounted as
+        warm-up."""
+        return _transfer.device_put(
+            WaveOperand.zeros(lanes).stamp(now).buf, self._operand_sharding,
+            metrics=self.metrics, purpose="warmup",
+        )
+
+    def _upload(self, waves, now: int, fs: FlushStages) -> list:
+        """Stamp `now` into each wave's operand and upload them: THE
+        host-to-device crossing of a flush, one array a wave, made
+        BEFORE the flush asks for the engine lock, so what runs under
+        the lock launches programs whose operands are all on the
+        device. One accounted h2d/serve record for the flush."""
+        if not waves:
+            return []
+        fs.h2d += len(waves)
+        return _transfer.device_put(
+            [w.stamp(now).buf for w in waves], self._operand_sharding,
+            metrics=self.metrics, purpose="serve",
+        )
+
     def warm_store_path(self) -> None:
         """Compile the store-path kernels (the with_store decide variant,
         probe_exists, gather_rows) at serving shapes so the first flush
@@ -2024,13 +2090,12 @@ class MeshEngine(EngineBase):
         cfg = self.cfg
         z64 = np.zeros(B, np.int64)
         now = self.now_fn()
+        op = self._warm_operand(B, now)
         with self._lock, self.topo.dispatch_guard(), _transfer.account(
             self.metrics, "d2h", "warmup"
         ) as tx:
-            table, out = self.K.decide(
-                self.table, RequestBatch.zeros(B), now, cfg.ways, True
-            )
-            tx.add(np.asarray(out.status))
+            table, out = self.K.decide_packed(self.table, op, cfg.ways, True)
+            tx.add(np.asarray(out))
             self.table = table
             tx.add(np.asarray(
                 self.K.probe_exists(
@@ -2431,7 +2496,7 @@ class MeshEngine(EngineBase):
                 self._maybe_prune_key_strings()
 
         with tracing.stage("flush.waves", fs, fs.ids):
-            asm = _WaveAssembler(RequestBatch.zeros, B)
+            asm = _WaveAssembler(WaveOperand.zeros, B)
             placements: List[Optional[tuple]] = []
             wave_rows: List[list] = []  # per-wave (req, hi, lo, grp) for bulk fill
             wave_lanes: List[list] = []
@@ -2443,8 +2508,7 @@ class MeshEngine(EngineBase):
             # the replica keyspace, waves assemble per (home, slot) so the
             # round-robin home device rides the wave batch, and placements
             # carry an "r" tag so _complete demuxes from the replica outputs.
-            r_asm = _WaveAssembler(RequestBatch.zeros, B) if rt is not None else None
-            replica_homes: List[np.ndarray] = []
+            r_asm = _WaveAssembler(WaveOperand.zeros, B) if rt is not None else None
 
             carry: List[Tuple[RateLimitReq, object]] = []
             new_strings: Dict[Tuple[int, int], str] = {}
@@ -2463,14 +2527,15 @@ class MeshEngine(EngineBase):
                     self._home_rr += 1
                     wb, w, lane = placed
                     try:
-                        encode_one(wb, lane, req, now, rt.num_rgroups, key=(hi, lo))
+                        encode_one(
+                            wb.batch, lane, req, now, rt.num_rgroups,
+                            key=(hi, lo),
+                        )
                     except EncodeError as e:
                         fut.set_result(RateLimitResp(error=str(e)))
                         placements.append(None)
                         continue
-                    while len(replica_homes) < len(r_asm.waves):
-                        replica_homes.append(np.zeros(B, dtype=np.int64))
-                    replica_homes[w][lane] = home
+                    wb.home[lane] = home
                     r_asm.commit(w, (home, slot))
                     placements.append(("r", w, lane, hi, lo))
                     continue
@@ -2487,7 +2552,10 @@ class MeshEngine(EngineBase):
                 if req.behavior & GREG:
                     # calendar resolution stays per-item (rare path)
                     try:
-                        encode_one(wb, lane, req, now, cfg.num_groups, key=(hi, lo))
+                        encode_one(
+                            wb.batch, lane, req, now, cfg.num_groups,
+                            key=(hi, lo),
+                        )
                     except EncodeError as e:
                         fut.set_result(RateLimitResp(error=str(e)))
                         placements.append(None)
@@ -2507,7 +2575,7 @@ class MeshEngine(EngineBase):
 
             for w, rows in enumerate(wave_rows):
                 if rows:
-                    encode_rows(asm.waves[w], wave_lanes[w], rows, now)
+                    encode_rows(asm.waves[w].batch, wave_lanes[w], rows, now)
             waves = asm.waves
 
             # Bucket each wave's device width to its occupancy (the kernel's
@@ -2525,7 +2593,7 @@ class MeshEngine(EngineBase):
                         if s >= fill and s < Bn:
                             Bn = s
                     if Bn < B:
-                        waves[w] = jax.tree.map(lambda a: a[:Bn], waves[w])
+                        waves[w] = waves[w].narrowed(Bn)
 
             # Execute waves sequentially against the (donated) table. With a
             # Store attached, each wave runs the reference's exact per-request
@@ -2548,12 +2616,14 @@ class MeshEngine(EngineBase):
             # _complete_ticket). Request spans link to it and back.
             r_waves = r_asm.waves if r_asm is not None else []
             n_waves = len(waves) + len(r_waves)
+            ops = self._upload(waves, now, fs)
+            r_ops = self._upload(r_waves, now, fs)
         fspan = self._start_flush_span(
             items, seq, path="object", layout=cfg.layout,
             items=len(items), waves=n_waves,
             batch_width=len(items) - len(carry),
         )
-        widths = [int(w.active.shape[0]) for w in waves]  # guberlint: allow-host-sync -- static shape metadata, no device readback
+        widths = [w.lanes for w in waves]
         widths += [B] * len(r_waves)  # replica waves stay full-width
         # Retrace attribution (runtime/telemetry.py): stamp this
         # thread's shape signature so a compile observed during the
@@ -2565,8 +2635,8 @@ class MeshEngine(EngineBase):
                 fspan
             ):
                 outs, r_outs, wave_rows_host, events = self._execute_waves(
-                    waves, wave_lane_req, now, prefetched, fs,
-                    r_waves=r_waves, r_homes=replica_homes,
+                    waves, ops, wave_lane_req, now, prefetched, fs,
+                    r_ops=r_ops,
                 )
         except Exception as e:
             tracing.end_span(fspan, error=e)
@@ -2591,16 +2661,17 @@ class MeshEngine(EngineBase):
         cfg = self.cfg
         fs = t.stages
         t_c0 = time.perf_counter()
-        # The np.asarray syncs live in _materialize_out (the sanctioned
-        # completion-stage readback). Sharded ("s") and replica ("r")
-        # outputs materialize side by side; placements tag which list a
-        # lane demuxes from.
+        # The np.asarray syncs live in _read_waves (the sanctioned
+        # completion-stage readback: one read a wave). Sharded ("s") and
+        # replica ("r") outputs materialize side by side; placements tag
+        # which list a lane demuxes from.
         try:
             with tracing.stage("flush.readback", fs, fs.ids):
-                host = {
-                    "s": [_materialize_out(o) for o in t.outs],
-                    "r": [_materialize_out(o) for o in t.r_outs],
-                }
+                s_rows, s_tot = _read_waves(
+                    t.outs, fs, self.store is not None
+                )
+                r_rows, r_tot = _read_waves(t.r_outs, fs)
+                host = {"s": s_rows, "r": r_rows}
         finally:
             self.metrics.busy_exit()  # entered in _execute_waves
         t_sync = time.perf_counter()
@@ -2615,17 +2686,14 @@ class MeshEngine(EngineBase):
 
             if cfg.keep_key_strings:
                 self._drop_displaced_strings(t.events)
-            tot = [
-                sum(h[i] for hs in host.values() for h in hs)
-                for i in (4, 5, 6, 7)
-            ]
+            tot = [a + b for a, b in zip(s_tot, r_tot)]
             dur = time.perf_counter() - t.t0
             em = self.metrics
             trace_id = (t.trace_id or "") if cfg.exemplars else ""
             em.observe(tot[0], tot[1], tot[2], tot[3], t.waves, t.served, dur)
             em.observe_flush(
                 "object", t.served, t.waves, dur, dev_s, trace_id,
-                collective=self.topo.n_dev > 1,
+                collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
             )
             em.observe_stage("assemble", t.t_dev - t.t0)
             # `dispatch` (the launches under the lock) and `lock_wait` were
@@ -2646,7 +2714,7 @@ class MeshEngine(EngineBase):
             # its response can rely on the store reflecting it (the reference's
             # OnChange runs within the request, algorithms.go:149-153).
             if self.store is not None:
-                self._store_write_behind(t.items, t.placements, t.outs, t.rows)
+                self._store_write_behind(t.items, t.placements, s_rows, t.rows)
 
             # GUBER_STAGE_METADATA: the flush-level stage times every served
             # item shares, built once; each response appends its own queue
@@ -2677,7 +2745,10 @@ class MeshEngine(EngineBase):
                     continue  # resolved (encode error) or deferred
                 path, w, lane = place[0], place[1], place[2]
                 hw = host[path][w]
-                st, rem, rst, lim = hw[0], hw[1], hw[2], hw[3]
+                st, rem, rst, lim = (
+                    hw[OUT_STATUS], hw[OUT_REMAINING], hw[OUT_RESET_TIME],
+                    hw[OUT_LIMIT],
+                )
                 status = int(st[lane])  # guberlint: allow-host-sync -- numpy demux of already-materialized rows
                 if dirty_agg is not None:
                     dirty_agg.append((req.hash_key(), max(int(req.hits), 0)))
@@ -2826,7 +2897,7 @@ class MeshEngine(EngineBase):
             fs.publish()  # the refused attempt's hash and waves
             return None
         n = cols.n
-        wb, wave, lane, ix, W, B = asm
+        wo, wave, lane, ix, W, B = asm
 
         def key_str(j: int) -> str:
             return orig_cols.key_string(
@@ -2906,7 +2977,8 @@ class MeshEngine(EngineBase):
                     self._maybe_prune_key_strings()
 
         with tracing.stage("flush.waves", fs, fs.ids):
-            wave_slices = [jax.tree.map(lambda a, w=w: a[w], wb) for w in range(W)]
+            wave_slices = [wo.wave(w) for w in range(W)]
+            ops = self._upload(wave_slices, now, fs)
             lane_reqs: List[Dict[int, tuple]] = [{} for _ in range(W)]
             resolver = None
             if store is not None:
@@ -2922,7 +2994,7 @@ class MeshEngine(EngineBase):
             layout=cfg.layout,
         ) as fspan:
             outs, _r_outs, wave_rows_host, events = self._execute_waves(
-                wave_slices, lane_reqs, now, prefetched, fs,
+                wave_slices, ops, lane_reqs, now, prefetched, fs,
                 req_resolver=resolver,
             )
 
@@ -2930,10 +3002,10 @@ class MeshEngine(EngineBase):
                 with tracing.stage(
                     "flush.readback", fs, fs.ids
                 ), _transfer.account(self.metrics, "d2h", "serve") as tx:
-                    status, r_limit, remaining, reset_time = (
-                        _stack_wave_outputs(outs)
+                    out_rows, totals = _read_waves(
+                        outs, fs, store is not None
                     )
-                    tx.add((status, r_limit, remaining, reset_time))
+                    tx.add(out_rows)
             finally:
                 self.metrics.busy_exit()  # entered in _execute_waves
         dev_s = time.perf_counter() - t_dev
@@ -2946,19 +3018,19 @@ class MeshEngine(EngineBase):
                 # hygiene — same semantics as the object path's flush.
                 self._store_write_behind_core(
                     list(zip(strs, wave_l, lane_l, hi_l, lo_l)),
-                    outs, wave_rows_host,
+                    out_rows, wave_rows_host,
                 )
                 if cfg.keep_key_strings:
                     self._drop_displaced_strings(events)
 
-            tot_hits, tot_miss, tot_evic, tot_over = _wave_totals(outs)
+            tot_hits, tot_miss, tot_evic, tot_over = totals
             dur = time.perf_counter() - t_start
             em = self.metrics
             em.observe(tot_hits, tot_miss, tot_evic, tot_over, W, n, dur)
             em.observe_flush(
                 "columnar", n, W, dur, dev_s,
                 flush_trace_id if cfg.exemplars else "",
-                collective=self.topo.n_dev > 1,
+                collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
             )
             em.observe_stage("assemble", t_dev - t_start)
             em.observe_stage("device_sync", dev_s)
@@ -2969,7 +3041,9 @@ class MeshEngine(EngineBase):
                 ticket=fs.ids["flush"], call=fs.ids["call"],
                 stages_us=fs.us,
             )
-            st_req = status[ix]
+            st_req, r_limit, remaining, reset_time = _demux_lanes(
+                out_rows, ix
+            )
             if em.hotkeys.k > 0:
                 _note_hotkeys_columnar(em.hotkeys, hi, lo, cols.hits, st_req)
             with raceguard.racy_read(
@@ -2979,7 +3053,7 @@ class MeshEngine(EngineBase):
                 track_dirty = self._dirty is not None
             if track_dirty:
                 self._note_dirty_columnar(hi, lo, cols.hits)
-            out = (st_req, r_limit[ix], remaining[ix], reset_time[ix])
+            out = (st_req, r_limit, remaining, reset_time)
         fs.publish()
         return out
 
@@ -3001,12 +3075,14 @@ class MeshEngine(EngineBase):
         if select is not None and len(select) == 0:
             return None
         with tracing.stage("flush.waves", fs, fs.ids):
-            asm = self._assemble_replica_split(cols, now, select, hi, lo, grp)
+            asm = self._assemble_replica_split(
+                cols, now, select, hi, lo, grp, fs
+            )
         if asm is None:
             fs.publish()
             return None
-        (cols, hi, lo, s_asm, r_asm, ng_idx, g_idx, wave_slices, r_slices,
-         r_homes) = asm
+        (cols, hi, lo, s_asm, r_asm, ng_idx, g_idx, wave_slices, ops,
+         r_ops) = asm
         n = cols.n
 
         _telemetry.set_shape_hint(
@@ -3020,8 +3096,8 @@ class MeshEngine(EngineBase):
             # _execute_waves supplies the lock, the collective guard,
             # page residency (paged mesh), and unified recovery.
             s_outs, r_outs, _rows, _events = self._execute_waves(
-                wave_slices, [{} for _ in wave_slices], now, {}, fs,
-                r_waves=r_slices, r_homes=r_homes,
+                wave_slices, ops, [{} for _ in wave_slices], now, {}, fs,
+                r_ops=r_ops,
             )
 
         status = np.zeros(n, np.int64)
@@ -3039,15 +3115,12 @@ class MeshEngine(EngineBase):
                 ):
                     if asm is None:
                         continue
-                    st, li, re, rst = _stack_wave_outputs(outs)
-                    tx.add((st, li, re, rst))
-                    ix = asm[3]
-                    status[idx] = st[ix]
-                    r_limit[idx] = li[ix]
-                    remaining[idx] = re[ix]
-                    reset_time[idx] = rst[ix]
+                    out_rows, totals = _read_waves(outs, fs)
+                    tx.add(out_rows)
+                    (status[idx], r_limit[idx], remaining[idx],
+                     reset_time[idx]) = _demux_lanes(out_rows, asm[3])
                     waves_total += asm[4]
-                    for j, v in enumerate(_wave_totals(outs)):
+                    for j, v in enumerate(totals):
                         tots[j] += v
         finally:
             self.metrics.busy_exit()  # entered in _execute_waves
@@ -3062,7 +3135,7 @@ class MeshEngine(EngineBase):
             em.observe_flush(
                 "columnar", n, waves_total, dur, dev_s,
                 flush_trace_id if cfg.exemplars else "",
-                collective=self.topo.n_dev > 1,
+                collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
             )
             em.observe_stage("assemble", t_dev - t_start)
             em.observe_stage("device_sync", dev_s)
@@ -3078,10 +3151,11 @@ class MeshEngine(EngineBase):
         fs.publish()
         return (status, r_limit, remaining, reset_time)
 
-    def _assemble_replica_split(self, cols, now, select, hi, lo, grp):
+    def _assemble_replica_split(self, cols, now, select, hi, lo, grp, fs):
         """The host assembly of _check_columns_replica_split: the
-        sharded and the replica waves and their per-wave slices, or None
-        where the batch needs the object path."""
+        sharded and the replica waves, their per-wave slices and their
+        uploaded operands, or None where the batch needs the object
+        path."""
         cfg = self.cfg
         rt = self._rtier
         if select is not None:
@@ -3105,7 +3179,7 @@ class MeshEngine(EngineBase):
                 return None
 
         # -- assemble the replica (GLOBAL) waves --
-        r_asm, homes_wb = None, None
+        r_asm = None
         if len(g_idx):
             r_cols = _select_columns(cols, g_idx)
             r_lo = lo[g_idx]
@@ -3125,31 +3199,22 @@ class MeshEngine(EngineBase):
             )
             if r_asm is None:
                 return None
-            r_wb, _rw, _rl, r_ix, RW, RB = r_asm
-            r_wb.group[r_ix] = slot.astype(np.int32)
-            homes_wb = np.zeros((RW, RB), dtype=np.int64)
-            homes_wb[r_ix] = homes
+            r_wo, r_ix = r_asm[0], r_asm[3]
+            r_wo.batch.group[r_ix] = slot.astype(np.int32)
+            r_wo.home[r_ix] = homes
 
-        wave_slices, r_slices, r_homes = [], [], []
+        wave_slices, r_slices = [], []
         if s_asm is not None:
-            wb = s_asm[0]
-            wave_slices = [
-                jax.tree.map(lambda a, w=w: a[w], wb)
-                for w in range(s_asm[4])
-            ]
+            wave_slices = [s_asm[0].wave(w) for w in range(s_asm[4])]
         if r_asm is not None:
-            r_wb = r_asm[0]
-            r_slices = [
-                jax.tree.map(lambda a, w=w: a[w], r_wb)
-                for w in range(r_asm[4])
-            ]
-            r_homes = [homes_wb[w] for w in range(r_asm[4])]
+            r_slices = [r_asm[0].wave(w) for w in range(r_asm[4])]
         return (cols, hi, lo, s_asm, r_asm, ng_idx, g_idx, wave_slices,
-                r_slices, r_homes)
+                self._upload(wave_slices, now, fs),
+                self._upload(r_slices, now, fs))
 
     def _execute_waves(
-        self, waves, lane_reqs, now, prefetched, fs, req_resolver=None,
-        r_waves=(), r_homes=(),
+        self, waves, ops, lane_reqs, now, prefetched, fs, req_resolver=None,
+        r_ops=(),
     ):
         """Run decide over scatter-disjoint waves under the device lock,
         with the store's per-wave sequence when a Store is attached:
@@ -3158,12 +3223,19 @@ class MeshEngine(EngineBase):
         the gathered rows let write-behind persist the value the caller
         observed even if a later wave displaces the slot).
 
-        lane_reqs: per-wave {lane: (req_or_index, key_hi, key_lo)}; with
-        req_resolver set, the first element is an index resolved lazily
-        (columnar path). r_waves/r_homes: GLOBAL replica waves + their
-        per-lane home devices (replica topologies only), decided against
-        the replica tier after the sharded waves. Returns
-        (outs, r_outs, wave_rows_host, events).
+        waves: the host WaveOperands (the pager, the shard attribution
+        and the store's probe read their columns); ops: the same waves
+        as _upload left them on the device, ONE array each — the only
+        operand of a launch beside the device-resident table, so nothing
+        crosses to the device under the lock. lane_reqs: per-wave
+        {lane: (req_or_index, key_hi, key_lo)}; with req_resolver set,
+        the first element is an index resolved lazily (columnar path).
+        r_ops: the uploaded GLOBAL replica waves (replica topologies
+        only; their per-lane home device rides the operand), decided
+        against the replica tier after the sharded waves. Returns
+        (outs, r_outs, wave_rows_host, events): one output vector a
+        wave, still on the device unless a Store made the flush read it
+        here.
 
         `fs` (FlushStages) takes the two stages every path shares:
         `flush.lock_wait` (waiting for the engine lock and the
@@ -3198,12 +3270,12 @@ class MeshEngine(EngineBase):
             # rates"): host-side bincount over the waves' group arrays
             # BEFORE device dispatch — this is the one choke point both
             # the object and columnar paths flow through.
-            self._note_shard_decisions(waves)
+            self._note_shard_decisions([w.batch for w in waves])
         # Under the lock every microsecond is serial for all callers, so
         # it holds the stages' two clock reads and nothing else of them:
         # the intervals go to `fs` after the release. Only a capture or
         # a DEBUG SDK (`live`, None otherwise) opens spans in there.
-        n_dispatch = len(waves) + len(r_waves)
+        n_dispatch = len(ops) + len(r_ops)
         self.metrics.busy_enter()
         live = tracing.open_live("flush.lock_wait", fs.ids)
         t_wait = time.perf_counter_ns()
@@ -3217,45 +3289,54 @@ class MeshEngine(EngineBase):
             table = self.table
             rstate = rt.state if rt is not None else None
             try:
-                for w, wb in enumerate(waves):
+                for w, wo in enumerate(waves):
                     if self._pager is not None:
                         # Promote every page this wave touches BEFORE
                         # its probe/decide (a probe-miss against a
                         # demoted page must resolve against promoted
                         # state, not the sentinel). Same lock as the
                         # decide: a promotion can never race a flush.
+                        wb = wo.batch
                         table = self._pager.ensure_resident(
                             table,
                             self._pager.touched_pages(wb.group, wb.active),
                         )
                     if store is not None:
                         table = self._wave_readthrough(
-                            table, wb, lane_reqs[w], now,
+                            table, wo.batch, lane_reqs[w], now,
                             prefetched, served, wave_rows_host, events,
                             req_resolver=req_resolver,
                         )
-                    table, out = self.K.decide(
-                        table, wb, now, cfg.ways, store is not None
+                    table, out = self.K.decide_packed(
+                        table, ops[w], cfg.ways, store is not None
                     )
-                    outs.append(out)
                     if store is not None:
-                        rows = self.K.gather_rows(table, out.slot)
+                        # The store's sequence is synchronous per wave:
+                        # the wave's one read happens here, and its slot
+                        # column drives the row gather (a program of its
+                        # own, K.gather_rows).
                         with _transfer.account(
                             self.metrics, "d2h", "serve"
                         ) as tx:
+                            out = np.asarray(out)  # guberlint: allow-host-sync -- store path: the wave's one read, synchronous by design
+                            fs.d2h += 1
+                            o_rows, _tot = split_output(out, True)
+                            rows = self.K.gather_rows(
+                                table, o_rows[OUT_SLOT]
+                            )
                             rows_h = jax.tree.map(np.asarray, rows)
-                            tx.add(rows_h)
-                            ehi = np.asarray(out.evicted_hi)
-                            elo = np.asarray(out.evicted_lo)
-                            tx.add((ehi, elo))
+                            tx.add((out, rows_h))
+                        ehi = o_rows[OUT_EVICTED_HI]
+                        elo = o_rows[OUT_EVICTED_LO]
                         wave_rows_host.append(rows_h)
                         for j in np.nonzero((ehi != 0) | (elo != 0))[0]:
                             events.append(("d", (int(ehi[j]), int(elo[j]))))
                         for lane, entry in lane_reqs[w].items():
                             served[(entry[1], entry[2])] = (w, lane)
                             events.append(("i", (entry[1], entry[2])))
-                for wb, hm in zip(r_waves, r_homes):
-                    rstate, out = rt.decide(rstate, wb, hm, now)
+                    outs.append(out)
+                for op in r_ops:
+                    rstate, out = rt.decide(rstate, op)
                     r_outs.append(out)
                 self.table = table
                 if rt is not None:
@@ -3389,7 +3470,7 @@ class MeshEngine(EngineBase):
             events.append(("i", (hi, lo)))
         return table
 
-    def _store_write_behind(self, items, placements, outs, rows) -> None:
+    def _store_write_behind(self, items, placements, out_rows, rows) -> None:
         def seq():
             for (req, _), place in zip(items, placements):
                 if place is None or place == "carry":
@@ -3399,15 +3480,16 @@ class MeshEngine(EngineBase):
                     continue  # replica lanes never persist to a Store
                 yield req.hash_key(), w, lane, hi, lo
 
-        self._store_write_behind_core(seq(), outs, rows)
+        self._store_write_behind_core(seq(), out_rows, rows)
 
     _WB_FIELDS = (
         "used", "key_hi", "key_lo", "algo", "status", "limit", "duration",
         "remaining", "stamp", "expire_at", "invalid_at", "burst",
     )
 
-    def _store_write_behind_core(self, seq, outs, rows) -> None:
-        """seq yields (hash_key, wave, lane, hi, lo) in REQUEST order.
+    def _store_write_behind_core(self, seq, out_rows, rows) -> None:
+        """seq yields (hash_key, wave, lane, hi, lo) in REQUEST order;
+        out_rows are the waves' output rows as _read_waves gives them.
 
         Rows were gathered per-wave from the intermediate tables (and
         already materialized), so each lane sees exactly the state its
@@ -3430,7 +3512,7 @@ class MeshEngine(EngineBase):
             ].tolist()
             for f in self._WB_FIELDS
         }
-        freed_v = np.stack([np.asarray(o.freed) for o in outs])[
+        freed_v = np.stack([r[OUT_FREED] for r in out_rows])[
             w_arr, l_arr
         ].tolist()
 
@@ -3789,9 +3871,10 @@ def _assemble_column_waves(
     """Vectorized wave assembly shared by the engines' columnar paths:
     wave = occurrence rank within the group (stable sort keeps arrival
     order, preserving per-key sequencing); lane = arrival rank within
-    the wave. Returns (wb, wave, lane, ix, W, B) with `wb` a (W, B)
-    stacked RequestBatch, or None when the batch exceeds the wave/lane
-    bounds (caller falls back to the object path).
+    the wave. Returns (wo, wave, lane, ix, W, B) with `wo` the W stacked
+    waves' WaveOperand (its `batch` fields are (W, B) views of the one
+    buffer), or None when the batch exceeds the wave/lane bounds (caller
+    falls back to the object path).
 
     `width_candidates` optionally narrows the device batch width to the
     actual occupancy — the kernel's cost is per-LANE — using only
@@ -3837,26 +3920,8 @@ def _assemble_column_waves(
     )
 
     W = num_waves
-
-    def stack(dtype):
-        return np.zeros((W, B), dtype=dtype)
-
-    wb = RequestBatch(
-        key_hi=stack(np.int64),
-        key_lo=stack(np.int64),
-        group=stack(np.int32),
-        algo=stack(np.int8),
-        behavior=stack(np.int32),
-        hits=stack(np.int64),
-        limit=stack(np.int64),
-        duration=stack(np.int64),
-        rate_num=stack(np.int64),
-        eff_duration=stack(np.int64),
-        greg_expire=stack(np.int64),
-        burst=stack(np.int64),
-        created_at=stack(np.int64),
-        active=stack(bool),
-    )
+    wo = WaveOperand.zeros(B, W)
+    wb = wo.batch
     ix = (wave, lane)
     wb.key_hi[ix] = hi
     wb.key_lo[ix] = lo
@@ -3871,18 +3936,19 @@ def _assemble_column_waves(
     wb.burst[ix] = burst
     wb.created_at[ix] = created
     wb.active[ix] = True
-    return wb, wave, lane, ix, W, B
+    return wo, wave, lane, ix, W, B
 
 
-def _stack_wave_outputs(outs):
-    """(status, limit, remaining, reset_time) stacked (W, B) host arrays
-    from per-wave DecideOutputs — the demux shared by the engines'
-    columnar paths."""
-    return (
-        np.stack([np.asarray(o.status) for o in outs]),
-        np.stack([np.asarray(o.limit) for o in outs]),
-        np.stack([np.asarray(o.remaining) for o in outs]),
-        np.stack([np.asarray(o.reset_time) for o in outs]),
+def _demux_lanes(out_rows, ix):
+    """(status, limit, remaining, reset_time) in request order from the
+    output rows of equally wide waves (_read_waves): `ix` is each
+    request's (wave, lane) — the demux shared by the engines' columnar
+    paths."""
+    wave, lane = ix
+    stacked = np.stack(out_rows)  # (W, R, B)
+    return tuple(
+        stacked[wave, r, lane]
+        for r in (OUT_STATUS, OUT_LIMIT, OUT_REMAINING, OUT_RESET_TIME)
     )
 
 
@@ -3906,17 +3972,6 @@ def _note_hotkeys_columnar(hk, hi, lo, hits, status) -> None:
             ent[1] += o
     if agg:
         hk.update([(k, v[0], v[1], None) for k, v in agg.items()])
-
-
-def _wave_totals(outs):
-    """(hits, misses, unexpired_evictions, over_limit) summed across
-    waves for EngineMetrics.observe."""
-    return (
-        sum(int(o.hits) for o in outs),
-        sum(int(o.misses) for o in outs),
-        sum(int(o.unexpired_evictions) for o in outs),
-        sum(int(o.over_limit) for o in outs),
-    )
 
 
 def _select_columns(cols, select: np.ndarray):

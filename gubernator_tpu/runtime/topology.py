@@ -35,6 +35,7 @@ from __future__ import annotations
 import contextlib
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from gubernator_tpu.ops.kernels import (
     get_admission,
@@ -157,6 +158,11 @@ class SingleChipTopology:
     def build_replica(self, cfg, metrics):
         return None  # no mesh to replicate over
 
+    def operand_sharding(self, cfg):
+        """Where a wave's operand is uploaded: the table's own device
+        (None = the process default, where an unpinned table lives)."""
+        return getattr(cfg, "device", None)
+
     def dispatch_guard(self):
         """Single-device programs cannot rendezvous: no guard."""
         return contextlib.nullcontext()
@@ -217,6 +223,11 @@ class IciMeshTopology:
             self.mesh, cfg, metrics,
             tuple(int(k) for k in cfg.census_thresholds),
         )
+
+    def operand_sharding(self, cfg):
+        """A wave's operand is replicated over the mesh: every shard
+        unpacks the same array and masks it to the lanes it owns."""
+        return NamedSharding(self.mesh, P())
 
     def dispatch_guard(self):
         """Process-wide multi-device enqueue lock (parallel/mesh.py):

@@ -14,9 +14,11 @@ Device-tier debug surface (docs/monitoring.md; no reference analog):
 - GET /debug/profile?seconds=N — on-demand jax.profiler capture to a
   temp dir (one capture at a time process-wide; 503 when busy or when
   the profiler is unavailable). Works on CPU too — the XLA profiler is
-  backend-agnostic. The capture holds the program's own rpc.*, call.*
-  and flush.* spans in plane /host:CPU; `python=1` adds Python frames
-  (and slows the server it traces).
+  backend-agnostic. A capture whose stop outlasts its window by more
+  than ten seconds answers 200 at once and keeps the connection alive
+  with newlines until the JSON follows. The capture holds the
+  program's own rpc.*, call.* and flush.* spans in plane /host:CPU;
+  `python=1` adds Python frames (and slows the server it traces).
 - GET /debug/slo — the SLO observatory: per-SLO multi-window burn
   rates, alert states, remaining error budgets, and the self-watchdog's
   per-loop heartbeat table (docs/monitoring.md "SLOs & burn rates").
@@ -31,12 +33,15 @@ from __future__ import annotations
 import asyncio
 import functools
 import json
+import logging
 
 from aiohttp import web
 
 from gubernator_tpu.service import pb
 from gubernator_tpu.service import profiler as _profiler
 from gubernator_tpu.service.server import ApiError, V1Service
+
+log = logging.getLogger("gubernator_tpu.gateway")
 
 # jax.profiler state is process-global: exactly one capture at a time,
 # regardless of how many daemons/listeners share the process. The guard
@@ -45,6 +50,26 @@ from gubernator_tpu.service.server import ApiError, V1Service
 # historical gateway names importable.
 _PROFILE_GUARD = _profiler.PROFILE_GUARD
 _PROFILE_MAX_SECONDS = _profiler.PROFILE_MAX_SECONDS
+
+
+# A capture still running this long after its window gets a reply that
+# is kept alive (debug_profile).
+_PROFILE_HEARTBEAT_S = 10.0
+
+
+def _capture_ended(fut) -> None:
+    if not fut.cancelled():
+        fut.exception()  # retrieved here if the request is gone
+    _PROFILE_GUARD.release()
+
+
+def _capture_reply(fut) -> tuple:
+    """(JSON body, status) of a finished capture."""
+    try:
+        return fut.result(), 200
+    except Exception as e:
+        log.warning("profile capture failed: %s", e)
+        return {"error": f"profiler unavailable: {e}"}, 503
 
 
 def add_debug_routes(app: web.Application, svc: V1Service) -> None:
@@ -73,19 +98,32 @@ def add_debug_routes(app: web.Application, svc: V1Service) -> None:
                 headers={"Retry-After": str(int(seconds) or 1)},
             )
         python = request.query.get("python", "0") in ("1", "true")
-        try:
-            out = await asyncio.get_running_loop().run_in_executor(
-                None, functools.partial(
-                    _profiler.capture, seconds, python=python
-                )
-            )
-        except Exception as e:
-            return web.json_response(
-                {"error": f"profiler unavailable: {e}"}, status=503
-            )
-        finally:
-            _PROFILE_GUARD.release()
-        return web.json_response(out)
+        fut = asyncio.get_running_loop().run_in_executor(
+            None, functools.partial(_profiler.capture, seconds, python=python)
+        )
+        # A capture cannot be cancelled: the guard goes back when its
+        # thread ends, whatever becomes of this request.
+        fut.add_done_callback(_capture_ended)
+        done, _ = await asyncio.wait(
+            {fut}, timeout=seconds + _PROFILE_HEARTBEAT_S
+        )
+        if done:
+            out, status = _capture_reply(fut)
+            return web.json_response(out, status=status)
+        # stop_trace outlives the capture by a minute and more on a
+        # loaded server (it decodes ~900 device events a decide launch,
+        # PERF.md §6): a reply that stayed silent that long runs into
+        # the idle timeout of the client or of a proxy between. Start
+        # the reply and send a newline, which JSON allows before a
+        # value, every few seconds until the trace is on disk.
+        resp = web.StreamResponse(headers={"Content-Type": "application/json"})
+        await resp.prepare(request)
+        while not done:
+            await resp.write(b"\n")
+            done, _ = await asyncio.wait({fut}, timeout=_PROFILE_HEARTBEAT_S)
+        await resp.write(json.dumps(_capture_reply(fut)[0]).encode())
+        await resp.write_eof()
+        return resp
 
     async def debug_device(request: web.Request) -> web.Response:
         """Device-resource observatory (docs/monitoring.md "Device

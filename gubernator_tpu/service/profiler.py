@@ -116,6 +116,11 @@ def capture(
     t_stopped = time.perf_counter()
     files, nbytes = _dir_stats(trace_dir)
     rotated = rotate(keep, root)
+    log.info(
+        "profile capture: %.2f s window, stop_trace %.1f s, %d file(s), "
+        "%.1f MB in %s", seconds, t_stopped - t_stop, files, nbytes / 1e6,
+        trace_dir,
+    )
     return {
         "trace_dir": trace_dir,
         "seconds": seconds,

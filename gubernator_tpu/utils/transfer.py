@@ -11,6 +11,14 @@ paged table's page-migration moves (runtime/pager.py): demote = d2h
 page evacuation to the host-DRAM tier, promote = h2d page fill on a
 probe against a demoted page.
 
+The serving upload is an accounted record too: a flush uploads its
+waves' operands, ONE int64 array a wave (ops/layout.py WaveOperand),
+through device_put(..., purpose="serve") before it asks for the engine
+lock, one "h2d"/"serve" record a flush. (Until PR 25 the jit call
+transferred fourteen host arrays a wave implicitly, under the lock, and
+the ledger never saw them.) Its "d2h"/"serve" twin is the flush's
+readback: one output vector a wave.
+
 Honesty note on timing: d2h materializations (np.asarray of device
 arrays) block until the copy lands, so their latency is the real
 transfer + any pending compute it waits on. h2d device_put is ASYNC on

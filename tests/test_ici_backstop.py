@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from gubernator_tpu.api.types import Behavior, RateLimitReq
 from gubernator_tpu.ops.encode import encode_batch
+from gubernator_tpu.ops.layout import batch_entry
 from gubernator_tpu.parallel import ici
 from gubernator_tpu.parallel import mesh as pmesh
 from gubernator_tpu.runtime.ici_engine import IciEngine, IciEngineConfig
@@ -50,7 +51,7 @@ def test_forged_collision_strands_capped_tick_and_full_tick_heals(monkeypatch):
     num_slots, ways = 64, 2
     num_groups = num_slots // ways
     state = ici.create_ici_state(mesh, num_slots, ways)
-    replica_fn = ici.make_replica_decide(mesh, num_slots, ways)
+    replica_fn = batch_entry(ici.make_replica_decide(mesh, num_slots, ways))
     capped_fn = ici.make_sync_step(mesh, num_slots, ways, max_sync_groups=2)
     full_fn = ici.make_sync_step(mesh, num_slots, ways, max_sync_groups=None)
 

@@ -21,6 +21,7 @@ from gubernator_tpu.api.keys import group_of, key_hash128
 from gubernator_tpu.api.types import Algorithm, Behavior, RateLimitReq
 from gubernator_tpu.models.oracle import OracleEngine
 from gubernator_tpu.ops.encode import encode_batch
+from gubernator_tpu.ops.layout import batch_entry
 from gubernator_tpu.parallel import ici
 from gubernator_tpu.parallel import mesh as pmesh
 
@@ -228,7 +229,9 @@ def _run_fuzz(seed: int, num_slots: int, ways: int, layout: str = "fused"):
     mesh = pmesh.make_mesh(jax.devices()[:NDEV])
     num_groups = num_slots // ways
     state = ici.create_ici_state(mesh, num_slots, ways, layout=layout)
-    replica_fn = ici.make_replica_decide(mesh, num_slots, ways, layout=layout)
+    replica_fn = batch_entry(
+        ici.make_replica_decide(mesh, num_slots, ways, layout=layout)
+    )
     sync_fn = ici.make_sync_step(mesh, num_slots, ways, layout=layout)
     model = IciModel(num_slots, ways)
 
@@ -331,7 +334,7 @@ def test_capped_sync_matches_full(seed, ways):
     num_groups = num_slots // ways
     state_a = ici.create_ici_state(mesh, num_slots, ways)
     state_b = ici.create_ici_state(mesh, num_slots, ways)
-    replica_fn = ici.make_replica_decide(mesh, num_slots, ways)
+    replica_fn = batch_entry(ici.make_replica_decide(mesh, num_slots, ways))
     sync_full = ici.make_sync_step(mesh, num_slots, ways)
     sync_cap = ici.make_sync_step(mesh, num_slots, ways, max_sync_groups=2)
 

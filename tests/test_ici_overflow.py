@@ -33,6 +33,7 @@ import jax
 
 from gubernator_tpu.api.types import Algorithm, Behavior, RateLimitReq
 from gubernator_tpu.ops.encode import encode_batch
+from gubernator_tpu.ops.layout import batch_entry
 from gubernator_tpu.parallel import ici
 from gubernator_tpu.parallel import mesh as pmesh
 
@@ -60,7 +61,9 @@ class _Driver:
         self.num_groups = num_slots // ways
         self.mesh = _mesh()
         self.state = ici.create_ici_state(self.mesh, num_slots, ways)
-        self.decide = ici.make_replica_decide(self.mesh, num_slots, ways)
+        self.decide = batch_entry(
+            ici.make_replica_decide(self.mesh, num_slots, ways)
+        )
         self.sync = ici.make_sync_step(self.mesh, num_slots, ways)
         self.kept = self.dropped = 0
 

@@ -12,6 +12,7 @@ from jax.sharding import Mesh
 from gubernator_tpu.api.types import Algorithm, Behavior, RateLimitReq, Status
 from gubernator_tpu.models.oracle import OracleEngine
 from gubernator_tpu.ops.encode import encode_batch
+from gubernator_tpu.ops.layout import WaveOperand, batch_entry, output_struct
 from gubernator_tpu.parallel import ici
 from gubernator_tpu.parallel import mesh as pmesh
 
@@ -35,7 +36,9 @@ def mk(key, hits=1, **kw):
 def test_sharded_decide_matches_oracle(mesh):
     num_groups = 8 * NDEV
     table = pmesh.create_sharded_table(mesh, num_groups, ways=8)
-    decide_fn = pmesh.make_sharded_decide(mesh, num_groups, ways=8)
+    decide_fn = batch_entry(
+        pmesh.make_sharded_decide(mesh, num_groups, ways=8)
+    )
 
     oracle = OracleEngine()
     reqs = [
@@ -76,7 +79,7 @@ def _global_req(key, hits, limit=1000):
 def test_ici_replica_answers_locally_and_converges(mesh):
     num_slots = 64 * NDEV
     state = ici.create_ici_state(mesh, num_slots)
-    replica_fn = ici.make_replica_decide(mesh, num_slots)
+    replica_fn = batch_entry(ici.make_replica_decide(mesh, num_slots))
     sync_fn = ici.make_sync_step(mesh, num_slots)
 
     # One key, hit from replica (home=3). home != owner for determinism:
@@ -114,7 +117,7 @@ def test_ici_replica_answers_locally_and_converges(mesh):
 def test_ici_hits_from_many_replicas_sum_at_owner(mesh):
     num_slots = 64 * NDEV
     state = ici.create_ici_state(mesh, num_slots)
-    replica_fn = ici.make_replica_decide(mesh, num_slots)
+    replica_fn = batch_entry(ici.make_replica_decide(mesh, num_slots))
     sync_fn = ici.make_sync_step(mesh, num_slots)
 
     key = "account:ici-multi"
@@ -134,7 +137,7 @@ def test_ici_hits_from_many_replicas_sum_at_owner(mesh):
 def test_ici_over_limit_drains(mesh):
     num_slots = 64 * NDEV
     state = ici.create_ici_state(mesh, num_slots)
-    replica_fn = ici.make_replica_decide(mesh, num_slots)
+    replica_fn = batch_entry(ici.make_replica_decide(mesh, num_slots))
     sync_fn = ici.make_sync_step(mesh, num_slots)
 
     key = "account:ici-drain"
@@ -168,7 +171,7 @@ def test_ici_eviction_drops_stale_pending(mesh):
 
     num_slots = 8 * NDEV  # tiny table to find collisions quickly
     state = ici.create_ici_state(mesh, num_slots)
-    replica_fn = ici.make_replica_decide(mesh, num_slots)
+    replica_fn = batch_entry(ici.make_replica_decide(mesh, num_slots))
     sync_fn = ici.make_sync_step(mesh, num_slots)
 
     # find two distinct keys colliding at one slot
@@ -218,7 +221,7 @@ def test_replica_scan_matches_single_steps(mesh):
     num_slots, ways, S = 64 * NDEV, 4, 5
     state_a = ici.create_ici_state(mesh, num_slots, ways)
     state_b = ici.create_ici_state(mesh, num_slots, ways)
-    step_fn = ici.make_replica_decide(mesh, num_slots, ways)
+    step_fn = batch_entry(ici.make_replica_decide(mesh, num_slots, ways))
     scan_fn = ici.make_replica_decide_scan(mesh, num_slots, ways)
 
     num_groups = num_slots // ways
@@ -237,18 +240,18 @@ def test_replica_scan_matches_single_steps(mesh):
         state_a, out = step_fn(state_a, b, h, t)
         outs_a.append(out)
 
-    import jax as _jax
-
-    stacked = _jax.tree.map(lambda *xs: np.stack(xs), *batches)
-    state_b, outs_b = scan_fn(
-        state_b, stacked, np.stack(homes), np.array(nows, dtype=np.int64)
-    )
+    stacked = np.stack([
+        WaveOperand.of(b, t, h).buf for b, h, t in zip(batches, homes, nows)
+    ])
+    state_b, vecs_b = scan_fn(state_b, stacked)
+    vecs_b = np.asarray(vecs_b)
 
     for s, out in enumerate(outs_a):
+        out_b = output_struct(vecs_b[s])
         for f in ("status", "remaining", "reset_time", "limit"):
             np.testing.assert_array_equal(
                 np.asarray(getattr(out, f)),
-                np.asarray(getattr(outs_b, f))[s],
+                np.asarray(getattr(out_b, f)),
                 err_msg=f"step {s} field {f}",
             )
     np.testing.assert_array_equal(

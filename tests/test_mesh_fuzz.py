@@ -14,6 +14,7 @@ from gubernator_tpu.api.keys import group_of, key_hash128
 from gubernator_tpu.api.types import Algorithm, Behavior, RateLimitReq
 from gubernator_tpu.models.oracle import OracleEngine
 from gubernator_tpu.ops.encode import encode_batch
+from gubernator_tpu.ops.layout import batch_entry
 from gubernator_tpu.parallel import mesh as pmesh
 
 NOW = 1_753_700_000_000
@@ -33,7 +34,9 @@ B = 16
 def test_sharded_mesh_fuzz(seed, layout):
     mesh = pmesh.make_mesh(jax.devices()[:NDEV])
     table = pmesh.create_sharded_table(mesh, NUM_GROUPS, ways=4, layout=layout)
-    decide_fn = pmesh.make_sharded_decide(mesh, NUM_GROUPS, ways=4, layout=layout)
+    decide_fn = batch_entry(
+        pmesh.make_sharded_decide(mesh, NUM_GROUPS, ways=4, layout=layout)
+    )
     oracle = OracleEngine()
 
     rng = random.Random(seed)

@@ -178,9 +178,11 @@ class _FailingKernels:
         self.creates += 1
         return self._real.create(*a, **kw)
 
-    def decide(self, *a, **kw):
+    def decide_packed(self, *a, **kw):
+        # the launch the engine makes: one uploaded operand in, one
+        # output vector out
         self.decide_calls += 1
-        out = self._real.decide(*a, **kw)
+        out = self._real.decide_packed(*a, **kw)
         if self.decide_calls == self.fail_on_call:
             raise RuntimeError("injected device failure")
         return out

@@ -153,18 +153,18 @@ def test_completion_thread_compile_is_counted(monkeypatch):
     )
     try:
         assert eng.metrics.cold_compiles == 0
-        real = engine_mod._materialize_out
+        real = engine_mod._read_waves
         fired = {"n": 0}
         # geometry this process never compiled (48 groups, width 12)
         scratch = eng.K.create(48, 4)
 
-        def cold_then_real(o):
+        def cold_then_real(*a, **kw):
             if fired["n"] == 0:
                 fired["n"] = 1
                 eng.K.decide(scratch, RequestBatch.zeros(12), NOW, 4, False)
-            return real(o)
+            return real(*a, **kw)
 
-        monkeypatch.setattr(engine_mod, "_materialize_out", cold_then_real)
+        monkeypatch.setattr(engine_mod, "_read_waves", cold_then_real)
         eng.check_batch([mk(f"c{i}") for i in range(10)])
         assert fired["n"] == 1
         assert eng.metrics.cold_compiles > 0
