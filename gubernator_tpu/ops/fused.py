@@ -1,24 +1,51 @@
-"""Fused slot-table layout: ONE (N, C) tensor, one gather, one scatter.
+"""Fused slot-table layout: ONE tensor of 32-bit words, one gather, one scatter.
 
-Round-3 profiling showed the multi-column SoA kernels lose 2+ orders of
-magnitude at large tables: XLA (CPU at least) fails to elide defensive
-whole-table copies when many same-buffer gather->scatter column chains
-are composed in one program — per-step cost became linear in TABLE size
-(the 10M-key collapse: 341ms/batch at 16M slots where the constituent
-gathers/scatters each cost ~1ms). Fusing every column into a single
-(N, C) int64 tensor reduces the program to ONE row-block gather
-(B, W, C) and ONE row scatter (B, C): 3.6ms/batch at 16M slots on the
-same machine, ~95x faster, and per-step cost is once again O(batch), not
-O(table).
+A decide program reads the lanes' slots (B x W of them), computes on
+int64, and writes one slot a lane: its cost has to follow the lanes, not
+the table. The table is therefore what the device can index in place.
 
-This shape is also what a TPU wants: a group's W x C block is contiguous
-in HBM, so the probe is a coalesced DMA stream rather than W x C strided
-loads; the chosen way's state needs NO second gather (it is a slice of
-the already-fetched block); and the scatter writes one contiguous row
-per lane.
+A TPU has no 64-bit lanes. XLA rewrites every s64 value into two u32
+values and converts a whole int64 *parameter* with `X64SplitLow` /
+`X64SplitHigh` and a whole int64 *result* with `X64Combine`: with the
+table as one (N, C) int64 array, each dispatch read and wrote the table
+three times over to touch a few kilobytes (3.1 ms of a 3.1 ms program at
+2,097,152 slots on a v5e; PERF.md §6, PR 29). So the table is stored as
+the words themselves, and only the gathered (B, W, C) block and the
+(B, C) result row are widened (`join` / `split`: `(hi << 32) | lo`,
+exact for every int64).
 
-Columns (all int64; META packs lru<<4 | status<<2 | algo<<1 | used, as
-in ops/packed.py):
+The words' arrangement is the one the TPU indexes natively. A slot is
+SLOT_WORDS = 32 words: its NCOLS low words, its NCOLS high words, 12 of
+padding (128 B, what the int64 table took too: `s64[N, 10]` sat at 16
+sublanes of 8 B). LINE_SLOTS = 8 consecutive slots make a line, and the
+table is (N / 8, 256) uint32: a minor dimension of two whole 128-lane
+tiles, which the device keeps row-major. A program gathers whole lines
+by their row index and scatter-adds whole lines (`read_windows`,
+`add_windows`): rows are what the device gathers and scatters natively,
+at every table size, in place on the donated buffer. A lane's slot moves
+by `new - old` modulo 2**32 and the rest of its line by zero, so lanes
+whose slots share a line (two 4-way groups) still add up to both.
+
+What was tried and measured on the chip (PERF.md §6, PR 29): `(N, 20)`
+words, two `(N, 10)` halves and `(20, N)` planes all get the slot axis as
+the minor one (20 words pad to 24 sublanes); for a table of up to 262,144
+slots the compiler then re-lays-out the whole table around the gather
+and back after the scatter (two table-sized copies a dispatch), and the
+replica tier's (1, N, 20) shard gets a (1, 128) tiling and is copied
+both ways at every size. Lines have neither: a (1, N / 8, 256) shard is
+the flat table's own tiling. A gather or scatter of *windows inside* a
+line (a slot's 32 words at a word offset) is run as a serial loop of
+`dynamic-slice` / `dynamic-update-slice`, 1-3.5 us a window: hence
+whole lines, and the window picked by mask and sum.
+
+Whole-table reads (snapshots, the census, the admission scan, the sync
+tick, the host views) go through `word` / `col` / `cols` and `_lines`,
+column by column through the transposed table; they are conversions, off
+the decide path. `pack_table` / `unpack_table` (`from_wide` / `to_wide`)
+stay the interchange.
+
+Columns (int64; META packs lru<<4 | status<<2 | algo<<1 | used, as in
+ops/packed.py):
 
   KHI KLO META EXP LIM DUR REM STM BUR INV
 
@@ -32,6 +59,7 @@ cache.go:43-57.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -52,47 +80,163 @@ from gubernator_tpu.ops.packed import (
 )
 
 I64 = jnp.int64
+U32 = jnp.uint32
 
 KHI, KLO, META, EXP, LIM, DUR, REM, STM, BUR, INV = range(10)
 NCOLS = 10
+NWORDS = 2 * NCOLS  # a slot's state: NCOLS low words, then NCOLS high words
+
+# A slot takes SLOT_WORDS words of a line (its NWORDS and padding: 128 B,
+# what the int64 table took at 16 sublanes of 8 B), and a line holds
+# LINE_SLOTS consecutive slots: a whole number of 128-lane tiles, so
+# the device keeps the table row-major and a slot is 128 contiguous
+# bytes of HBM (see the module docstring).
+SLOT_WORDS = 32
+LINE_SLOTS = 8
+
+_LOW = 0xFFFFFFFF
+
+
+def join(lo, hi):
+    """Two uint32 word arrays -> the int64 they spell, two's complement:
+    `(hi << 32) | lo`, exact for every int64."""
+    return (hi.astype(I64) << 32) | lo.astype(I64)
+
+
+def split(x):
+    """int64 -> (low, high) uint32 words, the inverse of `join`. Masked
+    before the narrowing so that every converted value is in range."""
+    x = x.astype(I64)
+    return (x & _LOW).astype(U32), ((x >> 32) & _LOW).astype(U32)
+
+
+def join_words(words):
+    """(..., SLOT_WORDS) uint32 slot words -> (..., NCOLS) int64."""
+    return join(words[..., :NCOLS], words[..., NCOLS:NWORDS])
+
+
+def split_words(x):
+    """(..., NCOLS) int64 -> (..., SLOT_WORDS) uint32 slot words, the
+    padding zero."""
+    lo, hi = split(x)
+    pad = jnp.zeros(lo.shape[:-1] + (SLOT_WORDS - NWORDS,), dtype=U32)
+    return jnp.concatenate([lo, hi, pad], axis=-1)
+
+
+def _per_line(n: int) -> int:
+    """Slots a line of an N-slot table holds: LINE_SLOTS, or for a table
+    whose N is no multiple of it (tests' tiny ones) the most that
+    divides N. The form is a function of N alone."""
+    return math.gcd(n, LINE_SLOTS)
+
+
+def _lines(words):
+    """A list of SLOT_WORDS or fewer (..., N) uint32 word columns, in
+    slot order (the rest is padding) -> the (..., N / per, per *
+    SLOT_WORDS) lines. Column-wise, through the transposed table: a
+    whole-table conversion never makes an array whose minor dimension is
+    a slot's few words, which the device would pad to 128 lanes."""
+    n = words[0].shape[-1]
+    per = _per_line(n)
+    lead = words[0].shape[:-1]
+    zero = jnp.zeros(lead + (per, n // per), dtype=U32)
+    planes = [  # word w of the slot at place k of line l: (..., per, L)
+        jnp.swapaxes(w.astype(U32).reshape(lead + (n // per, per)), -1, -2)
+        for w in words
+    ] + [zero] * (SLOT_WORDS - len(words))
+    t = jnp.stack(planes, axis=-2)  # (..., per, SLOT_WORDS, L)
+    t = t.reshape(lead + (per * SLOT_WORDS, n // per))
+    return jnp.swapaxes(t, -1, -2)
+
+
+def _planes(data):
+    """The table transposed: word w of the slot at place k of line l at
+    [..., k, w, l]. The inverse of `_lines`, column-wise for the same
+    reason."""
+    lines, per = data.shape[-2], data.shape[-1] // SLOT_WORDS
+    return jnp.swapaxes(data, -1, -2).reshape(
+        data.shape[:-2] + (per, SLOT_WORDS, lines)
+    )
+
+
+def _slot_order(plane):
+    """(..., per, L) -> (..., N): slot s is place s % per of line
+    s // per."""
+    return jnp.swapaxes(plane, -1, -2).reshape(
+        plane.shape[:-2] + (plane.shape[-2] * plane.shape[-1],)
+    )
+
+
+# Jitted, so that a host view taken outside a program (the engine's
+# warm-up probe, key pruning) costs one fused pass and not a copy of the
+# table for each step of the conversion.
+@functools.partial(jax.jit, static_argnums=(1,))
+def _word(data, w: int):
+    return _slot_order(_planes(data)[..., w, :])
+
+
+@jax.jit
+def _cols(data):
+    t = _planes(data)
+    return [
+        join(_slot_order(t[..., c, :]), _slot_order(t[..., NCOLS + c, :]))
+        for c in range(NCOLS)
+    ]
 
 
 class FusedTable(NamedTuple):
-    """One (N, NCOLS) int64 tensor; a JAX pytree with a single leaf."""
+    """One (N / LINE_SLOTS, LINE_SLOTS * SLOT_WORDS) uint32 tensor of
+    lines; slot s is words [(s % LINE_SLOTS) * SLOT_WORDS, +SLOT_WORDS)
+    of line s // LINE_SLOTS. A JAX pytree with a single leaf, slot
+    order along its first axis."""
 
-    data: jnp.ndarray  # (N, NCOLS) int64
+    data: jnp.ndarray  # (N / LINE_SLOTS, LINE_SLOTS * SLOT_WORDS) uint32
 
     @property
     def num_slots(self) -> int:
-        return self.data.shape[0]
+        return self.data.shape[-2] * (self.data.shape[-1] // SLOT_WORDS)
 
-    # Wide-compatible host views (live_count, key pruning, tests).
-    # `...` indexing so they also work on a device-stacked (D, N, C)
-    # table (parallel/ici.py IciState).
+    def word(self, w: int) -> jnp.ndarray:
+        """Word `w` of every slot, (..., N) in slot order (a whole-table
+        read: host views and conversions, never the decide path)."""
+        return _word(self.data, w)
+
+    def col(self, c: int) -> jnp.ndarray:
+        """Column `c` of every slot as int64, (..., N)."""
+        return join(self.word(c), self.word(NCOLS + c))
+
+    def cols(self) -> list:
+        return _cols(self.data)
+
+    # Wide-compatible host views (live_count, key pruning, tests). They
+    # also work on a device-stacked (D, ...) table (parallel/ici.py
+    # IciState).
     @property
     def used(self) -> jnp.ndarray:
-        return (self.data[..., META] & META_USED) != 0
+        return (self.word(META) & META_USED) != 0
 
     @property
     def key_hi(self) -> jnp.ndarray:
-        return self.data[..., KHI]
+        return self.col(KHI)
 
     @property
     def key_lo(self) -> jnp.ndarray:
-        return self.data[..., KLO]
+        return self.col(KLO)
 
     @property
     def expire_at(self) -> jnp.ndarray:
-        return self.data[..., EXP]
+        return self.col(EXP)
 
     @property
     def remaining(self) -> jnp.ndarray:
-        return self.data[..., REM]
+        return self.col(REM)
 
     @staticmethod
     def create(num_groups: int, ways: int = 8) -> "FusedTable":
+        n = num_groups * ways
+        per = _per_line(n)
         return FusedTable(
-            data=jnp.zeros((num_groups * ways, NCOLS), dtype=jnp.int64)
+            data=jnp.zeros((n // per, per * SLOT_WORDS), dtype=U32)
         )
 
 
@@ -110,28 +254,33 @@ def pack_table(wide: SlotTable) -> FusedTable:
     cols[STM] = wide.stamp
     cols[BUR] = wide.burst
     cols[INV] = wide.invalid_at
-    return FusedTable(data=jnp.stack(cols, axis=-1))
+    lo, hi = zip(*(split(c) for c in cols))
+    return FusedTable(data=_lines(list(lo) + list(hi)))
+
+
+def _wide(cols) -> SlotTable:
+    """NCOLS int64 columns (each any one shape) -> the wide struct."""
+    meta = cols[META]
+    return SlotTable(
+        key_hi=cols[KHI],
+        key_lo=cols[KLO],
+        used=(meta & META_USED) != 0,
+        algo=((meta >> META_ALGO_SHIFT) & 1).astype(jnp.int8),
+        status=((meta >> META_STATUS_SHIFT) & 3).astype(jnp.int8),
+        limit=cols[LIM],
+        duration=cols[DUR],
+        remaining=cols[REM],
+        stamp=cols[STM],
+        expire_at=cols[EXP],
+        invalid_at=cols[INV],
+        burst=cols[BUR],
+        lru=meta >> META_LRU_SHIFT,
+    )
 
 
 @jax.jit
 def unpack_table(fused: FusedTable) -> SlotTable:
-    d = fused.data
-    meta = d[:, META]
-    return SlotTable(
-        key_hi=d[:, KHI],
-        key_lo=d[:, KLO],
-        used=(meta & META_USED) != 0,
-        algo=((meta >> META_ALGO_SHIFT) & 1).astype(jnp.int8),
-        status=((meta >> META_STATUS_SHIFT) & 3).astype(jnp.int8),
-        limit=d[:, LIM],
-        duration=d[:, DUR],
-        remaining=d[:, REM],
-        stamp=d[:, STM],
-        expire_at=d[:, EXP],
-        invalid_at=d[:, INV],
-        burst=d[:, BUR],
-        lru=meta >> META_LRU_SHIFT,
-    )
+    return _wide(fused.cols())
 
 
 def probe_ways(w_khi, w_klo, w_meta, w_exp, w_inv, batch, now):
@@ -164,6 +313,110 @@ def probe_ways(w_khi, w_klo, w_meta, w_exp, w_inv, batch, now):
     return exists, matched_way, insert_way, cat
 
 
+def _pick(blocks, which):
+    """blocks (M, P, X), which (M,) in [0, P) -> blocks[m, which[m]],
+    (M, X), by mask and sum: elementwise work on what was gathered (the
+    TPU runs a gather of windows inside a row as a serial loop, one
+    `dynamic-slice` a window: 1 us each, PERF.md §6, PR 29)."""
+    if blocks.shape[1] == 1:
+        return blocks[:, 0]
+    ids = jnp.arange(blocks.shape[1], dtype=I64)
+    sel = ids[None, :, None] == which[:, None, None]
+    return jnp.where(sel, blocks, 0).sum(axis=1, dtype=blocks.dtype)
+
+
+def _per(data) -> int:
+    """Slots a line of `data` holds."""
+    return data.shape[-1] // SLOT_WORDS
+
+
+def read_windows(data, first, k: int):
+    """The words of the `k` consecutive slots from slot `first` (M,),
+    (M, k, SLOT_WORDS); `k` divides a line's slots and `first` is a
+    multiple of it. The table is read by whole lines, the rows the
+    device gathers natively, and the window picked from its line. A
+    slot past the end reads the last line (ops/paged.py: a clamped row
+    never matches the key)."""
+    per = _per(data)
+    lines = data[first // per].reshape(-1, per // k, k * SLOT_WORDS)
+    return _pick(lines, (first % per) // k).reshape(-1, k, SLOT_WORDS)
+
+
+def add_windows(data, first, delta):
+    """`data` with `delta` (M, k, SLOT_WORDS) added, modulo 2**32, to the
+    words of the `k` consecutive slots from slot `first` (M,) (`k` and
+    `first` as in read_windows); a slot of N or more (an inactive lane)
+    adds nothing. Whole lines again, zero outside the window: two
+    windows of one line add up to both, so the lanes of a wave need not
+    lie in different lines. In place on a donated table."""
+    per = _per(data)
+    m, k = delta.shape[:2]
+    place = (
+        jnp.arange(per // k, dtype=I64)[None, :, None]
+        == ((first % per) // k)[:, None, None]
+    )
+    lines = jnp.where(place, delta.reshape(m, 1, k * SLOT_WORDS), 0)
+    return data.at[first // per].add(
+        lines.reshape(m, per * SLOT_WORDS), mode="drop"
+    )
+
+
+def _group_windows(data, group, ways: int):
+    """(first, k): the windows of `k` slots from slots `first` that make
+    up groups `group` (B,), in order. One window a group where a line
+    holds whole groups, else (tests' odd geometries) one a slot."""
+    grp_base = group.astype(I64) * ways
+    if _per(data) % ways == 0:
+        return grp_base, ways
+    way_ix = grp_base[:, None] + jnp.arange(ways, dtype=I64)[None, :]
+    return way_ix.reshape(-1), 1
+
+
+def _group_words(data, group, ways: int):
+    """The words of every slot of groups `group` (B,): (B, W,
+    SLOT_WORDS)."""
+    first, k = _group_windows(data, group, ways)
+    return read_windows(data, first, k).reshape(-1, ways, SLOT_WORDS)
+
+
+def _gather_groups(data, group, ways: int):
+    """The rows of groups `group` (B,), widened to int64: (B, W, NCOLS).
+    The only place a decide program reads the table, and what it reads
+    is the lanes' lines."""
+    return join_words(_group_words(data, group, ways))
+
+
+def _scatter(data, idx, rows, old=None):
+    """Write (B, NCOLS) int64 `rows` at slots `idx` (B,), which hold
+    `old` (read here if not given): each slot's words move by new - old
+    (see add_windows)."""
+    if old is None:
+        old = join_words(read_windows(data, idx, 1)[:, 0])
+    delta = split_words(rows) - split_words(old)
+    return add_windows(data, idx, delta[:, None, :])
+
+
+def take_groups(table: FusedTable, gids, ways: int) -> FusedTable:
+    """The table of groups `gids` (C,) alone, in that order: lines of one
+    group each. An index past the end reads slots of the last line."""
+    words = _group_words(table.data, gids, ways)
+    return FusedTable(data=words.reshape(-1, ways * SLOT_WORDS))
+
+
+def put_groups(
+    table: FusedTable, gids, ways: int, part: FusedTable
+) -> FusedTable:
+    """`table` with the groups of `part` (whatever its line width)
+    written back at `gids`; a group past the end writes nothing."""
+    data = table.data
+    new = part.data.reshape(-1, ways, SLOT_WORDS)
+    delta = new - _group_words(data, gids, ways)
+    first, k = _group_windows(data, gids, ways)
+    return FusedTable(
+        data=add_windows(data, first, delta.reshape(-1, k, SLOT_WORDS))
+    )
+
+
 def _probe(rows, batch, now):
     """Way selection over a gathered (B, W, C) block (see probe_ways)."""
     return probe_ways(
@@ -178,11 +431,10 @@ def _decide_fused_impl(table: FusedTable, batch: RequestBatch, now, *, ways: int
     with jax.named_scope("layout_in"):
         now = jnp.asarray(now, dtype=I64)
         data = table.data
-        n = data.shape[0]
+        n = table.num_slots
         grp_base = batch.group.astype(I64) * ways
-        way_ix = grp_base[:, None] + jnp.arange(ways, dtype=I64)[None, :]
 
-        rows = data[way_ix]  # (B, W, C) — the ONE gather
+        rows = _gather_groups(data, batch.group, ways)  # (B, W, C) — the ONE gather
 
     with jax.named_scope("probe"):
         exists, matched_way, insert_way, cat = _probe(rows, batch, now)
@@ -268,7 +520,7 @@ def _decide_fused_impl(table: FusedTable, batch: RequestBatch, now, *, ways: int
         new_row = jnp.stack([c.astype(I64) for c in cols], axis=-1)  # (B, C)
 
         idx = jnp.where(batch.active, slot, n)
-        new_data = data.at[idx].set(new_row, mode="drop")  # the ONE scatter
+        new_data = _scatter(data, idx, new_row, st_row)  # the ONE scatter
 
     with jax.named_scope("layout_out"):
         act = batch.active
@@ -309,9 +561,7 @@ def decide_scan_fused(table: FusedTable, batches: RequestBatch, nows, ways: int 
 def probe_exists_fused(table: FusedTable, key_hi, key_lo, group, now, ways: int = 8):
     """Residency probe (store read-through seam), fused layout."""
     now = jnp.asarray(now, dtype=I64)
-    grp_base = group.astype(I64) * ways
-    way_ix = grp_base[:, None] + jnp.arange(ways, dtype=I64)[None, :]
-    rows = table.data[way_ix]
+    rows = _gather_groups(table.data, group, ways)
     w_meta = rows[..., META]
     w_used = (w_meta & META_USED) != 0
     w_invalid = rows[..., INV]
@@ -334,29 +584,15 @@ def gather_rows_fused(table: FusedTable, slots) -> SlotTable:
     n = table.num_slots
     safe = jnp.clip(slots, 0, n - 1)
     valid = slots < n
-    rows = jnp.where(valid[:, None], table.data[safe], 0)  # (B, C)
-    meta = rows[:, META]
-    return SlotTable(
-        key_hi=rows[:, KHI],
-        key_lo=rows[:, KLO],
-        used=(meta & META_USED) != 0,
-        algo=((meta >> META_ALGO_SHIFT) & 1).astype(jnp.int8),
-        status=((meta >> META_STATUS_SHIFT) & 3).astype(jnp.int8),
-        limit=rows[:, LIM],
-        duration=rows[:, DUR],
-        remaining=rows[:, REM],
-        stamp=rows[:, STM],
-        expire_at=rows[:, EXP],
-        invalid_at=rows[:, INV],
-        burst=rows[:, BUR],
-        lru=meta >> META_LRU_SHIFT,
-    )
+    rows = join_words(read_windows(table.data, safe, 1)[:, 0])
+    rows = jnp.where(valid[:, None], rows, 0)  # (B, C)
+    return _wide([rows[:, c] for c in range(NCOLS)])
 
 
 def _inject_fused_impl(table: FusedTable, items, now, ways: int):
     now = jnp.asarray(now, dtype=I64)
     data = table.data
-    n = data.shape[0]
+    n = table.num_slots
     batch_like = RequestBatch.zeros(items.key_hi.shape[0])._replace(
         key_hi=items.key_hi,
         key_lo=items.key_lo,
@@ -364,8 +600,7 @@ def _inject_fused_impl(table: FusedTable, items, now, ways: int):
         active=items.active,
     )
     grp_base = batch_like.group.astype(I64) * ways
-    way_ix = grp_base[:, None] + jnp.arange(ways, dtype=I64)[None, :]
-    rows = data[way_ix]
+    rows = _gather_groups(data, batch_like.group, ways)
     exists, matched_way, insert_way, _cat = _probe(rows, batch_like, now)
     way = jnp.where(exists, matched_way, insert_way)
     slot = grp_base + way
@@ -399,7 +634,7 @@ def _inject_fused_impl(table: FusedTable, items, now, ways: int):
     new_row = jnp.stack([c.astype(I64) for c in cols], axis=-1)
     idx = jnp.where(items.active, slot, n)
     return (
-        FusedTable(data=data.at[idx].set(new_row, mode="drop")),
+        FusedTable(data=_scatter(data, idx, new_row, st_row)),
         evicted_hi,
         evicted_lo,
     )

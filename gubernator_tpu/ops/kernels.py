@@ -5,9 +5,10 @@ The engine selects a table layout by name (EngineConfig.layout):
 - "wide": one int64 column per field (ops/layout.py + ops/decide.py) —
   the reference-shaped baseline.
 - "packed": narrowed/packed columns with a 3-gather probe (ops/packed.py).
-- "fused": ONE (N, C) tensor, one gather + one scatter (ops/fused.py) —
-  the fastest at scale (the SoA layouts hit XLA defensive whole-table
-  copies; see ops/fused.py's module docstring) and the flagship default.
+- "fused": ONE tensor of 32-bit words, one gather + one scatter of the
+  lanes' slots (ops/fused.py) — the fastest at scale (a program's cost
+  follows its lanes, not the table; see ops/fused.py's module docstring)
+  and the flagship default.
 - "narrow": fused v2 — a split-word (N, 9) tensor (ops/narrow.py)
   ordered so way selection reads only a 5-column row PREFIX (40 B/way,
   half of fused's probe DMA) and the int32-clamped counters bit-pack
@@ -25,6 +26,7 @@ import functools
 from typing import NamedTuple
 
 import jax
+import jax.numpy as jnp
 
 # The registry every layout-selection surface validates against
 # (EngineConfig.layout, GUBER_TABLE_LAYOUT / GUBER_ICI_LAYOUT, bench.py
@@ -270,6 +272,11 @@ def packed_decide(layout: str):
     return decide_packed
 
 
+def _group_slots(gids, ways: int):
+    """Every slot of groups `gids` (C,), group by group: (C * ways,)."""
+    return (gids[:, None] * ways + jnp.arange(ways, dtype=gids.dtype)).reshape(-1)
+
+
 class RawKernels(NamedTuple):
     """UNJITTED impls for composition inside shard_map/pjit (the
     multi-device tier, parallel/mesh.py + parallel/ici.py). The jitted
@@ -287,6 +294,21 @@ class RawKernels(NamedTuple):
     inject: object  # (table, items, now, ways) -> (table, ehi, elo)
     to_wide: object  # table -> SlotTable (traceable)
     from_wide: object  # SlotTable -> table (traceable)
+    # The sync tick's compaction and fingerprints (parallel/ici.py),
+    # layout-native: the table of groups `gids` (C,) alone, `ways` slots
+    # each (an index past the end reads the last slots); `table` with
+    # such a table written back at those groups (an index past the end
+    # writes nothing); a pytree of per-slot (N, ...) arrays holding the
+    # state.
+    take_groups: object = lambda t, gids, ways: jax.tree.map(
+        lambda a: jnp.take(a, _group_slots(gids, ways), axis=0, mode="clip"),
+        t,
+    )
+    put_groups: object = lambda t, gids, ways, part: jax.tree.map(
+        lambda full, p: full.at[_group_slots(gids, ways)].set(p, mode="drop"),
+        t, part,
+    )
+    slot_leaves: object = lambda t: t
 
 
 def get_census(layout: str, ways: int, **kwargs):
@@ -383,6 +405,9 @@ def get_raw_kernels(layout: str) -> RawKernels:
             ),
             to_wide=_f.unpack_table,
             from_wide=_f.pack_table,
+            take_groups=_f.take_groups,
+            put_groups=_f.put_groups,
+            slot_leaves=_f.FusedTable.cols,
         )
     elif layout == "narrow":
         from gubernator_tpu.ops import narrow as _n

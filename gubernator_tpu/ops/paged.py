@@ -40,8 +40,10 @@ the page's slot range as wide (SlotTable) rows and `write_page` packs
 them back with `lax.dynamic_update_slice` at the new physical offset.
 Way order and LRU stamps survive byte-for-byte, so demote -> promote is
 an identity on table state (acceptance: zero-loss round trip). Every
-layout keeps axis 0 == num_slots on every pytree leaf, which is what
-lets the page ops be one generic `jax.tree.map` over the native table.
+layout keeps slot order along axis 0 of every pytree leaf, a whole
+number of slots a row (one, or the fused layout's line of eight), which
+is what lets the page ops be one generic `jax.tree.map` over the native
+table (`zero_region`, `write_region`).
 """
 
 from __future__ import annotations
@@ -128,6 +130,51 @@ class PagedKernels(NamedTuple):
     num_phys_pages: int
     num_logical_pages: int
     num_logical_groups: int
+
+
+def _leaf_start(leaf, num_slots: int, start, slots: int):
+    """Start indices, along every axis of `leaf`, of the `slots` slots
+    from slot `start`. A leaf keeps slot order along its first axis, a
+    whole number of slots a row: one, or the fused layout's line of
+    eight."""
+    per = num_slots // leaf.shape[0]
+    if slots % per:
+        raise ValueError(
+            f"a page of {slots} slots does not divide into rows of {per} slots"
+        )
+    z = jnp.asarray(0, dtype=jnp.int32)
+    return (jnp.asarray(start // per, dtype=jnp.int32),) + (z,) * (
+        leaf.ndim - 1
+    )
+
+
+def zero_region(data, start, slots: int):
+    """`data` (a layout-native table) with the `slots` slots from slot
+    `start` zeroed, leaf by leaf."""
+    n = data.num_slots
+
+    def z(leaf):
+        rows = slots * leaf.shape[0] // n
+        blk = jnp.zeros((rows,) + leaf.shape[1:], dtype=leaf.dtype)
+        return jax.lax.dynamic_update_slice(
+            leaf, blk, _leaf_start(leaf, n, start, slots)
+        )
+
+    return jax.tree.map(z, data)
+
+
+def write_region(data, rows, start):
+    """`data` with the layout-native table `rows` written over the slots
+    from slot `start` (positional: way order and LRU stamps survive)."""
+    n = data.num_slots
+
+    def upd(leaf, r):
+        r = r.astype(leaf.dtype).reshape((-1,) + leaf.shape[1:])
+        return jax.lax.dynamic_update_slice(
+            leaf, r, _leaf_start(leaf, n, start, rows.num_slots)
+        )
+
+    return jax.tree.map(upd, data, rows)
 
 
 def logical_page_of(group: int, groups_per_page: int) -> int:
@@ -235,29 +282,16 @@ def make_paged_kernels(
         g = _xlate(pt.page_map, group)
         return base.probe_exists(pt.data, hi, lo, g, now, ways)
 
-    def _starts(start, ndim):
-        z = jnp.asarray(0, dtype=jnp.int32)
-        return (jnp.asarray(start, dtype=jnp.int32),) + (z,) * (ndim - 1)
-
-    def _zero_region(data, start):
-        def z(leaf):
-            blk = jnp.zeros((page_slots,) + leaf.shape[1:], dtype=leaf.dtype)
-            return jax.lax.dynamic_update_slice(
-                leaf, blk, _starts(start, leaf.ndim)
-            )
-
-        return jax.tree.map(z, data)
-
     @functools.partial(jax.jit, donate_argnums=(0,))
     def _bind_page(pt, lp, pp):
-        data = _zero_region(pt.data, pp * page_slots)
+        data = zero_region(pt.data, pp * page_slots, page_slots)
         return PagedTable(data, pt.page_map.at[lp].set(pp))
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def _unbind_page(pt, lp, pp):
         # Zero the evacuated frame too: census and key-string pruning
         # scan the PHYSICAL table and must not see ghost rows.
-        data = _zero_region(pt.data, pp * page_slots)
+        data = zero_region(pt.data, pp * page_slots, page_slots)
         return PagedTable(data, pt.page_map.at[lp].set(jnp.int32(-1)))
 
     @jax.jit
@@ -268,14 +302,7 @@ def make_paged_kernels(
     @functools.partial(jax.jit, donate_argnums=(0,))
     def _write_page(pt, lp, pp, rows_wide):
         rows = raw.from_wide(SlotTable(*rows_wide))
-        start = pp * page_slots
-
-        def upd(leaf, r):
-            return jax.lax.dynamic_update_slice(
-                leaf, r.astype(leaf.dtype), _starts(start, leaf.ndim)
-            )
-
-        data = jax.tree.map(upd, pt.data, rows)
+        data = write_region(pt.data, rows, pp * page_slots)
         return PagedTable(data, pt.page_map.at[lp].set(pp))
 
     def _create(*_a, **_k) -> PagedTable:

@@ -406,15 +406,16 @@ def _scalars_vector(out: DecideOutput, scan: WaveScan) -> jnp.ndarray:
 
 def _reference_wave(layout, data, page_map, batch, now, *, ways, gpp):
     """Plain-XLA lowering with the mosaic kernel's read discipline
-    translated to gather shapes: a probe gather of ONLY the way-
-    selection columns plus ONE full-row gather at the selected slot —
+    translated to gather shapes; for narrow: a probe gather of ONLY the
+    way-selection columns plus ONE full-row gather at the selected slot —
     never a full (B, W, C) block off HBM. The gathered pieces are
     reassembled into the (B, W, C) layout `_wave_compute` expects (true
     probe columns everywhere, selected-row state one-hot-placed at its
     way, zeros elsewhere); since the shared compute body reads state
     columns only through `_pick_way`'s one-hot reduce, the assembly is
     bit-exact with a full gather while moving ~half the bytes."""
-    n = data.shape[0]
+    fused = layout == "fused"
+    n = _f.FusedTable(data).num_slots if fused else data.shape[0]
     if page_map is not None:
         g32 = batch.group.astype(I32)
         pp = page_map[g32 // gpp]
@@ -428,29 +429,26 @@ def _reference_wave(layout, data, page_map, batch, now, *, ways, gpp):
         + jnp.arange(ways, dtype=I64)[None, :]
     )
     res_bw = resident[:, None]
-    if layout == "narrow":
-        # probe columns ARE the row prefix (the layout's design)
-        hot = jnp.where(
-            res_bw[..., None], _n._gather_cols(data, way_ix, _n.N_HOT), 0
+    if fused:
+        # A fused slot is one window of words (ops/fused.py): the probe
+        # reads whole slots, which is the block the kernels hold in VMEM.
+        rows = jnp.where(
+            res_bw[..., None], _f._gather_groups(data, phys_grp, ways), 0
         )
-        probe = {
-            _n.KHI: hot[..., _n.KHI], _n.KLO: hot[..., _n.KLO],
-            _n.META: hot[..., _n.META], _n.EXP: hot[..., _n.EXP],
-            _n.INV: hot[..., _n.INV],
-        }
-    else:
-        # fused: KHI KLO META EXP are the prefix; INV sits at col 9
-        hot = jnp.where(
-            res_bw[..., None], _n._gather_cols(data, way_ix, 4), 0
+        new_row, out, scan = _wave_compute(
+            layout, rows, batch, now, n, resident, phys_grp, ways
         )
-        probe = {
-            _f.KHI: hot[..., _f.KHI], _f.KLO: hot[..., _f.KLO],
-            _f.META: hot[..., _f.META], _f.EXP: hot[..., _f.EXP],
-            _f.INV: jnp.where(res_bw, data[way_ix, _f.INV], 0),
-        }
-        KHI, KLO, META, EXPC, INVC = _f.KHI, _f.KLO, _f.META, _f.EXP, _f.INV
-    if layout == "narrow":
-        KHI, KLO, META, EXPC, INVC = _n.KHI, _n.KLO, _n.META, _n.EXP, _n.INV
+        return _f._scatter(data, out.slot, new_row), out, scan
+    # narrow: the probe columns ARE the row prefix (the layout's design)
+    hot = jnp.where(
+        res_bw[..., None], _n._gather_cols(data, way_ix, _n.N_HOT), 0
+    )
+    probe = {
+        _n.KHI: hot[..., _n.KHI], _n.KLO: hot[..., _n.KLO],
+        _n.META: hot[..., _n.META], _n.EXP: hot[..., _n.EXP],
+        _n.INV: hot[..., _n.INV],
+    }
+    KHI, KLO, META, EXPC, INVC = _n.KHI, _n.KLO, _n.META, _n.EXP, _n.INV
     # Same way selection _wave_compute re-derives from the same probe
     # dict (same function, same inputs — XLA CSEs the duplicate); the
     # selected-row gather this slot feeds is therefore bit-identical to
@@ -480,8 +478,18 @@ _VMEM_COLS = (
 )
 
 
-def _make_kernel(layout, ways, ncols, block_b, n, paged, gpp):
-    """Build the kernel body for one static configuration."""
+def _make_kernel(layout, ways, block_b, n, paged, gpp, per_line):
+    """Build the kernel body for one static configuration. A fused table
+    is lines of `per_line` slots of uint32 words (ops/fused.py): its
+    slots are loaded and stored one window each and widened to int64 in
+    VMEM; a narrow table is (n, NCOLS) int64 rows (`per_line` 0)."""
+    words = _f.SLOT_WORDS
+
+    def slot_window(ref, slot):
+        """The window of `ref` (a fused table) that is slot `slot`."""
+        return ref.at[
+            pl.ds(slot // per_line, 1), pl.ds((slot % per_line) * words, words)
+        ]
 
     def kernel(*refs):
         it = iter(refs)
@@ -492,7 +500,7 @@ def _make_kernel(layout, ways, ncols, block_b, n, paged, gpp):
         now_ref = next(it)  # SMEM (1,) i64
         pmap_ref = next(it) if paged else None  # SMEM (n_log_pages,) i32
         vmem_cols = [next(it) for _ in _VMEM_COLS]  # VMEM (block_b,) i64
-        data_ref = next(it)  # ANY (n+? rows, C) — aliased input
+        data_ref = next(it)  # ANY, the table's own shape — aliased input
         out_data_ref = next(it)  # ANY — aliased output (same buffer)
         status_ref = next(it)  # VMEM (block_b,) i32
         limit_ref = next(it)
@@ -503,8 +511,8 @@ def _make_kernel(layout, ways, ncols, block_b, n, paged, gpp):
         elo_ref = next(it)
         freed_ref = next(it)  # VMEM (block_b,) i32
         scal_ref = next(it)  # VMEM (1, N_SCAL) i64, accumulated
-        rows = next(it)  # VMEM scratch (block_b, W, C) i64
-        newrow = next(it)  # VMEM scratch (block_b, C) i64
+        rows = next(it)  # VMEM scratch (block_b, W, C) i64 / (.., words) u32
+        newrow = next(it)  # VMEM scratch (block_b, C) i64 / (.., words) u32
         physg = next(it)  # SMEM scratch (block_b,) i32
         res = next(it)  # SMEM scratch (block_b,) i32
         slotg = next(it)  # SMEM scratch (block_b,) i32
@@ -517,11 +525,19 @@ def _make_kernel(layout, ways, ncols, block_b, n, paged, gpp):
 
         now = now_ref[0]
 
-        def _load_copy(j):
+        def _load_copies(j):
             start = physg[j] * ways
-            return pltpu.make_async_copy(
-                data_ref.at[pl.ds(start, ways), :], rows.at[j], lsem.at[j]
-            )
+            if not per_line:
+                return [pltpu.make_async_copy(
+                    data_ref.at[pl.ds(start, ways), :], rows.at[j], lsem.at[j]
+                )]
+            return [
+                pltpu.make_async_copy(
+                    slot_window(data_ref, start + k),
+                    rows.at[j, pl.ds(k, 1), :], lsem.at[j],
+                )
+                for k in range(ways)
+            ]
 
         # Phase 1: translate + start one DMA per lane. The page-map
         # lookup happens HERE, as a scalar SMEM read folded into the DMA
@@ -539,13 +555,14 @@ def _make_kernel(layout, ways, ncols, block_b, n, paged, gpp):
 
             @pl.when(res[j] != 0)
             def _go():
-                _load_copy(j).start()
+                for c in _load_copies(j):
+                    c.start()
 
             @pl.when(res[j] == 0)
             def _zero():
                 # Sentinel lane: treat the group as empty (deterministic
                 # way-choice metadata; see module docstring).
-                rows[j] = jnp.zeros((ways, ncols), dtype=I64)
+                rows[j] = jnp.zeros(rows.shape[1:], dtype=rows.dtype)
 
             return 0
 
@@ -554,7 +571,8 @@ def _make_kernel(layout, ways, ncols, block_b, n, paged, gpp):
         def wait(j, _):
             @pl.when(res[j] != 0)
             def _w():
-                _load_copy(j).wait()
+                for c in _load_copies(j):
+                    c.wait()
 
             return 0
 
@@ -580,10 +598,11 @@ def _make_kernel(layout, ways, ncols, block_b, n, paged, gpp):
             active=act,
         )
         resident = res[...] != 0
+        block = _f.join_words(rows[...]) if per_line else rows[...]
         new_row, out, scan = _wave_compute(
-            layout, rows[...], batch, now, n, resident, physg[...], ways
+            layout, block, batch, now, n, resident, physg[...], ways
         )
-        newrow[...] = new_row
+        newrow[...] = _f.split_words(new_row) if per_line else new_row
         status_ref[...] = out.status.astype(I32)
         limit_ref[...] = out.limit
         remaining_ref[...] = out.remaining
@@ -602,7 +621,8 @@ def _make_kernel(layout, ways, ncols, block_b, n, paged, gpp):
         def _store_copy(j):
             return pltpu.make_async_copy(
                 newrow.at[pl.ds(j, 1), :],
-                out_data_ref.at[pl.ds(slotg[j], 1), :],
+                slot_window(out_data_ref, slotg[j]) if per_line
+                else out_data_ref.at[pl.ds(slotg[j], 1), :],
                 ssem.at[j],
             )
 
@@ -633,8 +653,16 @@ def _make_kernel(layout, ways, ncols, block_b, n, paged, gpp):
 
 @functools.lru_cache(maxsize=None)
 def _build_pallas_call(
-    layout, ways, ncols, n, bp, block_b, paged, gpp, n_log_pages, interpret
+    layout, ways, data_shape, bp, block_b, paged, gpp, n_log_pages, interpret
 ):
+    if layout == "fused":
+        per_line = data_shape[-1] // _f.SLOT_WORDS
+        n = data_shape[0] * per_line
+        row, dtype = (_f.SLOT_WORDS,), jnp.uint32
+    else:
+        per_line = 0
+        n = data_shape[0]
+        row, dtype = data_shape[1:], I64
     nb = bp // block_b
     grid = (nb,)
 
@@ -669,7 +697,7 @@ def _build_pallas_call(
         pl.BlockSpec((1, N_SCAL), lambda i: (0, 0)),  # scalars, accumulated
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((n, ncols), I64),
+        jax.ShapeDtypeStruct(data_shape, dtype),
         jax.ShapeDtypeStruct((bp,), I32),
         jax.ShapeDtypeStruct((bp,), I64),
         jax.ShapeDtypeStruct((bp,), I64),
@@ -681,15 +709,15 @@ def _build_pallas_call(
         jax.ShapeDtypeStruct((1, N_SCAL), I64),
     ]
     scratch_shapes = [
-        pltpu.VMEM((block_b, ways, ncols), I64),
-        pltpu.VMEM((block_b, ncols), I64),
+        pltpu.VMEM((block_b, ways) + row, dtype),
+        pltpu.VMEM((block_b,) + row, dtype),
         pltpu.SMEM((block_b,), I32),
         pltpu.SMEM((block_b,), I32),
         pltpu.SMEM((block_b,), I32),
         pltpu.SemaphoreType.DMA((block_b,)),
         pltpu.SemaphoreType.DMA((block_b,)),
     ]
-    kernel = _make_kernel(layout, ways, ncols, block_b, n, paged, gpp)
+    kernel = _make_kernel(layout, ways, block_b, n, paged, gpp, per_line)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -713,12 +741,11 @@ def _pad_to(x, bp):
 def _pallas_wave(
     layout, data, page_map, batch, now, *, ways, gpp, block_b, interpret
 ):
-    n, ncols = data.shape
     b = batch.key_hi.shape[0]
     bp = -(-b // block_b) * block_b
     paged = page_map is not None
     call = _build_pallas_call(
-        layout, ways, ncols, n, bp, block_b, paged, gpp,
+        layout, ways, data.shape, bp, block_b, paged, gpp,
         page_map.shape[0] if paged else 0, interpret,
     )
     pb = jax.tree.map(lambda x: _pad_to(jnp.asarray(x, x.dtype), bp), batch)

@@ -45,6 +45,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from gubernator_tpu.api.types import Behavior
 from gubernator_tpu.models.bucket import FIXED_SHIFT
+from gubernator_tpu.ops.fused import join, split
 from gubernator_tpu.ops.kernels import get_raw_kernels
 from gubernator_tpu.ops.layout import (
     SlotTable,
@@ -66,8 +67,11 @@ class IciState(NamedTuple):
     """Per-device replica tables + pending hit deltas.
 
     Every table leaf is stacked (D, ...) and sharded on the device
-    axis; `pending` is (D, N) int64 hit deltas awaiting the next sync,
-    recorded at the slot where the key resides on THAT device. `tick`
+    axis; `pending` is the hit deltas awaiting the next sync, one int64
+    a slot, recorded at the slot where the key resides on THAT device,
+    and stored as (D, 2, N) uint32: the low words, then the high words
+    (ops/fused.py `join` / `split`: a TPU converts a whole int64
+    parameter and result on every dispatch, words it indexes in place). `tick`
     is a (D,) sync-tick counter (identical on every device) — the
     capped sync's scan rotation mixes it with `now` so back-to-back
     ticks at a coarse timestamp still rotate over a backlog.
@@ -100,12 +104,19 @@ def create_ici_state(
         sharding, metrics=metrics,
     )
     pending = transfer.device_put(
-        jnp.zeros((n_dev, num_slots), dtype=I64), sharding, metrics=metrics
+        jnp.zeros((n_dev, 2, num_slots), dtype=jnp.uint32), sharding,
+        metrics=metrics,
     )
     tick = transfer.device_put(
         jnp.zeros((n_dev,), dtype=I64), sharding, metrics=metrics
     )
     return IciState(table=stacked, pending=pending, tick=tick)
+
+
+def pending_hits(state: IciState) -> jnp.ndarray:
+    """`state.pending` as what it spells: (D, N) int64 hit deltas (a
+    whole-array conversion, for tools and tests)."""
+    return join(state.pending[:, 0], state.pending[:, 1])
 
 
 def _squeeze(tree):
@@ -134,7 +145,7 @@ def _replica_step(RK, ways, groups_per, num_slots, dev, tbl, pending,
         (out.evicted_hi != 0) | (out.evicted_lo != 0) | out.freed
     )
     evict_idx = jnp.where(drop, out.slot, num_slots)
-    pending = pending.at[evict_idx].set(0, mode="drop")
+    pending = pending.at[:, evict_idx].set(0, mode="drop")
 
     # Accumulate deltas for lanes I answered but do not own
     # (reference globalManager.QueueHit, global.go:74-78).
@@ -142,7 +153,12 @@ def _replica_step(RK, ways, groups_per, num_slots, dev, tbl, pending,
     is_global = (batch.behavior & int(Behavior.GLOBAL)) != 0
     pend_mask = mine & ~owned & is_global & (batch.hits != 0)
     idx = jnp.where(pend_mask, out.slot, num_slots)
-    pending = pending.at[idx].add(batch.hits, mode="drop")
+    # A 64-bit add on the two words: read, add, write back. A wave's
+    # lanes lie in distinct groups, hence distinct slots (the table's
+    # own scatter needs that already), so no delta is written twice.
+    held = pending[:, jnp.minimum(idx, num_slots - 1)]
+    added = jnp.stack(split(join(held[0], held[1]) + batch.hits))
+    pending = pending.at[:, idx].set(added, mode="drop")
     return tbl, pending, out
 
 
@@ -269,7 +285,7 @@ def make_inject_replicas(
             & (tbl.key_lo[way_ix] == items.key_lo[:, None])
         )
         idx = jnp.where(landed, way_ix, num_slots).reshape(-1)
-        pending = pending.at[idx].set(0, mode="drop")
+        pending = pending.at[:, idx].set(0, mode="drop")
         return IciState(
             table=_unsqueeze(tbl), pending=pending[None], tick=state.tick
         )
@@ -344,7 +360,7 @@ def make_sync_step(
         collectives."""
         accs = [jnp.zeros(num_slots, jnp.uint64) for _ in range(2)]
         col = 0
-        for leaf in jax.tree_util.tree_leaves(native):
+        for leaf in jax.tree_util.tree_leaves(RK.slot_leaves(native)):
             x = leaf.reshape(num_slots, -1).astype(jnp.uint64)
             for s in range(2):
                 salts = (
@@ -601,7 +617,7 @@ def make_sync_step(
     def local(state: IciState, now):
         dev = jax.lax.axis_index(AXIS).astype(I64)
         native = _squeeze(state.table)
-        pending = state.pending[0]
+        pending = join(state.pending[0, 0], state.pending[0, 1])
         psum = lambda x: jax.lax.psum(x, AXIS)  # noqa: E731
 
         if not capped:
@@ -617,7 +633,7 @@ def make_sync_step(
             return (
                 IciState(
                     table=_unsqueeze(RK.from_wide(new_t)),
-                    pending=new_p[None],
+                    pending=jnp.stack(split(new_p))[None],
                     tick=state.tick + 1,
                 ),
                 diag,
@@ -682,18 +698,14 @@ def make_sync_step(
             gids[:, None] * W + jnp.arange(W, dtype=I64)[None, :]
         ).reshape(C * W)
 
-        gather = lambda a: jnp.take(a, slots, axis=0, mode="clip")  # noqa: E731
-        native_c = jax.tree.map(gather, native)
-        pending_c = gather(pending)
+        native_c = RK.take_groups(native, gids, W)
+        pending_c = jnp.take(pending, slots, axis=0, mode="clip")
         new_tc, new_pc, kept_c, dropped_c = merge_block(
             dev, RK.to_wide(native_c), pending_c, gids, valid, now, psum
         )
         native_new_c = RK.from_wide(new_tc)
         # Sentinel groups scatter to slot >= num_slots -> dropped.
-        new_native = jax.tree.map(
-            lambda full, comp: full.at[slots].set(comp, mode="drop"),
-            native, native_new_c,
-        )
+        new_native = RK.put_groups(native, gids, W, native_new_c)
         new_pending = pending.at[slots].set(new_pc, mode="drop")
 
         # kept/dropped counters from UNSELECTED overflow groups carry
@@ -705,7 +717,8 @@ def make_sync_step(
         diag = jnp.stack([kept_c, dropped_c, backlog, merged])[None, :]
         return (
             IciState(
-                table=_unsqueeze(new_native), pending=new_pending[None],
+                table=_unsqueeze(new_native),
+                pending=jnp.stack(split(new_pending))[None],
                 tick=state.tick + 1,
             ),
             diag,

@@ -277,7 +277,12 @@ def _make_mesh_paged_kernels(
 ):
     # Lazy import mirrors ops/kernels.get_paged_kernels: flat mesh tables
     # never pay for the paged module.
-    from gubernator_tpu.ops.paged import PagedKernels, PagedTable
+    from gubernator_tpu.ops.paged import (
+        PagedKernels,
+        PagedTable,
+        write_region,
+        zero_region,
+    )
 
     n_dev = mesh.devices.size
     if groups_per_page <= 0:
@@ -338,19 +343,6 @@ def _make_mesh_paged_kernels(
         g = _xlate(pt.page_map, group)
         return base.probe_exists(pt.data, hi, lo, g, now, ways)
 
-    def _starts(start, ndim):
-        z = jnp.asarray(0, dtype=jnp.int32)
-        return (jnp.asarray(start, dtype=jnp.int32),) + (z,) * (ndim - 1)
-
-    def _zero_region(data, start):
-        def z(leaf):
-            blk = jnp.zeros((page_slots,) + leaf.shape[1:], dtype=leaf.dtype)
-            return jax.lax.dynamic_update_slice(
-                leaf, blk, _starts(start, leaf.ndim)
-            )
-
-        return jax.tree.map(z, data)
-
     # Page moves are the single-chip programs with output shardings
     # pinned: the physical table stays sharded along the slot axis and
     # the page map stays replicated, regardless of what GSPMD would
@@ -359,7 +351,7 @@ def _make_mesh_paged_kernels(
         jax.jit, donate_argnums=(0,), out_shardings=pt_sharding
     )
     def _bind_page(pt, lp, pp):
-        data = _zero_region(pt.data, pp * page_slots)
+        data = zero_region(pt.data, pp * page_slots, page_slots)
         return PagedTable(data, pt.page_map.at[lp].set(pp))
 
     @functools.partial(
@@ -368,7 +360,7 @@ def _make_mesh_paged_kernels(
     def _unbind_page(pt, lp, pp):
         # Zero the evacuated frame: census and key-string pruning scan
         # the PHYSICAL table and must not see ghost rows.
-        data = _zero_region(pt.data, pp * page_slots)
+        data = zero_region(pt.data, pp * page_slots, page_slots)
         return PagedTable(data, pt.page_map.at[lp].set(jnp.int32(-1)))
 
     @functools.partial(jax.jit, out_shardings=repl)
@@ -381,14 +373,7 @@ def _make_mesh_paged_kernels(
     )
     def _write_page(pt, lp, pp, rows_wide):
         rows = raw.from_wide(SlotTable(*rows_wide))
-        start = pp * page_slots
-
-        def upd(leaf, r):
-            return jax.lax.dynamic_update_slice(
-                leaf, r.astype(leaf.dtype), _starts(start, leaf.ndim)
-            )
-
-        data = jax.tree.map(upd, pt.data, rows)
+        data = write_region(pt.data, rows, pp * page_slots)
         return PagedTable(data, pt.page_map.at[lp].set(pp))
 
     def _create(*_a, **_k):

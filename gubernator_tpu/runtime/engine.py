@@ -153,8 +153,8 @@ class EngineConfig:
     fast_buckets: bool = False
     device: Optional[object] = None  # jax device for the table
     # Table layout: "wide" (one int64 column per field), "packed"
-    # (narrowed columns, 3-gather probe), "fused" (one (N, C) tensor,
-    # one gather + one scatter, see ops/fused.py), or "narrow" (fused
+    # (narrowed columns, 3-gather probe), "fused" (one tensor of 32-bit
+    # words, one gather + one scatter, see ops/fused.py), or "narrow" (fused
     # v2: probe reads a 5-column row prefix, half the probe DMA — see
     # ops/narrow.py). All are oracle-exact; Loader snapshots are
     # portable across them (ops/kernels.py LAYOUTS).
