@@ -784,8 +784,7 @@ def main() -> None:
     parser.add_argument(
         "--mode", default="kernel",
         choices=("kernel", "engine", "engine_ab", "server", "global",
-                 "kernel10m", "latency", "ici", "edge", "ab", "mesh_ab",
-                 "kernel_ab"),
+                 "kernel10m", "latency", "ici", "edge", "ab", "mesh_ab"),
         help="kernel: device decide throughput @1M keys (headline); "
         "engine: end-to-end host+device serving path; "
         "engine_ab: serial (depth 1) vs pipelined (depth 2) engine A/B, "
@@ -800,14 +799,11 @@ def main() -> None:
         "ab: --layout vs fused decide-throughput A/B at the 2M- and "
         "16M-slot geometries, comparison rows ledgered; "
         "mesh_ab: single-chip vs mesh unified-core A/B (fresh process "
-        "per cell), comparison row ledgered; "
-        "kernel_ab: GUBER_KERNEL pallas-vs-xla decide backend A/B at "
-        "identical geometry/layout (fresh process per cell), "
-        "comparison row ledgered",
+        "per cell), comparison row ledgered",
     )
     parser.add_argument(
         "--layout", default=None,
-        choices=("wide", "packed", "fused", "narrow"),  # kernels.LAYOUTS
+        choices=("wide", "fused"),  # kernels.LAYOUTS
         help="table layout for kernel modes (ops/kernels.py); default "
         "fused, and an unset layout lets --gate compare against rows of "
         "any layout",
@@ -862,8 +858,6 @@ def main() -> None:
         result = bench_ab(cand=args.layout)
     elif args.mode == "mesh_ab":
         result = bench_mesh_ab()
-    elif args.mode == "kernel_ab":
-        result = bench_kernel_ab(layout=args.layout)
     else:
         result = bench_kernel(args.mode, args.layout)
 
@@ -1065,7 +1059,7 @@ def _bench_kernel_fresh(mode: str, layout: str) -> dict:
 
 
 def bench_ab(
-    sizes=("kernel", "kernel10m"), base: str = "fused", cand: str = "narrow"
+    sizes=("kernel", "kernel10m"), base: str = "fused", cand: str = "wide"
 ) -> dict:
     """Layout A/B on the kernel benchmark: run `base` then `cand` at each
     geometry (kernel = 1M keys / 2M slots, kernel10m = 10M keys / 16M
@@ -1105,78 +1099,6 @@ def bench_ab(
             "vs_baseline": round(ratio, 3),
         }
         ledger.append(row, job=f"bench_ab_{mode}", mode="ab", layout=cand)
-        print("RESULT " + json.dumps(row), flush=True)
-        if headline is None:
-            headline = row
-    return headline or {}
-
-
-def _bench_kernel_fresh_backend(mode: str, layout: str, backend: str) -> dict:
-    """bench_kernel under GUBER_KERNEL=<backend>: the backend is resolved
-    at kernel-registry build time, so it MUST be in the child's
-    environment before the child imports anything."""
-    return _run_fresh(
-        f"bench_kernel({mode!r}, {layout!r})",
-        env=dict(os.environ, GUBER_KERNEL=backend),
-    )
-
-
-def bench_kernel_ab(sizes=("kernel",), layout: str = "fused") -> dict:
-    """Pallas-vs-XLA decide backend A/B at identical geometry and
-    layout: the same seeded Zipf trace through GUBER_KERNEL=xla and
-    GUBER_KERNEL=pallas cells — each in a fresh process on CPU (the
-    backend binds at registry-build time, and cells must not share
-    warmth) — with one raw row per cell and one comparison row
-    (value = pallas/xla throughput ratio) ledgered per geometry. On a
-    TPU the pallas cells exercise the mosaic lowering; on CPU
-    they run the reference lowering (the same fused program XLA-lowered),
-    which is the honest non-TPU serving path, not interpret mode.
-    Returns the headline (first-geometry) comparison row."""
-    import jax
-
-    from gubernator_tpu.utils import ledger
-
-    platform = jax.devices()[0].platform
-    headline = None
-    for mode in sizes:
-        pair = {}
-        for backend in ("xla", "pallas"):
-            if platform == "cpu":
-                r = _bench_kernel_fresh_backend(mode, layout, backend)
-            else:
-                # A TPU is exclusively held by THIS process (bench_ab).
-                prior = os.environ.get("GUBER_KERNEL")
-                os.environ["GUBER_KERNEL"] = backend
-                try:
-                    r = bench_kernel(mode, layout)
-                finally:
-                    if prior is None:
-                        os.environ.pop("GUBER_KERNEL", None)
-                    else:
-                        os.environ["GUBER_KERNEL"] = prior
-            ledger.append(
-                r, job=f"bench_kernel_ab_{mode}_{backend}",
-                mode=mode, layout=layout,
-            )
-            print("RESULT " + json.dumps(r), flush=True)
-            pair[backend] = float(r["value"])
-        ratio = pair["pallas"] / max(pair["xla"], 1.0)
-        label = "16M" if mode == "kernel10m" else "2M"
-        row = {
-            "metric": (
-                f"pallas/xla decide backend A/B (kernel_ab, {layout}) "
-                f"@{label}-slot table ({mode}, {platform}); "
-                f"xla={pair['xla']:.0f} pallas={pair['pallas']:.0f} "
-                f"decisions/s"
-            ),
-            "value": round(ratio, 3),
-            "unit": "x",
-            "vs_baseline": round(ratio, 3),
-        }
-        ledger.append(
-            row, job=f"bench_kernel_ab_{mode}", mode="kernel_ab",
-            layout=layout,
-        )
         print("RESULT " + json.dumps(row), flush=True)
         if headline is None:
             headline = row

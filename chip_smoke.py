@@ -166,7 +166,7 @@ def metrics_text(addr: str) -> str:
 
 def metric(text: str, series: str) -> float:
     """Value of one exposition line, e.g.
-    'gubernator_kernel_backend{backend="xla"}'."""
+    'gubernator_engine_flush_duration_count{path="columnar"}'."""
     for line in text.splitlines():
         if line.startswith(series + " "):
             return float(line.rsplit(" ", 1)[1])
@@ -430,8 +430,7 @@ def read_device(http_addr: str, platform: str, min_devices: int) -> dict:
     return dev
 
 
-def require_served_columnar(http_addr: str, grpc_calls: int,
-                            kernel: str) -> None:
+def require_served_columnar(http_addr: str, grpc_calls: int) -> None:
     text = metrics_text(http_addr)
     flushes = metric(
         text, 'gubernator_engine_flush_duration_count{path="columnar"}'
@@ -443,12 +442,8 @@ def require_served_columnar(http_addr: str, grpc_calls: int,
     )
     cold = metric(text, "gubernator_engine_cold_compile_count")
     require(cold == 0, f"cold_compile_count={cold:.0f} on the serving path")
-    require(
-        metric(text, f'gubernator_kernel_backend{{backend="{kernel}"}}') == 1,
-        f"kernel backend is not {kernel}",
-    )
     say(f"columnar_flushes={flushes:.0f} grpc_calls={grpc_calls} "
-        f"cold_compile_count={cold:.0f} kernel_backend={kernel}")
+        f"cold_compile_count={cold:.0f}")
 
 
 def print_start_facts(label: str, dev: dict, start_s: float,
@@ -484,8 +479,6 @@ def start_daemon(args, label: str, extra: dict, children: list):
         "GUBER_PREWARM_BUCKETS": "true",
         **extra,
     }
-    if args.kernel != "xla":
-        env["GUBER_KERNEL"] = args.kernel
     child = Child(label, "gubernator_tpu.cmd.daemon", [],
                   child_env(args.platform, args.chips, env))
     children.append(child)
@@ -523,7 +516,7 @@ def serve_and_check(args, child: Child, grpc_addr: str, http_addr: str,
         say(f"branch_items={chk.items - len(loaded)} "
             f"mismatches={chk.mismatches}")
     child.require_running()
-    require_served_columnar(http_addr, chk.calls, args.kernel)
+    require_served_columnar(http_addr, chk.calls)
     return chk
 
 
@@ -639,7 +632,7 @@ def phase_ici(args, children: list) -> dict:
             min(used) > 0 and max(used) < 2 * min(used),
             f"table not spread over the devices: bytes_in_use={used}",
         )
-    require_served_columnar(http_addr, chk.calls, args.kernel)
+    require_served_columnar(http_addr, chk.calls)
     stop_daemon(child)
     return dev
 
@@ -738,8 +731,6 @@ def main() -> int:
     p.add_argument("--platform", choices=("tpu", "cpu"), default="tpu",
                    help="cpu is the explicit rehearsal; nothing selects "
                    "it implicitly")
-    p.add_argument("--kernel", choices=("xla", "pallas"), default="xla",
-                   help="decide backend the daemon is started with")
     args = p.parse_args()
     args.cache_size = 1
     while args.cache_size < 2 * args.keys:

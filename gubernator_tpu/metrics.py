@@ -1205,31 +1205,6 @@ class Metrics:
             "gubernator_compile_duration_seconds",
             "Cumulative wall seconds spent in XLA backend compiles.",
         )
-        # Decide-kernel backend (docs/monitoring.md "Device resources"):
-        # which decide program serves (GUBER_KERNEL) and, on the pallas
-        # backend, the autotuned lane tile + tune-cache provenance
-        # (ops/pallas_decide.py, runtime/kerneltune.py).
-        self.kernel_backend_info = Gauge(
-            "gubernator_kernel_backend",
-            "Active decide-kernel backend: 1 for the serving backend "
-            "label (xla = per-layout XLA chain, pallas = fused "
-            "one-HBM-pass Pallas program), 0 otherwise.",
-            ["backend"],
-            registry=r,
-        )
-        self.pallas_block_lanes = Gauge(
-            "gubernator_pallas_block_lanes",
-            "Lane tile (block_b) the Pallas decide program was built "
-            "with on this engine — the runtime/kerneltune.py choice; "
-            "0 when the XLA backend serves.",
-            registry=r,
-        )
-        self.pallas_tune_cache_hits = counter(
-            "gubernator_pallas_tune_cache_hits",
-            "Engine boots that reused a persisted Pallas lane-tile "
-            "choice (pallas_tune.json beside the compile cache) "
-            "instead of re-running autotune trials.",
-        )
         self.engine_table_occupancy = Gauge(
             "gubernator_engine_table_occupancy",
             "Fraction of device slot-table slots occupied (0-1), "
@@ -1793,21 +1768,6 @@ def engine_sync(engine):
         m.compile_cache_hits.set(cc["cache_hits"])
         m.compile_count.set(cc["compiles"])
         m.compile_duration_seconds.set(cc["compile_seconds"])
-        # Decide-backend provenance: pinned on the engine at build time
-        # (runtime/topology.py resolves GUBER_KERNEL once per registry
-        # build), so the scrape is pure host attribute reads.
-        kb = getattr(engine, "kernel_backend", "xla")
-        for backend in ("xla", "pallas"):
-            m.kernel_backend_info.labels(backend).set(
-                1 if backend == kb else 0
-            )
-        m.pallas_block_lanes.set(getattr(engine, "pallas_block", 0) or 0)
-        if kb == "pallas":
-            from gubernator_tpu.runtime import kerneltune as _kt
-
-            m.pallas_tune_cache_hits.set(
-                _kt.tuning_stats()["tune_cache_hits"]
-            )
 
     return _sync
 

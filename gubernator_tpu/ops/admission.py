@@ -35,7 +35,7 @@ in-flight replica admissions in the engine/auditor layers — see
 runtime/engine.py admission_snapshot and parallel/auditor.py.
 
 The program is built from the layout's traceable `to_wide` (same as the
-census), so one implementation covers wide/packed/fused/narrow, both
+census), so one implementation covers wide and fused, both
 ici tiers (`stacked=True` scans replica 0), and the paged table's
 physical frames; the host-DRAM cold tier is scanned by the numpy
 oracle below (runtime/engine.py, same pattern as the census host tier).

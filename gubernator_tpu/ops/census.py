@@ -37,7 +37,7 @@ tests/test_table_census.py):
 
 The program is built from the layout's traceable `to_wide` (the same
 converter the ici sync tick uses), so one implementation covers
-wide/packed/fused/narrow and both ici tiers; the replica tier passes
+wide and fused and both ici tiers; the replica tier passes
 `stacked=True` and the program scans replica 0's table (replicas
 mirror each other post-sync).
 """

@@ -44,8 +44,7 @@ column by column through the transposed table; they are conversions, off
 the decide path. `pack_table` / `unpack_table` (`from_wide` / `to_wide`)
 stay the interchange.
 
-Columns (int64; META packs lru<<4 | status<<2 | algo<<1 | used, as in
-ops/packed.py):
+Columns (int64; META packs lru<<4 | status<<2 | algo<<1 | used):
 
   KHI KLO META EXP LIM DUR REM STM BUR INV
 
@@ -69,18 +68,24 @@ from gubernator_tpu.api.types import Algorithm, Behavior, Status
 from gubernator_tpu.ops.decide import _leaky_paths, _token_paths
 from gubernator_tpu.ops.layout import DecideOutput, RequestBatch, SlotTable
 
-# The meta-word bit layout is a cross-layout contract (Loader snapshot
-# interop): share packed.py's definition, never redeclare it.
-from gubernator_tpu.ops.packed import (
-    META_ALGO_SHIFT,
-    META_LRU_SHIFT,
-    META_STATUS_SHIFT,
-    META_USED,
-    _pack_meta,
-)
-
 I64 = jnp.int64
 U32 = jnp.uint32
+
+# The META word: lru_stamp_ms << 4 | status << 2 | algo << 1 | used.
+META_USED = 1
+META_ALGO_SHIFT = 1
+META_STATUS_SHIFT = 2
+META_LRU_SHIFT = 4
+
+
+def _pack_meta(used, algo, status, lru):
+    return (
+        (lru.astype(I64) << META_LRU_SHIFT)
+        | (status.astype(I64) & 3) << META_STATUS_SHIFT
+        | (algo.astype(I64) & 1) << META_ALGO_SHIFT
+        | used.astype(I64)
+    )
+
 
 KHI, KLO, META, EXP, LIM, DUR, REM, STM, BUR, INV = range(10)
 NCOLS = 10
@@ -287,8 +292,7 @@ def probe_ways(w_khi, w_klo, w_meta, w_exp, w_inv, batch, now):
     """Way-selection policy over per-way column arrays (each (B, W)):
     returns (exists, matched_way, insert_way, cat). Policy identical to
     the wide kernel's _choose_slot: matched-expired > empty > expired >
-    LRU. Shared by the fused and narrow layouts so the two can never
-    drift — narrow feeds it slices of its (B, W, C64) hot block."""
+    LRU."""
     w_used = (w_meta & META_USED) != 0
     w_lru = w_meta >> META_LRU_SHIFT
     w_expired = w_used & ((w_exp < now) | ((w_inv != 0) & (w_inv < now)))

@@ -1,23 +1,14 @@
-"""Layout-agnostic kernel facade.
+"""Kernel facade over the two table layouts (EngineConfig.layout).
 
-The engine selects a table layout by name (EngineConfig.layout):
-
-- "wide": one int64 column per field (ops/layout.py + ops/decide.py) —
-  the reference-shaped baseline.
-- "packed": narrowed/packed columns with a 3-gather probe (ops/packed.py).
 - "fused": ONE tensor of 32-bit words, one gather + one scatter of the
-  lanes' slots (ops/fused.py) — the fastest at scale (a program's cost
-  follows its lanes, not the table; see ops/fused.py's module docstring)
-  and the flagship default.
-- "narrow": fused v2 — a split-word (N, 9) tensor (ops/narrow.py)
-  ordered so way selection reads only a 5-column row PREFIX (40 B/way,
-  half of fused's probe DMA) and the int32-clamped counters bit-pack
-  into one word; still exactly one gather + one scatter.
+  lanes' slots (ops/fused.py): a program's cost follows its lanes, not
+  the table. What every daemon serves from; no option chooses it.
+- "wide": one int64 column per field (ops/layout.py + ops/decide.py),
+  the plain reference. Tests build wide engines to compare against, and
+  snapshots are ALWAYS exchanged in the wide format (to_wide/from_wide).
 
-All are bit-exact against the oracle (tests/test_kernel_fuzz.py runs the
-whole differential suite per layout). Snapshots are ALWAYS exchanged in
-the wide format (to_wide/from_wide), so Loader files are portable across
-layouts.
+Both are bit-exact against the oracle (tests/test_kernel_fuzz.py runs
+the whole differential suite on each).
 """
 
 from __future__ import annotations
@@ -29,16 +20,14 @@ import jax
 import jax.numpy as jnp
 
 # The registry every layout-selection surface validates against
-# (EngineConfig.layout, GUBER_TABLE_LAYOUT / GUBER_ICI_LAYOUT, bench.py
-# --layout, the kernel fuzz suite).
-LAYOUTS = ("wide", "packed", "fused", "narrow")
+# (EngineConfig.layout, IciEngineConfig.layout, bench.py --layout, the
+# kernel fuzz suite).
+LAYOUTS = ("wide", "fused")
 
 # Resident bytes per table slot, by layout (engine table-size gates,
 # e.g. the bucket-warmer's scratch-copy budget; see each layout module
 # for the field-by-field accounting).
-BYTES_PER_SLOT = {"wide": 83, "packed": 72, "fused": 80, "narrow": 72}
-
-import os
+BYTES_PER_SLOT = {"wide": 83, "fused": 80}
 
 from gubernator_tpu.ops.decide import (
     decide as _wd,
@@ -52,27 +41,6 @@ from gubernator_tpu.ops.layout import (
     pack_output,
     unpack_operand,
 )
-
-# Decide-program backends (GUBER_KERNEL). "xla" is the grown fleet of
-# per-layout XLA programs; "pallas" routes the narrow/fused decide hot
-# path through the hand-written one-HBM-pass kernel
-# (ops/pallas_decide.py) with the XLA path kept as the fallback and the
-# bit-exactness oracle. Layouts pallas does not lower (wide/packed — the
-# diagnostic layouts) and all non-decide entry points stay on XLA.
-KERNEL_BACKENDS = ("xla", "pallas")
-
-
-def kernel_backend() -> str:
-    """Decide-program backend, read from GUBER_KERNEL at registry-build
-    time (engine/topology startup — NOT per decide call), so a built
-    `Kernels` facade is pinned to one backend and the warmed programs
-    are exactly the served programs."""
-    v = os.environ.get("GUBER_KERNEL", "xla").strip().lower() or "xla"
-    if v not in KERNEL_BACKENDS:
-        raise ValueError(
-            f"GUBER_KERNEL={v!r}: expected one of {KERNEL_BACKENDS}"
-        )
-    return v
 
 
 class Kernels(NamedTuple):
@@ -116,31 +84,6 @@ _WIDE = Kernels(
 )
 
 
-def _packed():
-    from gubernator_tpu.ops import packed as _p
-
-    return Kernels(
-        layout="packed",
-        create=_p.PackedTable.create,
-        decide=lambda table, batch, now, ways, with_store=False: _p.decide_packed(
-            table, batch, now, ways=ways
-        ),
-        decide_scan=lambda table, batches, nows, ways, with_store=False: (
-            _p.decide_scan_packed(table, batches, nows, ways=ways)
-        ),
-        inject=lambda table, items, now, ways: _p.inject_packed(
-            table, items, now, ways=ways
-        ),
-        probe_exists=lambda table, hi, lo, group, now, ways: (
-            _p.probe_exists_packed(table, hi, lo, group, now, ways=ways)
-        ),
-        gather_rows=_p.gather_rows_packed,
-        to_wide=_p.unpack_table,
-        from_wide=_p.pack_table,
-        bytes_per_slot=BYTES_PER_SLOT["packed"],
-    )
-
-
 def _fused():
     from gubernator_tpu.ops import fused as _f
 
@@ -166,75 +109,14 @@ def _fused():
     )
 
 
-def _narrow():
-    from gubernator_tpu.ops import narrow as _n
-
-    return Kernels(
-        layout="narrow",
-        create=_n.NarrowTable.create,
-        decide=lambda table, batch, now, ways, with_store=False: _n.decide_narrow(
-            table, batch, now, ways=ways
-        ),
-        decide_scan=lambda table, batches, nows, ways, with_store=False: (
-            _n.decide_scan_narrow(table, batches, nows, ways=ways)
-        ),
-        inject=lambda table, items, now, ways: _n.inject_narrow(
-            table, items, now, ways=ways
-        ),
-        probe_exists=lambda table, hi, lo, group, now, ways: (
-            _n.probe_exists_narrow(table, hi, lo, group, now, ways=ways)
-        ),
-        gather_rows=_n.gather_rows_narrow,
-        to_wide=_n.unpack_table,
-        from_wide=_n.pack_table,
-        bytes_per_slot=BYTES_PER_SLOT["narrow"],
-    )
-
-
-def _pallas(layout: str, base: Kernels) -> Kernels:
-    """Reroute the decide hot path of `base` through the fused Pallas
-    program; every other entry point (inject, probes, snapshots) keeps
-    the XLA impls — they are not wave-rate paths."""
-    from gubernator_tpu.ops import pallas_decide as _pd
-
-    return base._replace(
-        decide=lambda table, batch, now, ways, with_store=False: (
-            _pd.decide_flat(table, batch, now, layout=layout, ways=ways)
-        ),
-        decide_scan=lambda table, batches, nows, ways, with_store=False: (
-            _pd.decide_scan_flat(
-                table, batches, nows, layout=layout, ways=ways
-            )
-        ),
-    )
-
-
 def get_kernels(layout: str) -> Kernels:
     if layout == "wide":
         base = _WIDE
-    elif layout == "packed":
-        base = _packed()
     elif layout == "fused":
         base = _fused()
-    elif layout == "narrow":
-        base = _narrow()
     else:
         raise ValueError(f"unknown table layout: {layout!r}")
-    if layout in ("fused", "narrow") and kernel_backend() == "pallas":
-        base = _pallas(layout, base)
     return base._replace(decide_packed=packed_decide(layout))
-
-
-def program_variant(layout: str, lanes: int, paged: bool = False):
-    """What besides shapes selects the decide program: "xla", or the
-    Pallas route's lowering and lane tile, which it resolves when it is
-    traced. A static argument of the packed entries, so that a change
-    of either traces a program of its own."""
-    if layout not in ("fused", "narrow") or kernel_backend() != "pallas":
-        return "xla"
-    from gubernator_tpu.ops import pallas_decide as _pd
-
-    return ("pallas", _pd.pallas_mode(), _pd.choose_block(layout, paged, lanes))
 
 
 @functools.lru_cache(maxsize=None)
@@ -244,7 +126,7 @@ def _packed_program(layout: str):
     pack the output. Named after the layout so a profile shows the
     program under the name it always had (`jit_decide_fused`)."""
 
-    def entry(table, operand, ways, with_store, variant):
+    def entry(table, operand, ways, with_store):
         batch, _home, now = unpack_operand(operand)
         table, out = get_raw_kernels(layout).decide(table, batch, now, ways)
         return table, pack_output(out, with_store)
@@ -252,7 +134,7 @@ def _packed_program(layout: str):
     entry.__name__ = entry.__qualname__ = f"decide_{layout}"
     return jax.jit(
         entry,
-        static_argnames=("ways", "with_store", "variant"),
+        static_argnames=("ways", "with_store"),
         donate_argnums=(0,),
     )
 
@@ -265,8 +147,7 @@ def packed_decide(layout: str):
 
     def decide_packed(table, operand, ways, with_store=False):
         return program(
-            table, operand, ways=ways, with_store=bool(with_store),
-            variant=program_variant(layout, operand.shape[-1]),
+            table, operand, ways=ways, with_store=bool(with_store)
         )
 
     return decide_packed
@@ -299,7 +180,8 @@ class RawKernels(NamedTuple):
     # each (an index past the end reads the last slots); `table` with
     # such a table written back at those groups (an index past the end
     # writes nothing); a pytree of per-slot (N, ...) arrays holding the
-    # state.
+    # state. The defaults index per-slot leaves and are wide's; fused
+    # has its own, over lines. These are the only two.
     take_groups: object = lambda t, gids, ways: jax.tree.map(
         lambda a: jnp.take(a, _group_slots(gids, ways), axis=0, mode="clip"),
         t,
@@ -376,25 +258,10 @@ def get_raw_kernels(layout: str) -> RawKernels:
             to_wide=lambda t: t,
             from_wide=lambda t: t,
         )
-    if layout == "packed":
-        from gubernator_tpu.ops import packed as _p
-
-        return RawKernels(
-            layout="packed",
-            create=_p.PackedTable.create,
-            decide=lambda t, b, now, ways: _p._decide_packed_impl(
-                t, b, now, ways=ways
-            ),
-            inject=lambda t, i, now, ways: _p._inject_packed_impl(
-                t, i, now, ways
-            ),
-            to_wide=_p.unpack_table,
-            from_wide=_p.pack_table,
-        )
     if layout == "fused":
         from gubernator_tpu.ops import fused as _f
 
-        raw = RawKernels(
+        return RawKernels(
             layout="fused",
             create=_f.FusedTable.create,
             decide=lambda t, b, now, ways: _f._decide_fused_impl(
@@ -409,33 +276,4 @@ def get_raw_kernels(layout: str) -> RawKernels:
             put_groups=_f.put_groups,
             slot_leaves=_f.FusedTable.cols,
         )
-    elif layout == "narrow":
-        from gubernator_tpu.ops import narrow as _n
-
-        raw = RawKernels(
-            layout="narrow",
-            create=_n.NarrowTable.create,
-            decide=lambda t, b, now, ways: _n._decide_narrow_impl(
-                t, b, now, ways=ways
-            ),
-            inject=lambda t, i, now, ways: _n._inject_narrow_impl(
-                t, i, now, ways
-            ),
-            to_wide=_n.unpack_table,
-            from_wide=_n.pack_table,
-        )
-    else:
-        raise ValueError(f"unknown table layout: {layout!r}")
-    if kernel_backend() == "pallas":
-        # The mesh tier composes RawKernels.decide inside shard_map
-        # (parallel/mesh.py local_decide), so routing the raw decide here
-        # is what makes IciMeshTopology dispatch the Pallas program PER
-        # SHARD: each shard's slice traces its own pallas_call.
-        from gubernator_tpu.ops import pallas_decide as _pd
-
-        raw = raw._replace(
-            decide=lambda t, b, now, ways: _pd.raw_decide_flat(
-                t, b, now, layout=layout, ways=ways
-            )
-        )
-    return raw
+    raise ValueError(f"unknown table layout: {layout!r}")

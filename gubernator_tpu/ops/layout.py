@@ -20,8 +20,8 @@ struct-of-arrays region designed for vectorized gather/scatter:
 
 All arrays are int64/bool; (key_hi, key_lo) == (0, 0) marks empty.
 
-SlotTable is also the CANONICAL interchange row format: every other
-layout (ops/packed.py, ops/fused.py, ops/narrow.py) converts to/from it
+SlotTable is also the CANONICAL interchange row format: the serving
+layout (ops/fused.py) converts to/from it
 for Loader snapshots, the ici sync tick's merge, and store write-behind
 rows, so on-disk state and cross-layer seams never depend on the
 device-resident packing (ops/kernels.py to_wide/from_wide).

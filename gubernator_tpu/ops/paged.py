@@ -14,9 +14,8 @@ Everything downstream of the translation is the UNMODIFIED layout kernel
 (ops/kernels.py registry): every decide/inject/probe impl derives slot
 indices exclusively from the batch's `group` field (`grp_base = group *
 ways`), so translating the batch — not the kernel — keeps the paged path
-bit-exact with the flat table for resident pages across all four
-layouts (pinned by tests/test_kernel_fuzz.py's paged differential
-suite).
+bit-exact with the flat table for resident pages on both layouts
+(pinned by tests/test_kernel_fuzz.py's paged differential suite).
 
 Non-resident pages map to -1; translation sends those lanes to the
 sentinel physical group `num_phys_pages * groups_per_page`, one past the
@@ -56,10 +55,8 @@ import jax.numpy as jnp
 
 from gubernator_tpu.ops.kernels import (
     BYTES_PER_SLOT,
-    program_variant,
     get_kernels,
     get_raw_kernels,
-    kernel_backend,
 )
 from gubernator_tpu.ops.layout import SlotTable, pack_output, unpack_operand
 
@@ -218,55 +215,30 @@ def make_paged_kernels(
         phys = jnp.where(pp >= 0, pp * gpp + g % gpp, sentinel)
         return phys.astype(group.dtype)
 
-    if kernel_backend() == "pallas" and layout in ("narrow", "fused"):
-        # Pallas backend: the page-map lookup happens INSIDE the decide
-        # kernel (a scalar SMEM read folded into each lane's DMA offset),
-        # so the standalone `_xlate` gather disappears from the decide
-        # hot path. Every other kernel (inject/probe/page ops — not
-        # wave-rate) keeps the translate-then-XLA path above.
-        from gubernator_tpu.ops import pallas_decide as _pd
+    def _raw_decide(pt, batch, now):
+        b = batch._replace(group=_xlate(pt.page_map, batch.group))
+        data, out = raw.decide(pt.data, b, now, ways)
+        return PagedTable(data, pt.page_map), out
 
-        def _decide(pt, batch, now):
-            return _pd.decide_paged(
-                pt, batch, now, layout=layout, ways=ways, gpp=gpp
-            )
+    _decide = jax.jit(_raw_decide, donate_argnums=(0,))
 
-        def _decide_scan(pt, batches, nows):
-            return _pd.decide_scan_paged(
-                pt, batches, nows, layout=layout, ways=ways, gpp=gpp
-            )
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def _decide_scan(pt, batches, nows):
+        pm = pt.page_map
 
-        def _raw_decide(pt, batch, now):
-            return _pd.raw_decide_paged(
-                pt, batch, now, layout=layout, ways=ways, gpp=gpp
-            )
+        def step(data, xs):
+            b, now = xs
+            b = b._replace(group=_xlate(pm, b.group))
+            data, out = raw.decide(data, b, now, ways)
+            return data, out
 
-    else:
-
-        def _raw_decide(pt, batch, now):
-            b = batch._replace(group=_xlate(pt.page_map, batch.group))
-            data, out = raw.decide(pt.data, b, now, ways)
-            return PagedTable(data, pt.page_map), out
-
-        _decide = jax.jit(_raw_decide, donate_argnums=(0,))
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def _decide_scan(pt, batches, nows):
-            pm = pt.page_map
-
-            def step(data, xs):
-                b, now = xs
-                b = b._replace(group=_xlate(pm, b.group))
-                data, out = raw.decide(data, b, now, ways)
-                return data, out
-
-            data, outs = jax.lax.scan(step, pt.data, (batches, nows))
-            return PagedTable(data, pm), outs
+        data, outs = jax.lax.scan(step, pt.data, (batches, nows))
+        return PagedTable(data, pm), outs
 
     @functools.partial(
-        jax.jit, static_argnames=("with_store", "variant"), donate_argnums=(0,)
+        jax.jit, static_argnames=("with_store",), donate_argnums=(0,)
     )
-    def _decide_packed(pt, operand, with_store, variant):
+    def _decide_packed(pt, operand, with_store):
         batch, _home, now = unpack_operand(operand)
         pt, out = _raw_decide(pt, batch, now)
         return pt, pack_output(out, with_store)
@@ -324,10 +296,7 @@ def make_paged_kernels(
             t, b, now
         ),
         decide_packed=lambda t, op, ways_=ways, with_store=False: (
-            _decide_packed(
-                t, op, with_store=bool(with_store),
-                variant=program_variant(layout, op.shape[-1], paged=True),
-            )
+            _decide_packed(t, op, with_store=bool(with_store))
         ),
         decide_scan=lambda t, bs, ns, ways_=ways, with_store=False: (
             _decide_scan(t, bs, ns)

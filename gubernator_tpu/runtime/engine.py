@@ -152,12 +152,11 @@ class EngineConfig:
     # the columnar edge can size the kernel to each call's occupancy.
     fast_buckets: bool = False
     device: Optional[object] = None  # jax device for the table
-    # Table layout: "wide" (one int64 column per field), "packed"
-    # (narrowed columns, 3-gather probe), "fused" (one tensor of 32-bit
-    # words, one gather + one scatter, see ops/fused.py), or "narrow" (fused
-    # v2: probe reads a 5-column row prefix, half the probe DMA — see
-    # ops/narrow.py). All are oracle-exact; Loader snapshots are
-    # portable across them (ops/kernels.py LAYOUTS).
+    # Table layout: "fused" (one tensor of 32-bit words, one gather +
+    # one scatter, see ops/fused.py), what a daemon serves from, or
+    # "wide" (one int64 column per field), the reference tests build.
+    # Both are oracle-exact; Loader snapshots are portable across them
+    # (ops/kernels.py LAYOUTS).
     layout: str = "fused"
     # Table observatory (docs/monitoring.md "Table census"): TTL of the
     # cached census snapshot (GUBER_TABLE_CENSUS_TTL) — every scrape
@@ -1011,8 +1010,6 @@ class EngineBase:
             "batch_size": cfg.batch_size,
             "max_waves": cfg.max_waves,
             "pipeline_depth": self._pipe_depth,
-            "kernel_backend": getattr(self, "kernel_backend", "xla"),
-            "pallas_block": getattr(self, "pallas_block", 0),
             "inflight": getattr(self, "_inflight", 0),
             "queue_depth": self.queue_depth(),
             "counters": counters,
@@ -1647,22 +1644,6 @@ class MeshEngine(EngineBase):
         # Where a wave's operand is uploaded to (None: the default
         # device; the configured device; replicated over the mesh).
         self._operand_sharding = self.topo.operand_sharding(config)
-        # Decide backend provenance (GUBER_KERNEL, resolved by the
-        # topology's registry build) + the Pallas lane tile. Tuning runs
-        # HERE — before _warmup compiles the decide program — so the
-        # tile the trials pick is the tile the warmed (and therefore
-        # served) executable is built with; the serving path never
-        # retunes (pinned by tests/test_pallas_engine.py).
-        self.kernel_backend = getattr(self.topo, "kernel_backend", "xla")
-        self.pallas_block = 0
-        if self.kernel_backend == "pallas":
-            from gubernator_tpu.runtime import kerneltune
-
-            self.pallas_block = kerneltune.ensure_tuned(
-                config.layout,
-                config.batch_size,
-                paged=int(getattr(config, "page_groups", 0) or 0) > 0,
-            )
         # Every facade accepts (and the paged/mesh ones ignore) the
         # flat geometry args, so creation is uniform across all four
         # kernel cases.
@@ -1861,8 +1842,7 @@ class MeshEngine(EngineBase):
         # A second table is transient compile fodder; skip bucket warming
         # when that copy would be expensive (huge HBM tables) — the
         # always-warm batch_size shape still serves the fast path. Sized
-        # by the LAYOUT's resident bytes/slot (a narrow table crosses
-        # the threshold later than a wide one).
+        # by the LAYOUT's resident bytes/slot.
         # Paged mode subsumes the old whole-table gate: the RESIDENT
         # footprint (physical frames, not the logical keyspace) is what
         # a scratch copy costs, and paging keeps it bounded regardless

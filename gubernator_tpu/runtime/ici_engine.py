@@ -90,9 +90,8 @@ class IciEngineConfig:
     # (GUBER_ADMISSION_TTL; the scan covers BOTH tiers).
     admission_ttl_s: float = 5.0
     # Table layout for BOTH the sharded and replica tiers (the
-    # ops/kernels.py LAYOUTS registry; "narrow" halves probe DMA at
-    # large tables); fused is the TPU production layout (VERDICT r4
-    # item 2).
+    # ops/kernels.py LAYOUTS registry): fused serves, wide is the
+    # reference tests build.
     layout: str = "fused"
     # Per-tick sync work cap (groups). The tick merges only groups whose
     # content diverges across replicas or that hold pending deltas, up

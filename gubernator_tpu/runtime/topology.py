@@ -42,7 +42,6 @@ from gubernator_tpu.ops.kernels import (
     get_census,
     get_kernels,
     get_paged_kernels,
-    kernel_backend,
 )
 from gubernator_tpu.parallel import ici
 from gubernator_tpu.parallel import mesh as pmesh
@@ -124,17 +123,11 @@ class SingleChipTopology:
     mesh_shape = (1,)
     primary_tier = "device"
     thread_name = "gubernator-tpu-engine"
-    kernel_backend = "xla"  # resolved for real in build_kernels
 
     def build_kernels(self, cfg, metrics):
         """(Kernels, Pager|None) for one chip — the pre-unification
         DeviceEngine binding: paged facade + Pager when page_groups is
-        set, the flat layout jits otherwise. The decide backend
-        (GUBER_KERNEL: XLA chain vs fused Pallas program) resolves
-        inside the registry at THIS moment and is pinned on the
-        topology so the engine can tune/warm/report the program it
-        will actually serve."""
-        self.kernel_backend = kernel_backend()
+        set, the flat layout jits otherwise."""
         pg = int(getattr(cfg, "page_groups", 0) or 0)
         if pg > 0:
             budget = int(getattr(cfg, "page_budget", 0) or 0)
@@ -179,7 +172,6 @@ class IciMeshTopology:
 
     primary_tier = "sharded"
     thread_name = "ici-engine"
-    kernel_backend = "xla"  # resolved for real in build_kernels
 
     def __init__(self, devices=None):
         self.devices = list(devices) if devices else jax.devices()
@@ -190,12 +182,7 @@ class IciMeshTopology:
     def build_kernels(self, cfg, metrics):
         """(Kernels, Pager|None) over the mesh: shard_map ownership
         programs, with the paged indirection layer (replicated map,
-        sharded frames, per-shard pools) when page_groups is set.
-        Under GUBER_KERNEL=pallas the registry routes the RAW decide
-        the shard_map body composes (parallel/mesh.py local_decide)
-        through the fused Pallas program, so every shard dispatches
-        its own pallas_call over its table slice."""
-        self.kernel_backend = kernel_backend()
+        sharded frames, per-shard pools) when page_groups is set."""
         pg = int(getattr(cfg, "page_groups", 0) or 0)
         budget = int(getattr(cfg, "page_budget", 0) or 0)
         if pg > 0:

@@ -229,14 +229,6 @@ class DaemonConfig:
     # (reference default 50k items, config.go:139-140)
     cache_size: int = 50_000
 
-    # Device table layout for the single-chip engine (GUBER_TABLE_LAYOUT;
-    # ops/kernels.py LAYOUTS). All layouts are oracle-exact and Loader
-    # snapshots are portable across them; "narrow" halves the probe DMA
-    # at large tables (ops/narrow.py). Ignored when `engine` is set
-    # explicitly; the ici tier has its own knob (IciEngineConfig.layout /
-    # GUBER_ICI_LAYOUT).
-    table_layout: str = "fused"
-
     behaviors: BehaviorConfig = dataclasses.field(default_factory=BehaviorConfig)
     engine: Optional[EngineConfig] = None
     # The jax device this daemon's table lives on (None: the process
@@ -438,13 +430,6 @@ class DaemonConfig:
     def engine_config(self) -> EngineConfig:
         if self.engine is not None:
             return self.engine
-        from gubernator_tpu.ops.kernels import LAYOUTS
-
-        if self.table_layout not in LAYOUTS:
-            raise ValueError(
-                f"table_layout={self.table_layout!r} is invalid; choices "
-                f"are {list(LAYOUTS)}"
-            )
         ways = 8
         groups = 1
         while groups * ways < self.cache_size:
@@ -458,7 +443,7 @@ class DaemonConfig:
             # compile in the background at boot.
             fast_buckets=True,
             device=self.device,
-            layout=self.table_layout,
+            layout="fused",
             hotkeys_k=self.hotkeys_k,
             stage_metadata=self.stage_metadata,
             exemplars=self.exemplars,

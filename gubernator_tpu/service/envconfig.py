@@ -108,9 +108,31 @@ def _parse_census_thresholds(v: str) -> tuple:
     return out
 
 
+# GUBER_<name> knobs that chose among programs a daemon no longer has:
+# (name, the one value that still says what runs). A start that asks for
+# anything else is refused, not served from a different program.
+_RETIRED = (
+    ("KERNEL", "xla"),
+    ("TABLE_LAYOUT", "fused"),
+    ("ICI_LAYOUT", "fused"),
+    ("PALLAS_BLOCK", ""),
+    ("PALLAS_INTERPRET", ""),
+    ("PALLAS_TUNE", ""),
+    ("PALLAS_TUNE_CACHE", ""),
+)
+
+
 def setup_daemon_config(config_file: Optional[str] = None) -> DaemonConfig:
     if config_file:
         load_config_file(config_file)
+    for name, only in _RETIRED:
+        v = _env("GUBER_" + name)
+        if v.strip().lower() not in ("", only):
+            raise ValueError(
+                f"'GUBER_{name}={v}' is invalid; the knob is retired (a "
+                "daemon serves the fused table layout under XLA and "
+                "nothing selects another): unset it"
+            )
 
     behaviors = BehaviorConfig(
         batch_timeout_s=parse_duration_s(_env("GUBER_BATCH_TIMEOUT"), 0.5),
@@ -226,7 +248,6 @@ def setup_daemon_config(config_file: Optional[str] = None) -> DaemonConfig:
         advertise_address=_env("GUBER_ADVERTISE_ADDRESS", ""),
         data_center=_env("GUBER_DATA_CENTER", ""),
         cache_size=_env_int("GUBER_CACHE_SIZE", 50_000),
-        table_layout=_env("GUBER_TABLE_LAYOUT", "fused"),
         behaviors=behaviors,
         global_mode=_env("GUBER_GLOBAL_MODE", "grpc"),
         grpc_max_conn_age_s=float(_env_int("GUBER_GRPC_MAX_CONN_AGE_SEC", 0)),
@@ -360,22 +381,6 @@ def setup_daemon_config(config_file: Optional[str] = None) -> DaemonConfig:
             "enables paging"
         )
 
-    # Table layouts validate EARLY against the one registry
-    # (ops/kernels.py) so a typo'd GUBER_TABLE_LAYOUT / GUBER_ICI_LAYOUT
-    # fails at config time, not at first engine construction.
-    from gubernator_tpu.ops.kernels import LAYOUTS
-
-    if conf.table_layout not in LAYOUTS:
-        raise ValueError(
-            f"'GUBER_TABLE_LAYOUT={conf.table_layout}' is invalid; "
-            f"choices are {list(LAYOUTS)}"
-        )
-    if conf.ici is not None and conf.ici.layout not in LAYOUTS:
-        raise ValueError(
-            f"'GUBER_ICI_LAYOUT={conf.ici.layout}' is invalid; "
-            f"choices are {list(LAYOUTS)}"
-        )
-
     # ICI-mode sizing (GUBER_GLOBAL_MODE=ici): the replica table must be
     # sized so live GLOBAL keys per group stay <= replica ways, or keys
     # degrade to per-replica counting (docs/architecture.md "Overflow
@@ -402,7 +407,6 @@ def setup_daemon_config(config_file: Optional[str] = None) -> DaemonConfig:
             sync_wait_s=behaviors.global_sync_wait_s,
             batch_wait_s=behaviors.batch_wait_s,
             batch_limit=behaviors.batch_limit,
-            layout=_env("GUBER_ICI_LAYOUT", base.layout),  # LAYOUTS-validated below
             pipeline_depth=conf.pipeline_depth,
             hotkeys_k=conf.hotkeys_k,
             stage_metadata=conf.stage_metadata,

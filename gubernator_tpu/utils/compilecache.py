@@ -39,8 +39,7 @@ DEFAULT_DIR = os.path.join(
 
 
 def cache_dir() -> str:
-    """The directory in force, whether or not the cache is enabled yet
-    (runtime/kerneltune.py keeps its tune file beside it)."""
+    """The directory in force, whether or not the cache is enabled yet."""
     return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_DIR
 
 

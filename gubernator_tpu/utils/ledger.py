@@ -219,13 +219,13 @@ _MODE_FROM_JOB = re.compile(
     # tools/jobs/ must key to exactly one of these modes — guberlint
     # GL016 pins the parity (a job whose name matches nothing would
     # ledger with mode="" and silently fall out of gate() baselines).
-    r"(kernel10m|kernel_ab|kernel|engine_ab|engine|server|global|latency"
+    r"(kernel10m|kernel|engine_ab|engine|server|global|latency"
     r"|edge|mesh_ab|mesh|ici|paged_table|table_census|lease_soak"
     r"|admission_soak|slo_soak|crash_soak|overload_soak|chaos_soak"
     r"|consistency_soak"
-    r"|sanity|device_observatory|rolling_restart|pallas_ab|ab_narrow)"
+    r"|sanity|device_observatory|rolling_restart)"
 )
-_LAYOUT_FROM_JOB = re.compile(r"(fused|packed|wide|narrow)")
+_LAYOUT_FROM_JOB = re.compile(r"(fused|wide)")
 
 
 def infer_mode_layout(job: str, metric: str = "") -> tuple[str, str]:

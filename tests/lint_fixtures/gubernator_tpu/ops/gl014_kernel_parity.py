@@ -5,7 +5,7 @@ Scanned only when passed explicitly; the path maps to
 gubernator_tpu/ops/gl014_kernel_parity.py, which is listed in
 _KERNEL_REGISTRY_FILES so the registry-surface predicate fires. The
 parity map itself is the REAL tests/test_kernel_fuzz.py one, so
-covered names (decide, decide_flat, ...) must stay quiet here while
+covered names (decide, decide_fused, ...) must stay quiet here while
 invented variants fire.
 """
 
@@ -15,7 +15,7 @@ class _FakeOps:
     decide_scan_turbo = None
     decide_hyper = None
     decide = None
-    decide_flat = None
+    decide_fused = None
 
 
 def build_registry(ops):
@@ -27,8 +27,8 @@ def build_registry(ops):
     hyper = ops.decide_hyper  # guberlint: allow-kernel-parity
     # ok: covered by the real parity map
     base = ops.decide
-    flat = ops.decide_flat
-    return turbo, turbo_scan, hyper, base, flat
+    fused = ops.decide_fused
+    return turbo, turbo_scan, hyper, base, fused
 
 
 # ok: reasoned pragma — witnessed-intentional uncovered reference

@@ -1,6 +1,7 @@
 """The fused table as 32-bit words (ops/fused.py): the two helpers and the
 interchange are exact, and no program that serves a wave does work that
-grows with the table.
+grows with the table. Then the registry of the two layouts
+(ops/kernels.py): what it says of each, and what engines take from it.
 
 The structural cases are what a CPU run can give: counts read off the
 traced program. The times are the chip's (PERF.md §6, PR 29).
@@ -14,8 +15,16 @@ import pytest
 from gubernator_tpu.models.bucket import FIXED_SHIFT
 from gubernator_tpu.ops import fused as F
 from gubernator_tpu.ops.inject import InjectBatch
-from gubernator_tpu.ops.kernels import get_raw_kernels, packed_decide
+from gubernator_tpu.api.types import Behavior, RateLimitReq, Status
+from gubernator_tpu.ops.kernels import (
+    BYTES_PER_SLOT,
+    LAYOUTS,
+    get_kernels,
+    get_raw_kernels,
+    packed_decide,
+)
 from gubernator_tpu.ops.layout import OPERAND_ROWS, SlotTable
+from gubernator_tpu.runtime.engine import DeviceEngine, EngineConfig
 
 I64_MIN, I64_MAX = -(1 << 63), (1 << 63) - 1
 
@@ -65,7 +74,7 @@ def fuzzed_wide(rng, shape) -> SlotTable:
         status=rng.integers(0, 4, size=shape).astype(np.int8),
         limit=i64(), duration=i64(), remaining=i64(), stamp=i64(),
         expire_at=i64(), invalid_at=i64(), burst=i64(),
-        # the meta word keeps the stamp's low 60 bits (ops/packed.py)
+        # the meta word keeps the stamp's low 60 bits (ops/fused.py META)
         lru=rng.integers(0, 1 << 59, size=shape, dtype=np.int64),
     )
 
@@ -261,3 +270,93 @@ def test_no_work_follows_the_table(name):
         )
         seen.append(n_eqns)
     assert seen[0] == seen[1], f"{name}: {seen} equations at the two sizes"
+
+
+# ---------------------------------------------------------------------------
+# The registry of the two layouts, and the engine seams that read it.
+
+NOW = 1_753_700_000_000
+
+
+def test_round_trip_through_every_layout():
+    """The wide row format is the canonical interchange: converting the
+    SAME snapshot through each layout's from_wide/to_wide must be the
+    identity, which is what makes Loader files portable."""
+    wide = jax.tree.map(
+        jnp.asarray, fuzzed_wide(np.random.default_rng(13), (256,))
+    )
+    for layout in LAYOUTS:
+        K = get_kernels(layout)
+        assert_same_tree(K.to_wide(K.from_wide(wide)), wide)
+
+
+def test_bytes_per_slot_registry():
+    # The registry drives engine table-size gates: one entry a layout,
+    # and every facade reports its own.
+    assert BYTES_PER_SLOT == {"wide": 83, "fused": 80}
+    for layout in LAYOUTS:
+        assert get_kernels(layout).bytes_per_slot == BYTES_PER_SLOT[layout]
+
+
+def mk(key="k", **kw):
+    kw.setdefault("name", "t")
+    kw.setdefault("duration", 60_000)
+    kw.setdefault("limit", 10)
+    kw.setdefault("hits", 1)
+    return RateLimitReq(unique_key=key, **kw)
+
+
+def _engine(layout, **kw):
+    kw.setdefault("num_groups", 1 << 10)
+    kw.setdefault("batch_size", 64)
+    kw.setdefault("batch_wait_s", 0.002)
+    return DeviceEngine(EngineConfig(layout=layout, **kw), now_fn=lambda: NOW)
+
+
+@pytest.mark.parametrize("src,dst", [("fused", "wide"), ("wide", "fused")])
+def test_snapshot_portable_across_layouts(src, dst):
+    """Counters survive a snapshot/restore across DIFFERENT table
+    layouts — the Loader interchange stays the wide row format."""
+    a = _engine(src)
+    try:
+        a.check_batch([mk(key="port", hits=7), mk(key="other", hits=3)])
+        snap = a.snapshot()
+    finally:
+        a.close()
+    b = _engine(dst)
+    try:
+        b.restore(snap)
+        out = b.check_batch([mk(key="port", hits=0), mk(key="other", hits=2)])
+        assert out[0].remaining == 3  # 10 - 7, carried across layouts
+        assert out[1].remaining == 5  # 10 - 3 - 2, counter continued
+    finally:
+        b.close()
+
+
+def test_warm_buckets_oversized_table_skips(monkeypatch):
+    """The bucket-warm ladder compiles against a THROWAWAY table copy;
+    beyond the scratch budget it is skipped (runtime/engine.py
+    _warm_buckets) and only batch_size stays warm. Pin the interaction:
+    a single NO_BATCHING request on such an engine is still served —
+    through a batch_size-wide dispatch, never a mid-request JIT stall."""
+    monkeypatch.setattr(DeviceEngine, "_WARM_TABLE_BUDGET", 1)
+    eng = _engine("fused", batch_size=512, fast_buckets=True)
+    try:
+        # The warmer must exit promptly (it skipped), leaving only the
+        # always-warm batch_size shape.
+        assert eng.wait_warm(timeout_s=60.0)
+        assert eng._warm_shapes == (512,)
+        rl = eng.check_batch([mk(behavior=Behavior.NO_BATCHING)])[0]
+        assert (rl.status, rl.remaining) == (Status.UNDER_LIMIT, 9)
+    finally:
+        eng.close()
+
+
+def test_warm_buckets_budget_uses_layout_bytes():
+    """The gate is sized by the LAYOUT's resident bytes/slot: a fused
+    table (80 B/slot) fits a budget the wide layout (83 B/slot) would
+    blow, so the ladder still warms where the bytes actually allow it."""
+    budget = DeviceEngine._WARM_TABLE_BUDGET
+    groups = budget // (8 * BYTES_PER_SLOT["fused"])  # fused under, wide over
+    assert groups * 8 * BYTES_PER_SLOT["fused"] <= budget
+    assert groups * 8 * BYTES_PER_SLOT["wide"] > budget

@@ -382,9 +382,73 @@ def test_ici_sync_matches_model_wide(seed, ways):
     _run_fuzz(seed, num_slots=NDEV * 8, ways=ways, layout="wide")
 
 
-# The narrow (split-word) layout runs the replica decide layout-native
-# and crosses the to_wide/from_wide seam every sync tick — the packed
-# LIMBUR word must survive the psum merge bit-exactly (ops/narrow.py).
-@pytest.mark.parametrize("seed,ways", [(3, 1), (4, 4)])
-def test_ici_sync_matches_model_narrow(seed, ways):
-    _run_fuzz(seed, num_slots=NDEV * 8, ways=ways, layout="narrow")
+# Replica groups of 2 and of 8 ways: four groups share one fused line
+# of a replica's table, or one group fills it, and the sync tick takes
+# and puts whole lines either way (ops/fused.py take_groups/put_groups).
+@pytest.mark.parametrize("seed,ways", [(3, 2), (4, 8)])
+def test_ici_sync_matches_model_line_geometry(seed, ways):
+    _run_fuzz(seed, num_slots=NDEV * 8, ways=ways, layout="fused")
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+@pytest.mark.parametrize("seed", [31, 32, 33, 34])
+def test_replica_decide_matches_oracle(seed, n_dev):
+    """The replica decide (the program GLOBAL traffic launches) against
+    the oracle, at the replica tier's 4 ways: a key always lands on the
+    same replica here, so with no sync in between one oracle stands for
+    them all. Waves of up to 16 lanes in distinct groups, the kernel
+    fuzz's request mix with GLOBAL on most lanes."""
+    ways, groups, lanes = 4, 256, 16
+    mesh = pmesh.make_mesh(jax.devices()[:n_dev])
+    state = ici.create_ici_state(mesh, groups * ways, ways)
+    decide = batch_entry(ici.make_replica_decide(mesh, groups * ways, ways))
+    oracle = OracleEngine()
+    rng = random.Random(seed)
+    keys = [f"rp:{i}" for i in range(40)]
+    now = NOW
+    for step in range(60):
+        now += rng.choice([0, 1, 7, 500, 3000, 61_000])
+        reqs, homes, used = [], [], set()
+        for _ in range(rng.randrange(1, lanes + 1)):
+            behavior = int(Behavior.GLOBAL) if rng.random() < 0.8 else 0
+            if rng.random() < 0.08:
+                behavior |= Behavior.RESET_REMAINING
+            if rng.random() < 0.15:
+                behavior |= Behavior.DRAIN_OVER_LIMIT
+            r = RateLimitReq(
+                name=rng.choice(["a", "b"]),
+                unique_key=rng.choice(keys),
+                algorithm=rng.choice(
+                    [Algorithm.TOKEN_BUCKET, Algorithm.LEAKY_BUCKET]
+                ),
+                behavior=behavior,
+                duration=rng.choice([0, 5, 1000, 30_000, 60_000]),
+                limit=rng.choice([0, 1, 2, 10, 100, 2000]),
+                hits=rng.choice([-5, -1, 0, 1, 1, 2, 5, 10, 99, 3000]),
+                burst=rng.choice([0, 0, 5, 30]),
+            )
+            lo = key_hash128(r.hash_key())[1]
+            g = group_of(lo, groups)
+            if g not in used:
+                used.add(g)
+                reqs.append(r)
+                homes.append(lo % n_dev)
+        b = encode_batch(
+            [dataclasses.replace(r) for r in reqs], now, groups, lanes
+        )
+        home = np.zeros(lanes, dtype=np.int64)
+        home[: len(homes)] = homes
+        state, out = decide(state, b, home, now)
+        for i, r in enumerate(reqs):
+            want = oracle.decide(dataclasses.replace(r), now)
+            got = (int(out.status[i]), int(out.limit[i]),
+                   int(out.remaining[i]), int(out.reset_time[i]))
+            assert got == (int(want.status), int(want.limit),
+                           int(want.remaining), int(want.reset_time)), (
+                f"seed {seed} x{n_dev} step {step} lane {i}: {r}"
+            )
+    # A replica owes the owner what it took of keys it does not own:
+    # one device owns everything, four leave deltas behind.
+    owed = np.asarray(ici.pending_hits(state))
+    assert owed.shape == (n_dev, groups * ways)
+    assert bool(owed.any()) == (n_dev > 1)

@@ -183,7 +183,7 @@ _CASES = [
         {"'decide_turbo'", "'decide_scan_turbo'",
          "requires a non-empty reason"},
         3,  # 2 uncovered entry points + 1 reason-less pragma; names
-            # covered by the real parity map (decide, decide_flat) and
+            # covered by the real parity map (decide, decide_fused) and
             # the reasoned-pragma reference stay quiet
     ),
     (

@@ -1,6 +1,6 @@
 """Unified mesh engine (runtime/engine.py MeshEngine + runtime/topology.py):
 mesh shape (1,) IS the single-chip engine, and the (chips,) sharded tier
-must be bit-exact with it — across every table layout, flat AND paged,
+must be bit-exact with it — on both table layouts, flat AND paged,
 through demote/promote churn, across pipeline depths, and across a
 snapshot handover between a flat single-chip engine and a paged mesh
 engine. The single-chip depth/bit-exactness pins live in
@@ -76,20 +76,25 @@ def _fuzz_reqs(rng, n, keys):
 
 
 # ---------------------------------------------------------------------------
-# mesh vs single-chip bit-exact parity, all four layouts, flat AND paged
+# mesh vs single-chip bit-exact parity, both layouts, flat AND paged, over
+# all 8 faked devices and over 4 (a v5e host's mesh)
 
 
-@pytest.mark.parametrize("layout", ["fused", "narrow", "wide", "packed"])
-def test_mesh_matches_single_chip(layout):
+@pytest.mark.parametrize("n_dev", [8, 4])
+@pytest.mark.parametrize("layout", ["fused", "wide"])
+def test_mesh_matches_single_chip(layout, n_dev):
     """The same fuzz stream (duplicates, resets, clock jumps, both
     algorithms) through the flat single-chip engine (the oracle — mesh
     shape (1,)), the flat mesh sharded tier, and the PAGED mesh sharded
     tier: every response bit-exact, at every step."""
+    import jax
+
     clock = {"now": NOW}
     rng = random.Random(hash(layout) & 0xFFFF)
+    devices = jax.devices()[:n_dev]
     single = mk_flat_single(layout, clock)
-    mesh_flat = mk_mesh(layout, clock)
-    mesh_paged = mk_mesh(layout, clock, paged=True)
+    mesh_flat = mk_mesh(layout, clock, devices=devices)
+    mesh_paged = mk_mesh(layout, clock, paged=True, devices=devices)
     try:
         for _ in range(5):
             clock["now"] += rng.choice([1, 700, 6_000])
@@ -100,11 +105,11 @@ def test_mesh_matches_single_chip(layout):
             got_flat = [tup(r) for r in mesh_flat.check_batch(
                 [dataclasses.replace(r) for r in reqs]
             )]
-            assert got_flat == want, f"flat mesh diverged ({layout})"
+            assert got_flat == want, f"flat mesh diverged ({layout} x{n_dev})"
             got_paged = [tup(r) for r in mesh_paged.check_batch(
                 [dataclasses.replace(r) for r in reqs]
             )]
-            assert got_paged == want, f"paged mesh diverged ({layout})"
+            assert got_paged == want, f"paged mesh diverged ({layout} x{n_dev})"
     finally:
         single.close()
         mesh_flat.close()

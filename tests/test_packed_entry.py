@@ -1,7 +1,7 @@
 """The decide program's packed interface (ops/layout.py): one uploaded
 operand in, one output vector out, bit-identical to the RequestBatch
-entry it wraps — for every layout, with and without the store columns,
-through the paged kernels, the Pallas route, and the mesh and replica
+entry it wraps — for both layouts at 4 and 8 ways, with and without
+the store columns, through the paged kernels, and the mesh and replica
 programs on faked devices."""
 
 import dataclasses
@@ -129,32 +129,35 @@ def test_operand_round_trip():
     np.testing.assert_array_equal(again.buf, op.wave(1).buf)
 
 
+@pytest.mark.parametrize("ways", [4, 8])
 @pytest.mark.parametrize("with_store", [False, True])
 @pytest.mark.parametrize("layout", LAYOUTS)
-def test_packed_entry_is_the_batch_entry(layout, with_store):
+def test_packed_entry_is_the_batch_entry(layout, with_store, ways):
     K = get_kernels(layout)
-    ta, tb = K.create(NUM_GROUPS, WAYS), K.create(NUM_GROUPS, WAYS)
+    groups = NUM_GROUPS * WAYS // ways  # as many slots, so groups still fill
+    ta, tb = K.create(groups, ways), K.create(groups, ways)
     evicted = 0
-    for i, (batch, now) in enumerate(corpus(7)):
-        ta, want = K.decide(ta, batch, now, WAYS, with_store)
+    for i, (batch, now) in enumerate(corpus(7, num_groups=groups)):
+        ta, want = K.decide(ta, batch, now, ways, with_store)
         tb, vec = K.decide_packed(
-            tb, WaveOperand.of(batch, now).buf, WAYS, with_store
+            tb, WaveOperand.of(batch, now).buf, ways, with_store
         )
         vec = np.asarray(vec)
         assert vec.dtype == np.int64
         assert vec.shape == ((8 if with_store else 4) * B + 4,)
         assert_same(output_struct(vec, with_store), want, with_store,
-                    f"{layout} step {i}")
+                    f"{layout} {ways} ways step {i}")
         evicted += int(np.count_nonzero(np.asarray(want.evicted_hi)))
     assert evicted > 0  # the store columns carried values
     assert_same_table(K, ta, tb)
 
 
-@pytest.mark.parametrize("layout", ["fused", "narrow"])
-def test_paged_packed_entry_is_the_batch_entry(layout):
-    PK = get_paged_kernels(layout, NUM_GROUPS, WAYS, 8, 8)
+@pytest.mark.parametrize("gpp", [8, 4])
+def test_paged_packed_entry_is_the_batch_entry(gpp):
+    layout, pages = "fused", NUM_GROUPS // gpp
+    PK = get_paged_kernels(layout, NUM_GROUPS, WAYS, gpp, pages)
     pa, pb = PK.create(), PK.create()
-    for lp in range(8):  # every logical page resident
+    for lp in range(pages):  # every logical page resident
         z = np.int32(lp)
         pa, pb = PK.bind_page(pa, z, z), PK.bind_page(pb, z, z)
     for i, (batch, now) in enumerate(corpus(11)):
@@ -163,39 +166,23 @@ def test_paged_packed_entry_is_the_batch_entry(layout):
             pb, WaveOperand.of(batch, now).buf, WAYS, True
         )
         assert_same(output_struct(vec, True), want, True,
-                    f"paged {layout} step {i}")
+                    f"paged {gpp} groups a page step {i}")
     assert_same_table(PK, pa, pb)
-
-
-@pytest.mark.parametrize("layout", ["fused", "narrow"])
-def test_pallas_route_packed_entry(layout, monkeypatch):
-    """GUBER_KERNEL=pallas routes both entries through the Pallas
-    program's lowering (the XLA reference lowering off the chip)."""
-    monkeypatch.setenv("GUBER_KERNEL", "pallas")
-    K = get_kernels(layout)
-    ta, tb = K.create(NUM_GROUPS, WAYS), K.create(NUM_GROUPS, WAYS)
-    for i, (batch, now) in enumerate(corpus(13, steps=20)):
-        ta, want = K.decide(ta, batch, now, WAYS, True)
-        tb, vec = K.decide_packed(
-            tb, WaveOperand.of(batch, now).buf, WAYS, True
-        )
-        assert_same(output_struct(vec, True), want, True,
-                    f"pallas {layout} step {i}")
-    assert_same_table(K, ta, tb)
 
 
 NDEV = 8
 
 
+@pytest.mark.parametrize("n_dev", [NDEV, 4])
 @pytest.mark.parametrize("layout", ["fused", "wide"])
-def test_mesh_program_is_the_batch_entry(layout):
-    """The owner-sharded packed program over 8 faked devices answers
-    as the single-table RequestBatch entry does: every lane has one
-    owner, so the psum of the packed vectors is that owner's answer."""
+def test_mesh_program_is_the_batch_entry(layout, n_dev):
+    """The owner-sharded packed program over 8 (and 4) faked devices
+    answers as the single-table RequestBatch entry does: every lane has
+    one owner, so the psum of the packed vectors is that owner's answer."""
     from gubernator_tpu.parallel import mesh as pmesh
 
     groups = 8 * NDEV
-    mesh = pmesh.make_mesh(jax.devices()[:NDEV])
+    mesh = pmesh.make_mesh(jax.devices()[:n_dev])
     table = pmesh.create_sharded_table(mesh, groups, ways=WAYS, layout=layout)
     decide = pmesh.make_sharded_decide(mesh, groups, ways=WAYS, layout=layout)
     K = get_kernels(layout)
@@ -204,7 +191,7 @@ def test_mesh_program_is_the_batch_entry(layout):
         flat, want = K.decide(flat, batch, now, WAYS, False)
         table, vec = decide(table, WaveOperand.of(batch, now).buf)
         assert_same(output_struct(vec), want, False,
-                    f"mesh {layout} step {i}")
+                    f"mesh {layout} x{n_dev} step {i}")
     assert_same_table(K, flat, table)
 
 

@@ -5,7 +5,7 @@ snapshot/restore and ownership handover — and promotion must be safe
 against concurrent flushes (it runs under the same engine lock).
 
 The ops-level twin (scrambled placement, demand-paging churn vs the
-flat kernel oracle, all four layouts) lives in tests/test_kernel_fuzz.py;
+flat kernel oracle, both layouts) lives in tests/test_kernel_fuzz.py;
 here the flat DeviceEngine is the oracle.
 """
 
@@ -89,20 +89,18 @@ def _fuzz_reqs(seed, n=120, keys=20):
 # bit-exactness vs the flat engine
 
 
-@pytest.mark.parametrize("layout", ["fused", "narrow"])
-def test_paged_engine_matches_flat(layout):
+@pytest.mark.parametrize("page_groups", [PAGE_GROUPS, 8])
+def test_paged_engine_matches_flat(page_groups):
     """Same request stream, small mixed batches: a fully-resident paged
-    engine and demand-paged engine (budget 2 of 8 pages) must both
-    answer exactly like the flat engine."""
+    engine and demand-paged engine (budget 2 of the 8, or 32, pages)
+    must both answer exactly like the flat engine."""
     reqs = _fuzz_reqs(7)
-    flat = make_engine(layout=layout)
+    flat = make_engine()
     resident = make_engine(
-        layout=layout, page_groups=PAGE_GROUPS, page_budget=8
+        page_groups=page_groups, page_budget=NUM_GROUPS // page_groups
     )
     # budget=2: single-key batches so one wave never exceeds the budget
-    paged = make_engine(
-        layout=layout, page_groups=PAGE_GROUPS, page_budget=2
-    )
+    paged = make_engine(page_groups=page_groups, page_budget=2)
     try:
         for i in range(0, len(reqs), 4):
             chunk = [dataclasses.replace(r) for r in reqs[i:i + 4]]
@@ -121,7 +119,7 @@ def test_paged_engine_matches_flat(layout):
             assert got_paged == want, f"demand-paged diverged at chunk {i}"
         pager = paged._pager
         assert pager.demotes > 0 and pager.promotes > 0, (
-            "budget 2 of 8 pages never cycled — the test isn't "
+            "budget 2 never cycled — the test isn't "
             "exercising demand paging"
         )
     finally:

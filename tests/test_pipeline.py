@@ -80,15 +80,16 @@ def test_depth1_matches_depth2_bitexact():
     assert a == b
 
 
+@pytest.mark.parametrize("depth", [3, 4])
 @pytest.mark.parametrize("layout", list(LAYOUTS))
-def test_pipelined_matches_serial_all_layouts(layout):
-    """Bit-exact across every table layout with pipelining on (the
+def test_pipelined_matches_serial_all_layouts(layout, depth):
+    """Bit-exact on both table layouts with pipelining on (the
     engine-level twin of the kernel fuzz suite's acceptance)."""
     import dataclasses
 
     reqs = _trace(n=120, n_keys=11)
     a = _run(1, [dataclasses.replace(r) for r in reqs], layout=layout)
-    b = _run(3, [dataclasses.replace(r) for r in reqs], layout=layout)
+    b = _run(depth, [dataclasses.replace(r) for r in reqs], layout=layout)
     assert a == b
 
 

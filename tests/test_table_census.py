@@ -1,5 +1,5 @@
-"""Table census: device program vs pure-numpy oracle (bit-exact, all
-four layouts + the stacked ici-replica variant), clamp/wraparound and
+"""Table census: device program vs pure-numpy oracle (bit-exact, both
+layouts at 8 and 4 ways + the stacked ici-replica variant), clamp/wraparound and
 expired-slot edges, determinism, the engine-side TTL cache + churn
 ledger, and the scrape-never-compiles invariant the observatory is
 built around (guberlint GL009; docs/monitoring.md "Table census")."""
@@ -38,9 +38,7 @@ def mk(key="k", **kw):
 def random_wide(rng, groups=GROUPS, ways=WAYS, density=0.5, now=NOW):
     """Random WIDE table (host numpy arrays) with adversarial time
     fields: ages up to ~weeks, future stamps (wraparound clamp), and a
-    mix of expired and live windows. Value ranges stay inside every
-    packed layout's representable field widths so the round trip
-    through from_wide/to_wide is lossless."""
+    mix of expired and live windows."""
     n = groups * ways
     used = rng.random(n) < density
     z = np.zeros(n, dtype=np.int64)
@@ -86,37 +84,41 @@ def assert_census_equals_oracle(out: CensusOutput, want: dict):
 # ---- kernel vs oracle -------------------------------------------------------
 
 
+@pytest.mark.parametrize("ways", [WAYS, 4])
 @pytest.mark.parametrize("layout", list(LAYOUTS))
-def test_census_bit_exact_vs_oracle(layout):
+def test_census_bit_exact_vs_oracle(layout, ways):
     rng = np.random.default_rng(0xCE)
     RK = get_raw_kernels(layout)
-    census = get_census(layout, WAYS, heatmap_width=16)
+    census = get_census(layout, ways, heatmap_width=16)
     for trial in range(4):
-        wide = random_wide(rng, density=(0.1, 0.5, 0.9, 1.0)[trial])
+        wide = random_wide(
+            rng, ways=ways, density=(0.1, 0.5, 0.9, 1.0)[trial]
+        )
         table = RK.to_wide(RK.from_wide(wide))  # oracle sees the exact
         out = census(RK.from_wide(wide), NOW)  # logical table the
         want = census_oracle(  # device scans
             jax.tree.map(np.asarray, table),
             NOW,
-            ways=WAYS,
+            ways=ways,
             heatmap_width=16,
         )
         assert_census_equals_oracle(out, want)
 
 
+@pytest.mark.parametrize("ways", [WAYS, 4])
 @pytest.mark.parametrize("layout", list(LAYOUTS))
-def test_census_stacked_replica_tier_matches_flat(layout):
+def test_census_stacked_replica_tier_matches_flat(layout, ways):
     """The ici replica tier's stacked=True variant scans replica 0 of a
     (D, ...) stacked table — identical output to the flat program."""
     rng = np.random.default_rng(7)
     RK = get_raw_kernels(layout)
-    wide = random_wide(rng, groups=16, density=0.6)
+    wide = random_wide(rng, groups=16, ways=ways, density=0.6)
     table = RK.from_wide(wide)
     stacked = jax.tree.map(
         lambda x: np.stack([np.asarray(x)] * 2), table
     )
-    flat = get_census(layout, WAYS, heatmap_width=8)(table, NOW)
-    rep = get_census(layout, WAYS, heatmap_width=8, stacked=True)(
+    flat = get_census(layout, ways, heatmap_width=8)(table, NOW)
+    rep = get_census(layout, ways, heatmap_width=8, stacked=True)(
         stacked, NOW
     )
     for f in flat._fields:

@@ -1168,8 +1168,8 @@ _parity_cases_cache: Optional[Tuple[Dict[str, str], Set[str]]] = None
 
 
 def _normalize_decide_name(name: str) -> str:
-    """Registry spelling -> parity-map key: `_decide_narrow_impl` and
-    `decide_narrow` are the same entry point."""
+    """Registry spelling -> parity-map key: `_decide_fused_impl` and
+    `decide_fused` are the same entry point."""
     name = name.lstrip("_")
     if name.endswith("_impl"):
         name = name[: -len("_impl")]
