@@ -730,6 +730,13 @@ def engine_histograms() -> dict:
             "Groups merged per ICI GLOBAL sync tick.",
             scale=cnt, n_buckets=26,
         ),
+        "ici_tick_width": Log2Histogram(
+            "gubernator_ici_tick_width",
+            "Width in groups of the block an ICI GLOBAL sync tick "
+            "merged at: the least of its ladder that held the groups "
+            "it found active (the whole table on a full tick).",
+            scale=cnt, n_buckets=26,
+        ),
         "stage_duration": Log2Histogram(
             "gubernator_engine_stage_duration",
             "Per-stage request-lifecycle latency in seconds, by stage: "

@@ -421,6 +421,117 @@ def put_groups(
     )
 
 
+def _mix32(x):
+    """murmur3's 32-bit finalizer (elementwise, uint32): the avalanche
+    of the sync tick's content fingerprints. A bijection, and arithmetic
+    the device has lanes for."""
+    x = (x ^ (x >> 16)) * U32(0x85EBCA6B)
+    x = (x ^ (x >> 13)) * U32(0xC2B2AE35)
+    return x ^ (x >> 16)
+
+
+def _any_of_group(flags, ways: int):
+    """(N,) bool -> (G,) bool: whether any of a group's `ways`
+    consecutive flags holds. 128 lanes hold 128 / ways whole groups, and
+    a 0/1 matrix on the matrix unit sums each group's lanes (exact: 0
+    and 1 in bfloat16, the sums in float32). A reshape to (G, ways)
+    makes the device pad `ways` to 128 lanes: 0.4 ms a plane of
+    1,048,576 slots on a v5e, against 0.07 (PERF.md §6, PR 31). Tables
+    that do not divide so (tests' tiny ones) take the reshape."""
+    if flags.shape[0] % 128 or 128 % ways:
+        return flags.reshape(-1, ways).any(axis=1)
+    group_of_lane = jnp.arange(128, dtype=jnp.int32) // ways
+    pick = (
+        group_of_lane[:, None]
+        == jnp.arange(128 // ways, dtype=jnp.int32)[None, :]
+    )
+    held = jnp.dot(
+        flags.reshape(-1, 128).astype(jnp.bfloat16),
+        pick.astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
+    )
+    return (held > 0).reshape(-1)
+
+
+# 32-bit accumulators of a group's content fingerprint: 128 bits, what the
+# generic walk's two uint64 sums hold (parallel/ici.py).
+FINGERPRINT_WORDS = 4
+
+
+def group_signals(table: FusedTable, pending, now, ways: int):
+    """What the capped sync tick selects groups by (parallel/ici.py
+    make_sync_step), from one read of the table as it lies: a group is
+    `ways * SLOT_WORDS` consecutive words of the lines, so the table
+    reshapes to one row a group, and every output is a sum over a row's
+    lanes in 32-bit arithmetic. `pending` is the (2, N) uint32 hit
+    deltas (low words, high words). Returns
+
+    - (FINGERPRINT_WORDS, G) int32 content fingerprints: independently
+      salted sums over the group's words, each word mixed with a salt
+      of its place in the group (way and word) and of the accumulator,
+      so the same keys at other ways read differently. int32 because
+      the chips compare them by a psum. `pending` is not in them: where
+      any of it is set the next flag holds, and where none is, it is
+      the same zeros on every chip;
+    - (G,) bool: some slot of the group has hits pending;
+    - (G,) bool: some slot of the group is used and expired by `now`
+      (the full merge would erase it, though it reads the same on every
+      chip). A slot's three facts sit in three lanes (META's used bit,
+      EXP's two words against `now`'s), so each lane contributes its
+      bits to its way's nibble of one more sum (up to 8 ways a sum), and
+      the nibbles are judged group by group: no lane meets another but
+      in a sum."""
+    g = table.num_slots // ways
+    width = ways * SLOT_WORDS
+    # Behind a barrier: left free, the compiler may move the lane-wise
+    # work before the reshape and re-lay-out each of its results, three
+    # copies of the table where this is one (seen for a one-device mesh).
+    rows = jax.lax.optimization_barrier(table.data.reshape(g, width))
+    place = jnp.arange(width, dtype=U32)
+    word, way = place % SLOT_WORDS, place // SLOT_WORDS
+    fps = []
+    for a in range(1, FINGERPRINT_WORDS + 1):
+        salt = _mix32(place * U32(0x9E3779B9) + U32((0x7F4A7C15 * a) & _LOW))
+        fps.append(_mix32(rows ^ salt).sum(axis=1, dtype=U32))
+
+    now_lo, now_hi = split(now)
+
+    def signed(x):
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+    # used: 1, EXP's low word below now's: 2, high word below: 4, equal: 8
+    facts = jnp.where(
+        word == META,
+        rows & META_USED,
+        jnp.where(
+            word == EXP,
+            (rows < now_lo).astype(U32) << 1,
+            jnp.where(
+                word == NCOLS + EXP,
+                (signed(rows) < signed(now_hi)).astype(U32) << 2
+                | (rows == now_hi).astype(U32) << 3,
+                0,
+            ),
+        ),
+    )
+    expired = jnp.zeros(g, dtype=bool)
+    for first in range(0, ways, 8):
+        mine = (way >= first) & (way < first + 8)
+        nibbles = jnp.where(mine, facts << (4 * (way % 8)), 0).sum(
+            axis=1, dtype=U32
+        )
+        for w in range(min(8, ways - first)):
+            n = nibbles >> (4 * w)
+            expired |= ((n & 1) != 0) & (
+                ((n & 4) != 0) | (((n & 8) != 0) & ((n & 2) != 0))
+            )
+    return (
+        jax.lax.bitcast_convert_type(jnp.stack(fps), jnp.int32),
+        _any_of_group((pending[0] | pending[1]) != 0, ways),
+        expired,
+    )
+
+
 def _probe(rows, batch, now):
     """Way selection over a gathered (B, W, C) block (see probe_ways)."""
     return probe_ways(

@@ -175,13 +175,15 @@ class RawKernels(NamedTuple):
     inject: object  # (table, items, now, ways) -> (table, ehi, elo)
     to_wide: object  # table -> SlotTable (traceable)
     from_wide: object  # SlotTable -> table (traceable)
-    # The sync tick's compaction and fingerprints (parallel/ici.py),
+    # The sync tick's compaction and selection (parallel/ici.py),
     # layout-native: the table of groups `gids` (C,) alone, `ways` slots
     # each (an index past the end reads the last slots); `table` with
     # such a table written back at those groups (an index past the end
-    # writes nothing); a pytree of per-slot (N, ...) arrays holding the
-    # state. The defaults index per-slot leaves and are wide's; fused
-    # has its own, over lines. These are the only two.
+    # writes nothing); (table, pending words, now, ways) -> the groups'
+    # content fingerprints, pending flags and expiry flags
+    # (ops/fused.py group_signals). The defaults index per-slot leaves
+    # and are wide's (no selector: the tick walks its leaves); fused has
+    # its own, over lines. These are the only two.
     take_groups: object = lambda t, gids, ways: jax.tree.map(
         lambda a: jnp.take(a, _group_slots(gids, ways), axis=0, mode="clip"),
         t,
@@ -190,7 +192,7 @@ class RawKernels(NamedTuple):
         lambda full, p: full.at[_group_slots(gids, ways)].set(p, mode="drop"),
         t, part,
     )
-    slot_leaves: object = lambda t: t
+    group_signals: object = None
 
 
 def get_census(layout: str, ways: int, **kwargs):
@@ -274,6 +276,6 @@ def get_raw_kernels(layout: str) -> RawKernels:
             from_wide=_f.pack_table,
             take_groups=_f.take_groups,
             put_groups=_f.put_groups,
-            slot_leaves=_f.FusedTable.cols,
+            group_signals=_f.group_signals,
         )
     raise ValueError(f"unknown table layout: {layout!r}")

@@ -309,6 +309,59 @@ def _mix64(x):
     return x ^ (x >> jnp.uint64(31))
 
 
+def _leaf_signals(table, pending, now, ways: int):
+    """The capped tick's selector over a table that is a pytree of
+    per-slot (N, ...) leaves (wide, the reference), in the form of
+    ops/kernels.py `RawKernels.group_signals`; the serving layout brings
+    its own over its lines (ops/fused.py `group_signals`). TWO
+    independently-salted per-group uint64 content fingerprints over the
+    leaves + pending, accumulated in a single traversal. Way position is
+    salted in, so the same keys at different ways on different devices
+    still diverge. Returned as int64 (same bits, same wrap-around sums):
+    XLA:TPU lowers a 64-bit Sum all-reduce for s64 only and refuses the
+    u64 one ("UNIMPLEMENTED: Supported lowering only of Sum all
+    reduce", libtpu 0.0.34)."""
+    num_slots = pending.shape[-1]
+    G, W = num_slots // ways, ways
+    accs = [jnp.zeros(num_slots, jnp.uint64) for _ in range(2)]
+    col = 0
+    for leaf in jax.tree_util.tree_leaves(table):
+        x = leaf.reshape(num_slots, -1).astype(jnp.uint64)
+        for s in range(2):
+            salts = (
+                jnp.arange(x.shape[1], dtype=jnp.uint64)
+                + jnp.uint64(col + s + 1)
+            ) * jnp.uint64(0x9E3779B97F4A7C15)
+            accs[s] = accs[s] + _mix64(x + salts[None, :]).sum(
+                axis=1, dtype=jnp.uint64
+            )
+        col += x.shape[1]
+    wsalt = jnp.arange(W, dtype=jnp.uint64) * jnp.uint64(0xD6E8FEB86659FD93)
+    p64 = join(pending[0], pending[1])
+    fps = [
+        _mix64(
+            (accs[s] + _mix64(p64.astype(jnp.uint64) + jnp.uint64(col + s + 1)))
+            .reshape(G, W)
+            + wsalt[None, :]
+        ).sum(axis=1, dtype=jnp.uint64)
+        for s in range(2)
+    ]
+    return (
+        jnp.stack(fps).astype(I64),
+        (p64 != 0).reshape(G, W).any(axis=1),
+        (table.used & (table.expire_at < now)).reshape(G, W).any(axis=1),
+    )
+
+
+def _block_widths(cap: int) -> tuple:
+    """The widths, in groups, a capped tick merges at, ascending: it
+    takes the least that holds the groups it found active. Three at
+    most and `cap` the widest: each is one more copy of the merge to
+    compile, and a narrower block than a sixty-fourth of the cap saves
+    nothing the fixed work of a tick would show."""
+    return tuple(sorted({max(1, cap // 64), max(1, cap // 8), cap}))
+
+
 def make_sync_step(
     mesh: Mesh,
     num_slots: int,
@@ -334,14 +387,18 @@ def make_sync_step(
     (G,W,W) merge + ~20 full-table psums scale with TABLE size and blow
     the 100ms cadence at 10M keys). When set, the tick first finds
     groups needing sync — any device's group content fingerprint
-    diverges, or pending deltas exist (three group-sized psums, the only
-    full-size collectives) — then gathers up to C=max_sync_groups of
-    them compactly and runs the identical merge on the compact view.
-    Tick cost then scales with ACTIVE groups, not table size. Overflow
-    beyond C stays dirty and is picked up next tick (diag[2] reports the
-    backlog); the scan start rotates with `now` so a persistent
-    over-budget load cannot starve any group. None = unbounded (exact
-    single-pass semantics; the two paths are differentially tested)."""
+    diverges, pending deltas exist, or an entry has expired (one
+    group-sized psum, the only full-size collective; the fingerprints are
+    the layout's own, `RawKernels.group_signals`, one read of the table
+    as it lies) — then gathers up to C=max_sync_groups of them
+    compactly and runs the identical merge on the compact view, at the
+    least of `_block_widths(C)` that holds what it found: every chip
+    knows the count, so the choice is made inside the one program.
+    Overflow beyond C stays dirty and is picked up next tick (diag[2]
+    reports the backlog); the scan start rotates with `now` so a
+    persistent over-budget load cannot starve any group. None =
+    unbounded (exact single-pass semantics; the two paths are
+    differentially tested)."""
     n_dev = mesh.devices.size
     num_groups = num_slots // ways
     groups_per = num_groups // n_dev
@@ -349,40 +406,7 @@ def make_sync_step(
     RK = get_raw_kernels(layout)
     C = G if max_sync_groups is None else max(1, min(int(max_sync_groups), G))
     capped = C < G
-
-    def group_fps(native, pending):
-        """TWO independently-salted per-group uint64 content fingerprints
-        over the layout-native leaves + pending, accumulated in a single
-        traversal (this full-table pass is the capped tick's dominant
-        fixed cost — don't walk the leaves twice). Way position is
-        salted in, so the same keys at different ways on different
-        devices still diverge. Elementwise + local only — no
-        collectives."""
-        accs = [jnp.zeros(num_slots, jnp.uint64) for _ in range(2)]
-        col = 0
-        for leaf in jax.tree_util.tree_leaves(RK.slot_leaves(native)):
-            x = leaf.reshape(num_slots, -1).astype(jnp.uint64)
-            for s in range(2):
-                salts = (
-                    jnp.arange(x.shape[1], dtype=jnp.uint64)
-                    + jnp.uint64(col + s + 1)
-                ) * jnp.uint64(0x9E3779B97F4A7C15)
-                accs[s] = accs[s] + _mix64(x + salts[None, :]).sum(
-                    axis=1, dtype=jnp.uint64
-                )
-            col += x.shape[1]
-        wsalt = jnp.arange(W, dtype=jnp.uint64) * jnp.uint64(
-            0xD6E8FEB86659FD93
-        )
-        p64 = pending.astype(jnp.uint64)
-        return tuple(
-            _mix64(
-                (accs[s] + _mix64(p64 + jnp.uint64(col + s + 1)))
-                .reshape(G, W)
-                + wsalt[None, :]
-            ).sum(axis=1, dtype=jnp.uint64)
-            for s in range(2)
-        )
+    signals = RK.group_signals or _leaf_signals
 
     def merge_block(dev, t, pending, gids, valid, now, psum):
         """The sync merge over a block of groups. `t` is a wide SlotTable
@@ -617,18 +641,19 @@ def make_sync_step(
     def local(state: IciState, now):
         dev = jax.lax.axis_index(AXIS).astype(I64)
         native = _squeeze(state.table)
-        pending = join(state.pending[0, 0], state.pending[0, 1])
+        words = state.pending[0]
         psum = lambda x: jax.lax.psum(x, AXIS)  # noqa: E731
 
         if not capped:
             gids = jnp.arange(G, dtype=I64)
             valid = jnp.ones(G, dtype=bool)
             new_t, new_p, kept_total, dropped_total = merge_block(
-                dev, RK.to_wide(native), pending, gids, valid, now, psum
+                dev, RK.to_wide(native), join(words[0], words[1]),
+                gids, valid, now, psum,
             )
             diag = jnp.stack(
                 [kept_total, dropped_total, jnp.zeros((), I64),
-                 jnp.full((), G, I64)]
+                 jnp.full((), G, I64), jnp.full((), G, I64)]
             )[None, :]
             return (
                 IciState(
@@ -641,30 +666,23 @@ def make_sync_step(
 
         # Delta compaction: find groups needing sync (content diverges
         # across devices, or pending deltas exist anywhere), then merge
-        # up to C of them on a compact gather. Two salted fingerprints
-        # make a cross-device hash collision (a diverged group reading
-        # as clean) astronomically unlikely; identical-content groups
-        # are exactly the ones the full merge would leave unchanged.
-        # Compared on the int64 view (same bits, same wrap-around sums):
-        # XLA:TPU lowers a 64-bit Sum all-reduce for s64 only and refuses
-        # the u64 one ("UNIMPLEMENTED: Supported lowering only of Sum
-        # all reduce", libtpu 0.0.34).
-        f1, f2 = (f.astype(I64) for f in group_fps(native, pending))
-        nd = jnp.int64(n_dev)
-        diverged = (psum(f1) != f1 * nd) | (psum(f2) != f2 * nd)
-        has_pend = psum(
-            (pending != 0).reshape(G, W).any(axis=1).astype(I64)
-        ) > 0
-        # Expired-but-identical groups fool the fingerprint (content
-        # equal everywhere) yet the full merge would ERASE them; flag
-        # them active so capped and unbounded sync stay bit-identical.
-        # Local-only: identical content expires identically on every
-        # device, no collective needed.
-        expired_any = (
-            (native.used & (native.expire_at < now))
-            .reshape(G, W).any(axis=1)
+        # up to C of them on a compact gather. Independently salted
+        # fingerprints make a cross-device hash collision (a diverged
+        # group reading as clean) astronomically unlikely;
+        # identical-content groups are exactly the ones the full merge
+        # would leave unchanged. Expired-but-identical groups fool the
+        # fingerprint (content equal everywhere) yet the full merge would
+        # ERASE them; they are active too, so capped and unbounded sync
+        # stay bit-identical. That flag is local-only: identical content
+        # expires identically on every device, no collective needed.
+        fps, has_pend, expired_any = signals(native, words, now, W)
+        total = psum(
+            jnp.concatenate([fps, has_pend.astype(fps.dtype)[None]])
         )
-        g_act = diverged | has_pend | expired_any
+        diverged = (total[:-1] != fps * n_dev).any(axis=0)
+        g_act = diverged | (total[-1] > 0) | expired_any
+        # The most any chip counts: what every chip then chooses by.
+        n_act = jax.lax.pmax(jnp.sum(g_act, dtype=jnp.int32), AXIS)
 
         # Rotate the scan start with `now` AND the tick counter so a
         # sustained backlog can't starve any group, even when `now` is
@@ -679,46 +697,102 @@ def make_sync_step(
             % G
         )
         act_rot = jnp.roll(g_act, -start)
-        # The first C active groups, compacted in order. The rank is an
-        # associative_scan and the compaction one scatter — not
-        # jnp.cumsum + jnp.nonzero(size=C), which are three cumulative
-        # sums inside: on TPU those lower to reduce-windows whose
-        # compile time explodes with length (ops/census.py measured
-        # 205 s for one over 262,144 elements on a v5e).
-        rank = jax.lax.associative_scan(jnp.add, act_rot.astype(I64))
-        in_cap = act_rot & (rank <= C)
-        idx_rot = (
-            jnp.full((C,), -1, dtype=I64)
-            .at[jnp.where(in_cap, rank - 1, C)]
-            .set(jnp.arange(G, dtype=I64), mode="drop")
-        )
-        valid = idx_rot >= 0
-        gids = jnp.where(valid, (idx_rot + start) % G, G)  # G = sentinel
-        slots = (
-            gids[:, None] * W + jnp.arange(W, dtype=I64)[None, :]
-        ).reshape(C * W)
+        # The rank of each active group in scan order. An
+        # associative_scan — not jnp.cumsum + jnp.nonzero(size=C), which
+        # are three cumulative sums inside: on TPU those lower to
+        # reduce-windows whose compile time explodes with length
+        # (ops/census.py measured 205 s for one over 262,144 elements on
+        # a v5e).
+        rank = jax.lax.associative_scan(jnp.add, act_rot.astype(jnp.int32))
 
-        native_c = RK.take_groups(native, gids, W)
-        pending_c = jnp.take(pending, slots, axis=0, mode="clip")
-        new_tc, new_pc, kept_c, dropped_c = merge_block(
-            dev, RK.to_wide(native_c), pending_c, gids, valid, now, psum
+        def merge_at(width):
+            """The tick's merge on a block of `width` groups: the first
+            `width` active ones in scan order, compacted."""
+
+            def merge(native, words):
+                # Where the k-th active group lies: a binary search of
+                # the ranks for each k (log2 G gathers of `width`), or
+                # one scatter of every group's index to its rank (G
+                # updates), whichever moves fewer elements: on a v5e, G
+                # 262,144, the search takes 0.14 / 1.1 / 8.9 ms at 1,024
+                # / 8,192 / 65,536 and the scatter 1.3 at any (PERF.md
+                # §6, PR 31).
+                if width * G.bit_length() < G:
+                    idx_rot = jnp.searchsorted(
+                        rank, jnp.arange(1, width + 1, dtype=jnp.int32)
+                    ).astype(jnp.int32)
+                    valid = idx_rot < G
+                else:
+                    in_block = act_rot & (rank <= width)
+                    idx_rot = (
+                        jnp.full((width,), -1, dtype=jnp.int32)
+                        .at[jnp.where(in_block, rank - 1, width)]
+                        .set(jnp.arange(G, dtype=jnp.int32), mode="drop")
+                    )
+                    valid = idx_rot >= 0
+                gid = idx_rot.astype(I64) + start
+                gids = jnp.where(  # G = sentinel
+                    valid, jnp.where(gid >= G, gid - G, gid), G
+                )
+                slots = (
+                    gids[:, None] * W + jnp.arange(W, dtype=I64)[None, :]
+                ).reshape(width * W)
+
+                native_c = RK.take_groups(native, gids, W)
+                held = jnp.take(words, slots, axis=1, mode="clip")
+                new_tc, new_pc, kept_c, dropped_c = merge_block(
+                    dev, RK.to_wide(native_c), join(held[0], held[1]),
+                    gids, valid, now, psum,
+                )
+                # Sentinel groups scatter to slot >= num_slots -> dropped.
+                return (
+                    RK.put_groups(native, gids, W, RK.from_wide(new_tc)),
+                    words.at[:, slots].set(
+                        jnp.stack(split(new_pc)), mode="drop"
+                    ),
+                    jnp.stack([
+                        kept_c, dropped_c, jnp.sum(valid.astype(I64)),
+                        jnp.full((), width, I64),
+                    ]),
+                )
+
+            return merge
+
+        widths = _block_widths(C)
+        rung = jnp.sum(
+            n_act > jnp.asarray(widths[:-1], dtype=jnp.int32), dtype=jnp.int32
         )
-        native_new_c = RK.from_wide(new_tc)
-        # Sentinel groups scatter to slot >= num_slots -> dropped.
-        new_native = RK.put_groups(native, gids, W, native_new_c)
-        new_pending = pending.at[slots].set(new_pc, mode="drop")
+        # Each width's merge as a loop of one turn or none, not a branch
+        # of a `lax.switch`: a loop's state stays in its buffers, where
+        # XLA:TPU copies the table into a conditional and out of it
+        # (two to four copies of 134 MB a tick here, compiled for a
+        # v5e; none this way).
+        merged_state = (
+            native, words,
+            jax.lax.pcast(jnp.zeros(4, I64), AXIS, to="varying"),
+        )
+        for k, w in enumerate(widths):
+            merged_state = jax.lax.fori_loop(
+                0, (rung == k).astype(jnp.int32),
+                lambda _, s, merge=merge_at(w): merge(s[0], s[1]),
+                merged_state,
+            )
+        new_native, new_words, (kept_c, dropped_c, merged, width) = (
+            merged_state
+        )
 
         # kept/dropped counters from UNSELECTED overflow groups carry
         # over from the previous tick's table unchanged; the gauges
         # reflect blocks actually merged this tick, plus the backlog of
         # active groups the cap pushed to the next tick.
-        merged = jnp.sum(valid.astype(I64))
         backlog = jnp.sum(g_act.astype(I64)) - merged
-        diag = jnp.stack([kept_c, dropped_c, backlog, merged])[None, :]
+        diag = jnp.stack(
+            [kept_c, dropped_c, backlog, merged, width]
+        )[None, :]
         return (
             IciState(
                 table=_unsqueeze(new_native),
-                pending=jnp.stack(split(new_pending))[None],
+                pending=new_words[None],
                 tick=state.tick + 1,
             ),
             diag,
@@ -731,13 +805,14 @@ def make_sync_step(
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def sync_fn(state: IciState, now):
-        """Returns (new_state, diag) where diag is (n_dev, 4) int64:
+        """Returns (new_state, diag) where diag is (n_dev, 5) int64:
         diag[d] = [overflow entries kept replica-local on device d (among
                    groups merged this tick), overflow survivors dropped
                    on device d this tick, active groups beyond the cap
                    left for the next tick (identical on every device; 0
                    when unbounded), groups merged this tick (identical
-                   on every device; G when unbounded)]."""
+                   on every device; G when unbounded), the width in
+                   groups of the block they were merged in (likewise)]."""
         with jax.named_scope("ici.tick"):  # profile metadata only
             return sharded(state, jnp.asarray(now, I64))
 

@@ -234,11 +234,14 @@ class IciEngine(MeshEngine):
             self.sync_backlog = int(d[:, 2].max())
         dur = time.perf_counter() - t0
         groups = int(d[:, 3].max())
+        width = int(d[:, 4].max())
         em = self.metrics
         em.ici_tick_duration.observe(dur)
         em.ici_tick_groups.observe(groups)
+        em.ici_tick_width.observe(width)
         em.recorder.record(
             path="ici-sync", layout=self.cfg.layout, groups=groups,
+            width=width,
             backlog=self.sync_backlog, overflow_keys=self.overflow_keys,
             dur_us=int(dur * 1e6),
             trace_id=tracing.trace_id_of(tick_span),

@@ -6,10 +6,11 @@ diverged group reads as converged and is stranded forever — the merge
 never runs for it. The backstop forces one full-table tick every N
 capped ticks, bounding the stranded window to N * sync_wait_s.
 
-The collision is forged by monkeypatching the fingerprint mixer
-(ici._mix64) to a constant BEFORE the sync programs trace, making the
-selector fingerprint-blind; divergence is then planted with zero
-pending deltas (the only signal the blinded selector has left).
+The collision is forged by monkeypatching the fingerprint mixer (the
+fused layout's own, ops/fused.py _mix32) to a constant BEFORE the sync
+programs trace, making the selector fingerprint-blind; divergence is
+then planted with zero pending deltas (the only signal the blinded
+selector has left).
 """
 
 import dataclasses
@@ -22,6 +23,7 @@ import jax.numpy as jnp
 
 from gubernator_tpu.api.types import Behavior, RateLimitReq
 from gubernator_tpu.ops.encode import encode_batch
+from gubernator_tpu.ops import fused
 from gubernator_tpu.ops.layout import batch_entry
 from gubernator_tpu.parallel import ici
 from gubernator_tpu.parallel import mesh as pmesh
@@ -41,12 +43,12 @@ def _tables_equal_across_devices(state) -> bool:
 
 
 def test_forged_collision_strands_capped_tick_and_full_tick_heals(monkeypatch):
-    # Blind the selector: both salted fingerprints become the constant 0
-    # on every device, so content divergence can never be detected. Must
-    # land before make_sync_step traces (the mixer is baked in at trace).
-    monkeypatch.setattr(
-        ici, "_mix64", lambda x: jnp.zeros_like(x, dtype=jnp.uint64)
-    )
+    # Blind the selector: every salted fingerprint becomes the constant
+    # 0 on every device, so content divergence can never be detected.
+    # Must land before make_sync_step traces (the mixer is baked in at
+    # trace). The serving layout fingerprints its groups itself
+    # (ops/fused.py), so the mixer to blind is its own.
+    monkeypatch.setattr(fused, "_mix32", jnp.zeros_like)
     mesh = pmesh.make_mesh(jax.devices()[:NDEV])
     num_slots, ways = 64, 2
     num_groups = num_slots // ways

@@ -12,6 +12,7 @@ different devices and the merge must key-match across ways).
 
 import copy
 import dataclasses
+import functools
 import random
 
 import numpy as np
@@ -322,21 +323,31 @@ def _sync_fixpoint(sync_fn, state, now, max_ticks=64):
     raise AssertionError("sync never reached a fixpoint")
 
 
-@pytest.mark.parametrize("seed,ways", [(5, 1), (6, 4)])
-def test_capped_sync_matches_full(seed, ways):
-    """Delta-compacted sync (max_sync_groups=C) must reach the same
-    fixpoint as the unbounded merge at the same timestamp — under
-    random GLOBAL traffic including overflow/retention regimes. The
-    merge is group-local, so which tick a group is processed on cannot
-    change where it converges."""
+@functools.lru_cache(maxsize=None)
+def _capped_and_full(num_slots: int, ways: int, cap: int):
+    """(mesh, replica decide, uncapped sync, sync capped at `cap`) of one
+    geometry, compiled once for all the cases that share it."""
     mesh = pmesh.make_mesh(jax.devices()[:NDEV])
-    num_slots = NDEV * 8
+    return (
+        mesh,
+        batch_entry(ici.make_replica_decide(mesh, num_slots, ways)),
+        ici.make_sync_step(mesh, num_slots, ways),
+        ici.make_sync_step(mesh, num_slots, ways, max_sync_groups=cap),
+    )
+
+
+def _assert_same_tables(state_a, state_b):
+    for x, y in zip(_table_arrays(state_a), _table_arrays(state_b)):
+        np.testing.assert_array_equal(x, y)
+
+
+def _random_traffic_matches(seed, num_slots, ways, cap):
+    mesh, replica_fn, sync_full, sync_cap = _capped_and_full(
+        num_slots, ways, cap
+    )
     num_groups = num_slots // ways
     state_a = ici.create_ici_state(mesh, num_slots, ways)
     state_b = ici.create_ici_state(mesh, num_slots, ways)
-    replica_fn = batch_entry(ici.make_replica_decide(mesh, num_slots, ways))
-    sync_full = ici.make_sync_step(mesh, num_slots, ways)
-    sync_cap = ici.make_sync_step(mesh, num_slots, ways, max_sync_groups=2)
 
     rng = random.Random(seed)
     keys = [f"cf:{i}" for i in range(24)]
@@ -365,13 +376,76 @@ def test_capped_sync_matches_full(seed, ways):
         else:
             state_a = _sync_fixpoint(sync_full, state_a, now)
             state_b = _sync_fixpoint(sync_cap, state_b, now)
-            for x, y in zip(_table_arrays(state_a), _table_arrays(state_b)):
-                np.testing.assert_array_equal(x, y)
+            _assert_same_tables(state_a, state_b)
 
     state_a = _sync_fixpoint(sync_full, state_a, now)
     state_b = _sync_fixpoint(sync_cap, state_b, now)
-    for x, y in zip(_table_arrays(state_a), _table_arrays(state_b)):
-        np.testing.assert_array_equal(x, y)
+    _assert_same_tables(state_a, state_b)
+
+
+def _planted_groups_match(active, num_slots, ways, cap):
+    """One hit in each of `active` distinct groups, then the ticks: the
+    first capped one merges what fits its cap at the least width of the
+    ladder that holds it, and leaves the rest as its backlog."""
+    mesh, replica_fn, sync_full, sync_cap = _capped_and_full(
+        num_slots, ways, cap
+    )
+    num_groups = num_slots // ways
+    state_a = ici.create_ici_state(mesh, num_slots, ways)
+    state_b = ici.create_ici_state(mesh, num_slots, ways)
+    seen = set()
+    i = 0
+    while len(seen) < active:
+        i += 1
+        req = RateLimitReq(
+            name="z", unique_key=f"pl:{i}", behavior=Behavior.GLOBAL,
+            duration=60_000, limit=100, hits=1,
+        )
+        group = group_of(key_hash128(req.hash_key())[1], num_groups)
+        if group in seen:
+            continue
+        seen.add(group)
+        hm = np.full((2,), i % NDEV, dtype=np.int64)
+        b = encode_batch([dataclasses.replace(req)], NOW, num_groups, 2)
+        state_a, _ = replica_fn(state_a, b, hm, NOW)
+        b2 = encode_batch([dataclasses.replace(req)], NOW, num_groups, 2)
+        state_b, _ = replica_fn(state_b, b2, hm, NOW)
+
+    state_b, diag = sync_cap(state_b, NOW)
+    _kept, _dropped, backlog, merged, width = (
+        int(x) for x in np.asarray(diag)[0]
+    )
+    assert merged == min(active, cap)
+    assert backlog == active - merged
+    assert width == min(w for w in ici._block_widths(cap) if w >= merged)
+
+    state_a = _sync_fixpoint(sync_full, state_a, NOW)
+    state_b = _sync_fixpoint(sync_cap, state_b, NOW)
+    _assert_same_tables(state_a, state_b)
+
+
+# `active` None: random GLOBAL traffic. A number: that many groups made
+# active at once, below, on and above each width of the ladder (1, 8, 64
+# of 256 groups; 1, 4, 32 of 64 four-way ones) and above the cap.
+@pytest.mark.parametrize(
+    "seed,ways,active",
+    [(5, 1, None), (6, 4, None)]
+    + [(7, 1, n) for n in (0, 1, 2, 7, 8, 9, 63, 64, 65, 100)]
+    + [(8, 4, n) for n in (1, 3, 4, 5, 31, 32, 33, 40)],
+)
+def test_capped_sync_matches_full(seed, ways, active):
+    """Delta-compacted sync (max_sync_groups=C) must reach the same
+    fixpoint as the unbounded merge at the same timestamp — under
+    random GLOBAL traffic including overflow/retention regimes, and
+    whatever width of its ladder a tick merges at. The merge is
+    group-local, so which tick a group is processed on, and in how wide
+    a block, cannot change where it converges."""
+    if active is None:
+        _random_traffic_matches(seed, num_slots=NDEV * 8, ways=ways, cap=2)
+    else:
+        _planted_groups_match(
+            active, num_slots=NDEV * 64, ways=ways, cap={1: 64, 4: 32}[ways]
+        )
 
 
 # The factories default to the fused layout (the two suites above), so

@@ -4,6 +4,8 @@ counter pins the "serving path never compiles" invariant (both the
 warmed-engine 0 and the deliberately-cold detection), and the occupancy
 gauges reflect table state."""
 
+import os
+
 import pytest
 
 from gubernator_tpu.api.types import Behavior, RateLimitReq
@@ -175,13 +177,17 @@ def test_completion_thread_compile_is_counted(monkeypatch):
 # ---- ICI tier ---------------------------------------------------------------
 
 
-def test_ici_tick_telemetry():
+# 512 replica groups: the default cap is no cap (a full tick, as wide
+# as the table); a cap of 64 merges at 1, 8 or 64 groups.
+@pytest.mark.parametrize("cap,widths", [(65536, (512,)), (64, (1, 8, 64))])
+def test_ici_tick_telemetry(cap, widths):
     from gubernator_tpu.runtime.ici_engine import IciEngine, IciEngineConfig
 
     eng = IciEngine(
         IciEngineConfig(
             num_groups=1 << 9, num_slots=1 << 11, batch_size=64,
             batch_wait_s=0.002, sync_wait_s=3600,  # manual ticks only
+            max_sync_groups=cap,
         ),
         now_fn=lambda: NOW,
     )
@@ -201,6 +207,35 @@ def test_ici_tick_telemetry():
         assert len(tick) == 1
         assert tick[0]["groups"] >= 1  # GLOBAL traffic dirtied groups
         assert tick[0]["backlog"] == 0
+        # merged at the least width of the ladder that held them
+        assert em.ici_tick_width.summary()["count"] == 1
+        assert tick[0]["width"] == min(
+            w for w in widths if w >= tick[0]["groups"]
+        )
+        # ... and the benchmark's reader of the two histograms finds
+        # them under the names it asks /metrics for.
+        from benchmarks import readers
+        from gubernator_tpu.metrics import Metrics, wire_engine_telemetry
+
+        m = Metrics()
+        wire_engine_telemetry(m, eng)
+        series = {}
+        for line in m.render().decode().splitlines():
+            name, _, value = line.rpartition(" ")
+            if name and not line.startswith("#"):
+                series[name] = float(value)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        fill = readers.read(
+            os.path.join(root, "benchmarks", "metrics", "ici_tick_fill.json"),
+            readers.Context(
+                before={}, after=series, device={}, phases={}, generator={},
+                trace=None, conf={}, traffic={}, table={}, items_answered=0,
+                root=root,
+            ),
+        )
+        assert fill == pytest.approx(
+            100.0 * tick[0]["groups"] / tick[0]["width"]
+        )
         # warmed tick + warmed serving path: still zero cold compiles
         assert em.cold_compiles == 0
         snap = eng.debug_snapshot()
