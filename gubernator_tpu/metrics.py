@@ -1539,6 +1539,14 @@ class Metrics:
             "topologies.",
             registry=r,
         )
+        self.shard_decisions = counter(
+            "gubernator_shard_decisions",
+            "Lanes the owner-sharded decide answered on each shard of "
+            "the mesh (shard = group // groups per shard), columnar and "
+            "object path alike; the series sum to the sharded lanes "
+            "answered. Absent on single-device topologies.",
+            ["shard"],
+        )
 
         # Overload control plane (service/overload.py; GUBER_OVERLOAD —
         # docs/robustness.md "Overload control & brownout").
@@ -1748,8 +1756,11 @@ def engine_sync(engine):
             # scans, so this stays zero-device-work even when the
             # census cache is cold (it just omits occupancy then).
             ss = engine.shard_stats()
-            if ss is not None and ss.get("imbalance_ratio") is not None:
-                m.shard_imbalance_ratio.set(ss["imbalance_ratio"])
+            if ss is not None:
+                for shard, lanes in enumerate(ss["decisions"]):
+                    m.shard_decisions.labels(shard).set(lanes)
+                if ss.get("imbalance_ratio") is not None:
+                    m.shard_imbalance_ratio.set(ss["imbalance_ratio"])
         if hasattr(engine, "overflow_keys"):  # ici-mode engines only
             m.global_overflow_keys.set(engine.overflow_keys)
             m.global_overflow_drops.set(engine.overflow_drops)

@@ -114,8 +114,14 @@ def _sharded_packed_decide(mesh: Mesh, groups_per: int, decide, xlate=None):
     `table` is then the PagedTable and only its data is sharded."""
 
     def local(data, batch, now, with_store):
-        data, out = decide(data, _mask_to_local(groups_per, batch), now)
-        return data, jax.lax.psum(pack_output(out, with_store), AXIS)
+        # named scopes are profile metadata only: they name a trace's
+        # operations by phase and change nothing that is computed
+        with jax.named_scope("owner_mask"):
+            mine = _mask_to_local(groups_per, batch)
+        with jax.named_scope("decide"):
+            data, out = decide(data, mine, now)
+        with jax.named_scope("psum_merge"):
+            return data, jax.lax.psum(pack_output(out, with_store), AXIS)
 
     @functools.partial(
         jax.jit, static_argnames=("with_store",), donate_argnums=(0,)
