@@ -646,17 +646,18 @@ EDGE_REASONS["peer_object"] = EDGE_REASONS["object"]
 
 
 def engine_wave_transfers() -> _BareCounter:
-    """The engine-owned counter of a wave's crossings of the host-device
-    boundary. The engine adds to it where it observes
-    gubernator_engine_flush_waves, and wire_engine_telemetry exposes it
-    on the very next lines, so a scrape reads the two as one: their
-    ratio is the arrays a wave costs each way."""
+    """The engine-owned counter of the crossings of the host-device
+    boundary that serving waves make. The engine adds to it where it
+    observes gubernator_engine_flush_waves, and wire_engine_telemetry
+    exposes it on the very next lines, so a scrape reads the two as one:
+    their ratio is the arrays a wave costs each way."""
     c = _BareCounter(
         "gubernator_engine_wave_transfers",
         "Arrays that crossed the host-device boundary for serving "
         "waves: operands uploaded (h2d) and outputs read (d2h). One of "
-        "each a wave; over gubernator_engine_flush_waves_sum it is the "
-        "arrays a wave costs each way.",
+        "each a launch (a wave, or a run of waves stacked); over "
+        "gubernator_engine_flush_waves_sum it is the arrays a wave "
+        "costs each way.",
         ["direction"],
     )
     for direction in ("h2d", "d2h"):
@@ -688,6 +689,15 @@ def engine_histograms() -> dict:
         "flush_waves": Log2Histogram(
             "gubernator_engine_flush_waves",
             "Sequential decide() waves per engine flush.",
+            scale=cnt, n_buckets=12,
+        ),
+        "flush_launches": Log2Histogram(
+            "gubernator_engine_flush_launches",
+            "Decide programs launched per engine flush: a run of "
+            "consecutive waves of one width is one operand, one launch "
+            "under the engine lock and one read. Over "
+            "gubernator_engine_flush_waves it says how far a flush's "
+            "waves shared their launches.",
             scale=cnt, n_buckets=12,
         ),
         "batch_width": Log2Histogram(

@@ -22,7 +22,12 @@ import jax.numpy as jnp
 
 from gubernator_tpu.api.types import Algorithm, Behavior, Status
 from gubernator_tpu.models.bucket import FIXED_SHIFT, MAX_ELAPSED_MS
-from gubernator_tpu.ops.layout import DecideOutput, RequestBatch, SlotTable
+from gubernator_tpu.ops.layout import (
+    DecideOutput,
+    RequestBatch,
+    SlotTable,
+    vary_like,
+)
 
 I64 = jnp.int64
 U64 = jnp.uint64
@@ -361,6 +366,27 @@ def _leaky_paths(batch: RequestBatch, st, b_greg, b_reset, b_drain, exists_any, 
     return state, resp
 
 
+def _both_paths(batch: RequestBatch, st, b_greg, b_reset, b_drain, exists_any, now):
+    """(state_update, resp) of every lane by its own algorithm: THE
+    selection both layouts share. The token path is lane arithmetic and
+    runs for every wave; the leaky path holds all six division loops
+    (five in _leak_fixed, one for the rate) and runs only where the wave
+    carries a leaky lane. A wave without one hands the token values to
+    both sides of the per-lane select, which takes the token side in
+    every lane either way, so the result is the same bit for bit. The
+    conditional wraps lane vectors only, never the table."""
+    args = (batch, st, b_greg, b_reset, b_drain, exists_any, now)
+    tok = _token_paths(*args)
+    is_leaky = batch.algo == jnp.int8(Algorithm.LEAKY_BUCKET)
+    # (inside a shard_map both branches vary over the inputs' mesh axes)
+    lky = jax.lax.cond(
+        jnp.any(is_leaky),
+        lambda: vary_like(_leaky_paths(*args), args),
+        lambda: vary_like(tok, args),
+    )
+    return jax.tree.map(lambda t, l: jnp.where(is_leaky, l, t), tok, lky)
+
+
 def _decide_impl(table: SlotTable, batch: RequestBatch, now, *, ways: int):
     now = jnp.asarray(now, dtype=I64)
     slot, exists, evicts_live, evicted_hi, evicted_lo = _choose_slot(
@@ -392,16 +418,7 @@ def _decide_impl(table: SlotTable, batch: RequestBatch, now, *, ways: int):
     b_reset = (bhv & int(Behavior.RESET_REMAINING)) != 0
     b_drain = (bhv & int(Behavior.DRAIN_OVER_LIMIT)) != 0
 
-    tok_state, tok_resp = _token_paths(batch, st, b_greg, b_reset, b_drain, exists, now)
-    lky_state, lky_resp = _leaky_paths(batch, st, b_greg, b_reset, b_drain, exists, now)
-
-    is_leaky = batch.algo == jnp.int8(Algorithm.LEAKY_BUCKET)
-
-    def pick(t, l):
-        return jnp.where(is_leaky, l, t)
-
-    new_state = {k: pick(tok_state[k], lky_state[k]) for k in tok_state}
-    resp = {k: pick(tok_resp[k], lky_resp[k]) for k in tok_resp}
+    new_state, resp = _both_paths(batch, st, b_greg, b_reset, b_drain, exists, now)
 
     # Scatter back. Inactive (padding) lanes target index N -> dropped.
     n = table.num_slots

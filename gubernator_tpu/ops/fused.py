@@ -48,8 +48,9 @@ Columns (int64; META packs lru<<4 | status<<2 | algo<<1 | used):
 
   KHI KLO META EXP LIM DUR REM STM BUR INV
 
-Branch semantics are bit-exact with the wide kernel: _token_paths /
-_leaky_paths from ops/decide.py are reused verbatim, and the layout runs
+Branch semantics are bit-exact with the wide kernel: _both_paths from
+ops/decide.py (the token path, and the leaky path where a wave holds a
+leaky lane) is reused verbatim, and the layout runs
 the full oracle fuzz (tests/test_kernel_fuzz.py). Bucket field contract:
 reference store.go:29-43; LRU/expiry policy: reference lrucache.go:98-118,
 cache.go:43-57.
@@ -64,8 +65,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from gubernator_tpu.api.types import Algorithm, Behavior, Status
-from gubernator_tpu.ops.decide import _leaky_paths, _token_paths
+from gubernator_tpu.api.types import Behavior
+from gubernator_tpu.ops.decide import _both_paths
 from gubernator_tpu.ops.layout import DecideOutput, RequestBatch, SlotTable
 
 I64 = jnp.int64
@@ -596,16 +597,7 @@ def _decide_fused_impl(table: FusedTable, batch: RequestBatch, now, *, ways: int
         b_reset = (bhv & int(Behavior.RESET_REMAINING)) != 0
         b_drain = (bhv & int(Behavior.DRAIN_OVER_LIMIT)) != 0
 
-        tok_state, tok_resp = _token_paths(batch, st, b_greg, b_reset, b_drain, exists, now)
-        lky_state, lky_resp = _leaky_paths(batch, st, b_greg, b_reset, b_drain, exists, now)
-
-        is_leaky = batch.algo == jnp.int8(Algorithm.LEAKY_BUCKET)
-
-        def both(t, l):
-            return jnp.where(is_leaky, l, t)
-
-        new_state = {k: both(tok_state[k], lky_state[k]) for k in tok_state}
-        resp = {k: both(tok_resp[k], lky_resp[k]) for k in tok_resp}
+        new_state, resp = _both_paths(batch, st, b_greg, b_reset, b_drain, exists, now)
 
     with jax.named_scope("scatter"):
         freed = ~new_state["used"]

@@ -36,11 +36,7 @@ from gubernator_tpu.ops.decide import (
     probe_exists as _wpe,
 )
 from gubernator_tpu.ops.inject import inject as _wi
-from gubernator_tpu.ops.layout import (
-    SlotTable,
-    pack_output,
-    unpack_operand,
-)
+from gubernator_tpu.ops.layout import SlotTable, packed_waves
 
 
 class Kernels(NamedTuple):
@@ -55,8 +51,9 @@ class Kernels(NamedTuple):
     from_wide: object  # SlotTable -> table
     bytes_per_slot: int = 83  # resident table bytes per slot
     # What an engine launches: (table, operand, ways, with_store) ->
-    # (table, output vector). One uploaded operand in, one array out
-    # (ops/layout.py WaveOperand / split_output).
+    # (table, output). One uploaded operand in, one array out, for one
+    # wave or a stacked run of them (ops/layout.py WaveOperand /
+    # packed_waves / split_output).
     decide_packed: object = None
 
 
@@ -122,14 +119,18 @@ def get_kernels(layout: str) -> Kernels:
 @functools.lru_cache(maxsize=None)
 def _packed_program(layout: str):
     """The jitted packed entry of `layout`, one per process (the jit
-    cache lives on it): unpack the operand, run the layout's raw decide,
-    pack the output. Named after the layout so a profile shows the
-    program under the name it always had (`jit_decide_fused`)."""
+    cache lives on it): unpack the operand, run the layout's raw decide
+    (once a wave of a stacked operand: ops/layout.py packed_waves), pack
+    the output. Named after the layout so a profile shows the program
+    under the name it always had (`jit_decide_fused`), one wave or a
+    run of them: the operand's rank picks the variant."""
 
     def entry(table, operand, ways, with_store):
-        batch, _home, now = unpack_operand(operand)
-        table, out = get_raw_kernels(layout).decide(table, batch, now, ways)
-        return table, pack_output(out, with_store)
+        decide = get_raw_kernels(layout).decide
+        return packed_waves(
+            lambda t, batch, now: decide(t, batch, now, ways),
+            table, operand, with_store,
+        )
 
     entry.__name__ = entry.__qualname__ = f"decide_{layout}"
     return jax.jit(
@@ -140,9 +141,10 @@ def _packed_program(layout: str):
 
 
 def packed_decide(layout: str):
-    """(table, operand, ways, with_store=False) -> (table, output
-    vector): the launch of one wave whose only operand beside the table
-    is the uploaded (OPERAND_ROWS, B) int64 array."""
+    """(table, operand, ways, with_store=False) -> (table, output): the
+    launch of one wave, or of a run of waves, whose only operand beside
+    the table is the uploaded (OPERAND_ROWS, B) or (W, OPERAND_ROWS, B)
+    int64 array; the output is one vector, or one a wave."""
     program = _packed_program(layout)
 
     def decide_packed(table, operand, ways, with_store=False):

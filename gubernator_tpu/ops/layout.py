@@ -32,6 +32,7 @@ from __future__ import annotations
 import sys
 from typing import NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -131,7 +132,10 @@ class RequestBatch(NamedTuple):
 # to a RequestBatch inside the jit (unpack_operand) and packs its
 # DecideOutput to one int64 vector (pack_output) that the host reads in
 # one go (split_output). The per-field structs live only inside the
-# compiled program.
+# compiled program. A RUN of waves of one width crosses once each way
+# too: the buffers stacked to (W, OPERAND_ROWS, B) in, the vectors
+# stacked to (W, rows * B + 4) out, the waves applied in order inside
+# the one program (packed_waves).
 #
 # Rows: ten int64 fields, then two shared words (group | behavior << 32;
 # algo | active << 8), the replica tier's per-lane home device, and `now`
@@ -218,6 +222,15 @@ class WaveOperand:
     def wave(self, w: int) -> "WaveOperand":
         return WaveOperand(self.buf[w])
 
+    @staticmethod
+    def stacked(waves, depth: int) -> "WaveOperand":
+        """A copy of equally wide `waves`, in order, as one operand of
+        `depth` waves; the waves past the last are empty (no lane
+        active) and the program does not run them."""
+        buf = np.zeros((depth,) + waves[0].buf.shape, dtype=np.int64)
+        np.stack([w.buf for w in waves], out=buf[: len(waves)])
+        return WaveOperand(buf)
+
     def narrowed(self, lanes: int) -> "WaveOperand":
         """The first `lanes` lanes as an operand of its own."""
         return WaveOperand(np.ascontiguousarray(self.buf[..., :lanes]))
@@ -241,6 +254,60 @@ def unpack_operand(operand):
         **{f: operand[i] for i, f in enumerate(_OP_I64)},
     )
     return batch, operand[OP_HOME], operand[OP_NOW, 0]
+
+
+def vary_like(values, refs):
+    """`values` with every leaf widened to vary over the mesh axes that
+    any leaf of `refs` varies over. Inside a shard_map the carry of a
+    loop and the two branches of a conditional must agree on those
+    axes; outside one there are none and nothing is done."""
+    axes = frozenset().union(
+        *(jax.typeof(x).vma for x in jax.tree.leaves(refs))
+    )
+    return jax.tree.map(
+        lambda x: jax.lax.pcast(
+            x, tuple(axes - jax.typeof(x).vma), to="varying"
+        ),
+        values,
+    )
+
+
+def packed_waves(step, state, operand, with_store: bool):
+    """THE body of every packed launch (each layout, the paged kernels,
+    the mesh): `step(state, batch, now) -> (state, DecideOutput)` applied
+    to one uploaded operand, the output packed to one int64 array.
+
+    A (OPERAND_ROWS, B) operand is one wave and gives one vector. A
+    (W, OPERAND_ROWS, B) operand is a run of waves of one flush: they
+    are applied in order, each to the state the one before left, inside
+    this one program, and the output is (W, rows * B + 4), one vector a
+    wave. The loop turns as often as the operand holds waves up to its
+    last one with an active lane, which the program reads from its
+    input: the empty waves that pad a run to a compiled depth cost no
+    device time and leave zeros in their rows."""
+    if operand.ndim == 2:
+        batch, _home, now = unpack_operand(operand)
+        state, out = step(state, batch, now)
+        return state, pack_output(out, with_store)
+    depth, _, lanes = operand.shape
+    active = ((operand[:, OP_ALGO_ACTIVE, :] >> 8) & 0xFF) != 0
+    real = jnp.max(
+        jnp.where(
+            active.any(axis=1), jnp.arange(1, depth + 1, dtype=jnp.int32), 0
+        )
+    )
+    rows = OUT_STORE_ROWS if with_store else OUT_LANE_ROWS
+    outs = vary_like(
+        jnp.zeros((depth, rows * lanes + OUT_TOTALS), dtype=jnp.int64), state
+    )
+
+    def wave(w, carry):
+        state, outs = carry
+        batch, _home, now = unpack_operand(operand[w])
+        state, out = step(state, batch, now)
+        return state, outs.at[w].set(pack_output(out, with_store))
+
+    return jax.lax.fori_loop(jnp.int32(0), real, wave, (state, outs))
 
 
 def pack_output(out: "DecideOutput", with_store: bool):

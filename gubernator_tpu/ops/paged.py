@@ -58,7 +58,7 @@ from gubernator_tpu.ops.kernels import (
     get_kernels,
     get_raw_kernels,
 )
-from gubernator_tpu.ops.layout import SlotTable, pack_output, unpack_operand
+from gubernator_tpu.ops.layout import SlotTable, packed_waves
 
 
 class PagedTable(NamedTuple):
@@ -239,9 +239,7 @@ def make_paged_kernels(
         jax.jit, static_argnames=("with_store",), donate_argnums=(0,)
     )
     def _decide_packed(pt, operand, with_store):
-        batch, _home, now = unpack_operand(operand)
-        pt, out = _raw_decide(pt, batch, now)
-        return pt, pack_output(out, with_store)
+        return packed_waves(_raw_decide, pt, operand, with_store)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def _inject(pt, items, now):

@@ -29,11 +29,7 @@ from gubernator_tpu.ops.kernels import (
     get_kernels,
     get_raw_kernels,
 )
-from gubernator_tpu.ops.layout import (
-    SlotTable,
-    pack_output,
-    unpack_operand,
-)
+from gubernator_tpu.ops.layout import SlotTable, packed_waves
 from gubernator_tpu.utils import lockorder, transfer
 
 AXIS = "owners"
@@ -104,40 +100,48 @@ def create_sharded_table(
 
 def _sharded_packed_decide(mesh: Mesh, groups_per: int, decide, xlate=None):
     """The packed launch over a sharded table: (table, operand,
-    with_store) -> (table', output vector), operand and output
-    replicated. The one operand is unpacked (ops/layout.py); each shard
-    masks the batch to the lanes it owns, runs `decide(table, batch,
-    now)` on its slice and packs its output; inactive lanes and foreign totals
-    are zeros, so ONE psum of the packed vector gives every lane its
-    single authoritative answer. `xlate(table, group)` (paged) maps
-    logical to physical groups, replicated, before the ownership mask;
-    `table` is then the PagedTable and only its data is sharded."""
+    with_store) -> (table', output), operand and output replicated. The
+    one operand, a wave or a stacked run of them, is unpacked and looped
+    over INSIDE the shard_map body (ops/layout.py packed_waves): for
+    each wave a shard masks the batch to the lanes it owns and runs
+    `decide(table, batch, now)` on its slice; inactive lanes and foreign
+    totals are zeros, so ONE psum of the packed output, after the last
+    wave, gives every lane its single authoritative answer.
+    `xlate(page_map, group)` (paged) maps logical to physical groups,
+    replicated, before the ownership mask; `table` is then the
+    PagedTable and only its data is sharded."""
 
-    def local(data, batch, now, with_store):
+    def local(data, operand, *page_map, with_store):
         # named scopes are profile metadata only: they name a trace's
         # operations by phase and change nothing that is computed
-        with jax.named_scope("owner_mask"):
-            mine = _mask_to_local(groups_per, batch)
-        with jax.named_scope("decide"):
-            data, out = decide(data, mine, now)
+        def wave(data, batch, now):
+            with jax.named_scope("owner_mask"):
+                if xlate is not None:
+                    batch = batch._replace(
+                        group=xlate(page_map[0], batch.group)
+                    )
+                mine = _mask_to_local(groups_per, batch)
+            with jax.named_scope("decide"):
+                return decide(data, mine, now)
+
+        data, out = packed_waves(wave, data, operand, with_store)
         with jax.named_scope("psum_merge"):
-            return data, jax.lax.psum(pack_output(out, with_store), AXIS)
+            return data, jax.lax.psum(out, AXIS)
 
     @functools.partial(
         jax.jit, static_argnames=("with_store",), donate_argnums=(0,)
     )
     def decide_fn(table, operand, with_store=False):
-        batch, _home, now = unpack_operand(operand)
+        paged = xlate is not None
         sharded = jax.shard_map(
             functools.partial(local, with_store=with_store),
             mesh=mesh,
-            in_specs=(P(AXIS), P(), P()),
+            in_specs=(P(AXIS), P()) + ((P(),) if paged else ()),
             out_specs=(P(AXIS), P()),
         )
-        if xlate is None:
-            return sharded(table, batch, now)
-        b = batch._replace(group=xlate(table.page_map, batch.group))
-        data, out = sharded(table.data, b, now)
+        if not paged:
+            return sharded(table, operand)
+        data, out = sharded(table.data, operand, table.page_map)
         return type(table)(data, table.page_map), out
 
     return decide_fn

@@ -337,7 +337,8 @@ class EngineMetrics:
 
     def observe_flush(self, path: str, n: int, waves: int, dur: float,
                       dev: float, trace_id: str = "",
-                      collective: bool = False, transfers=(0, 0)) -> None:
+                      collective: bool = False, transfers=(0, 0),
+                      launches: int = 0) -> None:
         """One flush's distribution samples (per FLUSH, not per
         request). A non-empty trace_id attaches an OpenMetrics exemplar
         to the latency buckets this flush lands in, so a p99 spike in
@@ -346,12 +347,15 @@ class EngineMetrics:
         collective-tick histogram: on a sharded decide the psum merge
         rendezvouses every shard, so this distribution is the
         shard-skew amplifier the SLO layer watches. `transfers` =
-        (operands uploaded, outputs read) for the flush's waves, counted
-        beside the waves themselves so a scrape sees both or neither."""
+        (operands uploaded, outputs read) for the flush's waves and
+        `launches` the decide programs it launched (a run of equally
+        wide waves is one operand, one launch, one output), counted
+        beside the waves themselves so a scrape sees all or none."""
         self.flush_duration.labels(path).observe(dur, trace_id)
         self.device_sync.labels(path).observe(dev, trace_id)
         self.batch_width.labels(path).observe(n)
         self.flush_waves.observe(waves)
+        self.flush_launches.observe(launches)
         self._wave_h2d.inc(transfers[0])
         self._wave_d2h.inc(transfers[1])
         if collective:
@@ -364,15 +368,17 @@ class FlushStages:
     them to the engine's stage histogram in one go and fills `us`,
     which the flight recorder keeps with the flush's record."""
 
-    __slots__ = ("em", "ids", "us", "_rows", "h2d", "d2h")
+    __slots__ = ("em", "ids", "us", "_rows", "h2d", "d2h", "launches")
 
     def __init__(self, em: EngineMetrics, flush: int, call: int):
         self.em = em
         self.ids = {"flush": flush, "call": call}
-        # wave operands uploaded / wave outputs read by this flush
-        # (EngineMetrics.observe_flush counts them beside its waves)
+        # wave operands uploaded / wave outputs read / decide programs
+        # launched by this flush (EngineMetrics.observe_flush counts
+        # them beside its waves)
         self.h2d = 0
         self.d2h = 0
+        self.launches = 0
         # every key from the start: the record shares this dict, and a
         # /debug/engine dump may walk it while publish() fills it in
         self.us: Dict[str, int] = dict.fromkeys(FLUSH_STAGES, 0)
@@ -433,8 +439,8 @@ class _FlushTicket:
     __slots__ = (
         "items",        # [(req, future-like)] — the flush's intake
         "placements",   # per-item routing (engine-specific)
-        "outs",         # per-wave output vectors (device arrays; host with a store)
-        "r_outs",       # ici replica-tier output vectors (device arrays)
+        "outs",         # per-launch (output, waves): device arrays; host with a store
+        "r_outs",       # ici replica-tier (output vector, 1) per wave
         "rows",         # store path: materialized per-wave gathered rows
         "events",       # store path: ('d'|'i', key) displacement events
         "served",       # items answered by this flush (excludes carry)
@@ -459,21 +465,25 @@ class _FlushTicket:
 
 def _read_waves(outs, fs: FlushStages, with_store: bool = False):
     """THE completion-stage flush-boundary readback, shared by every
-    path: ONE blocking read a wave (pipelined engines run it off the
-    pump thread, so the device never waits on host encode), sliced on
-    the host. Returns ([rows (R, B) per wave, indexed by OUT_*], [hits,
-    misses, unexpired_evictions, over_limit] summed over the waves). A
-    wave the store path already read under the lock arrives as its host
-    vector and is not read again."""
+    path: ONE blocking read a launch (pipelined engines run it off the
+    pump thread, so the device never waits on host encode), split per
+    wave and sliced on the host. `outs` is _execute_waves' list of
+    (output, waves): one wave's vector, or the (depth, L) array of a
+    stacked run whose first `waves` rows are its waves'. Returns ([rows
+    (R, B) per wave, indexed by OUT_*], [hits, misses,
+    unexpired_evictions, over_limit] summed over the waves). A wave the
+    store path already read under the lock arrives as its host vector
+    and is not read again."""
     rows = []
     totals = np.zeros(OUT_TOTALS, np.int64)
-    for o in outs:
+    for o, n in outs:
         if not isinstance(o, np.ndarray):
             o = np.asarray(o)  # guberlint: allow-host-sync -- completion-stage flush-boundary readback
             fs.d2h += 1
-        r, t = split_output(o, with_store)
-        rows.append(r)
-        totals += t
+        for vec in (o,) if o.ndim == 1 else o[:n]:
+            r, t = split_output(vec, with_store)
+            rows.append(r)
+            totals += t
     return rows, totals.tolist()
 
 
@@ -972,10 +982,12 @@ class EngineBase:
         # still alive when the interpreter finalizes, its GIL touch
         # turns into pthread_exit's forced unwind through C++ catch(...)
         # blocks — glibc aborts with "FATAL: exception not rethrown".
-        # _running=False stops it between shapes; join past the current
-        # compile.
+        # _running=False stops it between shapes (a warmer parked until
+        # its stacked shapes are wanted is woken to see it); join past
+        # the current compile.
         warm = getattr(self, "_warm_thread", None)
         if warm is not None and warm.is_alive():
+            self._stack_wanted.set()
             warm.join(timeout=60)
         comp = self._pipe_thread
         if comp is not None and comp.is_alive():
@@ -1679,6 +1691,11 @@ class MeshEngine(EngineBase):
         self._mem_subsystems = self._memory_subsystems()
         self._snapshot_staging_bytes = 0
 
+        # The stacked launch's warm (depth, width) shapes, published as
+        # _warm_shapes is: _warmup's at batch_size, the ladder's after.
+        self._warm_stacks: tuple = ()
+        # Set when the ladder's stacked shapes are wanted (_warm_buckets).
+        self._stack_wanted = threading.Event()
         self._warmup()
         self._init_base(self.topo.thread_name)
         # Columnar-path batch-width buckets compile in the background; the
@@ -1750,6 +1767,7 @@ class MeshEngine(EngineBase):
         warm = self._warm_thread
         if warm is None:
             return True
+        self._stack_wanted.set()  # the caller wants every shape
         warm.join(timeout=timeout_s)
         return not warm.is_alive()
 
@@ -1860,15 +1878,17 @@ class MeshEngine(EngineBase):
         while b < cfg.batch_size:
             shapes.append(b)
             b <<= 1
-        for B in shapes:
+
+        def warm(B: int, stacked: bool) -> bool:
+            """Width B's single-wave launch, or its stacked ones."""
             if not self._running:
-                return
+                return False
             if self.store is not None:
                 # Store-path flushes pin the batch width to batch_size
                 # (check_columns skips bucket narrowing), so narrower
                 # decide shapes would be dead weight: seconds of compile
                 # plus a throwaway table per shape, used by nothing.
-                return
+                return False
             try:
                 # Same device placement as the live table, or the compile
                 # lands in a different jit cache entry and the "warm"
@@ -1876,11 +1896,15 @@ class MeshEngine(EngineBase):
                 scratch = self._place(
                     lambda: self.K.create(cfg.num_groups, cfg.ways)
                 )
-                scratch, out = self.K.decide_packed(
-                    scratch, self._warm_operand(B, self.now_fn()),
-                    cfg.ways, self.store is not None,
-                )
-                np.asarray(out)
+                if stacked:
+                    scratch = self._warm_stacked(scratch, B)
+                else:
+                    scratch, out = self.K.decide_packed(
+                        scratch, self._warm_operand(B, self.now_fn()),
+                        cfg.ways, False,
+                    )
+                    np.asarray(out)  # guberlint: allow-host-sync -- warm-up on a throwaway table, off the serving path: the compile must end before the width is published
+                    self._warm_shapes = self._warm_shapes + (B,)
                 del scratch
             except Exception:
                 # A width that does not compile or does not fit on the
@@ -1894,8 +1918,25 @@ class MeshEngine(EngineBase):
                         "batch_size=%d stay cold and serve at full width",
                         B, cfg.batch_size,
                     )
+                return False
+            return True
+
+        for B in shapes:
+            if not warm(B, False):
                 return
-            self._warm_shapes = self._warm_shapes + (B,)
+        # The stacked launch (a run of waves in one program) at the
+        # ladder's narrowest width: the waves after a flush's first hold
+        # its repeated keys alone and narrow to it (batch_size has its
+        # own from _warmup; a several-wave columnar call takes the least
+        # of the two that holds it). A program costs seconds to compile
+        # and about two to load from the cache (measured, PERF.md §6 PR
+        # 35), and a daemon that serves one wave a flush never needs
+        # these: they are compiled once somebody does, a caller that
+        # waits for every shape (wait_warm) or the first run of waves
+        # that found none (_upload).
+        if shapes:
+            self._stack_wanted.wait()
+            warm(shapes[0], True)
 
     def _memory_subsystems(self) -> dict:
         """Static HBM attribution from engine geometry (bytes, computed
@@ -1980,6 +2021,9 @@ class MeshEngine(EngineBase):
                     self.table, op, self.cfg.ways, self.store is not None
                 )
                 tx.add(np.asarray(out))
+                # The stacked form of the same launch (a run of waves in
+                # one program), at every depth the serving path may pick.
+                table = self._warm_stacked(table, self.cfg.batch_size)
                 table, _, _ = self.K.inject(
                     table, InjectBatch.zeros(self.cfg.batch_size), now,
                     self.cfg.ways,
@@ -2037,28 +2081,95 @@ class MeshEngine(EngineBase):
         oracle in _census_scan), the table itself otherwise."""
         return table.data if self._pager is not None else table
 
-    def _warm_operand(self, lanes: int, now: int):
-        """An empty wave's operand on the device, placed as _upload
-        places a serving one (the jit cache keys on it), accounted as
-        warm-up."""
+    def _warm_operand(self, lanes: int, now: int, depth=None):
+        """An empty wave's operand (or an empty run's, `depth` waves
+        deep) on the device, placed as _upload places a serving one (the
+        jit cache keys on it), accounted as warm-up."""
         return _transfer.device_put(
-            WaveOperand.zeros(lanes).stamp(now).buf, self._operand_sharding,
-            metrics=self.metrics, purpose="warmup",
+            WaveOperand.zeros(lanes, depth).stamp(now).buf,
+            self._operand_sharding, metrics=self.metrics, purpose="warmup",
         )
 
-    def _upload(self, waves, now: int, fs: FlushStages) -> list:
+    def _wave_depths(self) -> tuple:
+        """The depths a stacked launch is compiled at, least first: a
+        run of waves is padded with empty waves to the least that holds
+        it. The program loops over the real waves only
+        (ops/layout.py packed_waves), so a depth costs its upload and
+        its compile, not device time: two are enough, one that holds a
+        call of a hundred skewed items (~7 waves) and one that holds
+        every run a flush can make."""
+        top = self.cfg.max_waves
+        if top < 2:
+            return ()
+        return (8, top) if top > 8 else (top,)
+
+    def _warm_stacked(self, table, lanes: int):
+        """Compile the stacked launch at `lanes` for every depth against
+        `table` (empty runs: no wave runs, the table comes back as it
+        went in) and publish the shapes; returns the table. A paged
+        table is served wave by wave (promotion is per wave), so it
+        warms none."""
+        if self._pager is not None:
+            return table
+        now = self.now_fn()
+        for depth in self._wave_depths():
+            table, out = self.K.decide_packed(
+                table, self._warm_operand(lanes, now, depth),
+                self.cfg.ways, False,
+            )
+            np.asarray(out)  # guberlint: allow-host-sync -- warm-up: the compile must end before the shape is published
+            self._warm_stacks = self._warm_stacks + ((depth, lanes),)
+        return table
+
+    def _upload(self, waves, now: int, fs: FlushStages, stack=True) -> list:
         """Stamp `now` into each wave's operand and upload them: THE
-        host-to-device crossing of a flush, one array a wave, made
-        BEFORE the flush asks for the engine lock, so what runs under
-        the lock launches programs whose operands are all on the
-        device. One accounted h2d/serve record for the flush."""
+        host-to-device crossing of a flush, made BEFORE the flush asks
+        for the engine lock, so what runs under the lock launches
+        programs whose operands are all on the device. One accounted
+        h2d/serve record for the flush.
+
+        Returns the flush's launches in order, [(first wave, waves,
+        operand on the device)]. A run of consecutive waves of one
+        width is ONE array, stacked to the least warm depth that holds
+        it, and one launch. What the engine observes decides: a run of
+        one wave stays the single-wave operand; a Store or a pager keeps
+        the per-wave sequence (read-through, the row gather and page
+        promotion are defined per wave), as `stack=False` does for the
+        replica tier's waves; a stacked shape that is not warm is not
+        used (no compile on the serving path)."""
         if not waves:
             return []
-        fs.h2d += len(waves)
-        return _transfer.device_put(
-            [w.stamp(now).buf for w in waves], self._operand_sharding,
+        stack = (
+            stack and len(waves) > 1
+            and self.store is None and self._pager is None
+        )
+        warm = self._warm_stacks  # immutable snapshot
+        runs, bufs = [], []
+        w = 0
+        while w < len(waves):
+            run, n = waves[w], 1
+            while stack and w + n < len(waves) and waves[w + n].lanes == run.lanes:
+                n += 1
+            if n > 1:
+                depths = sorted(d for d, b in warm if b == run.lanes)
+                if depths:
+                    # the least warm depth that holds the run, or as
+                    # much of the run as the deepest holds
+                    depth = next((d for d in depths if d >= n), depths[-1])
+                    n = min(n, depth)
+                    run = WaveOperand.stacked(waves[w:w + n], depth)
+                else:
+                    n = 1
+                    self._stack_wanted.set()  # per wave now; warm it for later
+            runs.append((w, n))
+            bufs.append(run.stamp(now).buf)
+            w += n
+        fs.h2d += len(bufs)
+        ops = _transfer.device_put(
+            bufs, self._operand_sharding,
             metrics=self.metrics, purpose="serve",
         )
+        return [(w, n, op) for (w, n), op in zip(runs, ops)]
 
     def warm_store_path(self) -> None:
         """Compile the store-path kernels (the with_store decide variant,
@@ -2597,7 +2708,7 @@ class MeshEngine(EngineBase):
             r_waves = r_asm.waves if r_asm is not None else []
             n_waves = len(waves) + len(r_waves)
             ops = self._upload(waves, now, fs)
-            r_ops = self._upload(r_waves, now, fs)
+            r_ops = self._upload(r_waves, now, fs, stack=False)
         fspan = self._start_flush_span(
             items, seq, path="object", layout=cfg.layout,
             items=len(items), waves=n_waves,
@@ -2674,6 +2785,7 @@ class MeshEngine(EngineBase):
             em.observe_flush(
                 "object", t.served, t.waves, dur, dev_s, trace_id,
                 collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
+                launches=fs.launches,
             )
             em.observe_stage("assemble", t.t_dev - t.t0)
             # `dispatch` (the launches under the lock) and `lock_wait` were
@@ -2872,6 +2984,7 @@ class MeshEngine(EngineBase):
                 # kernels are warmed (warm_store_path); narrower buckets
                 # would cold-compile probe/inject/gather under the lock.
                 width_candidates=self._warm_shapes if store is None else (),
+                stacked_widths={b for _d, b in self._warm_stacks},
             )
         if asm is None:
             fs.publish()  # the refused attempt's hash and waves
@@ -3011,6 +3124,7 @@ class MeshEngine(EngineBase):
                 "columnar", n, W, dur, dev_s,
                 flush_trace_id if cfg.exemplars else "",
                 collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
+                launches=fs.launches,
             )
             em.observe_stage("assemble", t_dev - t_start)
             em.observe_stage("device_sync", dev_s)
@@ -3116,6 +3230,7 @@ class MeshEngine(EngineBase):
                 "columnar", n, waves_total, dur, dev_s,
                 flush_trace_id if cfg.exemplars else "",
                 collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
+                launches=fs.launches,
             )
             em.observe_stage("assemble", t_dev - t_start)
             em.observe_stage("device_sync", dev_s)
@@ -3190,7 +3305,7 @@ class MeshEngine(EngineBase):
             r_slices = [r_asm[0].wave(w) for w in range(r_asm[4])]
         return (cols, hi, lo, s_asm, r_asm, ng_idx, g_idx, wave_slices,
                 self._upload(wave_slices, now, fs),
-                self._upload(r_slices, now, fs))
+                self._upload(r_slices, now, fs, stack=False))
 
     def _execute_waves(
         self, waves, ops, lane_reqs, now, prefetched, fs, req_resolver=None,
@@ -3205,17 +3320,21 @@ class MeshEngine(EngineBase):
 
         waves: the host WaveOperands (the pager, the shard attribution
         and the store's probe read their columns); ops: the same waves
-        as _upload left them on the device, ONE array each — the only
-        operand of a launch beside the device-resident table, so nothing
-        crosses to the device under the lock. lane_reqs: per-wave
+        as _upload left them on the device, [(first wave, waves,
+        operand)]: ONE array and ONE launch a run — the only operand of
+        a launch beside the device-resident table, so nothing crosses to
+        the device under the lock. A run of several waves is applied in
+        order inside its one program and commits whole or not at all;
+        with a Store or a pager every run is one wave (_upload), so
+        their per-wave sequence below is the whole loop. lane_reqs: per-wave
         {lane: (req_or_index, key_hi, key_lo)}; with req_resolver set,
         the first element is an index resolved lazily (columnar path).
         r_ops: the uploaded GLOBAL replica waves (replica topologies
         only; their per-lane home device rides the operand), decided
-        against the replica tier after the sharded waves. Returns
-        (outs, r_outs, wave_rows_host, events): one output vector a
-        wave, still on the device unless a Store made the flush read it
-        here.
+        against the replica tier after the sharded waves, wave by
+        wave. Returns (outs, r_outs, wave_rows_host, events): one
+        (output, waves) a launch for _read_waves, still on the device
+        unless a Store made the flush read it here.
 
         `fs` (FlushStages) takes the two stages every path shares:
         `flush.lock_wait` (waiting for the engine lock and the
@@ -3256,6 +3375,7 @@ class MeshEngine(EngineBase):
         # the intervals go to `fs` after the release. Only a capture or
         # a DEBUG SDK (`live`, None otherwise) opens spans in there.
         n_dispatch = len(ops) + len(r_ops)
+        fs.launches += n_dispatch
         self.metrics.busy_enter()
         live = tracing.open_live("flush.lock_wait", fs.ids)
         t_wait = time.perf_counter_ns()
@@ -3269,7 +3389,8 @@ class MeshEngine(EngineBase):
             table = self.table
             rstate = rt.state if rt is not None else None
             try:
-                for w, wo in enumerate(waves):
+                for w, n, op in ops:
+                    wo = waves[w]
                     if self._pager is not None:
                         # Promote every page this wave touches BEFORE
                         # its probe/decide (a probe-miss against a
@@ -3288,7 +3409,7 @@ class MeshEngine(EngineBase):
                             req_resolver=req_resolver,
                         )
                     table, out = self.K.decide_packed(
-                        table, ops[w], cfg.ways, store is not None
+                        table, op, cfg.ways, store is not None
                     )
                     if store is not None:
                         # The store's sequence is synchronous per wave:
@@ -3314,10 +3435,10 @@ class MeshEngine(EngineBase):
                         for lane, entry in lane_reqs[w].items():
                             served[(entry[1], entry[2])] = (w, lane)
                             events.append(("i", (entry[1], entry[2])))
-                    outs.append(out)
-                for op in r_ops:
+                    outs.append((out, n))
+                for _w, n, op in r_ops:
                     rstate, out = rt.decide(rstate, op)
-                    r_outs.append(out)
+                    r_outs.append((out, n))
                 self.table = table
                 if rt is not None:
                     rt.state = rstate
@@ -3846,7 +3967,7 @@ class DeviceEngine(MeshEngine):
 
 def _assemble_column_waves(
     cols, hi, lo, grp, now, batch_size: int, max_waves: int,
-    width_candidates=(),
+    width_candidates=(), stacked_widths=(),
 ):
     """Vectorized wave assembly shared by the engines' columnar paths:
     wave = occurrence rank within the group (stable sort keeps arrival
@@ -3858,7 +3979,10 @@ def _assemble_column_waves(
 
     `width_candidates` optionally narrows the device batch width to the
     actual occupancy — the kernel's cost is per-LANE — using only
-    already-compiled widths."""
+    already-compiled widths. A call of several waves narrows only to a
+    width among `stacked_widths`, those whose stacked launch is warm: a
+    wider wave costs microseconds of device time, a launch a wave costs
+    a millisecond each under the engine lock."""
     from gubernator_tpu.models.bucket import MAX_COUNT, MAX_DURATION_MS
 
     n = cols.n
@@ -3881,7 +4005,7 @@ def _assemble_column_waves(
 
     B = batch_size
     for s in width_candidates:  # immutable snapshot; warmer swaps atomically
-        if s > max_lane and s < B:
+        if s > max_lane and s < B and (num_waves == 1 or s in stacked_widths):
             B = s
 
     # Encode columns (the encode_one clamps, vectorized).

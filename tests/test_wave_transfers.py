@@ -1,8 +1,9 @@
-"""A wave crosses the host-device boundary once each way: one uploaded
-operand in, one output vector read (gubernator_engine_wave_transfers),
-on every flush path; and no launch under the engine lock passes the
-device anything from the host (jax.transfer_guard around
-_execute_waves)."""
+"""A launch crosses the host-device boundary once each way: one uploaded
+operand in, one output array read (gubernator_engine_wave_transfers),
+on every flush path. A run of equally wide waves of one flush is one
+launch (ISSUE 35); with a Store every wave is a launch of its own. And
+no launch under the engine lock passes the device anything from the host
+(jax.transfer_guard around _execute_waves)."""
 
 import jax
 import pytest
@@ -58,14 +59,14 @@ def flush_columnar(eng):
         now=NOW,
     )
     assert out is not None and out[2].tolist()[-1] == 996
-    return 4
+    return 4, 1
 
 
 def flush_pump(eng):
     """The object path through the pump: two waves."""
     got = eng.check_batch([mk("p1"), mk("p2"), mk("p2")])
     assert [r.remaining for r in got] == [999, 999, 998]
-    return 2
+    return 2, 1
 
 
 def flush_32_waves(eng):
@@ -73,7 +74,7 @@ def flush_32_waves(eng):
     got = eng.check_batch([mk("hot") for _ in range(40)])
     assert [r.remaining for r in got] == list(range(999, 959, -1))
     assert any(r["waves"] == 32 for r in eng.metrics.recorder.snapshot())
-    return 40
+    return 40, 2
 
 
 FLUSHES = {
@@ -85,11 +86,13 @@ FLUSHES = {
 
 @pytest.mark.parametrize("flush", FLUSHES.values(), ids=FLUSHES.keys())
 def test_one_operand_in_one_read_out_a_wave(engine, flush):
+    """Each flush function returns (its waves, its launches): the waves
+    of a flush are one width here, so a flush is one launch."""
     h0, d0, w0 = transfers(engine)
-    waves = flush(engine)
+    waves, launches = flush(engine)
     h1, d1, w1 = transfers(engine)
     assert w1 - w0 == waves
-    assert (h1 - h0, d1 - d0) == (waves, waves)
+    assert (h1 - h0, d1 - d0) == (launches, launches)
 
 
 @pytest.mark.parametrize("flush", FLUSHES.values(), ids=FLUSHES.keys())
@@ -100,7 +103,7 @@ def test_store_flush_counts_one_each_for_its_decide(engine, flush):
     store = MemoryStore()
     attach_store(engine, store)
     h0, d0, w0 = transfers(engine)
-    waves = flush(engine)
+    waves, _launches = flush(engine)
     h1, d1, w1 = transfers(engine)
     assert w1 - w0 == waves
     assert (h1 - h0, d1 - d0) == (waves, waves)
@@ -121,10 +124,10 @@ def test_refused_columnar_attempt_counts_nothing(engine):
 def test_counter_is_exported_per_direction(engine):
     m = Metrics()
     wire_engine_telemetry(m, engine)
-    waves = flush_pump(engine) + flush_32_waves(engine)
+    launches = flush_pump(engine)[1] + flush_32_waves(engine)[1]
     text = m.render().decode()
     h2d = engine.metrics.wave_h2d
-    assert h2d == engine.metrics.wave_d2h >= waves
+    assert h2d == engine.metrics.wave_d2h >= launches
     for direction in ("h2d", "d2h"):
         line = f'gubernator_engine_wave_transfers{{direction="{direction}"}}'
         got = [ln for ln in text.splitlines() if ln.startswith(line)]
@@ -168,7 +171,9 @@ def test_guard_catches_a_host_operand(engine, guarded, monkeypatch):
     itself is refused."""
     monkeypatch.setattr(
         engine, "_upload",
-        lambda waves, now, fs: [w.stamp(now).buf for w in waves],
+        lambda waves, now, fs: [
+            (i, 1, w.stamp(now).buf) for i, w in enumerate(waves)
+        ],
     )
     with pytest.raises(Exception, match="Disallowed host-to-device"):
         engine.check_columns(columns([mk("x")]), now=NOW)
@@ -196,12 +201,14 @@ def test_mesh_and_replica_launches_pass_no_host_array(guarded):
         assert [r.remaining for r in got] == [999, 998, 999, 999, 999]
         h1, d1, w1 = transfers(eng)
         assert w1 - w0 == 3  # two sharded waves, one replica wave
-        assert (h1 - h0, d1 - d0) == (3, 3)
+        # the sharded run is one launch, the replica wave its own
+        assert (h1 - h0, d1 - d0) == (2, 2)
         if wire.available():
             out = eng.check_columns(columns(reqs), now=NOW)
             assert out[2].tolist() == [997, 996, 999, 999, 998]
             h2, d2, w2 = transfers(eng)
-            assert (h2 - h1, d2 - d1) == (w2 - w1, w2 - w1) == (3, 3)
+            assert w2 - w1 == 3
+            assert (h2 - h1, d2 - d1) == (2, 2)
         assert len(guarded) >= 1
         assert eng.metrics.cold_compiles == 0
     finally:
