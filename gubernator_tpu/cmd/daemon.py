@@ -1,23 +1,36 @@
 """Daemon entry point: `python -m gubernator_tpu.cmd.daemon [--config f]`
-(reference cmd/gubernator/main.go:41-100)."""
+(reference cmd/gubernator/main.go:41-100). An embedder that sets what no
+environment variable can (`DaemonConfig.store`, `.loader`; the
+reference's `Config.Store`) calls `serve(conf)` with its own
+configuration: everything after the configuration is made."""
 
 from __future__ import annotations
 
 import argparse
 import asyncio
+import json
 import logging
 import signal
 
 
 def main() -> None:
-    import json
-    import os
-
     parser = argparse.ArgumentParser(description="gubernator-tpu daemon")
     parser.add_argument("--config", default=None, help="KEY=VALUE config file")
     parser.add_argument("--debug", action="store_true")
     args = parser.parse_args()
 
+    from gubernator_tpu.service.envconfig import setup_daemon_config
+
+    # Config FIRST so --config file keys (injected into the env) are seen
+    # by the log settings too (reference config.go:268-310 order).
+    serve(setup_daemon_config(args.config), debug=args.debug)
+
+
+def serve(conf, debug: bool = False) -> None:
+    """Run one daemon under `conf` until SIGINT/SIGTERM, then drain:
+    the compile cache, logging, the trace level, `Daemon.spawn`, the
+    signal handlers and the graceful drain. Blocks; call it from the
+    main thread (the signal handlers need it)."""
     from gubernator_tpu.utils.compilecache import enable_compile_cache
 
     # Persistent XLA cache: a restarted daemon deserializes its decide
@@ -26,17 +39,12 @@ def main() -> None:
     enable_compile_cache()
 
     from gubernator_tpu.service.daemon import Daemon
-    from gubernator_tpu.service.envconfig import setup_daemon_config
-
-    # Config FIRST so --config file keys (injected into the env) are seen
-    # by the log settings too (reference config.go:268-310 order).
-    conf = setup_daemon_config(args.config)
 
     # GUBER_LOG_LEVEL / GUBER_LOG_FORMAT=json / GUBER_DEBUG or --debug
     # (reference config.go:286-310)
     level = (
         logging.DEBUG
-        if args.debug or conf.debug
+        if debug or conf.debug
         else getattr(logging, conf.log_level.upper(), logging.INFO)
     )
     if conf.log_format.lower() == "json":

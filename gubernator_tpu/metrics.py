@@ -616,6 +616,10 @@ raceguard.guarded_by(HotKeySketch, {
 # the pipeline"), and every `stage` value of the engine's histogram.
 FLUSH_STAGES = (
     "hash", "waves", "keydict", "lock_wait", "dispatch", "readback", "post",
+    # the Store's sequence (docs/persistence.md), observed only with a
+    # Store attached: the first two a wave, inside dispatch; the third a
+    # flush, inside post (the pump: inside resolve)
+    "readthrough", "store_rows", "write_behind",
 )
 ENGINE_STAGES = (
     "intake", "assemble", "inflight_wait", "device_sync", "resolve",
@@ -663,6 +667,67 @@ def engine_wave_transfers() -> _BareCounter:
     for direction in ("h2d", "d2h"):
         c.labels(direction).inc(0)
     return c
+
+
+# The device programs of the Store's per-wave sequence, in its order.
+STORE_WAVE_PROGRAMS = ("probe", "inject", "decide", "gather_rows")
+
+
+def engine_wave_programs() -> _BareCounter:
+    """The engine-owned counter of the device programs the Store's
+    per-wave sequence launches, added where the engine observes
+    gubernator_engine_flush_waves and exposed beside it (after
+    gubernator_engine_flush_launches): over
+    gubernator_engine_flush_waves_sum it is the programs one wave costs
+    with a Store attached."""
+    c = _BareCounter(
+        "gubernator_engine_wave_programs",
+        "Device programs launched under the engine lock by the waves of "
+        "flushes that ran the Store's per-wave sequence, by program: "
+        "probe (residency of the wave's keys), inject (Store rows read "
+        "through, only for a wave with a miss the Store answered), "
+        "decide, gather_rows (the rows the write-behind persists). 0 "
+        "without a Store.",
+        ["program"],
+    )
+    for program in STORE_WAVE_PROGRAMS:
+        c.labels(program).inc(0)
+    return c
+
+
+def engine_store_counters() -> dict:
+    """The engine-owned counters of what it asks of an attached Store
+    (reference store.go:49-65), keyed as EngineMetrics holds them."""
+    gets = _BareCounter(
+        "gubernator_store_gets",
+        "Store.get calls the engine made for a key its table did not "
+        "hold (never seen by this process, or evicted), by result: hit "
+        "(the Store held it; the row is injected before the wave's "
+        "decide) or miss (also a Store that raised).",
+        ["result"],
+    )
+    for result in ("hit", "miss"):
+        gets.labels(result).inc(0)
+    return {
+        "store_gets": gets,
+        "store_injected_rows": _BareCounter(
+            "gubernator_store_injected_rows",
+            "Rows written into the table from a Store.get hit (the "
+            "read-through). A row displaced and re-seated inside one "
+            "flush comes from that flush's own gathered rows and counts "
+            "only as an inject program.",
+        ),
+        "store_on_change_items": _BareCounter(
+            "gubernator_store_on_change_items",
+            "Snapshots handed to Store.on_change after a flush: one a "
+            "key the flush changed, its last operation winning.",
+        ),
+        "store_removes": _BareCounter(
+            "gubernator_store_removes",
+            "Store.remove calls: keys whose last operation in a flush "
+            "was a token bucket's RESET_REMAINING.",
+        ),
+    }
 
 
 def engine_histograms() -> dict:
@@ -760,7 +825,10 @@ def engine_histograms() -> dict:
             "the object path): lock_wait (engine lock + collective "
             "guard), dispatch (the wave launches under the lock), "
             "readback (the blocking read). post is what follows the "
-            "read.",
+            "read. With a Store attached, a wave inside dispatch: "
+            "readthrough (probe, Store.get, inject) and store_rows (the "
+            "wave's read and its row gather); a flush inside post: "
+            "write_behind (snapshots, Store.remove, Store.on_change).",
             scale=us, n_buckets=24, labelnames=("stage",),
         ),
         "transfer_duration": Log2Histogram(
@@ -1809,6 +1877,10 @@ def wire_engine_telemetry(metrics: "Metrics", engine) -> None:
         metrics.register_renderable(h)
         if h is getattr(em, "flush_waves", None):
             metrics.register_renderable(em.wave_transfers)
+        if h is getattr(em, "flush_launches", None):
+            metrics.register_renderable(em.wave_programs)
+    for c in getattr(em, "store_counters", ()):
+        metrics.register_renderable(c)
     metrics.add_sync(engine_sync(engine))
 
 
@@ -1821,4 +1893,6 @@ def catalog_names() -> set:
     names = Metrics().sample_family_names()
     names |= {h.name for h in engine_histograms().values()}
     names.add(engine_wave_transfers().name)
+    names.add(engine_wave_programs().name)
+    names |= {c.name for c in engine_store_counters().values()}
     return names

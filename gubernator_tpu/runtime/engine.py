@@ -43,7 +43,10 @@ from gubernator_tpu.utils import raceguard
 from gubernator_tpu.metrics import (
     ENGINE_STAGES,
     FLUSH_STAGES,
+    STORE_WAVE_PROGRAMS,
     engine_histograms,
+    engine_store_counters,
+    engine_wave_programs,
     engine_wave_transfers,
 )
 from gubernator_tpu.api.keys import group_of, key_hash128, key_hash128_batch
@@ -235,6 +238,19 @@ class EngineMetrics:
         self.wave_transfers = engine_wave_transfers()
         self._wave_h2d = self.wave_transfers.labels("h2d")
         self._wave_d2h = self.wave_transfers.labels("d2h")
+        # What a Store costs (docs/persistence.md): the device programs
+        # of its per-wave sequence, counted beside the waves, and what
+        # the engine asked of the Store itself.
+        self.wave_programs = engine_wave_programs()
+        self._wave_program = {
+            p: self.wave_programs.labels(p) for p in STORE_WAVE_PROGRAMS
+        }
+        stores = engine_store_counters()
+        for attr, c in stores.items():
+            setattr(self, attr, c)
+        self.store_counters = tuple(stores.values())
+        self._store_hit = self.store_gets.labels("hit")
+        self._store_miss = self.store_gets.labels("miss")
         # Pre-resolved stage children (labels() lookups are per-flush
         # hot-path cost).
         self._stage = {
@@ -320,6 +336,13 @@ class EngineMetrics:
             )
         return out
 
+    def observe_store_gets(self, hits: int, misses: int) -> None:
+        """Store.get calls of one read-through site, by result."""
+        if hits:
+            self._store_hit.inc(hits)
+        if misses:
+            self._store_miss.inc(misses)
+
     def note_cold_compile(self) -> None:
         with self.lock:
             self.cold_compiles += 1
@@ -338,7 +361,7 @@ class EngineMetrics:
     def observe_flush(self, path: str, n: int, waves: int, dur: float,
                       dev: float, trace_id: str = "",
                       collective: bool = False, transfers=(0, 0),
-                      launches: int = 0) -> None:
+                      launches: int = 0, programs=None) -> None:
         """One flush's distribution samples (per FLUSH, not per
         request). A non-empty trace_id attaches an OpenMetrics exemplar
         to the latency buckets this flush lands in, so a p99 spike in
@@ -350,7 +373,9 @@ class EngineMetrics:
         (operands uploaded, outputs read) for the flush's waves and
         `launches` the decide programs it launched (a run of equally
         wide waves is one operand, one launch, one output), counted
-        beside the waves themselves so a scrape sees all or none."""
+        beside the waves themselves so a scrape sees all or none, as
+        are `programs`, the launches of the Store's per-wave sequence
+        by STORE_WAVE_PROGRAMS name (None without a Store)."""
         self.flush_duration.labels(path).observe(dur, trace_id)
         self.device_sync.labels(path).observe(dev, trace_id)
         self.batch_width.labels(path).observe(n)
@@ -358,6 +383,9 @@ class EngineMetrics:
         self.flush_launches.observe(launches)
         self._wave_h2d.inc(transfers[0])
         self._wave_d2h.inc(transfers[1])
+        if programs is not None:
+            for name, n in programs.items():
+                self._wave_program[name].inc(n)
         if collective:
             self.collective_tick.observe(dev)
 
@@ -368,7 +396,9 @@ class FlushStages:
     them to the engine's stage histogram in one go and fills `us`,
     which the flight recorder keeps with the flush's record."""
 
-    __slots__ = ("em", "ids", "us", "_rows", "h2d", "d2h", "launches")
+    __slots__ = (
+        "em", "ids", "us", "_rows", "h2d", "d2h", "launches", "programs",
+    )
 
     def __init__(self, em: EngineMetrics, flush: int, call: int):
         self.em = em
@@ -379,6 +409,9 @@ class FlushStages:
         self.h2d = 0
         self.d2h = 0
         self.launches = 0
+        # with a Store: the launches of its per-wave sequence by
+        # STORE_WAVE_PROGRAMS name (_execute_waves)
+        self.programs: Optional[Dict[str, int]] = None
         # every key from the start: the record shares this dict, and a
         # /debug/engine dump may walk it while publish() fills it in
         self.us: Dict[str, int] = dict.fromkeys(FLUSH_STAGES, 0)
@@ -2576,12 +2609,12 @@ class MeshEngine(EngineBase):
                             seen.add(k)
                             need.append((req, k))
                 for req, k in need:
-                    try:
-                        snap = self.store.get(req)
-                    except Exception:
-                        snap = None  # store outage == cache miss, not a crash
+                    snap = self._store_get(req)
                     if snap is not None:
                         prefetched[k] = snap
+                self.metrics.observe_store_gets(
+                    len(prefetched), len(need) - len(prefetched)
+                )
 
             if cfg.keep_key_strings:
                 self._maybe_prune_key_strings()
@@ -2785,7 +2818,7 @@ class MeshEngine(EngineBase):
             em.observe_flush(
                 "object", t.served, t.waves, dur, dev_s, trace_id,
                 collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
-                launches=fs.launches,
+                launches=fs.launches, programs=fs.programs,
             )
             em.observe_stage("assemble", t.t_dev - t.t0)
             # `dispatch` (the launches under the lock) and `lock_wait` were
@@ -2806,7 +2839,10 @@ class MeshEngine(EngineBase):
             # its response can rely on the store reflecting it (the reference's
             # OnChange runs within the request, algorithms.go:149-153).
             if self.store is not None:
-                self._store_write_behind(t.items, t.placements, s_rows, t.rows)
+                with tracing.stage("flush.write_behind", fs, fs.ids):
+                    self._store_write_behind(
+                        t.items, t.placements, s_rows, t.rows
+                    )
 
             # GUBER_STAGE_METADATA: the flush-level stage times every served
             # item shares, built once; each response appends its own queue
@@ -3037,12 +3073,12 @@ class MeshEngine(EngineBase):
                                 need.append((j, k))
                         self._key_strings.update(zip(keys_l, strs))
                     for j, k in need:
-                        try:
-                            snap = store.get(req_of(j))
-                        except Exception:
-                            snap = None  # store outage == cache miss
+                        snap = self._store_get(req_of(j))
                         if snap is not None:
                             prefetched[k] = snap
+                    self.metrics.observe_store_gets(
+                        len(prefetched), len(need) - len(prefetched)
+                    )
                     self._maybe_prune_key_strings()
                 # item indices per wave (for the lazy lane_req dicts)
                 by_wave = [[] for _ in range(W)]
@@ -3109,10 +3145,11 @@ class MeshEngine(EngineBase):
                 # Write-behind from the per-wave gathered rows
                 # (last-op-wins per key, request order) + key-dictionary
                 # hygiene — same semantics as the object path's flush.
-                self._store_write_behind_core(
-                    list(zip(strs, wave_l, lane_l, hi_l, lo_l)),
-                    out_rows, wave_rows_host,
-                )
+                with tracing.stage("flush.write_behind", fs, fs.ids):
+                    self._store_write_behind_core(
+                        list(zip(strs, wave_l, lane_l, hi_l, lo_l)),
+                        out_rows, wave_rows_host,
+                    )
                 if cfg.keep_key_strings:
                     self._drop_displaced_strings(events)
 
@@ -3124,7 +3161,7 @@ class MeshEngine(EngineBase):
                 "columnar", n, W, dur, dev_s,
                 flush_trace_id if cfg.exemplars else "",
                 collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
-                launches=fs.launches,
+                launches=fs.launches, programs=fs.programs,
             )
             em.observe_stage("assemble", t_dev - t_start)
             em.observe_stage("device_sync", dev_s)
@@ -3376,6 +3413,8 @@ class MeshEngine(EngineBase):
         # a DEBUG SDK (`live`, None otherwise) opens spans in there.
         n_dispatch = len(ops) + len(r_ops)
         fs.launches += n_dispatch
+        if store is not None and fs.programs is None:
+            fs.programs = dict.fromkeys(STORE_WAVE_PROGRAMS, 0)
         self.metrics.busy_enter()
         live = tracing.open_live("flush.lock_wait", fs.ids)
         t_wait = time.perf_counter_ns()
@@ -3403,11 +3442,12 @@ class MeshEngine(EngineBase):
                             self._pager.touched_pages(wb.group, wb.active),
                         )
                     if store is not None:
-                        table = self._wave_readthrough(
-                            table, wo.batch, lane_reqs[w], now,
-                            prefetched, served, wave_rows_host, events,
-                            req_resolver=req_resolver,
-                        )
+                        with tracing.stage("flush.readthrough", fs, fs.ids):
+                            table = self._wave_readthrough(
+                                table, wo.batch, lane_reqs[w], now,
+                                prefetched, served, wave_rows_host, events,
+                                fs, req_resolver=req_resolver,
+                            )
                     table, out = self.K.decide_packed(
                         table, op, cfg.ways, store is not None
                     )
@@ -3416,7 +3456,11 @@ class MeshEngine(EngineBase):
                         # the wave's one read happens here, and its slot
                         # column drives the row gather (a program of its
                         # own, K.gather_rows).
-                        with _transfer.account(
+                        fs.programs["decide"] += 1
+                        fs.programs["gather_rows"] += 1
+                        with tracing.stage(
+                            "flush.store_rows", fs, fs.ids
+                        ), _transfer.account(
                             self.metrics, "d2h", "serve"
                         ) as tx:
                             out = np.asarray(out)  # guberlint: allow-host-sync -- store path: the wave's one read, synchronous by design
@@ -3489,6 +3533,7 @@ class MeshEngine(EngineBase):
         served: Dict,
         wave_rows_host: List,
         events: List,
+        fs: FlushStages,
         req_resolver=None,
     ):
         """Reference miss path at wave granularity: probe the table for
@@ -3509,10 +3554,12 @@ class MeshEngine(EngineBase):
         from gubernator_tpu.ops.inject import InjectBatch
 
         cfg = self.cfg
+        fs.programs["probe"] += 1
         exists = np.asarray(
             self.K.probe_exists(table, wb.key_hi, wb.key_lo, wb.group, now, cfg.ways)
         )
         rows = []
+        gets = hits = from_store = 0
         for lane, (req, hi, lo) in lane_req.items():
             if exists[lane]:
                 continue
@@ -3537,14 +3584,17 @@ class MeshEngine(EngineBase):
             else:
                 snap = prefetched.get((hi, lo))
                 if snap is None:
-                    try:
-                        snap = self.store.get(req)
-                    except Exception:
-                        snap = None  # store outage == cache miss
+                    snap = self._store_get(req)
+                    gets += 1
+                    hits += snap is not None
+                from_store += snap is not None
             if snap is not None:
                 rows.append((lane, snap, hi, lo))
+        self.metrics.observe_store_gets(hits, gets - hits)
         if not rows:
             return table
+        fs.programs["inject"] += 1
+        self.metrics.store_injected_rows.inc(from_store)
         ib = InjectBatch.zeros(cfg.batch_size)
         for j, (lane, s, hi, lo) in enumerate(rows):
             ib.key_hi[j] = hi
@@ -3570,6 +3620,14 @@ class MeshEngine(EngineBase):
         for lane, snap, hi, lo in rows:
             events.append(("i", (hi, lo)))
         return table
+
+    def _store_get(self, req):
+        """Store.get for a read-through; a Store that raises is a cache
+        miss, never a crash and never table-fatal."""
+        try:
+            return self.store.get(req)
+        except Exception:
+            return None
 
     def _store_write_behind(self, items, placements, out_rows, rows) -> None:
         def seq():
@@ -3654,11 +3712,16 @@ class MeshEngine(EngineBase):
         # Store.OnChange has no error return either (store.go:49-65);
         # durability degrades, serving does not.
         try:
+            removes = 0
             for key, s in ops.items():
                 if s is None:
                     self.store.remove(key)
+                    removes += 1
+            if removes:
+                self.metrics.store_removes.inc(removes)
             if changes:
                 self.store.on_change(changes)
+                self.metrics.store_on_change_items.inc(len(changes))
         except Exception:
             import logging
 

@@ -299,7 +299,9 @@ def test_benchmark_reader_of_launches_per_flush():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     man = manifest.load(root)
     entry = {p["name"]: p for p in man["per_layer"]}["launches_per_flush"]
-    assert man["per_layer"][-1] is entry  # appended, nothing moved
+    names = [p["name"] for p in man["per_layer"]]
+    # appended by PR 35 after PR 32's last, and nothing moved since
+    assert names.index("launches_per_flush") == names.index("shard_imbalance") + 1
     assert entry["layer"] == "engine host stage"
     assert entry["moves"] == "decisions_per_s" and entry["better"] == "lower"
     assert entry["workloads"] == next(

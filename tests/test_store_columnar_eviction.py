@@ -1,0 +1,214 @@
+"""The Store path against the capacity-free reference, at a size where
+the table cannot hold the keys: a 64-group x 8-way engine (512 slots)
+with a MemoryStore, 4,000 keys, seeded scrambled-Zipf calls of 2, 100 and
+1,000 items. With a Store an evicted key is read back on its next
+request, so (a) every answer equals the reference's, which has no
+capacity (gubernator_tpu/models/oracle.py); (b) at the end the Store
+holds the reference's last state of every key touched; (c) the Store
+path's counters (gubernator_store_*, gubernator_engine_wave_programs)
+agree with what the Store saw and with each other.
+
+The small twin of the benchmark's `store-1m.calls100` (PERF.md §4).
+"""
+
+import dataclasses
+
+import numpy as np
+import pytest
+
+from gubernator_tpu import wire
+from gubernator_tpu.api.types import Algorithm, Behavior, RateLimitReq
+from gubernator_tpu.models.oracle import OracleEngine
+from gubernator_tpu.runtime.engine import DeviceEngine, EngineConfig
+from gubernator_tpu.service import pb
+from gubernator_tpu.store import MemoryStore, attach_store
+
+NOW = 1_753_700_000_000
+KEYS = 4_000
+SIZES = (2,) * 28 + (100,) * 12 + (1_000,) * 3
+
+pytestmark = pytest.mark.skipif(
+    not wire.available(), reason="native wirepath unavailable"
+)
+
+
+class CountingStore(MemoryStore):
+    """MemoryStore that counts what it is handed."""
+
+    def __init__(self):
+        super().__init__()
+        self.changed = self.removed = self.get_hits = 0
+
+    def on_change(self, items):
+        self.changed += len(items)
+        super().on_change(items)
+
+    def remove(self, key):
+        self.removed += 1
+        super().remove(key)
+
+    def get(self, req):
+        snap = super().get(req)
+        self.get_hits += snap is not None
+        return snap
+
+
+def counter(c) -> dict:
+    """A _BareCounter's exposition as {label values or "": value}."""
+    out = {}
+    for line in c.render_lines():
+        if not line.startswith("#"):
+            name, _, value = line.rpartition(" ")
+            out[name.partition("{")[2].rstrip("}")] = float(value)
+    return out
+
+
+def store_counts(em) -> dict:
+    gets = counter(em.store_gets)
+    programs = counter(em.wave_programs)
+    return {
+        "hit": gets['result="hit"'], "miss": gets['result="miss"'],
+        "injected": counter(em.store_injected_rows)[""],
+        "changed": counter(em.store_on_change_items)[""],
+        "removed": counter(em.store_removes)[""],
+        **{p: programs[f'program="{p}"']
+           for p in ("probe", "inject", "decide", "gather_rows")},
+    }
+
+
+def make_calls(seed: int):
+    """[(requests of one call)]: scrambled Zipf(0.99) over KEYS, even
+    keys token and odd keys leaky, one item in twenty a RESET_REMAINING."""
+    rng = np.random.default_rng(seed)
+    w = np.arange(1, KEYS + 1, dtype=np.float64) ** -0.99
+    cdf = np.cumsum(w) / w.sum()
+    scramble = rng.permutation(KEYS)
+    sizes = np.array(SIZES)
+    rng.shuffle(sizes)
+    calls = []
+    for size in sizes.tolist():
+        ranks = np.minimum(np.searchsorted(cdf, rng.random(size)), KEYS - 1)
+        reset = rng.random(size) < 0.05
+        calls.append([
+            RateLimitReq(
+                name="ev", unique_key=f"k{k:05d}", hits=1, limit=20,
+                duration=3_600_000,
+                algorithm=(Algorithm.LEAKY_BUCKET if k % 2
+                           else Algorithm.TOKEN_BUCKET),
+                behavior=int(Behavior.RESET_REMAINING) if r else 0,
+            )
+            for k, r in zip(scramble[ranks].tolist(), reset.tolist())
+        ])
+    return calls
+
+
+def to_proto_bytes(reqs):
+    msg = pb.pb.GetRateLimitsReq()
+    for r in reqs:
+        msg.requests.append(pb.req_to_pb(r))
+    return msg.SerializeToString()
+
+
+@pytest.fixture(scope="module", params=[11, 12])
+def run(request):
+    """One seeded run: every answer beside the reference's, the per-call
+    counter deltas, and the engine's final counters and Store."""
+    clock = {"now": NOW}
+    eng = DeviceEngine(
+        EngineConfig(num_groups=64, ways=8, batch_size=64, batch_wait_s=0.001),
+        now_fn=lambda: clock["now"],
+    )
+    store = CountingStore()
+    attach_store(eng, store)
+    oracle = OracleEngine()  # no capacity, no Store: the reference
+    out = {"store": store, "oracle": oracle, "answers": [], "columnar": [],
+           "touched": set()}
+    try:
+        for reqs in make_calls(request.param):
+            clock["now"] += 10
+            now = clock["now"]
+            before = store_counts(eng.metrics)
+            cols = wire.parse_requests(to_proto_bytes(reqs))
+            got = eng.check_columns(cols, now=now)
+            columnar = got is not None
+            if columnar:
+                got = list(zip(*(a.tolist() for a in got)))
+            else:  # over max_waves: the object path, same Store sequence
+                got = [(int(r.status), r.limit, r.remaining, r.reset_time)
+                       for r in eng.check_batch(
+                           [dataclasses.replace(r) for r in reqs])]
+            want = [oracle.decide(dataclasses.replace(r), now) for r in reqs]
+            want = [(int(w.status), w.limit, w.remaining, w.reset_time)
+                    for w in want]
+            after = store_counts(eng.metrics)
+            out["answers"].append((len(reqs), got, want))
+            if columnar:
+                out["columnar"].append((
+                    len(reqs), len({r.hash_key() for r in reqs}),
+                    {k: after[k] - before[k] for k in after}))
+            out["touched"].update(r.hash_key() for r in reqs)
+        em = eng.metrics
+        out["counts"] = store_counts(em)
+        out["waves"] = em.waves
+        out["evictions"] = em.unexpired_evictions
+    finally:
+        eng.close()
+    return out
+
+
+def test_answers_equal_the_capacity_free_reference(run):
+    """(a) eviction or not: 4,000 keys through 512 slots."""
+    assert run["evictions"] > 100 and run["counts"]["hit"] > 100
+    for n, (size, got, want) in enumerate(run["answers"]):
+        assert got == want, f"call {n} of {size} items"
+    sizes = [size for size, _, _ in run["columnar"]]
+    # the 2- and 100-item calls stay columnar; a 1,000-item call holds
+    # its hot key over max_waves times and takes the object path
+    assert sizes.count(2) == SIZES.count(2)
+    assert sizes.count(100) == SIZES.count(100)
+
+
+def test_store_holds_the_references_last_states(run):
+    """(b) for every key touched: remaining, stamp, expire_at, status;
+    a token key last freed by RESET_REMAINING is absent."""
+    store, oracle = run["store"], run["oracle"]
+    assert set(store.data) == set(oracle.cache) <= run["touched"]
+    for key, item in oracle.cache.items():
+        snap, v = store.data[key], item.value
+        if item.algorithm == Algorithm.TOKEN_BUCKET:
+            want = (v.remaining, v.created_at, item.expire_at, int(v.status))
+            got = (snap.remaining, snap.stamp, snap.expire_at, snap.status)
+        else:
+            want = (v.remaining_s, v.updated_at, item.expire_at)
+            got = (snap.remaining, snap.stamp, snap.expire_at)
+        assert got == want, key
+        assert (snap.algorithm, snap.limit, snap.duration) == (
+            item.algorithm, v.limit, v.duration), key
+    freed = run["touched"] - set(oracle.cache)
+    assert freed, "no key ended freed: the run exercises no remove"
+
+
+def test_store_counters_add_up(run):
+    """(c) the counters agree with the Store's own view and each other."""
+    c, store = run["counts"], run["store"]
+    # what was handed to the Store: one snapshot or one remove a key a
+    # flush, the key's last operation winning
+    assert c["changed"] == store.changed and c["removed"] == store.removed
+    assert c["hit"] == store.get_hits
+    assert c["hit"] + c["miss"] == store.get_calls
+    # a columnar call is one flush: every distinct key of it is handed
+    # over once, so where no key repeats (most two-item calls) that is
+    # the items answered
+    for size, distinct, d in run["columnar"]:
+        assert d["changed"] + d["removed"] == distinct, size
+        assert d["probe"] == d["decide"] == d["gather_rows"] >= 1
+        # every Store hit is injected before its wave's decide, and
+        # nothing else counts as a row read through
+        assert d["hit"] == d["injected"], size
+    # the object path may prefetch a key whose item the wave cap carries
+    # to the next flush, which asks the Store again: a hit more than rows
+    assert 0 < c["injected"] <= c["hit"]
+    # one probe, one decide and one row gather a wave; an inject only
+    # where a wave had a row to seat
+    assert c["decide"] == c["probe"] == c["gather_rows"] == run["waves"]
+    assert 0 < c["inject"] <= run["waves"]
