@@ -661,7 +661,9 @@ def engine_wave_transfers() -> _BareCounter:
         "waves: operands uploaded (h2d) and outputs read (d2h). One of "
         "each a launch (a wave, or a run of waves stacked); over "
         "gubernator_engine_flush_waves_sum it is the arrays a wave "
-        "costs each way.",
+        "costs each way. It never counted what else a Store's sequence "
+        "moves under the lock: gubernator_engine_store_wave_crossings "
+        "does.",
         ["direction"],
     )
     for direction in ("h2d", "d2h"):
@@ -692,6 +694,32 @@ def engine_wave_programs() -> _BareCounter:
     )
     for program in STORE_WAVE_PROGRAMS:
         c.labels(program).inc(0)
+    return c
+
+
+def engine_store_wave_crossings() -> _BareCounter:
+    """The engine-owned counter of the arrays the Store's per-wave
+    sequence moves across the host-device boundary while it holds the
+    engine lock, added where the engine observes
+    gubernator_engine_flush_waves and exposed after
+    gubernator_engine_wave_programs: over
+    gubernator_engine_flush_waves_sum it is the crossings one wave
+    makes under the lock with a Store attached."""
+    c = _BareCounter(
+        "gubernator_engine_store_wave_crossings",
+        "Arrays that crossed the host-device boundary under the engine "
+        "lock in the Store's per-wave sequence, by direction: d2h the "
+        "probe's answer, the wave's output vector and its packed rows "
+        "(three a wave), and the two key columns an inject displaced; "
+        "h2d the fields of an inject's operand (only a wave with a miss "
+        "the Store answered uploads anything: the probe and the row "
+        "gather read what is on the device). The operand's own upload, "
+        "before the lock, is gubernator_engine_wave_transfers'. 0 "
+        "without a Store.",
+        ["direction"],
+    )
+    for direction in ("h2d", "d2h"):
+        c.labels(direction).inc(0)
     return c
 
 
@@ -1879,6 +1907,7 @@ def wire_engine_telemetry(metrics: "Metrics", engine) -> None:
             metrics.register_renderable(em.wave_transfers)
         if h is getattr(em, "flush_launches", None):
             metrics.register_renderable(em.wave_programs)
+            metrics.register_renderable(em.store_wave_crossings)
     for c in getattr(em, "store_counters", ()):
         metrics.register_renderable(c)
     metrics.add_sync(engine_sync(engine))
@@ -1894,5 +1923,6 @@ def catalog_names() -> set:
     names |= {h.name for h in engine_histograms().values()}
     names.add(engine_wave_transfers().name)
     names.add(engine_wave_programs().name)
+    names.add(engine_store_wave_crossings().name)
     names |= {c.name for c in engine_store_counters().values()}
     return names

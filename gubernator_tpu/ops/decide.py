@@ -26,6 +26,9 @@ from gubernator_tpu.ops.layout import (
     DecideOutput,
     RequestBatch,
     SlotTable,
+    gathered_rows,
+    packed_cols,
+    unpack_operand,
     vary_like,
 )
 
@@ -479,16 +482,8 @@ def make_decide(ways: int = 8):
     return functools.partial(decide, ways=ways)
 
 
-@functools.partial(jax.jit, static_argnames=("ways",))
-def probe_exists(table: SlotTable, key_hi, key_lo, group, now, ways: int = 8):
-    """Ground-truth residency probe: True per lane iff the key has a LIVE
-    entry in its group (same lazy-expiry + invalidation semantics as the
-    decide kernel's match). The engine uses this right before each wave to
-    drive store read-through on actual table misses — the reference
-    consults the store on every cache miss (algorithms.go:45-51), and the
-    table, not host bookkeeping, is what defines a miss."""
-    now = jnp.asarray(now, dtype=I64)
-    grp_base = group.astype(I64) * ways
+def _probe_exists_impl(table: SlotTable, batch, now, ways: int):
+    grp_base = batch.group.astype(I64) * ways
     way_ix = grp_base[:, None] + jnp.arange(ways, dtype=I64)[None, :]
     w_used = table.used[way_ix]
     w_invalid = table.invalid_at[way_ix]
@@ -498,25 +493,38 @@ def probe_exists(table: SlotTable, key_hi, key_lo, group, now, ways: int = 8):
     live = (
         w_used
         & ~w_expired
-        & (table.key_hi[way_ix] == key_hi[:, None])
-        & (table.key_lo[way_ix] == key_lo[:, None])
+        & (table.key_hi[way_ix] == batch.key_hi[:, None])
+        & (table.key_lo[way_ix] == batch.key_lo[:, None])
     )
-    return jnp.any(live, axis=1)
+    return batch.active & jnp.any(live, axis=1)
 
 
-@jax.jit
-def gather_rows(table: SlotTable, slots):
-    """Post-decide row readback for the Store write-behind seam: returns
-    each slot's full state (padding slots index N -> zeros via clip+mask)."""
-    n = table.num_slots
-    safe = jnp.clip(slots, 0, n - 1)
-    valid = slots < n
+@functools.partial(jax.jit, static_argnames=("ways",))
+def probe_exists(table: SlotTable, operand, ways: int = 8):
+    """Ground-truth residency probe: True per lane iff the lane is active
+    and its key has a LIVE entry in its group (same lazy-expiry +
+    invalidation semantics as the decide kernel's match). The engine uses
+    this right before each wave to drive store read-through on actual
+    table misses — the reference consults the store on every cache miss
+    (algorithms.go:45-51), and the table, not host bookkeeping, is what
+    defines a miss. `operand` is the wave's own uploaded operand, the one
+    its decide takes next (ops/layout.py unpack_operand): the probe
+    brings nothing across the boundary but its answer."""
+    batch, _home, now = unpack_operand(operand)
+    return _probe_exists_impl(table, batch, now, ways)
 
-    def g(arr):
-        v = arr[safe]
-        return jnp.where(valid, v, jnp.zeros_like(v))
 
-    return SlotTable(*[g(getattr(table, f)) for f in SlotTable._fields])
+@functools.partial(jax.jit, static_argnames=("from_output",))
+def gather_rows(table: SlotTable, slots, from_output: bool = False):
+    """Post-decide row readback for the Store write-behind seam: each
+    slot's full state as one packed (NCOLS, B) int64 array (padding
+    slots index N -> zeros); ops/layout.py gathered_rows / wide_rows."""
+    return gathered_rows(
+        lambda safe: jnp.stack(
+            packed_cols(jax.tree.map(lambda a: a[safe], table))
+        ),
+        slots, table.num_slots, from_output,
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("ways",), donate_argnums=(0,))

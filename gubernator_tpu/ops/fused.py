@@ -67,29 +67,35 @@ import jax.numpy as jnp
 
 from gubernator_tpu.api.types import Behavior
 from gubernator_tpu.ops.decide import _both_paths
-from gubernator_tpu.ops.layout import DecideOutput, RequestBatch, SlotTable
+from gubernator_tpu.ops.layout import (
+    BUR,
+    DUR,
+    EXP,
+    INV,
+    KHI,
+    KLO,
+    LIM,
+    META,
+    META_ALGO_SHIFT,
+    META_LRU_SHIFT,
+    META_STATUS_SHIFT,
+    META_USED,
+    NCOLS,
+    REM,
+    STM,
+    DecideOutput,
+    RequestBatch,
+    SlotTable,
+    gathered_rows,
+    pack_meta as _pack_meta,
+    packed_cols,
+    unpack_operand,
+    wide_rows as _wide,
+)
 
 I64 = jnp.int64
 U32 = jnp.uint32
 
-# The META word: lru_stamp_ms << 4 | status << 2 | algo << 1 | used.
-META_USED = 1
-META_ALGO_SHIFT = 1
-META_STATUS_SHIFT = 2
-META_LRU_SHIFT = 4
-
-
-def _pack_meta(used, algo, status, lru):
-    return (
-        (lru.astype(I64) << META_LRU_SHIFT)
-        | (status.astype(I64) & 3) << META_STATUS_SHIFT
-        | (algo.astype(I64) & 1) << META_ALGO_SHIFT
-        | used.astype(I64)
-    )
-
-
-KHI, KLO, META, EXP, LIM, DUR, REM, STM, BUR, INV = range(10)
-NCOLS = 10
 NWORDS = 2 * NCOLS  # a slot's state: NCOLS low words, then NCOLS high words
 
 # A slot takes SLOT_WORDS words of a line (its NWORDS and padding: 128 B,
@@ -249,39 +255,8 @@ class FusedTable(NamedTuple):
 @jax.jit
 def pack_table(wide: SlotTable) -> FusedTable:
     """Wide -> fused conversion (canonical snapshot interop)."""
-    cols = [None] * NCOLS
-    cols[KHI] = wide.key_hi
-    cols[KLO] = wide.key_lo
-    cols[META] = _pack_meta(wide.used, wide.algo, wide.status, wide.lru)
-    cols[EXP] = wide.expire_at
-    cols[LIM] = wide.limit
-    cols[DUR] = wide.duration
-    cols[REM] = wide.remaining
-    cols[STM] = wide.stamp
-    cols[BUR] = wide.burst
-    cols[INV] = wide.invalid_at
-    lo, hi = zip(*(split(c) for c in cols))
+    lo, hi = zip(*(split(c) for c in packed_cols(wide)))
     return FusedTable(data=_lines(list(lo) + list(hi)))
-
-
-def _wide(cols) -> SlotTable:
-    """NCOLS int64 columns (each any one shape) -> the wide struct."""
-    meta = cols[META]
-    return SlotTable(
-        key_hi=cols[KHI],
-        key_lo=cols[KLO],
-        used=(meta & META_USED) != 0,
-        algo=((meta >> META_ALGO_SHIFT) & 1).astype(jnp.int8),
-        status=((meta >> META_STATUS_SHIFT) & 3).astype(jnp.int8),
-        limit=cols[LIM],
-        duration=cols[DUR],
-        remaining=cols[REM],
-        stamp=cols[STM],
-        expire_at=cols[EXP],
-        invalid_at=cols[INV],
-        burst=cols[BUR],
-        lru=meta >> META_LRU_SHIFT,
-    )
 
 
 @jax.jit
@@ -664,11 +639,8 @@ def decide_scan_fused(table: FusedTable, batches: RequestBatch, nows, ways: int 
     return jax.lax.scan(step, table, (batches, nows))
 
 
-@functools.partial(jax.jit, static_argnames=("ways",))
-def probe_exists_fused(table: FusedTable, key_hi, key_lo, group, now, ways: int = 8):
-    """Residency probe (store read-through seam), fused layout."""
-    now = jnp.asarray(now, dtype=I64)
-    rows = _gather_groups(table.data, group, ways)
+def _probe_exists_fused_impl(table: FusedTable, batch, now, ways: int):
+    rows = _gather_groups(table.data, batch.group, ways)
     w_meta = rows[..., META]
     w_used = (w_meta & META_USED) != 0
     w_invalid = rows[..., INV]
@@ -678,22 +650,28 @@ def probe_exists_fused(table: FusedTable, key_hi, key_lo, group, now, ways: int 
     live = (
         w_used
         & ~w_expired
-        & (rows[..., KHI] == key_hi[:, None])
-        & (rows[..., KLO] == key_lo[:, None])
+        & (rows[..., KHI] == batch.key_hi[:, None])
+        & (rows[..., KLO] == batch.key_lo[:, None])
     )
-    return jnp.any(live, axis=1)
+    return batch.active & jnp.any(live, axis=1)
 
 
-@jax.jit
-def gather_rows_fused(table: FusedTable, slots) -> SlotTable:
-    """Post-decide row readback, expanded to the wide row struct so the
-    engine's store write-behind code is layout-agnostic."""
-    n = table.num_slots
-    safe = jnp.clip(slots, 0, n - 1)
-    valid = slots < n
-    rows = join_words(read_windows(table.data, safe, 1)[:, 0])
-    rows = jnp.where(valid[:, None], rows, 0)  # (B, C)
-    return _wide([rows[:, c] for c in range(NCOLS)])
+@functools.partial(jax.jit, static_argnames=("ways",))
+def probe_exists_fused(table: FusedTable, operand, ways: int = 8):
+    """Residency probe (store read-through seam), fused layout, of the
+    wave's own uploaded operand (ops/layout.py unpack_operand)."""
+    batch, _home, now = unpack_operand(operand)
+    return _probe_exists_fused_impl(table, batch, now, ways)
+
+
+@functools.partial(jax.jit, static_argnames=("from_output",))
+def gather_rows_fused(table: FusedTable, slots, from_output: bool = False):
+    """Post-decide row readback: the lanes' rows as one packed
+    (NCOLS, B) int64 array (ops/layout.py gathered_rows / wide_rows)."""
+    return gathered_rows(
+        lambda safe: join_words(read_windows(table.data, safe, 1)[:, 0]).T,
+        slots, table.num_slots, from_output,
+    )
 
 
 def _inject_fused_impl(table: FusedTable, items, now, ways: int):

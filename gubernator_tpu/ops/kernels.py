@@ -45,8 +45,14 @@ class Kernels(NamedTuple):
     decide: object  # (table, batch, now, ways, with_store) -> (table, out)
     decide_scan: object  # (table, batches, nows, ways, with_store)
     inject: object  # (table, items, now, ways) -> (table, ehi, elo)
-    probe_exists: object  # (table, hi, lo, group, now, ways) -> bool[B]
-    gather_rows: object  # (table, slots) -> SlotTable rows (wide view)
+    # The Store's two other programs exchange packed device arrays as the
+    # decide does. probe_exists reads the wave's uploaded operand;
+    # gather_rows takes (B,) slots, or with from_output the vector the
+    # `with_store` decide just produced (its OUT_SLOT row, on the
+    # device), and returns ONE (NCOLS, B) int64 array that
+    # ops/layout.py wide_rows views as the wide struct on the host.
+    probe_exists: object  # (table, operand, ways) -> bool[B]
+    gather_rows: object  # (table, slots, from_output=False) -> packed rows
     to_wide: object  # table -> SlotTable
     from_wide: object  # SlotTable -> table
     bytes_per_slot: int = 83  # resident table bytes per slot
@@ -71,9 +77,7 @@ _WIDE = Kernels(
     decide=_wide_decide,
     decide_scan=_wide_scan,
     inject=lambda table, items, now, ways: _wi(table, items, now, ways=ways),
-    probe_exists=lambda table, hi, lo, group, now, ways: _wpe(
-        table, hi, lo, group, now, ways=ways
-    ),
+    probe_exists=lambda table, operand, ways: _wpe(table, operand, ways=ways),
     gather_rows=_wgr,
     to_wide=lambda t: t,
     from_wide=lambda t: t,
@@ -96,8 +100,8 @@ def _fused():
         inject=lambda table, items, now, ways: _f.inject_fused(
             table, items, now, ways=ways
         ),
-        probe_exists=lambda table, hi, lo, group, now, ways: (
-            _f.probe_exists_fused(table, hi, lo, group, now, ways=ways)
+        probe_exists=lambda table, operand, ways: (
+            _f.probe_exists_fused(table, operand, ways=ways)
         ),
         gather_rows=_f.gather_rows_fused,
         to_wide=_f.unpack_table,
@@ -175,6 +179,7 @@ class RawKernels(NamedTuple):
     create: object  # (num_groups, ways) -> table
     decide: object  # (table, batch, now, ways) -> (table, DecideOutput)
     inject: object  # (table, items, now, ways) -> (table, ehi, elo)
+    probe_exists: object  # (table, batch, now, ways) -> bool[B]
     to_wide: object  # table -> SlotTable (traceable)
     from_wide: object  # SlotTable -> table (traceable)
     # The sync tick's compaction and selection (parallel/ici.py),
@@ -251,7 +256,7 @@ def get_paged_kernels(
 
 def get_raw_kernels(layout: str) -> RawKernels:
     if layout == "wide":
-        from gubernator_tpu.ops.decide import _decide_impl
+        from gubernator_tpu.ops.decide import _decide_impl, _probe_exists_impl
         from gubernator_tpu.ops.inject import _inject_impl
 
         return RawKernels(
@@ -259,6 +264,7 @@ def get_raw_kernels(layout: str) -> RawKernels:
             create=SlotTable.create,
             decide=lambda t, b, now, ways: _decide_impl(t, b, now, ways=ways),
             inject=lambda t, i, now, ways: _inject_impl(t, i, now, ways=ways),
+            probe_exists=_probe_exists_impl,
             to_wide=lambda t: t,
             from_wide=lambda t: t,
         )
@@ -274,6 +280,7 @@ def get_raw_kernels(layout: str) -> RawKernels:
             inject=lambda t, i, now, ways: _f._inject_fused_impl(
                 t, i, now, ways
             ),
+            probe_exists=_f._probe_exists_fused_impl,
             to_wide=_f.unpack_table,
             from_wide=_f.pack_table,
             take_groups=_f.take_groups,

@@ -165,6 +165,85 @@ OUT_STORE_ROWS = 8
 OUT_TOTALS = 4  # hits, misses, unexpired_evictions, over_limit
 
 
+# ---- the packed row: what gather_rows hands to the host ---------------------
+#
+# A slot's state as NCOLS int64 columns, the narrow fields sharing the
+# META word (lru_stamp_ms << 4 | status << 2 | algo << 1 | used). These
+# are the fused table's own columns (ops/fused.py) and the ONE array,
+# (NCOLS, B), that every kernel set's gather_rows returns: a wave's rows
+# cross to the host in one read, 80 B a lane. packed_cols / wide_rows
+# convert from and to the wide struct on either side of the boundary
+# (they use operators only: numpy arrays in, numpy arrays out).
+KHI, KLO, META, EXP, LIM, DUR, REM, STM, BUR, INV = range(10)
+NCOLS = 10
+META_USED = 1
+META_ALGO_SHIFT = 1
+META_STATUS_SHIFT = 2
+META_LRU_SHIFT = 4
+
+
+def pack_meta(used, algo, status, lru):
+    return (
+        (lru.astype(np.int64) << META_LRU_SHIFT)
+        | (status.astype(np.int64) & 3) << META_STATUS_SHIFT
+        | (algo.astype(np.int64) & 1) << META_ALGO_SHIFT
+        | used.astype(np.int64)
+    )
+
+
+def packed_cols(wide: SlotTable) -> list:
+    """The wide struct's NCOLS int64 columns, in column order."""
+    cols = [None] * NCOLS
+    cols[KHI] = wide.key_hi
+    cols[KLO] = wide.key_lo
+    cols[META] = pack_meta(wide.used, wide.algo, wide.status, wide.lru)
+    cols[EXP] = wide.expire_at
+    cols[LIM] = wide.limit
+    cols[DUR] = wide.duration
+    cols[REM] = wide.remaining
+    cols[STM] = wide.stamp
+    cols[BUR] = wide.burst
+    cols[INV] = wide.invalid_at
+    return cols
+
+
+def wide_rows(cols) -> SlotTable:
+    """NCOLS int64 columns (a list, or the rows of one (NCOLS, ...)
+    array, on the device or on the host) -> the wide struct. Of the one
+    np.ndarray that gather_rows' read gives, this is THE host view: the
+    int64 fields are views of it, the META fields small arrays."""
+    meta = cols[META]
+    return SlotTable(
+        key_hi=cols[KHI],
+        key_lo=cols[KLO],
+        used=(meta & META_USED) != 0,
+        algo=((meta >> META_ALGO_SHIFT) & 1).astype(np.int8),
+        status=((meta >> META_STATUS_SHIFT) & 3).astype(np.int8),
+        limit=cols[LIM],
+        duration=cols[DUR],
+        remaining=cols[REM],
+        stamp=cols[STM],
+        expire_at=cols[EXP],
+        invalid_at=cols[INV],
+        burst=cols[BUR],
+        lru=meta >> META_LRU_SHIFT,
+    )
+
+
+def gathered_rows(gather, slots, num_slots: int, from_output: bool):
+    """THE body of every layout's gather_rows, inside its jit: `slots`
+    is (B,) int64, or with `from_output` the vector a `with_store`
+    decide just produced, whose OUT_SLOT row is taken here so that the
+    slot column never leaves the device. `gather(safe)` gives the
+    (NCOLS, B) columns of in-range slots; a slot past the table (a
+    padding lane's) reads zeros. Returns the packed rows, (NCOLS, B)
+    int64."""
+    if from_output:
+        slots = slots[:-OUT_TOTALS].reshape(OUT_STORE_ROWS, -1)[OUT_SLOT]
+    rows = gather(jnp.clip(slots, 0, num_slots - 1))
+    return jnp.where((slots < num_slots)[None, :], rows, 0)
+
+
 class WaveOperand:
     """The host side of one wave (buf (OPERAND_ROWS, B)) or of W stacked
     waves (buf (W, OPERAND_ROWS, B)): the buffer that is uploaded, its

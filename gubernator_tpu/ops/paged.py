@@ -58,7 +58,12 @@ from gubernator_tpu.ops.kernels import (
     get_kernels,
     get_raw_kernels,
 )
-from gubernator_tpu.ops.layout import SlotTable, packed_waves
+from gubernator_tpu.ops.layout import (
+    SlotTable,
+    packed_waves,
+    unpack_operand,
+    wide_rows,
+)
 
 
 class PagedTable(NamedTuple):
@@ -110,8 +115,10 @@ class PagedKernels(NamedTuple):
     decide_packed: object
     decide_scan: object  # (pt, batches, nows, ways, with_store)
     inject: object  # (pt, items, now, ways) -> (pt, ehi, elo)
-    probe_exists: object  # (pt, hi, lo, group, now, ways) -> bool[B]
-    gather_rows: object  # (pt, PHYSICAL slots) -> SlotTable rows
+    probe_exists: object  # (pt, operand, ways) -> bool[B]
+    # (pt, PHYSICAL slots, from_output=False) -> packed rows (the paged
+    # decide's slot output is physical already)
+    gather_rows: object
     to_wide: object  # pt -> SlotTable view of the PHYSICAL table
     from_wide: object  # raises NotImplementedError
     bytes_per_slot: int
@@ -248,9 +255,10 @@ def make_paged_kernels(
         return PagedTable(data, pt.page_map), ehi, elo
 
     @jax.jit
-    def _probe_exists(pt, hi, lo, group, now):
-        g = _xlate(pt.page_map, group)
-        return base.probe_exists(pt.data, hi, lo, g, now, ways)
+    def _probe_exists(pt, operand):
+        batch, _home, now = unpack_operand(operand)
+        b = batch._replace(group=_xlate(pt.page_map, batch.group))
+        return raw.probe_exists(pt.data, b, now, ways)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def _bind_page(pt, lp, pp):
@@ -267,7 +275,7 @@ def make_paged_kernels(
     @jax.jit
     def _extract_page(pt, pp):
         slots = pp * page_slots + jnp.arange(page_slots, dtype=jnp.int64)
-        return base.gather_rows(pt.data, slots)
+        return wide_rows(base.gather_rows(pt.data, slots))
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def _write_page(pt, lp, pp, rows_wide):
@@ -300,10 +308,10 @@ def make_paged_kernels(
             _decide_scan(t, bs, ns)
         ),
         inject=lambda t, i, now, ways_=ways: _inject(t, i, now),
-        probe_exists=lambda t, hi, lo, g, now, ways_=ways: _probe_exists(
-            t, hi, lo, g, now
+        probe_exists=lambda t, operand, ways_=ways: _probe_exists(t, operand),
+        gather_rows=lambda t, slots, from_output=False: base.gather_rows(
+            t.data, slots, from_output
         ),
-        gather_rows=lambda t, slots: base.gather_rows(t.data, slots),
         to_wide=lambda t: base.to_wide(t.data),
         from_wide=_from_wide,
         bytes_per_slot=BYTES_PER_SLOT[layout],

@@ -29,7 +29,12 @@ from gubernator_tpu.ops.kernels import (
     get_kernels,
     get_raw_kernels,
 )
-from gubernator_tpu.ops.layout import SlotTable, packed_waves
+from gubernator_tpu.ops.layout import (
+    SlotTable,
+    packed_waves,
+    unpack_operand,
+    wide_rows,
+)
 from gubernator_tpu.utils import lockorder, transfer
 
 AXIS = "owners"
@@ -349,9 +354,10 @@ def _make_mesh_paged_kernels(
         return PagedTable(data, pt.page_map), ehi, elo
 
     @jax.jit
-    def _probe_exists(pt, hi, lo, group, now):
-        g = _xlate(pt.page_map, group)
-        return base.probe_exists(pt.data, hi, lo, g, now, ways)
+    def _probe_exists(pt, operand):
+        batch, _home, now = unpack_operand(operand)
+        b = batch._replace(group=_xlate(pt.page_map, batch.group))
+        return raw.probe_exists(pt.data, b, now, ways)
 
     # Page moves are the single-chip programs with output shardings
     # pinned: the physical table stays sharded along the slot axis and
@@ -376,7 +382,7 @@ def _make_mesh_paged_kernels(
     @functools.partial(jax.jit, out_shardings=repl)
     def _extract_page(pt, pp):
         slots = pp * page_slots + jnp.arange(page_slots, dtype=jnp.int64)
-        return base.gather_rows(pt.data, slots)
+        return wide_rows(base.gather_rows(pt.data, slots))
 
     @functools.partial(
         jax.jit, donate_argnums=(0,), out_shardings=pt_sharding
@@ -410,10 +416,10 @@ def _make_mesh_paged_kernels(
         ),
         decide_scan=_no_scan,
         inject=lambda t, i, now, ways_=ways: _inject(t, i, now),
-        probe_exists=lambda t, hi, lo, g, now, ways_=ways: _probe_exists(
-            t, hi, lo, g, now
+        probe_exists=lambda t, operand, ways_=ways: _probe_exists(t, operand),
+        gather_rows=lambda t, slots, from_output=False: base.gather_rows(
+            t.data, slots, from_output
         ),
-        gather_rows=lambda t, slots: base.gather_rows(t.data, slots),
         to_wide=lambda t: base.to_wide(t.data),
         from_wide=_from_wide,
         bytes_per_slot=BYTES_PER_SLOT[layout],

@@ -46,6 +46,7 @@ from gubernator_tpu.metrics import (
     STORE_WAVE_PROGRAMS,
     engine_histograms,
     engine_store_counters,
+    engine_store_wave_crossings,
     engine_wave_programs,
     engine_wave_transfers,
 )
@@ -65,12 +66,12 @@ from gubernator_tpu.ops.layout import (
     OUT_LIMIT,
     OUT_REMAINING,
     OUT_RESET_TIME,
-    OUT_SLOT,
     OUT_STATUS,
     OUT_TOTALS,
     SlotTable,
     WaveOperand,
     split_output,
+    wide_rows,
 )
 from gubernator_tpu.ops.kernels import (
     get_admission,
@@ -245,6 +246,10 @@ class EngineMetrics:
         self._wave_program = {
             p: self.wave_programs.labels(p) for p in STORE_WAVE_PROGRAMS
         }
+        self.store_wave_crossings = engine_store_wave_crossings()
+        self._store_crossing = tuple(
+            self.store_wave_crossings.labels(d) for d in ("h2d", "d2h")
+        )
         stores = engine_store_counters()
         for attr, c in stores.items():
             setattr(self, attr, c)
@@ -361,7 +366,8 @@ class EngineMetrics:
     def observe_flush(self, path: str, n: int, waves: int, dur: float,
                       dev: float, trace_id: str = "",
                       collective: bool = False, transfers=(0, 0),
-                      launches: int = 0, programs=None) -> None:
+                      launches: int = 0, programs=None,
+                      crossings=None) -> None:
         """One flush's distribution samples (per FLUSH, not per
         request). A non-empty trace_id attaches an OpenMetrics exemplar
         to the latency buckets this flush lands in, so a p99 spike in
@@ -375,7 +381,9 @@ class EngineMetrics:
         wide waves is one operand, one launch, one output), counted
         beside the waves themselves so a scrape sees all or none, as
         are `programs`, the launches of the Store's per-wave sequence
-        by STORE_WAVE_PROGRAMS name (None without a Store)."""
+        by STORE_WAVE_PROGRAMS name, and `crossings`, the [uploaded,
+        read] arrays that sequence moved under the engine lock (both
+        None without a Store)."""
         self.flush_duration.labels(path).observe(dur, trace_id)
         self.device_sync.labels(path).observe(dev, trace_id)
         self.batch_width.labels(path).observe(n)
@@ -386,6 +394,8 @@ class EngineMetrics:
         if programs is not None:
             for name, n in programs.items():
                 self._wave_program[name].inc(n)
+            for child, n in zip(self._store_crossing, crossings):
+                child.inc(n)
         if collective:
             self.collective_tick.observe(dev)
 
@@ -398,6 +408,7 @@ class FlushStages:
 
     __slots__ = (
         "em", "ids", "us", "_rows", "h2d", "d2h", "launches", "programs",
+        "crossings",
     )
 
     def __init__(self, em: EngineMetrics, flush: int, call: int):
@@ -412,6 +423,9 @@ class FlushStages:
         # with a Store: the launches of its per-wave sequence by
         # STORE_WAVE_PROGRAMS name (_execute_waves)
         self.programs: Optional[Dict[str, int]] = None
+        # and the arrays that sequence moved across the host-device
+        # boundary under the engine lock, [uploaded, read]
+        self.crossings: Optional[List[int]] = None
         # every key from the start: the record shares this dict, and a
         # /debug/engine dump may walk it while publish() fills it in
         self.us: Dict[str, int] = dict.fromkeys(FLUSH_STAGES, 0)
@@ -2210,27 +2224,17 @@ class MeshEngine(EngineBase):
         doesn't cold-compile under the serving lock. Called by
         attach_store — at daemon init, before traffic, so briefly holding
         the lock here is free."""
-        B = self.cfg.batch_size
         cfg = self.cfg
-        z64 = np.zeros(B, np.int64)
-        now = self.now_fn()
-        op = self._warm_operand(B, now)
+        op = self._warm_operand(cfg.batch_size, self.now_fn())
         with self._lock, self.topo.dispatch_guard(), _transfer.account(
             self.metrics, "d2h", "warmup"
         ) as tx:
+            # an empty wave through the serving sequence, shape for shape
+            tx.add(np.asarray(self.K.probe_exists(self.table, op, cfg.ways)))
             table, out = self.K.decide_packed(self.table, op, cfg.ways, True)
-            tx.add(np.asarray(out))
             self.table = table
-            tx.add(np.asarray(
-                self.K.probe_exists(
-                    table, z64, z64, np.zeros(B, np.int32), now, cfg.ways
-                )
-            ))
-            tx.add(np.asarray(
-                self.K.gather_rows(
-                    table, np.full(B, table.num_slots, np.int64)
-                ).used
-            ))
+            tx.add(np.asarray(self.K.gather_rows(table, out, True)))
+            tx.add(np.asarray(out))
 
     # ---- introspection -----------------------------------------------------
 
@@ -2523,19 +2527,20 @@ class MeshEngine(EngineBase):
                 grp_dev[:, None] * np.int64(W)
                 + np.arange(W, dtype=np.int64)[None, :]
             ).reshape(-1)
-            rows = self.K.gather_rows(self.table, slots)
+            packed = self.K.gather_rows(self.table, slots)
         # Bounded O(K x ways) readback at debug-poll cadence; the
         # census bucket thresholds mirror table_census semantics.
         n = len(hashes)
-
-        def mat(col):
-            return np.asarray(col).reshape(n, W)  # guberlint: allow-host-sync -- hotkeys census join: O(K x ways) rows at debug cadence, outside the serving lock
-
         with _transfer.account(self.metrics, "d2h", "census") as tx:
-            r_hi, r_lo = mat(rows.key_hi), mat(rows.key_lo)
-            r_used, r_lru = mat(rows.used), mat(rows.lru)
-            r_dur, r_exp = mat(rows.duration), mat(rows.expire_at)
-            tx.add((r_hi, r_lo, r_used, r_lru, r_dur, r_exp))
+            packed = np.asarray(packed)  # guberlint: allow-host-sync -- hotkeys census join: one read of O(K x ways) packed rows at debug cadence, outside the serving lock
+            tx.add(packed)
+        rows = wide_rows(packed)
+        r_hi, r_lo, r_used, r_lru, r_dur, r_exp = (
+            col.reshape(n, W) for col in (
+                rows.key_hi, rows.key_lo, rows.used, rows.lru,
+                rows.duration, rows.expire_at,
+            )
+        )
         now = self.now_fn()
         cold_k = self._census_thresholds[
             min(1, len(self._census_thresholds) - 1)
@@ -2819,6 +2824,7 @@ class MeshEngine(EngineBase):
                 "object", t.served, t.waves, dur, dev_s, trace_id,
                 collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
                 launches=fs.launches, programs=fs.programs,
+                crossings=fs.crossings,
             )
             em.observe_stage("assemble", t.t_dev - t.t0)
             # `dispatch` (the launches under the lock) and `lock_wait` were
@@ -3162,6 +3168,7 @@ class MeshEngine(EngineBase):
                 flush_trace_id if cfg.exemplars else "",
                 collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
                 launches=fs.launches, programs=fs.programs,
+                crossings=fs.crossings,
             )
             em.observe_stage("assemble", t_dev - t_start)
             em.observe_stage("device_sync", dev_s)
@@ -3415,6 +3422,7 @@ class MeshEngine(EngineBase):
         fs.launches += n_dispatch
         if store is not None and fs.programs is None:
             fs.programs = dict.fromkeys(STORE_WAVE_PROGRAMS, 0)
+            fs.crossings = [0, 0]
         self.metrics.busy_enter()
         live = tracing.open_live("flush.lock_wait", fs.ids)
         t_wait = time.perf_counter_ns()
@@ -3444,7 +3452,7 @@ class MeshEngine(EngineBase):
                     if store is not None:
                         with tracing.stage("flush.readthrough", fs, fs.ids):
                             table = self._wave_readthrough(
-                                table, wo.batch, lane_reqs[w], now,
+                                table, op, wo.batch, lane_reqs[w], now,
                                 prefetched, served, wave_rows_host, events,
                                 fs, req_resolver=req_resolver,
                             )
@@ -3452,10 +3460,17 @@ class MeshEngine(EngineBase):
                         table, op, cfg.ways, store is not None
                     )
                     if store is not None:
-                        # The store's sequence is synchronous per wave:
-                        # the wave's one read happens here, and its slot
-                        # column drives the row gather (a program of its
-                        # own, K.gather_rows).
+                        # The store's sequence is synchronous per wave.
+                        # The row gather (a program of its own,
+                        # K.gather_rows) takes its slot column from the
+                        # decide's output on the device, so the two are
+                        # launched back to back and only then read: the
+                        # wave's output vector and its packed rows, two
+                        # arrays, nothing uploaded. Both host copies are
+                        # started before the first blocking read, so the
+                        # second rides the first's wait (3,347 -> 3,925
+                        # decisions/s on store-1m.calls100, PERF.md §6
+                        # PR 37).
                         fs.programs["decide"] += 1
                         fs.programs["gather_rows"] += 1
                         with tracing.stage(
@@ -3463,14 +3478,16 @@ class MeshEngine(EngineBase):
                         ), _transfer.account(
                             self.metrics, "d2h", "serve"
                         ) as tx:
-                            out = np.asarray(out)  # guberlint: allow-host-sync -- store path: the wave's one read, synchronous by design
+                            rows = self.K.gather_rows(table, out, True)
+                            out.copy_to_host_async()
+                            rows.copy_to_host_async()
+                            out = np.asarray(out)  # guberlint: allow-host-sync -- store path: the wave's own output vector, one read, synchronous by design
+                            rows = np.asarray(rows)  # guberlint: allow-host-sync -- store path: the wave's packed rows, one read; a later wave's read-through and the write-behind need them on the host
                             fs.d2h += 1
-                            o_rows, _tot = split_output(out, True)
-                            rows = self.K.gather_rows(
-                                table, o_rows[OUT_SLOT]
-                            )
-                            rows_h = jax.tree.map(np.asarray, rows)
-                            tx.add((out, rows_h))
+                            fs.crossings[1] += 2
+                            tx.add((out, rows))
+                        rows_h = wide_rows(rows)
+                        o_rows, _tot = split_output(out, True)
                         ehi = o_rows[OUT_EVICTED_HI]
                         elo = o_rows[OUT_EVICTED_LO]
                         wave_rows_host.append(rows_h)
@@ -3526,6 +3543,7 @@ class MeshEngine(EngineBase):
     def _wave_readthrough(
         self,
         table,
+        op,
         wb,
         lane_req: Dict[int, tuple],
         now,
@@ -3537,8 +3555,11 @@ class MeshEngine(EngineBase):
         req_resolver=None,
     ):
         """Reference miss path at wave granularity: probe the table for
-        each lane's key; for actual misses, recover the freshest state and
-        inject it so the wave's decide continues the counter (reference
+        each lane's key (the probe reads `op`, the wave's operand that
+        _upload put on the device before the lock; its answer is the one
+        array read here: Store.get and the inject depend on it); for
+        actual misses, recover the freshest state and inject it so the
+        wave's decide continues the counter (reference
         algorithms.go:45-51). Freshness order:
 
         1. a row this SAME flush already decided (the key was displaced
@@ -3555,9 +3576,8 @@ class MeshEngine(EngineBase):
 
         cfg = self.cfg
         fs.programs["probe"] += 1
-        exists = np.asarray(
-            self.K.probe_exists(table, wb.key_hi, wb.key_lo, wb.group, now, cfg.ways)
-        )
+        exists = np.asarray(self.K.probe_exists(table, op, cfg.ways))  # guberlint: allow-host-sync -- store path: the probe's answer (a byte a lane), needed under the lock before Store.get and the inject
+        fs.crossings[1] += 1
         rows = []
         gets = hits = from_store = 0
         for lane, (req, hi, lo) in lane_req.items():
@@ -3613,8 +3633,10 @@ class MeshEngine(EngineBase):
         with _transfer.account(self.metrics, "h2d", "inject") as tx:
             table, ehi, elo = self.K.inject(table, ib, now, cfg.ways)
             tx.add(ib)
-        ehi = np.asarray(ehi)
-        elo = np.asarray(elo)
+        ehi = np.asarray(ehi)  # guberlint: allow-host-sync -- store path, a wave with a miss only: the keys the inject displaced
+        elo = np.asarray(elo)  # guberlint: allow-host-sync -- as ehi
+        fs.crossings[0] += len(ib)  # the struct operand, field by field
+        fs.crossings[1] += 2
         for j in np.nonzero((ehi != 0) | (elo != 0))[0]:
             events.append(("d", (int(ehi[j]), int(elo[j]))))
         for lane, snap, hi, lo in rows:

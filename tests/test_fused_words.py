@@ -23,7 +23,12 @@ from gubernator_tpu.ops.kernels import (
     get_raw_kernels,
     packed_decide,
 )
-from gubernator_tpu.ops.layout import OPERAND_ROWS, SlotTable
+from gubernator_tpu.ops.layout import (
+    OPERAND_ROWS,
+    OUT_STORE_ROWS,
+    OUT_TOTALS,
+    SlotTable,
+)
 from gubernator_tpu.runtime.engine import DeviceEngine, EngineConfig
 
 I64_MIN, I64_MAX = -(1 << 63), (1 << 63) - 1
@@ -206,16 +211,16 @@ def inject_case(n):
 
 def probe_case(n):
     table = F.FusedTable.create(n // WAYS, WAYS)
-    z = jnp.zeros((B,), dtype=jnp.int64)
-    return (
-        lambda t: F.probe_exists_fused(t, z, z, z.astype(jnp.int32), 0, ways=WAYS),
-        (table,),
-    )
+    operand = jnp.zeros((OPERAND_ROWS, B), dtype=jnp.int64)
+    return lambda t, o: F.probe_exists_fused(t, o, ways=WAYS), (table, operand)
 
 
 def rows_case(n):
     table = F.FusedTable.create(n // WAYS, WAYS)
-    return lambda t: F.gather_rows_fused(t, jnp.arange(B)), (table,)
+    # the Store sequence's variant: the slot column read out of a
+    # `with_store` output vector, one packed (NCOLS, B) array back
+    out = jnp.zeros((OUT_STORE_ROWS * B + OUT_TOTALS,), dtype=jnp.int64)
+    return lambda t, o: F.gather_rows_fused(t, o, True), (table, out)
 
 
 NDEV = 4

@@ -451,6 +451,7 @@ def test_paged_nonresident_probe_safe(layout, ways, gpp):
     """A probe/decide against a demoted page must not corrupt resident
     state: gathers clamp (no spurious match), scatters drop."""
     from gubernator_tpu.ops.kernels import get_paged_kernels
+    from gubernator_tpu.ops.layout import WaveOperand
 
     PK = get_paged_kernels(layout, NUM_GROUPS, ways, gpp, 2)
     pt = PK.create()
@@ -483,12 +484,7 @@ def test_paged_nonresident_probe_safe(layout, ways, gpp):
     # Hammer the demoted page: decide + probe must be inert.
     pt, out = PK.decide(pt, db, NOW + 1, ways, False)
     exists = PK.probe_exists(
-        pt,
-        jnp.asarray(db.key_hi),
-        jnp.asarray(db.key_lo),
-        jnp.asarray(db.group),
-        NOW + 2,
-        ways,
+        pt, jnp.asarray(WaveOperand.of(db, NOW + 2).buf), ways
     )
     assert not bool(np.asarray(exists)[0])
     after = np.asarray(PK.to_wide(pt).remaining)

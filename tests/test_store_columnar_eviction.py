@@ -212,3 +212,155 @@ def test_store_counters_add_up(run):
     # where a wave had a row to seat
     assert c["decide"] == c["probe"] == c["gather_rows"] == run["waves"]
     assert 0 < c["inject"] <= run["waves"]
+
+
+# ---------------------------------------------------------------------------
+# What a wave moves across the host-device boundary under the engine
+# lock (ISSUE 37): gubernator_engine_store_wave_crossings.
+
+
+def crossings(em) -> dict:
+    c = counter(em.store_wave_crossings)
+    return {d: c[f'direction="{d}"'] for d in ("h2d", "d2h")}
+
+
+def small_engine(ways=8, num_groups=64):
+    clock = {"now": NOW}
+    eng = DeviceEngine(
+        EngineConfig(num_groups=num_groups, ways=ways, batch_size=64,
+                     batch_wait_s=0.001),
+        now_fn=lambda: clock["now"],
+    )
+    store = CountingStore()
+    attach_store(eng, store)
+    return eng, store
+
+
+def req(key, **kw):
+    kw.setdefault("hits", 1)
+    return RateLimitReq(name="ev", unique_key=key, limit=20,
+                        duration=3_600_000, **kw)
+
+
+def test_a_wave_without_a_miss_uploads_nothing_and_reads_three(monkeypatch):
+    """(d) a columnar call of four waves, no key with anything to read
+    through: under the engine lock nothing goes to the device (the
+    probe reads the operand _upload left there, the row gather the
+    decide's own output), which jax's transfer guard enforces on the
+    launches themselves, and three arrays a wave come back: the probe's
+    answer, the output vector, the packed rows."""
+    import jax
+
+    from gubernator_tpu.runtime.engine import MeshEngine
+
+    eng, store = small_engine()
+    real = MeshEngine._execute_waves
+
+    def under_guard(self, *a, **kw):
+        with jax.transfer_guard_host_to_device("disallow"):
+            return real(self, *a, **kw)
+
+    monkeypatch.setattr(MeshEngine, "_execute_waves", under_guard)
+    try:
+        for n, reqs in enumerate((
+            [req("a"), req("b"), req("dup"), req("dup"), req("dup"),
+             req("dup")],               # never seen: prefetched, all absent
+            [req("dup"), req("dup"), req("a"), req("dup"), req("dup")],
+        )):                             # resident: every probe finds them
+            before = {**store_counts(eng.metrics), **crossings(eng.metrics)}
+            waves0 = eng.metrics.waves
+            got = eng.check_columns(
+                wire.parse_requests(to_proto_bytes(reqs)), now=NOW + n
+            )
+            assert got is not None
+            after = {**store_counts(eng.metrics), **crossings(eng.metrics)}
+            d = {k: after[k] - before[k] for k in after}
+            waves = eng.metrics.waves - waves0
+            assert waves == 4
+            assert d["probe"] == d["decide"] == d["gather_rows"] == waves
+            assert d["inject"] == 0
+            assert d["h2d"] == 0
+            assert d["d2h"] == 3 * waves
+        assert store.data["ev_dup"].remaining == 20 - 8
+        assert eng.metrics.cold_compiles == 0
+    finally:
+        eng.close()
+
+
+def test_a_wave_with_an_inject_counts_its_struct_and_its_answer():
+    """The one upload left under the lock is the inject's 13-field
+    operand, and it reads two key columns more: a wave with a miss the
+    Store answered makes 13 + 5 crossings."""
+    eng, store = small_engine()
+    try:
+        first = eng.check_columns(
+            wire.parse_requests(to_proto_bytes([req("a", hits=3)])), now=NOW
+        )
+        assert first[2].tolist() == [17]
+        # forget the row behind the engine's back, keep the string: the
+        # probe misses and Store.get under the lock answers
+        with eng._lock:
+            eng.table = eng.K.create(eng.cfg.num_groups, eng.cfg.ways)
+        before = crossings(eng.metrics)
+        got = eng.check_columns(
+            wire.parse_requests(to_proto_bytes([req("a")])), now=NOW + 1
+        )
+        assert got[2].tolist() == [16]  # continued from the Store's row
+        after = crossings(eng.metrics)
+        assert after["h2d"] - before["h2d"] == 13
+        assert after["d2h"] - before["d2h"] == 3 + 2
+    finally:
+        eng.close()
+
+
+def same_group_pair(num_groups):
+    from gubernator_tpu.api.keys import group_of, key_hash128
+
+    seen = {}
+    for i in range(10_000):
+        k = f"g{i}"
+        g = group_of(key_hash128("ev_" + k)[1], num_groups)
+        if g in seen:
+            return seen[g], k
+        seen[g] = k
+    raise AssertionError("no two keys share a group")
+
+
+@pytest.mark.parametrize("path", ["columnar", "object"])
+@pytest.mark.parametrize("reset", [False, True], ids=["displaced", "freed"])
+def test_a_key_displaced_between_its_own_waves(path, reset):
+    """(e) one way a group, two keys of one group, one flush [A, B, A]:
+    B's wave displaces A, and A's second wave must continue from the row
+    A's first wave left, which only the flush's own gathered rows hold
+    (the Store still has A's state from before the flush: five hits).
+    Where A's first item is a RESET_REMAINING its row was freed, and the
+    second starts a fresh bucket: the stale Store row is not read."""
+    ka, kb = same_group_pair(16)
+    eng, store = small_engine(ways=1, num_groups=16)
+    try:
+        eng.check_batch([req(ka, hits=5)])
+        assert store.data[f"ev_{ka}"].remaining == 15
+        first = req(ka, behavior=int(Behavior.RESET_REMAINING)) if reset \
+            else req(ka)
+        reqs = [first, req(kb), req(ka)]
+        programs0 = store_counts(eng.metrics)
+        if path == "columnar":
+            got = eng.check_columns(
+                wire.parse_requests(to_proto_bytes(reqs)), now=NOW + 5
+            )
+            remaining = got[2].tolist()
+        else:
+            remaining = [r.remaining for r in eng.check_batch(reqs)]
+        # RESET answers a full bucket and frees the row; then 20 - 1
+        assert remaining == ([20, 19, 19] if reset else [14, 19, 13])
+        d = store_counts(eng.metrics)
+        assert d["decide"] - programs0["decide"] == 3  # a wave an item
+        # A's second wave re-seats its own earlier row (displaced), or
+        # finds nothing to seat (freed): the Store is not asked again
+        assert d["inject"] - programs0["inject"] == (0 if reset else 1)
+        # (only B, never seen, is looked up, and is not there)
+        assert d["hit"] == programs0["hit"]
+        assert store.data[f"ev_{ka}"].remaining == (19 if reset else 13)
+        assert store.data[f"ev_{kb}"].remaining == 19
+    finally:
+        eng.close()
