@@ -840,6 +840,18 @@ def engine_histograms() -> dict:
             "it found active (the whole table on a full tick).",
             scale=cnt, n_buckets=26,
         ),
+        "ici_tick_stage_duration": Log2Histogram(
+            "gubernator_ici_tick_stage_duration",
+            "Wall seconds of one ICI GLOBAL sync tick by stage: "
+            "lock_wait (asking for the engine lock and the collective "
+            "guard until both are held), launch (under them, until the "
+            "sync program's launch returns), read (the blocking read "
+            "of the tick's diagnostics: the wait for the device). One "
+            "observation of each a tick; they add up to "
+            "gubernator_ici_tick_duration less its first and last "
+            "lines.",
+            scale=us, n_buckets=24, labelnames=("stage",),
+        ),
         "stage_duration": Log2Histogram(
             "gubernator_engine_stage_duration",
             "Per-stage request-lifecycle latency in seconds, by stage: "
@@ -1208,6 +1220,45 @@ class Metrics:
             ["method"],
             registry=r,
         )
+        # The serving event loop and the interpreter lock, probed at
+        # 100 Hz while the daemon serves (utils/tracing.py
+        # HostProbes), and the CPU one call in sixteen used on its
+        # executor thread (service/fastpath.py).
+        self.loop_lag = Log2Histogram(
+            "gubernator_loop_lag_seconds",
+            "How late the serving event loop ran a 10 ms timer: every "
+            "hop a call makes through the loop (gRPC's completion "
+            "events, run_in_executor's return, the response's send) "
+            "waits about this long.",
+            scale=1e-6, n_buckets=24,
+        )
+        self.register_renderable(self.loop_lag)
+        self.interpreter_wait = Log2Histogram(
+            "gubernator_interpreter_wait_seconds",
+            "How far a thread overslept a 10 ms sleep that releases "
+            "the interpreter lock: the timer's slack plus the wait to "
+            "get the lock back, which every thread pays after each "
+            "blocking read, lock acquire and upload.",
+            scale=1e-6, n_buckets=24,
+        )
+        self.register_renderable(self.interpreter_wait)
+        self.call_cpu = Log2Histogram(
+            "gubernator_call_cpu_seconds",
+            "CPU seconds a call's executor thread used inside "
+            "try_serve, for one call in sixteen (by its sequence "
+            "number), by the path that served it.",
+            scale=1e-6, n_buckets=24, labelnames=("path",),
+        )
+        self.register_renderable(self.call_cpu)
+        self.call_cpu_wall = Log2Histogram(
+            "gubernator_call_cpu_wall_seconds",
+            "Wall seconds of the same interval of the same calls as "
+            "gubernator_call_cpu_seconds: wall less CPU less the waits "
+            "that have stages of their own is the call's queueing for "
+            "the interpreter lock.",
+            scale=1e-6, n_buckets=24, labelnames=("path",),
+        )
+        self.register_renderable(self.call_cpu_wall)
         # One timeline per call (docs/monitoring.md "Tracing the
         # pipeline"): the stages of a GetRateLimits / GetPeerRateLimits
         # handler, observed at its exit under the path that served it.
@@ -1236,6 +1287,12 @@ class Metrics:
         for path, reasons in EDGE_REASONS.items():
             for reason in reasons:
                 self.edge_calls.labels(path, reason).inc(0)
+        # path -> (CPU child, wall child), exposed at 0 like the stages
+        self.call_cpu_children = {
+            path: (self.call_cpu.declare(path),
+                   self.call_cpu_wall.declare(path))
+            for path in CALL_STAGES
+        }
         self.engine_busy_seconds = counter(
             "gubernator_engine_busy_seconds",
             "Seconds in which at least one flush was between asking "

@@ -641,6 +641,10 @@ class EngineBase:
         # pump-thread-only writer, read by the completion stage for the
         # host/device overlap ratio.
         self._host_busy = 0.0
+        # Pump-thread only: when the oldest entry of the batch being
+        # flushed was enqueued (_pump sets it, _dispatch shows the wait
+        # in a capture).
+        self._queue_since = None
         # Liveness (runtime/watchdog.py): the daemon injects its
         # Watchdog after construction; until then beats are no-ops.
         # The pump and completion threads are SERVING loops — their
@@ -767,10 +771,15 @@ class EngineBase:
             wd = self.watchdog
             if wd is not None:
                 wd.beat("engine-complete", serving=True)
+            # In a capture the wait for a ticket is a span of its own:
+            # while it is open nothing is in flight.
+            live = tracing.open_live("complete.idle", {}, otel=False)
             try:
                 t = self._pipe_q.get(timeout=0.5)
             except queue.Empty:
                 continue
+            finally:
+                tracing.next_live(live)
             if t is _STOP:
                 return
             try:
@@ -1314,6 +1323,9 @@ class EngineBase:
                 break
             batch: List[Tuple[RateLimitReq, object]] = list(carry)
             carry = []
+            # The enqueue of this batch's oldest entry: `flush.queue`
+            # in a capture runs from there to the flush's start.
+            self._queue_since = None
 
             def _extend(entry) -> bool:
                 """Add a queue entry (single triple or bulk); True if it
@@ -1329,6 +1341,8 @@ class EngineBase:
                 if type(entry) is _Bulk:
                     w = time.perf_counter() - entry.t_enq
                     qw.observe(w)
+                    if self._queue_since is None:
+                        self._queue_since = entry.t_enq
                     live = entry.work
                     if ov is not None:
                         ov.observe_wait(w)
@@ -1353,6 +1367,8 @@ class EngineBase:
                 req, fut, t_enq = entry
                 w = time.perf_counter() - t_enq
                 qw.observe(w)
+                if self._queue_since is None:
+                    self._queue_since = t_enq
                 if ov is not None:
                     ov.observe_wait(w)
                     dl = getattr(fut, "deadline_ms", None)
@@ -2582,6 +2598,13 @@ class MeshEngine(EngineBase):
         fs = FlushStages(self.metrics, seq, next(
             (c for c in (getattr(f, "call", 0) for _, f in items) if c), 0
         ))
+        # The pump's queue, from the enqueue of the batch's oldest
+        # entry to here: a mark at its end, carrying its length.
+        since, self._queue_since = self._queue_since, None
+        if since is not None and tracing.capturing():
+            tracing.rpc_mark("flush.queue", dict(
+                fs.ids, wait_us=int((time.perf_counter() - since) * 1e6)
+            ))
 
         # One native batch-hash call for the whole flush (assembler hot
         # loop; gubernator_tpu.native), then one-shot tolist conversions

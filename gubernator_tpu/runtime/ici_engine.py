@@ -167,6 +167,11 @@ class IciEngine(MeshEngine):
 
         super().__init__(cfg, now_fn, topology=IciMeshTopology(devices))
 
+        # A tick in three parts, exposed at 0 from the start (sync_now).
+        self._tick_stage = tuple(
+            self.metrics.ici_tick_stage_duration.declare(s)
+            for s in ("lock_wait", "launch", "read")
+        )
         self._stop_sync = threading.Event()
         self._sync_thread = threading.Thread(
             target=self._sync_loop, daemon=True, name="ici-sync"
@@ -203,40 +208,62 @@ class IciEngine(MeshEngine):
         now = self.now_fn()
         t0 = time.perf_counter()
         rt = self._rtier
-        with self._lock, self.topo.dispatch_guard():
-            # The tick is warmed in _warmup and must stay compile-free on
-            # the 100ms cadence — a cold tick stalls GLOBAL convergence,
-            # so it counts against the cold-compile invariant too.
-            with _telemetry.serving_scope(self.metrics), tracing.span(
-                "ici.sync_tick", level="DEBUG"
-            ) as tick_span:
-                sync = rt.sync
-                if rt.sync_full is not None:
-                    self._capped_ticks += 1
-                    if self._capped_ticks >= self.cfg.full_tick_every:
-                        # Collision backstop: merge the FULL table this
-                        # tick, healing any group a fingerprint collision
-                        # hid from the capped selector.
-                        self._capped_ticks = 0
-                        self.full_ticks += 1
-                        sync = rt.sync_full
-                rt.state, diag = sync(rt.state, now)
-                with _transfer.account(self.metrics, "d2h", "census") as tx:
-                    d = np.asarray(diag)
-                    tx.add(d)
-            # kept/dropped cover groups merged THIS tick; under a capped
-            # backlog, retained keys in unmerged groups surface when
-            # their group's turn comes. The backlog gauge (identical on
-            # every device; diag rows replicate it) is the overload
-            # signal.
-            self.overflow_keys = int(d[:, 0].sum())
-            self.overflow_drops += int(d[:, 1].sum())
-            self.sync_backlog = int(d[:, 2].max())
+        # The tick in three parts, each a histogram child and, in a
+        # capture, a span on this thread's line: the wait for the engine
+        # lock and the collective guard, the launch under them, the
+        # read (clock marks here, as under the lock in _execute_waves).
+        t_ask = time.perf_counter_ns()
+        live = tracing.open_live("tick.lock_wait", {}, otel=False)
+        try:
+            with self._lock, self.topo.dispatch_guard():
+                t_in = time.perf_counter_ns()
+                live = tracing.next_live(live, "tick.launch", otel=False)
+                # The tick is warmed in _warmup and must stay
+                # compile-free on the 100ms cadence — a cold tick stalls
+                # GLOBAL convergence, so it counts against the
+                # cold-compile invariant too.
+                with _telemetry.serving_scope(self.metrics), tracing.span(
+                    "ici.sync_tick", level="DEBUG"
+                ) as tick_span:
+                    sync = rt.sync
+                    if rt.sync_full is not None:
+                        self._capped_ticks += 1
+                        if self._capped_ticks >= self.cfg.full_tick_every:
+                            # Collision backstop: merge the FULL table
+                            # this tick, healing any group a fingerprint
+                            # collision hid from the capped selector.
+                            self._capped_ticks = 0
+                            self.full_ticks += 1
+                            sync = rt.sync_full
+                    rt.state, diag = sync(rt.state, now)
+                    t_launched = time.perf_counter_ns()
+                    live = tracing.next_live(live, "tick.read", otel=False)
+                    with _transfer.account(
+                        self.metrics, "d2h", "census"
+                    ) as tx:
+                        d = np.asarray(diag)
+                        tx.add(d)
+                    t_read = time.perf_counter_ns()
+                    live = tracing.next_live(live)
+                # kept/dropped cover groups merged THIS tick; under a
+                # capped backlog, retained keys in unmerged groups
+                # surface when their group's turn comes. The backlog
+                # gauge (identical on every device; diag rows replicate
+                # it) is the overload signal.
+                self.overflow_keys = int(d[:, 0].sum())
+                self.overflow_drops += int(d[:, 1].sum())
+                self.sync_backlog = int(d[:, 2].max())
+        finally:
+            tracing.next_live(live)
         dur = time.perf_counter() - t0
         groups = int(d[:, 3].max())
         width = int(d[:, 4].max())
         em = self.metrics
         em.ici_tick_duration.observe(dur)
+        for child, ns in zip(self._tick_stage, (
+            t_in - t_ask, t_launched - t_in, t_read - t_launched
+        )):
+            child.observe(ns * 1e-9)
         em.ici_tick_groups.observe(groups)
         em.ici_tick_width.observe(width)
         em.recorder.record(

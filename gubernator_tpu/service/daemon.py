@@ -25,7 +25,7 @@ from gubernator_tpu.service.config import DaemonConfig
 from gubernator_tpu.service.gateway import build_app
 from gubernator_tpu.service.grpc_service import PeersV1Servicer, V1Servicer
 from gubernator_tpu.service.server import V1Service
-from gubernator_tpu.utils import net
+from gubernator_tpu.utils import net, tracing
 
 log = logging.getLogger("gubernator.daemon")
 
@@ -190,6 +190,13 @@ class Daemon:
             port = self.grpc_server.add_insecure_port(conf.grpc_listen_address)
         self.grpc_address = f"{host}:{port}"
         await self.grpc_server.start()
+        # The serving loop's lateness and the interpreter lock's wait,
+        # probed from here to the drain (docs/monitoring.md "Tracing
+        # the pipeline").
+        self._host_probes = tracing.HostProbes(
+            metrics.loop_lag, metrics.interpreter_wait
+        )
+        self._host_probes.start(asyncio.get_running_loop())
 
         # Local identity must be known before peers are set
         advertise = conf.advertise_address or self.grpc_address
@@ -530,6 +537,8 @@ class Daemon:
             await self._auditor.close()
         if getattr(self, "_profiler", None) is not None:
             self._profiler.stop()
+        if getattr(self, "_host_probes", None) is not None:
+            self._host_probes.stop()
         # Ladder before the SLO sampler it reads, then sampler +
         # watchdog before the loops they observe: a loop stopping
         # during drain must not be flagged as a stall. The engine keeps
@@ -599,6 +608,10 @@ class Daemon:
             await self.http_runner.cleanup()
         if getattr(self, "status_runner", None) is not None:
             await self.status_runner.cleanup()
+        if getattr(self, "_host_probes", None) is not None:
+            # Stopped at the drain's start: its thread ended long ago.
+            self._host_probes.join()
+            self._host_probes = None
         self.state = "stopped"
 
     # -- peers ---------------------------------------------------------------

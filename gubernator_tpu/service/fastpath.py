@@ -30,6 +30,7 @@ for actual miss lanes.
 
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 import numpy as np
@@ -142,6 +143,14 @@ def try_serve(svc, data: bytes, peer_call: bool, call=tracing.NO_CALL):
     carrying trace metadata keep the object path.
     """
     call.mark("executor_wait")
+    # One call in sixteen, by its sequence number, reads this thread's
+    # CPU clock at both ends (a 7 us system call each on the TPU
+    # host): CPU against wall is the call's own queueing for the
+    # interpreter lock (docs/monitoring.md "Tracing the pipeline").
+    sampled = call.seq & 15 == 0 and call.seq > 0
+    if sampled:
+        wall0 = time.perf_counter_ns()
+        cpu0 = time.thread_time_ns()
     # Until _try_serve says which path serves the call: it raised.
     call.served("object", "error")
     phases = _Phases(call)
@@ -157,6 +166,12 @@ def try_serve(svc, data: bytes, peer_call: bool, call=tracing.NO_CALL):
         phases.close()
         if ctx is not None:
             ctx.__exit__(None, None, None)
+        if sampled:
+            cpu_ns = time.thread_time_ns() - cpu0
+            wall_ns = time.perf_counter_ns() - wall0
+            cpu, wall = svc.metrics.call_cpu_children[call.kind + call.path]
+            cpu.observe(cpu_ns * 1e-9)
+            wall.observe(wall_ns * 1e-9)
 
 
 def _try_serve(svc, data: bytes, peer_call: bool, call, phases):

@@ -171,6 +171,9 @@ class V1Service:
         self, reqs: Sequence[RateLimitReq], call
     ) -> List[RateLimitResp]:
         m = self.metrics
+        # In a capture, `route` as far as its first await: the routing
+        # loop and the submissions to the engine.
+        call.open("route")
         now = self.now_fn()
         n = len(reqs)
         responses: List[Optional[RateLimitResp]] = [None] * n
@@ -308,6 +311,8 @@ class V1Service:
                 for i, _ in local_items:
                     responses[i] = RateLimitResp(error=str(e))
 
+        if forward_tasks:
+            call.mark("route")  # closes the capture's span before an await
         for i, task in forward_tasks:
             try:
                 resp = await task
@@ -504,6 +509,7 @@ class V1Service:
             )
         from gubernator_tpu.utils import tracing
 
+        call.open("route")  # in a capture: as far as the first await
         has_global = False
         for req in reqs:
             # Extract the forwarding peer's trace context from the item's

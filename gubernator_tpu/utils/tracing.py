@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import contextlib
 import itertools
+import threading
 import time
 from typing import Dict, Optional
 
@@ -280,16 +281,20 @@ def _debug_spans() -> bool:
     return _OTEL and _LEVEL >= 2
 
 
-def open_live(name: str, attrs: dict):
+def open_live(name: str, attrs: dict, otel: bool = True):
     """Open the two readers of a stage that are there only sometimes:
     the capture's span and the SDK's DEBUG span. None, the usual case,
-    when neither is on; else what close_live() takes."""
-    if not _capturing and not _debug_spans():
+    when neither is on; else what close_live() takes. `otel` False
+    leaves the SDK out: a wait that no call owns (the sync tick's, the
+    completion thread's) would be a root of its own there, and a
+    CallRecord mark makes its stage's SDK span itself."""
+    debug = otel and _debug_spans()
+    if not _capturing and not debug:
         return None
     live = []
     if _capturing:
         live.append(_annotation(name, attrs))
-    if _debug_spans():
+    if debug:
         live.append(span(name, level="DEBUG", **attrs))
     for cm in live:
         cm.__enter__()
@@ -299,6 +304,15 @@ def open_live(name: str, attrs: dict):
 def close_live(live, *exc) -> None:
     for cm in reversed(live):
         cm.__exit__(*(exc or (None, None, None)))
+
+
+def next_live(live, name: str = "", otel: bool = True):
+    """For a site that takes its own clock marks between spans that
+    follow each other on its thread: close `live` (None: nothing is
+    open) and open `name`, or nothing."""
+    if live is not None:
+        close_live(live)
+    return open_live(name, {}, otel) if name else None
 
 
 class stage:
@@ -356,7 +370,7 @@ class CallRecord:
 
     __slots__ = (
         "seq", "ids", "kind", "path", "reason", "otel_ctx", "t0", "cursor",
-        "_sink", "_stages", "_last",
+        "_sink", "_stages", "_last", "_live",
     )
 
     def __init__(self, sink, kind: str = ""):
@@ -372,6 +386,7 @@ class CallRecord:
         self._sink = sink
         self._stages: Dict[str, int] = {}
         self._last = ""
+        self._live = None
 
     def begin(self, t_ns: int) -> None:
         self.t0 = self.cursor = t_ns
@@ -395,11 +410,23 @@ class CallRecord:
         self.cursor = t1_ns
         self._last = label
 
+    def open(self, label: str) -> None:
+        """Show the part of stage `label` that starts here in a
+        capture: a span the next mark() closes, so the caller marks
+        before its next `await` (coroutines interleave on the loop's
+        thread, where the profiler nests spans)."""
+        if _capturing:
+            self._live = open_live(f"call.{label}", self.ids, otel=False)
+
     def mark(self, label: str) -> None:
         """Close a stage that is a wait between threads or spans an
         `await`: histogram and OTel span, no profiler span (the gap
-        between two spans with this call's id is the wait)."""
+        between two spans with this call's id is the wait) unless
+        open() began one."""
         t1 = time.perf_counter_ns()
+        if self._live is not None:
+            close_live(self._live)
+            self._live = None
         if _debug_spans():
             _interval_span(
                 f"call.{label}", self.cursor, t1, self.otel_ctx, self.ids
@@ -438,6 +465,9 @@ class _NoCall:
     def add(self, label, t0_ns, t1_ns) -> None:
         pass
 
+    def open(self, label) -> None:
+        pass
+
     def mark(self, label) -> None:
         pass
 
@@ -455,7 +485,75 @@ def rpc_mark(name: str, ids: dict) -> None:
     """`rpc.begin` / `rpc.end`: the root of a call in a capture. The
     handler is a coroutine, and coroutines interleave on the loop's
     thread where the profiler nests spans, so the root is two short
-    marks carrying the call's id rather than one span."""
+    marks carrying the call's id rather than one span. A wait that is
+    over when it is known (`loop.lag`, `interp.wait`, `flush.queue`)
+    is such a mark too, at its end and carrying its length in us."""
     if _capturing:
         with _annotation(name, ids):
             pass
+
+
+class HostProbes:
+    """The two instruments of what no call's own thread times, at 100
+    Hz while a daemon serves (docs/monitoring.md "Tracing the
+    pipeline"). `loop.lag`: a timer on the serving event loop that
+    re-arms itself and observes how late it ran. `interp.wait`: a
+    thread that sleeps with the interpreter lock released and observes
+    how far it overslept, which is the timer's slack plus the wait to
+    get the lock back. Both land in a /metrics histogram always and,
+    while a capture runs, as a mark carrying the wait in us."""
+
+    PERIOD_S = 0.010
+
+    def __init__(self, loop_lag, interpreter_wait):
+        self._loop_lag = loop_lag
+        self._interp_wait = interpreter_wait
+        self._loop = None
+        self._timer = None
+        self._due = 0.0
+        self._thread = None
+        self._running = False
+
+    def start(self, loop) -> None:
+        """Called on `loop`'s own thread."""
+        self._loop = loop
+        self._running = True
+        self._arm()
+        self._thread = threading.Thread(
+            target=self._sleeper, daemon=True, name="interp-probe"
+        )
+        self._thread.start()
+
+    def stop(self) -> None:
+        """Called on the loop's thread, so it waits for nothing: the
+        timer is cancelled and the sleeper ends by itself within one
+        period (join() waits for that)."""
+        self._running = False
+        if self._timer is not None:
+            self._timer.cancel()
+            self._timer = None
+
+    def join(self) -> None:
+        if self._thread is not None:
+            self._thread.join(timeout=1.0)
+            self._thread = None
+
+    def _arm(self) -> None:
+        self._due = time.perf_counter() + self.PERIOD_S
+        self._timer = self._loop.call_later(self.PERIOD_S, self._fired)
+
+    def _fired(self) -> None:
+        lag = max(time.perf_counter() - self._due, 0.0)
+        self._loop_lag.observe(lag)
+        rpc_mark("loop.lag", {"lag_us": int(lag * 1e6)})
+        if self._running:
+            self._arm()
+
+    def _sleeper(self) -> None:
+        period = self.PERIOD_S
+        while self._running:
+            due = time.perf_counter() + period
+            time.sleep(period)
+            wait = max(time.perf_counter() - due, 0.0)
+            self._interp_wait.observe(wait)
+            rpc_mark("interp.wait", {"wait_us": int(wait * 1e6)})

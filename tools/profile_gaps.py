@@ -6,23 +6,31 @@
 A capture holds the device planes and, in plane /host:CPU on the same
 clock, the program's own spans (docs/monitoring.md "Tracing the
 pipeline"): `rpc.begin` / `rpc.end` marks, `call.*` and `flush.*`, each
-carrying the `call` and `flush` ids. Seventeen threads have spans open
-at once, so "which host span covers the gap" has no single answer. The
-rule here:
+carrying the `call` and `flush` ids, and what no call owns: `tick.*`,
+`complete.idle`, `loop.lag`, `interp.wait`. Seventeen threads have
+spans open at once, so "which host span covers the gap" has no single
+answer. The rule here:
 
 - a gap on a device plane ends when a device program starts;
 - that program was launched by the flush whose `flush.dispatch` span
   last began before it;
 - the gap is divided along that flush's own call: the parts of the gap
   its call spent in `executor_wait` (from `rpc.begin` to the call's
-  first span on another thread), `parse`, `hash`, `waves`, `keydict`,
-  `lock_wait` and `dispatch`, and, before `rpc.begin`, "call not yet in
-  the server";
+  first span on another thread), `parse`, `route`, `queue` (the pump's
+  queue: the `flush.queue` mark, which carries its length), `hash`,
+  `waves`, `keydict`, `lock_wait` and `dispatch`, and, before
+  `rpc.begin`, "call not yet in the server";
 - what none of these covers goes to another flush's `readback` or
   `post` where one was open (a pipelined pump waits for the flush before
-  last to be read before it launches the next), and is "unattributed"
-  otherwise (the runtime's own queue after the launch, a wait no span
-  times, a gap that ends with the capture).
+  last to be read before it launches the next);
+- then to what no call owns, whichever gap it is: the sync tick's
+  `tick.launch`, `tick.read` and `tick.lock_wait` (it holds, or waits
+  for, the engine lock the flushes need), `interp.wait` and `loop.lag`
+  (marks at the end of a wait for the interpreter lock or of the
+  serving loop's lateness, each as long as it says), `complete.idle`
+  (the completion thread had no ticket: nothing was in flight);
+- and is "unattributed" otherwise (the runtime's own queue after the
+  launch, a wait no span times, a gap that ends with the capture).
 
 Prints seconds per name and per device plane; the names add up to the
 plane's idle time. Reads the file with jax.profiler.ProfileData on the
@@ -44,10 +52,23 @@ OTHER = {"flush.readback": "another flush: readback",
          "flush.post": "another flush: post"}
 # Innermost first: where two of a call's intervals overlap (a pump
 # flush beside its call's own thread) the gap goes to the earlier name.
-STAGES = ("dispatch", "lock_wait", "keydict", "waves", "hash", "parse",
-          "executor_wait")
-ORDER = (NOT_YET, "executor_wait", "parse", "hash", "waves", "keydict",
-         "lock_wait", "dispatch", *OTHER.values(), UNATTRIBUTED)
+STAGES = ("dispatch", "lock_wait", "keydict", "waves", "hash", "queue",
+          "route", "parse", "executor_wait")
+# What no call owns, under its span's own name, in the order a gap
+# goes to them once the launching flush's call and the other flushes
+# have had theirs.
+GLOBAL = ("tick.launch", "tick.read", "tick.lock_wait", "interp.wait",
+          "loop.lag", "complete.idle")
+# A mark at the end of a wait carries the wait's length under this key.
+WAIT_US = {"flush.queue": "wait_us", "interp.wait": "wait_us",
+           "loop.lag": "lag_us"}
+PREFIXES = ("rpc.", "call.", "flush.", "tick.", "complete.", "loop.",
+            "interp.")
+RANK = {name: i for i, name in enumerate(
+    STAGES + (NOT_YET,) + tuple(OTHER.values()) + GLOBAL)}
+ORDER = (NOT_YET, "executor_wait", "parse", "route", "queue", "hash",
+         "waves", "keydict", "lock_wait", "dispatch", *OTHER.values(),
+         *GLOBAL, UNATTRIBUTED)
 
 
 def find_trace(path: str) -> str:
@@ -95,18 +116,16 @@ def call_intervals(call_spans: list, flush_spans: list) -> list:
 
 def divide(gap: tuple, intervals: list) -> dict:
     """Seconds of `gap` = (start, end) per name of `intervals`; each
-    instant goes to the first of STAGES (then NOT_YET, then OTHER) that
-    covers it."""
+    instant goes to the first of STAGES (then NOT_YET, then OTHER, then
+    GLOBAL) that covers it."""
     g0, g1 = gap
     cuts = sorted({g0, g1, *(t for a, b, _ in intervals for t in (a, b)
                              if g0 < t < g1)})
-    rank = {name: i for i, name in enumerate(
-        STAGES + (NOT_YET,) + tuple(OTHER.values()))}
     out: dict = {}
     for a, b in zip(cuts, cuts[1:]):
         mid = (a + b) / 2
         names = [n for s, e, n in intervals if s <= mid < e]
-        name = min(names, key=rank.__getitem__) if names else UNATTRIBUTED
+        name = min(names, key=RANK.__getitem__) if names else UNATTRIBUTED
         out[name] = out.get(name, 0.0) + (b - a)
     return out
 
@@ -127,16 +146,19 @@ def attribute_plane(programs: list, spans: list, t_lo: float, t_hi: float) -> di
             flush_call[fl] = call
         else:
             by_call.setdefault(call, []).append((a, b, n))
+    owned_by_none = [(a, b, n) for a, b, n, _, _ in spans if n in GLOBAL]
     totals: dict = {}
     for g0, g1, launched in busy_gaps(programs, t_lo, t_hi):
-        parts = {UNATTRIBUTED: g1 - g0}
+        intervals = [(a, b, n) for a, b, n in owned_by_none
+                     if a < g1 and b > g0]
         i = bisect.bisect_right(starts, g1) - 1
         if launched and i >= 0:
             fl = dispatches[i][1]
-            others = [(a, b, OTHER[n]) for a, b, n, _, f in spans
-                      if n in OTHER and f != fl and a < g1 and b > g0]
-            parts = divide((g0, g1), others + call_intervals(
-                by_call.get(flush_call.get(fl), []), by_flush.get(fl, [])))
+            intervals += [(a, b, OTHER[n]) for a, b, n, _, f in spans
+                          if n in OTHER and f != fl and a < g1 and b > g0]
+            intervals += call_intervals(
+                by_call.get(flush_call.get(fl), []), by_flush.get(fl, []))
+        parts = divide((g0, g1), intervals)
         for name, secs in parts.items():
             totals[name] = totals.get(name, 0.0) + secs
     return totals
@@ -160,10 +182,13 @@ def read_trace(path: str) -> tuple:
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith(("rpc.", "call.", "flush.")):
+                    if e.name.startswith(PREFIXES):
                         st = dict(e.stats)
                         a = e.start_ns * 1e-9
-                        spans.append((a, a + e.duration_ns * 1e-9, e.name,
+                        b = a + e.duration_ns * 1e-9
+                        if e.name in WAIT_US:  # a mark at the wait's end
+                            a = b - int(st.get(WAIT_US[e.name], 0)) * 1e-6
+                        spans.append((a, b, e.name,
                                       int(st.get("call", 0)),
                                       int(st.get("flush", 0))))
     return planes, spans
