@@ -616,6 +616,10 @@ raceguard.guarded_by(HotKeySketch, {
 # the pipeline"), and every `stage` value of the engine's histogram.
 FLUSH_STAGES = (
     "hash", "waves", "keydict", "lock_wait", "dispatch", "readback", "post",
+    # a columnar call's wait in the group commit at check_columns' entry:
+    # until it leads the waiting batch, or until that batch's leader has
+    # its answer (observed once a call that joined, never by a lone one)
+    "join",
     # the Store's sequence (docs/persistence.md), observed only with a
     # Store attached: the first two a wave, inside dispatch; the third a
     # flush, inside post (the pump: inside resolve)
@@ -793,6 +797,16 @@ def engine_histograms() -> dict:
             "waves shared their launches.",
             scale=cnt, n_buckets=12,
         ),
+        "flush_calls": Log2Histogram(
+            "gubernator_engine_flush_calls",
+            "Calls served per engine flush: the members of a columnar "
+            "flush (small calls that arrived while another flush was "
+            "in its host stage share the next one: one assembly, one "
+            "launch, one read), the distinct calls a pump flush "
+            "coalesced. _sum / _count is calls a flush; 1 where every "
+            "call is its own flush.",
+            scale=cnt, n_buckets=12,
+        ),
         "batch_width": Log2Histogram(
             "gubernator_engine_batch_width",
             "Requests served per engine flush, by serving path.",
@@ -860,7 +874,9 @@ def engine_histograms() -> dict:
             "launch), inflight_wait (dispatched, waiting for the "
             "completion stage), device_sync (host materialization of "
             "device results), resolve (telemetry + write-behind + "
-            "future resolution). Inside assemble: hash, waves, keydict. "
+            "future resolution). Before assemble, for a columnar call "
+            "that joined the group commit: join (its wait for the flush "
+            "that serves it). Inside assemble: hash, waves, keydict. "
             "Inside device_sync on the columnar path (after assemble on "
             "the object path): lock_wait (engine lock + collective "
             "guard), dispatch (the wave launches under the lock), "

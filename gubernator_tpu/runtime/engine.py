@@ -367,7 +367,7 @@ class EngineMetrics:
                       dev: float, trace_id: str = "",
                       collective: bool = False, transfers=(0, 0),
                       launches: int = 0, programs=None,
-                      crossings=None) -> None:
+                      crossings=None, calls: int = 1) -> None:
         """One flush's distribution samples (per FLUSH, not per
         request). A non-empty trace_id attaches an OpenMetrics exemplar
         to the latency buckets this flush lands in, so a p99 spike in
@@ -383,12 +383,15 @@ class EngineMetrics:
         are `programs`, the launches of the Store's per-wave sequence
         by STORE_WAVE_PROGRAMS name, and `crossings`, the [uploaded,
         read] arrays that sequence moved under the engine lock (both
-        None without a Store)."""
+        None without a Store). `calls` is the calls the flush served:
+        the members of a columnar flush (check_columns' group commit),
+        the distinct calls a pump flush coalesced."""
         self.flush_duration.labels(path).observe(dur, trace_id)
         self.device_sync.labels(path).observe(dev, trace_id)
         self.batch_width.labels(path).observe(n)
         self.flush_waves.observe(waves)
         self.flush_launches.observe(launches)
+        self.flush_calls.observe(calls)
         self._wave_h2d.inc(transfers[0])
         self._wave_d2h.inc(transfers[1])
         if programs is not None:
@@ -408,12 +411,17 @@ class FlushStages:
 
     __slots__ = (
         "em", "ids", "us", "_rows", "h2d", "d2h", "launches", "programs",
-        "crossings",
+        "crossings", "calls", "in_host_stage",
     )
 
     def __init__(self, em: EngineMetrics, flush: int, call: int):
         self.em = em
         self.ids = {"flush": flush, "call": call}
+        # the calls this flush serves (ids names the first of them)
+        self.calls = 1
+        # a columnar flush between "taken" and "launched", counted by
+        # the group commit's gate (MeshEngine._left_host_stage)
+        self.in_host_stage = False
         # wave operands uploaded / wave outputs read / decide programs
         # launched by this flush (EngineMetrics.observe_flush counts
         # them beside its waves)
@@ -532,6 +540,43 @@ def _read_waves(outs, fs: FlushStages, with_store: bool = False):
             rows.append(r)
             totals += t
     return rows, totals.tolist()
+
+
+class _Joined:
+    """One columnar call in the group commit's waiting batch
+    (MeshEngine.check_columns): what it asked and, once the batch's
+    leader has served it, its answer or the exception to raise in its
+    own thread. `latch` is a lock used as a one-shot signal: taken
+    here, released by whoever wakes the call, so the caller sleeps
+    outside the interpreter on its second acquire."""
+
+    __slots__ = ("cols", "now", "fs", "latch", "leads", "out", "exc")
+
+    def __init__(self, cols, now: int, fs: FlushStages):
+        self.cols = cols
+        self.now = now
+        self.fs = fs
+        self.latch = threading.Lock()
+        self.latch.acquire()
+        self.leads = False
+        self.out = None
+        self.exc = None
+
+
+class _FlushGate:
+    """The group commit's state: `active` columnar flushes between
+    "taken" and "launched" (their host stage; a promoted leader counts
+    from its promotion), and the calls that wait for the next turn with
+    their item count. Every field is read and written under `lock`, a
+    leaf (nothing is acquired inside it)."""
+
+    __slots__ = ("lock", "active", "waiting", "items")
+
+    def __init__(self):
+        self.lock = lockorder.make_lock("engine.coalesce")
+        self.active = 0
+        self.waiting: List[_Joined] = []
+        self.items = 0
 
 
 class _WaveAssembler:
@@ -1759,6 +1804,8 @@ class MeshEngine(EngineBase):
         self._warm_stacks: tuple = ()
         # Set when the ladder's stacked shapes are wanted (_warm_buckets).
         self._stack_wanted = threading.Event()
+        # Group commit at check_columns' entry.
+        self._gate = _FlushGate()
         self._warmup()
         self._init_base(self.topo.thread_name)
         # Columnar-path batch-width buckets compile in the background; the
@@ -1936,11 +1983,7 @@ class MeshEngine(EngineBase):
             approx_bytes = cfg.num_groups * cfg.ways * self.K.bytes_per_slot
         if approx_bytes > self._WARM_TABLE_BUDGET:
             return
-        shapes = []
-        b = 128
-        while b < cfg.batch_size:
-            shapes.append(b)
-            b <<= 1
+        shapes = self._ladder()
 
         def warm(B: int, stacked: bool) -> bool:
             """Width B's single-wave launch, or its stacked ones."""
@@ -2000,6 +2043,36 @@ class MeshEngine(EngineBase):
         if shapes:
             self._stack_wanted.wait()
             warm(shapes[0], True)
+
+    def _ladder(self) -> list:
+        """The widths below batch_size a launch may be compiled at,
+        narrowest first: powers of two from 128 lanes."""
+        shapes = []
+        b = 128
+        while b < self.cfg.batch_size:
+            shapes.append(b)
+            b <<= 1
+        return shapes
+
+    def _join_budget(self) -> int:
+        """The items check_columns' waiting batch may hold: the
+        narrowest width this engine has a warm launch at (one wave's or
+        a stacked run's), and no more than the ladder's first width. A
+        merged flush so fits programs that are compiled and warm, at
+        the width its smallest member runs at alone. Where nothing
+        narrower than batch_size is warm (a Store, a mesh, a table too
+        large for the ladder's scratch copy, or not yet) the merged
+        flush runs at batch_size as each member would have, and what
+        may merge stays what merges everywhere: calls small enough to
+        share the narrowest launch. A page of a hundred items is not
+        one of them: its waves add up with its peers' (the hot key is
+        in each), which wants wider and deeper stacked shapes and a
+        read that costs by the byte."""
+        narrowest = min(
+            self._warm_shapes + tuple(b for _d, b in self._warm_stacks)
+        )
+        ladder = self._ladder()
+        return min(narrowest, ladder[0]) if ladder else narrowest
 
     def _memory_subsystems(self) -> dict:
         """Static HBM attribution from engine geometry (bytes, computed
@@ -2594,10 +2667,13 @@ class MeshEngine(EngineBase):
         B = cfg.batch_size
         seq = self._flush_seq()
         # The flush names the first call it serves (a pump flush may
-        # coalesce several).
-        fs = FlushStages(self.metrics, seq, next(
-            (c for c in (getattr(f, "call", 0) for _, f in items) if c), 0
-        ))
+        # coalesce several) and counts them; entries that name no call
+        # (check_async, tests) count as one.
+        call_ids = [getattr(f, "call", 0) for _, f in items]
+        fs = FlushStages(
+            self.metrics, seq, next((c for c in call_ids if c), 0)
+        )
+        fs.calls = len(set(call_ids))
         # The pump's queue, from the enqueue of the batch's oldest
         # entry to here: a mark at its end, carrying its length.
         since, self._queue_since = self._queue_since, None
@@ -2847,7 +2923,7 @@ class MeshEngine(EngineBase):
                 "object", t.served, t.waves, dur, dev_s, trace_id,
                 collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
                 launches=fs.launches, programs=fs.programs,
-                crossings=fs.crossings,
+                crossings=fs.crossings, calls=fs.calls,
             )
             em.observe_stage("assemble", t.t_dev - t.t0)
             # `dispatch` (the launches under the lock) and `lock_wait` were
@@ -2861,7 +2937,7 @@ class MeshEngine(EngineBase):
                 carry=t.carry_n, widths=t.widths,
                 dur_us=int(dur * 1e6), dev_us=int(dev_s * 1e6),
                 ticket=t.seq, trace_id=t.trace_id or "",
-                call=fs.ids["call"], stages_us=fs.us,
+                call=fs.ids["call"], calls=fs.calls, stages_us=fs.us,
             )
 
             # Write-behind BEFORE resolving futures, so a caller that observed
@@ -3004,17 +3080,153 @@ class MeshEngine(EngineBase):
         bytes need no re-slicing. Results align with `select`'s order.
         `call` is the caller's tracing.CallRecord: its sequence number
         names this flush's stages in the flight recorder and a capture.
-        """
+
+        **Group commit** (docs/architecture.md "Calls that share a
+        flush"). A call that arrives while no columnar flush is in its
+        host stage (taken, not yet launched: hash, waves, key
+        dictionary, upload, engine lock, dispatch) is a flush of its
+        own, waits for nobody, and its columns are not copied. A small
+        call that arrives while one is (_may_join) joins the waiting
+        batch and sleeps. When a flush has launched and left the engine
+        lock (never when it has read), the batch's first call is woken
+        as its leader: it takes the whole batch, merges the members'
+        columns in arrival order (_merge_joined), runs _flush_columns
+        once and hands each member its slice. The semantics are the
+        pump's, which coalesces calls too: per-key order is arrival
+        order, each member's own `now` rides its lanes as created_at,
+        the flush's scalar `now` is the leader's. No window, timer or
+        knob: the batch is what arrived while the previous flush was
+        being assembled."""
+        if cols.n == 0:
+            return None
+        if now is None:
+            now = self.now_fn()
+        fs = FlushStages(self.metrics, self._flush_seq(), call.seq)
+        gate = self._gate
+        me = None
+        with gate.lock:
+            if (
+                gate.active and select is None
+                and self._may_join(cols, gate.items)
+            ):
+                me = _Joined(cols, now, fs)
+                gate.waiting.append(me)
+                gate.items += cols.n
+            else:
+                gate.active += 1
+                fs.in_host_stage = True
+        if me is None:
+            try:
+                return self._flush_columns(cols, now, select, hashes, fs)
+            finally:
+                self._left_host_stage(fs)
+        with tracing.stage("flush.join", fs, fs.ids):
+            me.latch.acquire()
+        if me.leads:
+            return self._lead(me)
+        fs.publish()  # its one stage: the wait
+        if me.exc is not None:
+            raise me.exc
+        return me.out
+
+    def _may_join(self, cols, waiting_items: int) -> bool:
+        """Whether a call may wait for the next merged flush (under the
+        gate's lock, and only while a flush is in its host stage):
+        decided by what is observed, the call's own size and the warm
+        shapes (_join_budget). A second call of its size must fit
+        beside it, so a call too large to ever meet a peer goes
+        straight through and waits for nobody; the batch must still
+        hold it; and no item asks for NO_BATCHING, which skips the
+        pump's window as well (_pump)."""
+        budget = self._join_budget()
+        n = cols.n
+        return (
+            2 * n <= budget and waiting_items + n <= budget
+            and not (cols.behavior & int(Behavior.NO_BATCHING)).any()
+        )
+
+    def _left_host_stage(self, fs: FlushStages) -> None:
+        """A columnar flush has launched and left the engine lock (or
+        ended without a launch): its turn passes to the waiting batch's
+        first call, which is woken to lead it, or lapses. Once a flush:
+        _flush_columns calls it after its launch, check_columns and
+        _lead again on every way out."""
+        if not fs.in_host_stage:
+            return
+        fs.in_host_stage = False
+        gate = self._gate
+        head = None
+        with gate.lock:
+            if gate.waiting and not gate.waiting[0].leads:
+                head = gate.waiting[0]
+                head.leads = True  # `active` stays: the turn is its own now
+            else:
+                gate.active -= 1
+        if head is not None:
+            head.latch.release()
+
+    def _lead(self, me: _Joined):
+        """Serve the waiting batch that `me` heads, on me's thread: one
+        _flush_columns over the merged columns, each member handed its
+        slice and woken. A batch of one is served as it stands. If the
+        merged assembly is refused (one key more than max_waves times
+        across the members) the members are served one after another
+        as lone flushes, each answered as it would have been alone; an
+        exception out of the merged flush reaches every member
+        (TableCommittedError must; any other sends each to the object
+        path, service/fastpath.py)."""
+        gate = self._gate
+        fs = me.fs
+        with gate.lock:
+            batch, gate.waiting, gate.items = gate.waiting, [], 0
+        fs.in_host_stage = True  # the turn it was handed with its promotion
+        woken = 1  # batch[:woken] need no waking any more: me, to begin with
+        try:
+            if len(batch) == 1:
+                return self._flush_columns(me.cols, me.now, None, None, fs)
+            fs.calls = len(batch)
+            try:
+                out = self._flush_columns(
+                    _merge_joined(batch), me.now, None, None, fs
+                )
+            except BaseException as e:
+                for m in batch[1:]:
+                    m.exc = e
+                raise
+            if out is not None:
+                lo = 0
+                for m in batch:
+                    hi = lo + m.cols.n
+                    m.out = tuple(a[lo:hi] for a in out)
+                    lo = hi
+                return me.out
+            fs.calls = 1
+            for m in batch:
+                try:
+                    m.out = self._flush_columns(
+                        m.cols, m.now, None, None, m.fs
+                    )
+                except Exception as e:  # its own, as if it had come alone
+                    m.exc = e
+                if m is not me:
+                    m.latch.release()
+                    woken += 1
+            if me.exc is not None:
+                raise me.exc
+            return me.out
+        finally:
+            self._left_host_stage(fs)
+            for m in batch[woken:]:
+                m.latch.release()
+
+    def _flush_columns(self, cols, now: int, select, hashes, fs: FlushStages):
+        """One columnar flush over `cols`: check_columns' body, for a
+        lone call's columns or a batch's merged ones."""
         from gubernator_tpu import native as _native
 
         cfg = self.cfg
         store = self.store
-        if cols.n == 0:
-            return None
         t_start = time.perf_counter()
-        if now is None:
-            now = self.now_fn()
-        fs = FlushStages(self.metrics, self._flush_seq(), call.seq)
 
         if hashes is None:
             with tracing.stage("flush.hash", fs, fs.ids):
@@ -3155,6 +3367,7 @@ class MeshEngine(EngineBase):
                 wave_slices, ops, lane_reqs, now, prefetched, fs,
                 req_resolver=resolver,
             )
+            self._left_host_stage(fs)  # launched: the next batch's turn
 
             try:
                 with tracing.stage(
@@ -3191,7 +3404,7 @@ class MeshEngine(EngineBase):
                 flush_trace_id if cfg.exemplars else "",
                 collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
                 launches=fs.launches, programs=fs.programs,
-                crossings=fs.crossings,
+                crossings=fs.crossings, calls=fs.calls,
             )
             em.observe_stage("assemble", t_dev - t_start)
             em.observe_stage("device_sync", dev_s)
@@ -3200,7 +3413,7 @@ class MeshEngine(EngineBase):
                 widths=[B] * W, dur_us=int(dur * 1e6),
                 dev_us=int(dev_s * 1e6), trace_id=flush_trace_id,
                 ticket=fs.ids["flush"], call=fs.ids["call"],
-                stages_us=fs.us,
+                calls=fs.calls, stages_us=fs.us,
             )
             st_req, r_limit, remaining, reset_time = _demux_lanes(
                 out_rows, ix
@@ -3260,6 +3473,7 @@ class MeshEngine(EngineBase):
                 wave_slices, ops, [{} for _ in wave_slices], now, {}, fs,
                 r_ops=r_ops,
             )
+            self._left_host_stage(fs)  # launched: the next batch's turn
 
         status = np.zeros(n, np.int64)
         r_limit = np.zeros(n, np.int64)
@@ -3297,7 +3511,7 @@ class MeshEngine(EngineBase):
                 "columnar", n, waves_total, dur, dev_s,
                 flush_trace_id if cfg.exemplars else "",
                 collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
-                launches=fs.launches,
+                launches=fs.launches, calls=fs.calls,
             )
             em.observe_stage("assemble", t_dev - t_start)
             em.observe_stage("device_sync", dev_s)
@@ -3306,7 +3520,7 @@ class MeshEngine(EngineBase):
                 carry=0, widths=[cfg.batch_size] * waves_total,
                 dur_us=int(dur * 1e6), dev_us=int(dev_s * 1e6),
                 trace_id=flush_trace_id, ticket=fs.ids["flush"],
-                call=fs.ids["call"], stages_us=fs.us,
+                call=fs.ids["call"], calls=fs.calls, stages_us=fs.us,
             )
             if em.hotkeys.k > 0:
                 _note_hotkeys_columnar(em.hotkeys, hi, lo, cols.hits, status)
@@ -4186,6 +4400,29 @@ def _note_hotkeys_columnar(hk, hi, lo, hits, status) -> None:
         hk.update([(k, v[0], v[1], None) for k, v in agg.items()])
 
 
+def _merge_joined(batch):
+    """The columns of one merged flush: the members' columns in arrival
+    order (wire.concat_columns), every lane carrying a created_at: its
+    item's own, else its member's `now` (the clock at the call's
+    arrival, or what its caller passed: a GLOBAL call's local decide
+    and its replicated legs share one stamp, service/fastpath.py). The
+    pump does the same for the calls it coalesces (check_bulk stamps
+    created_at at the call's arrival). The members' own columns are
+    left as they were: a member the batch cannot serve is still served
+    from them."""
+    from gubernator_tpu import wire as _wire
+
+    cols = _wire.concat_columns([m.cols for m in batch])
+    nows = np.repeat(
+        np.array([m.now for m in batch], np.int64),
+        [m.cols.n for m in batch],
+    )
+    carried = cols.has_created.astype(bool) & (cols.created_at != 0)
+    cols.created_at = np.where(carried, cols.created_at, nows)
+    cols.has_created = np.ones(cols.n, np.uint8)
+    return cols
+
+
 def _select_columns(cols, select: np.ndarray):
     """Subset view of RequestColumns for check_columns(select=...): field
     arrays are fancy-indexed; key bytes are NOT re-sliced — key hashes
@@ -4194,23 +4431,14 @@ def _select_columns(cols, select: np.ndarray):
     is poisoned to None so any code path that tries to hash or slice
     keys on the subset view fails loudly (TypeError) instead of reading
     misaligned offsets."""
-    import dataclasses as _dc
+    from gubernator_tpu.wire import PER_ITEM_FIELDS
 
-    return _dc.replace(
+    return dataclasses.replace(
         cols,
         n=int(len(select)),
-        hits=cols.hits[select],
-        limit=cols.limit[select],
-        duration=cols.duration[select],
-        algo=cols.algo[select],
-        behavior=cols.behavior[select],
-        burst=cols.burst[select],
-        created_at=cols.created_at[select],
-        has_created=cols.has_created[select],
-        slow=cols.slow[select],
-        name_lens=cols.name_lens[select],
         key_data=cols.key_data,
         key_offsets=None,  # poisoned: unusable after select (see above)
+        **{f: getattr(cols, f)[select] for f in PER_ITEM_FIELDS},
     )
 
 

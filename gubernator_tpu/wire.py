@@ -164,6 +164,37 @@ class RequestColumns:
         )
 
 
+# RequestColumns' fields that hold one value an item (the rest: n, and
+# the key bytes with their offsets).
+PER_ITEM_FIELDS = (
+    "hits", "limit", "duration", "algo", "behavior", "burst", "created_at",
+    "has_created", "slow", "name_lens",
+)
+
+
+def concat_columns(parts) -> "RequestColumns":
+    """Several calls' columns as one, in the order given: the per-item
+    fields concatenated, `key_data` appended and each part's
+    `key_offsets` shifted by the key bytes before it (the engine's group
+    commit, runtime/engine.py check_columns). The parts are not
+    changed."""
+    cat = np.concatenate
+    bases = np.cumsum([len(p.key_data) for p in parts])
+    offsets = [parts[0].key_offsets]
+    offsets += [
+        p.key_offsets[1:] + b for p, b in zip(parts[1:], bases.tolist())
+    ]
+    fields = {
+        f: cat([getattr(p, f) for p in parts]) for f in PER_ITEM_FIELDS
+    }
+    return RequestColumns(
+        n=sum(p.n for p in parts),
+        key_data=cat([p.key_data for p in parts]),
+        key_offsets=cat(offsets),
+        **fields,
+    )
+
+
 def req_from_columns(cols: "RequestColumns", i: int):
     """RateLimitReq object for one lane — the single shared builder for
     every consumer that needs objects from wire columns (forwarding path,

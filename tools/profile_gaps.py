@@ -17,9 +17,11 @@ answer. The rule here:
 - the gap is divided along that flush's own call: the parts of the gap
   its call spent in `executor_wait` (from `rpc.begin` to the call's
   first span on another thread), `parse`, `route`, `queue` (the pump's
-  queue: the `flush.queue` mark, which carries its length), `hash`,
-  `waves`, `keydict`, `lock_wait` and `dispatch`, and, before
-  `rpc.begin`, "call not yet in the server";
+  queue: the `flush.queue` mark, which carries its length), `join` (the
+  leader of a merged columnar flush waiting for its turn: the flush
+  before it had not launched yet), `hash`, `waves`, `keydict`,
+  `lock_wait` and `dispatch`, and, before `rpc.begin`, "call not yet in
+  the server";
 - what none of these covers goes to another flush's `readback` or
   `post` where one was open (a pipelined pump waits for the flush before
   last to be read before it launches the next);
@@ -52,8 +54,8 @@ OTHER = {"flush.readback": "another flush: readback",
          "flush.post": "another flush: post"}
 # Innermost first: where two of a call's intervals overlap (a pump
 # flush beside its call's own thread) the gap goes to the earlier name.
-STAGES = ("dispatch", "lock_wait", "keydict", "waves", "hash", "queue",
-          "route", "parse", "executor_wait")
+STAGES = ("dispatch", "lock_wait", "keydict", "waves", "hash", "join",
+          "queue", "route", "parse", "executor_wait")
 # What no call owns, under its span's own name, in the order a gap
 # goes to them once the launching flush's call and the other flushes
 # have had theirs.
@@ -66,8 +68,8 @@ PREFIXES = ("rpc.", "call.", "flush.", "tick.", "complete.", "loop.",
             "interp.")
 RANK = {name: i for i, name in enumerate(
     STAGES + (NOT_YET,) + tuple(OTHER.values()) + GLOBAL)}
-ORDER = (NOT_YET, "executor_wait", "parse", "route", "queue", "hash",
-         "waves", "keydict", "lock_wait", "dispatch", *OTHER.values(),
+ORDER = (NOT_YET, "executor_wait", "parse", "route", "queue", "join",
+         "hash", "waves", "keydict", "lock_wait", "dispatch", *OTHER.values(),
          *GLOBAL, UNATTRIBUTED)
 
 
