@@ -759,6 +759,13 @@ def engine_store_counters() -> dict:
             "Store.remove calls: keys whose last operation in a flush "
             "was a token bucket's RESET_REMAINING.",
         ),
+        "store_rows_skipped": _BareCounter(
+            "gubernator_store_rows_skipped",
+            "Lanes of a flush whose acknowledged change was not handed "
+            "to the Store because the row gathered for them was unused "
+            "or held another key. 0 wherever the row gather reads the "
+            "slots the decide wrote, on one device and on a mesh.",
+        ),
     }
 
 

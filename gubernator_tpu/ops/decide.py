@@ -514,15 +514,18 @@ def probe_exists(table: SlotTable, operand, ways: int = 8):
     return _probe_exists_impl(table, batch, now, ways)
 
 
+def _gather_cols(table: SlotTable, safe):
+    """The (NCOLS, B) packed columns of in-range slots `safe` (B,)."""
+    return jnp.stack(packed_cols(jax.tree.map(lambda a: a[safe], table)))
+
+
 @functools.partial(jax.jit, static_argnames=("from_output",))
 def gather_rows(table: SlotTable, slots, from_output: bool = False):
     """Post-decide row readback for the Store write-behind seam: each
     slot's full state as one packed (NCOLS, B) int64 array (padding
     slots index N -> zeros); ops/layout.py gathered_rows / wide_rows."""
     return gathered_rows(
-        lambda safe: jnp.stack(
-            packed_cols(jax.tree.map(lambda a: a[safe], table))
-        ),
+        functools.partial(_gather_cols, table),
         slots, table.num_slots, from_output,
     )
 

@@ -664,12 +664,17 @@ def probe_exists_fused(table: FusedTable, operand, ways: int = 8):
     return _probe_exists_fused_impl(table, batch, now, ways)
 
 
+def _gather_cols(table: FusedTable, safe):
+    """The (NCOLS, B) packed columns of in-range slots `safe` (B,)."""
+    return join_words(read_windows(table.data, safe, 1)[:, 0]).T
+
+
 @functools.partial(jax.jit, static_argnames=("from_output",))
 def gather_rows_fused(table: FusedTable, slots, from_output: bool = False):
     """Post-decide row readback: the lanes' rows as one packed
     (NCOLS, B) int64 array (ops/layout.py gathered_rows / wide_rows)."""
     return gathered_rows(
-        lambda safe: join_words(read_windows(table.data, safe, 1)[:, 0]).T,
+        functools.partial(_gather_cols, table),
         slots, table.num_slots, from_output,
     )
 

@@ -180,6 +180,10 @@ class RawKernels(NamedTuple):
     decide: object  # (table, batch, now, ways) -> (table, DecideOutput)
     inject: object  # (table, items, now, ways) -> (table, ehi, elo)
     probe_exists: object  # (table, batch, now, ways) -> bool[B]
+    # (table, slots) -> the (NCOLS, B) packed columns of in-range slots
+    # (ops/layout.py gathered_rows is the jitted kernel sets' use of it;
+    # parallel/mesh.py runs it on a shard's slice)
+    gather_cols: object
     to_wide: object  # table -> SlotTable (traceable)
     from_wide: object  # SlotTable -> table (traceable)
     # The sync tick's compaction and selection (parallel/ici.py),
@@ -256,7 +260,11 @@ def get_paged_kernels(
 
 def get_raw_kernels(layout: str) -> RawKernels:
     if layout == "wide":
-        from gubernator_tpu.ops.decide import _decide_impl, _probe_exists_impl
+        from gubernator_tpu.ops.decide import (
+            _decide_impl,
+            _gather_cols,
+            _probe_exists_impl,
+        )
         from gubernator_tpu.ops.inject import _inject_impl
 
         return RawKernels(
@@ -265,6 +273,7 @@ def get_raw_kernels(layout: str) -> RawKernels:
             decide=lambda t, b, now, ways: _decide_impl(t, b, now, ways=ways),
             inject=lambda t, i, now, ways: _inject_impl(t, i, now, ways=ways),
             probe_exists=_probe_exists_impl,
+            gather_cols=_gather_cols,
             to_wide=lambda t: t,
             from_wide=lambda t: t,
         )
@@ -281,6 +290,7 @@ def get_raw_kernels(layout: str) -> RawKernels:
                 t, i, now, ways
             ),
             probe_exists=_f._probe_exists_fused_impl,
+            gather_cols=_f._gather_cols,
             to_wide=_f.unpack_table,
             from_wide=_f.pack_table,
             take_groups=_f.take_groups,

@@ -230,6 +230,12 @@ def wide_rows(cols) -> SlotTable:
     )
 
 
+def output_slots(vec):
+    """Row OUT_SLOT of the vector a `with_store` decide produced, inside
+    a jit: the slot each lane's row was written to."""
+    return vec[:-OUT_TOTALS].reshape(OUT_STORE_ROWS, -1)[OUT_SLOT]
+
+
 def gathered_rows(gather, slots, num_slots: int, from_output: bool):
     """THE body of every layout's gather_rows, inside its jit: `slots`
     is (B,) int64, or with `from_output` the vector a `with_store`
@@ -239,7 +245,7 @@ def gathered_rows(gather, slots, num_slots: int, from_output: bool):
     padding lane's) reads zeros. Returns the packed rows, (NCOLS, B)
     int64."""
     if from_output:
-        slots = slots[:-OUT_TOTALS].reshape(OUT_STORE_ROWS, -1)[OUT_SLOT]
+        slots = output_slots(slots)
     rows = gather(jnp.clip(slots, 0, num_slots - 1))
     return jnp.where((slots < num_slots)[None, :], rows, 0)
 

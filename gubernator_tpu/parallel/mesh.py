@@ -31,6 +31,7 @@ from gubernator_tpu.ops.kernels import (
 )
 from gubernator_tpu.ops.layout import (
     SlotTable,
+    output_slots,
     packed_waves,
     unpack_operand,
     wide_rows,
@@ -103,7 +104,9 @@ def create_sharded_table(
     return transfer.put_tree(table, sharding, metrics=metrics)
 
 
-def _sharded_packed_decide(mesh: Mesh, groups_per: int, decide, xlate=None):
+def _sharded_packed_decide(
+    mesh: Mesh, groups_per: int, ways: int, decide, xlate=None
+):
     """The packed launch over a sharded table: (table, operand,
     with_store) -> (table', output), operand and output replicated. The
     one operand, a wave or a stacked run of them, is unpacked and looped
@@ -114,7 +117,19 @@ def _sharded_packed_decide(mesh: Mesh, groups_per: int, decide, xlate=None):
     wave, gives every lane its single authoritative answer.
     `xlate(page_map, group)` (paged) maps logical to physical groups,
     replicated, before the ownership mask; `table` is then the
-    PagedTable and only its data is sharded."""
+    PagedTable and only its data is sharded.
+
+    `with_store` (a Store is attached): the output's OUT_SLOT row has to
+    name the row of the WHOLE table that the lane's owner wrote, because
+    the row gather that follows (_sharded_gather_rows) finds the owner
+    by it. A shard's decide reports a slot of its own slice, and its
+    slice's length for a lane it does not own; here the owner rebases
+    its slot to the whole table's index less the table's length, every
+    other shard gives 0, and the first shard adds the table's length
+    once: the same one psum then yields the global slot, and the
+    table's length (reads zeros) for a lane nobody owns."""
+    slots_per = groups_per * ways
+    num_slots = mesh.devices.size * slots_per
 
     def local(data, operand, *page_map, with_store):
         # named scopes are profile metadata only: they name a trace's
@@ -127,7 +142,16 @@ def _sharded_packed_decide(mesh: Mesh, groups_per: int, decide, xlate=None):
                     )
                 mine = _mask_to_local(groups_per, batch)
             with jax.named_scope("decide"):
-                return decide(data, mine, now)
+                data, out = decide(data, mine, now)
+            if with_store:
+                with jax.named_scope("global_slot"):
+                    dev = jax.lax.axis_index(AXIS).astype(jnp.int64)
+                    out = out._replace(slot=jnp.where(
+                        mine.active,
+                        out.slot + (dev * slots_per - num_slots),
+                        0,
+                    ) + jnp.where(dev == 0, num_slots, 0))
+            return data, out
 
         data, out = packed_waves(wave, data, operand, with_store)
         with jax.named_scope("psum_merge"):
@@ -152,6 +176,76 @@ def _sharded_packed_decide(mesh: Mesh, groups_per: int, decide, xlate=None):
     return decide_fn
 
 
+def _sharded_gather_rows(mesh: Mesh, slots_per: int, gather_cols):
+    """The Store's row gather over a sharded table, an owner program in
+    the decide's image: gather_rows(table, slots, from_output=False) ->
+    the (NCOLS, B) packed rows, replicated. `slots` index the WHOLE
+    table (replicated; with `from_output` the `with_store` decide's
+    output vector, whose OUT_SLOT row is taken inside the program). A
+    shard reads the lanes whose slot lies in its slice out of that
+    slice, `gather_cols(slice, local slots)`, and gives zeros for the
+    rest; one psum hands every device every lane's row. A slot past the
+    table (a padding lane's) is in nobody's slice and reads zeros."""
+
+    def local(data, slots):
+        with jax.named_scope("owner_mask"):
+            at = slots - jax.lax.axis_index(AXIS).astype(jnp.int64) * slots_per
+            mine = (at >= 0) & (at < slots_per)
+        with jax.named_scope("store_rows_local"):
+            rows = gather_cols(data, jnp.where(mine, at, 0))
+            rows = jnp.where(mine[None, :], rows, 0)
+        with jax.named_scope("psum_rows"):
+            return jax.lax.psum(rows, AXIS)
+
+    sharded = jax.shard_map(
+        local, mesh=mesh, in_specs=(P(AXIS), P()), out_specs=P()
+    )
+
+    @functools.partial(jax.jit, static_argnames=("from_output",))
+    def gather_rows_fn(table, slots, from_output=False):
+        return sharded(table, output_slots(slots) if from_output else slots)
+
+    return gather_rows_fn
+
+
+def _sharded_probe_exists(
+    mesh: Mesh, groups_per: int, ways: int, probe, xlate=None
+):
+    """The Store's residency probe over a sharded table, an owner
+    program: probe_exists(table, operand, *page_map) -> bool[B],
+    replicated. Each shard probes the lanes it owns against its slice
+    (`probe(slice, batch, now, ways)`, the decide's ownership mask, the
+    paged `xlate` before it) and answers False for the rest; one psum of
+    the (B,) answers gives every device every lane's."""
+
+    def local(data, operand, *page_map):
+        batch, _home, now = unpack_operand(operand)
+        with jax.named_scope("owner_mask"):
+            if xlate is not None:
+                batch = batch._replace(group=xlate(page_map[0], batch.group))
+            mine = _mask_to_local(groups_per, batch)
+        with jax.named_scope("probe_local"):
+            found = probe(data, mine, now, ways)
+        with jax.named_scope("psum_probe"):
+            return jax.lax.psum(found.astype(jnp.int32), AXIS) != 0
+
+    paged = xlate is not None
+    sharded = jax.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(P(AXIS), P()) + ((P(),) if paged else ()),
+        out_specs=P(),
+    )
+
+    @jax.jit
+    def probe_exists_fn(table, operand):
+        if not paged:
+            return sharded(table, operand)
+        return sharded(table.data, operand, table.page_map)
+
+    return probe_exists_fn
+
+
 def make_sharded_decide(
     mesh: Mesh, num_groups: int, ways: int = 8, layout: str = DEFAULT_LAYOUT
 ):
@@ -160,7 +254,7 @@ def make_sharded_decide(
     (ops/layout.py WaveOperand) is replicated."""
     RK = get_raw_kernels(layout)
     return _sharded_packed_decide(
-        mesh, num_groups // mesh.devices.size,
+        mesh, num_groups // mesh.devices.size, ways,
         lambda t, b, now: RK.decide(t, b, now, ways),
     )
 
@@ -226,10 +320,13 @@ def make_mesh_kernels(
     core binds one kernel set and never learns the topology.
 
     Flat (page_groups == 0): returns an ops.kernels.Kernels whose
-    decide/inject are the shard_map ownership programs above and whose
-    read-side ops (probe_exists, gather_rows, to_wide, census input) are
-    the plain layout jits — GSPMD partitions them over the sharded table
-    automatically.
+    decide, inject and the Store's two other programs (probe_exists,
+    gather_rows) are the shard_map ownership programs above: every
+    program of a Store's per-wave sequence reads or writes a lane at the
+    shard that owns it and ends in one psum, so a Store on a sharded
+    table sees what a Store on one device sees. The whole-table reads
+    (to_wide, census input) are the plain layout jits — GSPMD partitions
+    them over the sharded table automatically.
 
     Paged (page_groups > 0): returns an ops.paged.PagedKernels-shaped
     facade where the PHYSICAL table is sharded along the slot axis and
@@ -251,6 +348,13 @@ def make_mesh_kernels(
         raw = get_raw_kernels(layout)
         decide_fn = make_sharded_decide(mesh, num_groups, ways, layout)
         inject_fn = make_sharded_inject(mesh, num_groups, ways, layout)
+        groups_per = num_groups // n_dev
+        probe_fn = _sharded_probe_exists(
+            mesh, groups_per, ways, raw.probe_exists
+        )
+        gather_fn = _sharded_gather_rows(
+            mesh, groups_per * ways, raw.gather_cols
+        )
         sharding = NamedSharding(mesh, P(AXIS))
 
         def _create(*_a, **_k):
@@ -270,8 +374,8 @@ def make_mesh_kernels(
             ),
             decide_scan=_no_scan,
             inject=lambda t, i, now, ways_=ways: inject_fn(t, i, now),
-            probe_exists=base.probe_exists,
-            gather_rows=base.gather_rows,
+            probe_exists=lambda t, operand, ways_=ways: probe_fn(t, operand),
+            gather_rows=gather_fn,
             to_wide=base.to_wide,
             from_wide=_from_wide,
             bytes_per_slot=BYTES_PER_SLOT[layout],
@@ -328,9 +432,17 @@ def _make_mesh_paged_kernels(
         return phys.astype(group.dtype)
 
     _decide_packed = _sharded_packed_decide(
-        mesh, groups_per,
+        mesh, groups_per, ways,
         lambda d, b, now: raw.decide(d, b, now, ways),
         xlate=_xlate,
+    )
+    # the Store's two other programs, over the PHYSICAL frames: slots
+    # index the physical table, as the paged decide reports them
+    _probe_exists = _sharded_probe_exists(
+        mesh, groups_per, ways, raw.probe_exists, xlate=_xlate
+    )
+    _gather_rows = _sharded_gather_rows(
+        mesh, groups_per * ways, raw.gather_cols
     )
 
     def _local_inject(data, items, now):
@@ -352,12 +464,6 @@ def _make_mesh_paged_kernels(
         i = items._replace(group=_xlate(pt.page_map, items.group))
         data, ehi, elo = _sharded_inject(pt.data, i, now)
         return PagedTable(data, pt.page_map), ehi, elo
-
-    @jax.jit
-    def _probe_exists(pt, operand):
-        batch, _home, now = unpack_operand(operand)
-        b = batch._replace(group=_xlate(pt.page_map, batch.group))
-        return raw.probe_exists(pt.data, b, now, ways)
 
     # Page moves are the single-chip programs with output shardings
     # pinned: the physical table stays sharded along the slot axis and
@@ -417,8 +523,8 @@ def _make_mesh_paged_kernels(
         decide_scan=_no_scan,
         inject=lambda t, i, now, ways_=ways: _inject(t, i, now),
         probe_exists=lambda t, operand, ways_=ways: _probe_exists(t, operand),
-        gather_rows=lambda t, slots, from_output=False: base.gather_rows(
-            t.data, slots, from_output
+        gather_rows=lambda t, slots, from_output=False: _gather_rows(
+            t.data, slots, from_output=from_output
         ),
         to_wide=lambda t: base.to_wide(t.data),
         from_wide=_from_wide,

@@ -1738,6 +1738,7 @@ class MeshEngine(EngineBase):
         self.topo = topology if topology is not None else SingleChipTopology()
         self.metrics = EngineMetrics()
         self.store = None  # optional Store plugin (gubernator_tpu.store)
+        self._skipped_logged = float("-inf")  # _note_skipped_rows' last line
         self._key_strings: Dict[Tuple[int, int], str] = {}
         self._lock = lockorder.make_lock("engine.table")  # guards table swap (load/restore)
         # guards the host key dictionaries (pump + executor threads)
@@ -2704,10 +2705,17 @@ class MeshEngine(EngineBase):
         prefetched: Dict[Tuple[int, int], object] = {}
         with tracing.stage("flush.keydict", fs, fs.ids):
             if self.store is not None and cfg.keep_key_strings:
+                # the replica tier's GLOBAL buckets are never persisted,
+                # so the Store is not asked for them either
+                replica = (
+                    int(Behavior.GLOBAL) if self._rtier is not None else 0
+                )
                 with self._keys_lock:
                     need = []
                     seen = set()
                     for i, (req, _) in enumerate(items):
+                        if req.behavior & replica:
+                            continue
                         k = (hi_l[i], lo_l[i])
                         if k not in self._key_strings and k not in seen:
                             seen.add(k)
@@ -3275,56 +3283,17 @@ class MeshEngine(EngineBase):
             )
 
         # Store path pre-work (the columnar twin of _process's read-through
-        # plumbing): request objects are built LAZILY, only for miss lanes;
-        # key strings are decoded once for the dictionary + write-behind;
-        # never-seen keys prefetch OUTSIDE the device lock.
+        # plumbing), shared with the replica-split path.
         prefetched: Dict[Tuple[int, int], object] = {}
-        strs = None
+        lane_reqs: List[Dict[int, tuple]] = [{} for _ in range(W)]
+        resolver = wb_entries = None
         with tracing.stage("flush.keydict", fs, fs.ids):
             if store is not None:
-                from gubernator_tpu import wire as _wire
-
-                if sel_map is None:
-                    strs = cols.key_strings_all()
-                else:
-                    strs = [key_str(j) for j in range(n)]
-
-                def req_of(j: int) -> RateLimitReq:
-                    i = int(sel_map[j]) if sel_map is not None else j
-                    return _wire.req_from_columns(orig_cols, i)
-
-                # One-shot tolist conversions: per-item numpy scalar boxing
-                # (int(hi[j]) etc.) dominated this path's host cost.
-                hi_l, lo_l = hi.tolist(), lo.tolist()
-                wave_l, lane_l = wave.tolist(), lane.tolist()
-                keys_l = list(zip(hi_l, lo_l))
-                keep = cfg.keep_key_strings
-                if keep:
-                    # Prefetch never-seen keys OUTSIDE the lock (the dict is
-                    # a superset of table residency, as in _process). Without
-                    # the dictionary there is no never-seen predicate: rely
-                    # on the in-lock per-wave probe alone rather than issuing
-                    # a blocking store.get for every key of every flush.
-                    need = []
-                    seen = set()
-                    with self._keys_lock:
-                        for j, k in enumerate(keys_l):
-                            if k not in self._key_strings and k not in seen:
-                                seen.add(k)
-                                need.append((j, k))
-                        self._key_strings.update(zip(keys_l, strs))
-                    for j, k in need:
-                        snap = self._store_get(req_of(j))
-                        if snap is not None:
-                            prefetched[k] = snap
-                    self.metrics.observe_store_gets(
-                        len(prefetched), len(need) - len(prefetched)
+                prefetched, lane_reqs, resolver, wb_entries = (
+                    self._store_columns_prework(
+                        orig_cols, sel_map, hi, lo, wave, lane, W
                     )
-                    self._maybe_prune_key_strings()
-                # item indices per wave (for the lazy lane_req dicts)
-                by_wave = [[] for _ in range(W)]
-                for j, w_ in enumerate(wave_l):
-                    by_wave[w_].append(j)
+                )
             elif cfg.keep_key_strings and cfg.record_columnar_keys:
                 # Store-less columnar edge: keep the key-string dictionary
                 # complete so handover/Loader snapshots are routable
@@ -3349,14 +3318,6 @@ class MeshEngine(EngineBase):
         with tracing.stage("flush.waves", fs, fs.ids):
             wave_slices = [wo.wave(w) for w in range(W)]
             ops = self._upload(wave_slices, now, fs)
-            lane_reqs: List[Dict[int, tuple]] = [{} for _ in range(W)]
-            resolver = None
-            if store is not None:
-                resolver = req_of
-                for w in range(W):
-                    lane_reqs[w] = {
-                        lane_l[j]: (j, hi_l[j], lo_l[j]) for j in by_wave[w]
-                    }
         _telemetry.set_shape_hint(f"{cfg.layout}:columnar:{W}x{B}")
         t_dev = time.perf_counter()
         with _telemetry.serving_scope(self.metrics), tracing.span(
@@ -3384,16 +3345,9 @@ class MeshEngine(EngineBase):
 
         with tracing.stage("flush.post", fs, fs.ids):
             if store is not None:
-                # Write-behind from the per-wave gathered rows
-                # (last-op-wins per key, request order) + key-dictionary
-                # hygiene — same semantics as the object path's flush.
-                with tracing.stage("flush.write_behind", fs, fs.ids):
-                    self._store_write_behind_core(
-                        list(zip(strs, wave_l, lane_l, hi_l, lo_l)),
-                        out_rows, wave_rows_host,
-                    )
-                if cfg.keep_key_strings:
-                    self._drop_displaced_strings(events)
+                self._store_columns_postwork(
+                    fs, wb_entries, out_rows, wave_rows_host, events
+                )
 
             tot_hits, tot_miss, tot_evic, tot_over = totals
             dur = time.perf_counter() - t_start
@@ -3431,6 +3385,80 @@ class MeshEngine(EngineBase):
         fs.publish()
         return out
 
+    def _store_columns_prework(self, orig_cols, idx_map, hi, lo, wave, lane, W):
+        """What a columnar flush with a Store needs before its waves run,
+        for the items (hi, lo, wave, lane) of one assembly: request
+        objects are built LAZILY, only for miss lanes; key strings are
+        decoded once for the dictionary and the write-behind; never-seen
+        keys prefetch OUTSIDE the device lock. `idx_map` maps an item to
+        its place in `orig_cols` (None: the items are `orig_cols`'
+        own, in order; a selection drops key_offsets, so strings resolve
+        through the original columns). Returns (prefetched, per-wave
+        {lane: (item, hi, lo)}, the item -> request resolver, the
+        write-behind's (key, wave, lane, hi, lo) entries in request
+        order)."""
+        from gubernator_tpu import wire as _wire
+
+        cfg = self.cfg
+        if idx_map is None:
+            strs = orig_cols.key_strings_all()
+        else:
+            idx_l = idx_map.tolist()
+            strs = [orig_cols.key_string(i) for i in idx_l]
+
+        def req_of(j: int) -> RateLimitReq:
+            return _wire.req_from_columns(
+                orig_cols, j if idx_map is None else idx_l[j]
+            )
+
+        # One-shot tolist conversions: per-item numpy scalar boxing
+        # (int(hi[j]) etc.) dominated this path's host cost.
+        hi_l, lo_l = hi.tolist(), lo.tolist()
+        wave_l, lane_l = wave.tolist(), lane.tolist()
+        keys_l = list(zip(hi_l, lo_l))
+        prefetched: Dict[Tuple[int, int], object] = {}
+        if cfg.keep_key_strings:
+            # Prefetch never-seen keys OUTSIDE the lock (the dict is
+            # a superset of table residency, as in _process). Without
+            # the dictionary there is no never-seen predicate: rely
+            # on the in-lock per-wave probe alone rather than issuing
+            # a blocking store.get for every key of every flush.
+            need = []
+            seen = set()
+            with self._keys_lock:
+                for j, k in enumerate(keys_l):
+                    if k not in self._key_strings and k not in seen:
+                        seen.add(k)
+                        need.append((j, k))
+                self._key_strings.update(zip(keys_l, strs))
+            for j, k in need:
+                snap = self._store_get(req_of(j))
+                if snap is not None:
+                    prefetched[k] = snap
+            self.metrics.observe_store_gets(
+                len(prefetched), len(need) - len(prefetched)
+            )
+            self._maybe_prune_key_strings()
+        # the lazy lane_req dicts: item indices, resolved only on a miss
+        lane_reqs: List[Dict[int, tuple]] = [{} for _ in range(W)]
+        for j, w_ in enumerate(wave_l):
+            lane_reqs[w_][lane_l[j]] = (j, hi_l[j], lo_l[j])
+        return (
+            prefetched, lane_reqs, req_of,
+            list(zip(strs, wave_l, lane_l, hi_l, lo_l)),
+        )
+
+    def _store_columns_postwork(self, fs, wb_entries, out_rows, rows, events):
+        """What a columnar flush with a Store does after its read, before
+        the call returns: write-behind from the per-wave gathered rows
+        (last-op-wins per key, request order), then key-dictionary
+        hygiene — same semantics as the object path's flush."""
+        if wb_entries:
+            with tracing.stage("flush.write_behind", fs, fs.ids):
+                self._store_write_behind_core(wb_entries, out_rows, rows)
+        if self.cfg.keep_key_strings:
+            self._drop_displaced_strings(events)
+
     def _check_columns_replica_split(
         self, cols, now, select, hashes, t_start, fs
     ):
@@ -3442,12 +3470,19 @@ class MeshEngine(EngineBase):
         handles pending bookkeeping internally; the GLOBAL bit stays SET
         — this engine routes_global_internally). Waves always run at the
         full batch width — a narrower width would cold-compile a second
-        SPMD program per shape."""
+        SPMD program per shape.
+
+        With a Store the sharded tier's waves run the Store's per-wave
+        sequence, as on every other path (_execute_waves), and the
+        write-behind is handed the sharded tier's lanes alone: GLOBAL
+        buckets of the replica tier are never persisted (the object
+        path's _store_write_behind says the same: `tag != "s"`)."""
         cfg = self.cfg
-        rt = self._rtier
+        store = self.store
         hi, lo, grp = hashes
         if select is not None and len(select) == 0:
             return None
+        orig_cols = cols
         with tracing.stage("flush.waves", fs, fs.ids):
             asm = self._assemble_replica_split(
                 cols, now, select, hi, lo, grp, fs
@@ -3458,6 +3493,20 @@ class MeshEngine(EngineBase):
         (cols, hi, lo, s_asm, r_asm, ng_idx, g_idx, wave_slices, ops,
          r_ops) = asm
         n = cols.n
+        prefetched: Dict[Tuple[int, int], object] = {}
+        lane_reqs: List[Dict[int, tuple]] = [{} for _ in wave_slices]
+        resolver = wb_entries = None
+        if store is not None and s_asm is not None:
+            with tracing.stage("flush.keydict", fs, fs.ids):
+                idx_map = ng_idx if select is None else select[ng_idx]
+                if select is None and len(g_idx) == 0:
+                    idx_map = None  # the call's own columns, in order
+                prefetched, lane_reqs, resolver, wb_entries = (
+                    self._store_columns_prework(
+                        orig_cols, idx_map, hi[ng_idx], lo[ng_idx],
+                        s_asm[1], s_asm[2], s_asm[4],
+                    )
+                )
 
         _telemetry.set_shape_hint(
             f"{cfg.layout}:mesh-columnar:B{cfg.batch_size}"
@@ -3469,9 +3518,9 @@ class MeshEngine(EngineBase):
         ) as fspan:
             # _execute_waves supplies the lock, the collective guard,
             # page residency (paged mesh), and unified recovery.
-            s_outs, r_outs, _rows, _events = self._execute_waves(
-                wave_slices, ops, [{} for _ in wave_slices], now, {}, fs,
-                r_ops=r_ops,
+            s_outs, r_outs, wave_rows_host, events = self._execute_waves(
+                wave_slices, ops, lane_reqs, now, prefetched, fs,
+                req_resolver=resolver, r_ops=r_ops,
             )
             self._left_host_stage(fs)  # launched: the next batch's turn
 
@@ -3485,12 +3534,16 @@ class MeshEngine(EngineBase):
             with tracing.stage(
                 "flush.readback", fs, fs.ids
             ), _transfer.account(self.metrics, "d2h", "serve") as tx:
-                for outs, asm, idx in (
-                    (s_outs, s_asm, ng_idx), (r_outs, r_asm, g_idx),
+                s_rows = None  # the sharded tier's, for the write-behind
+                for outs, asm, idx, with_store in (
+                    (s_outs, s_asm, ng_idx, store is not None),
+                    (r_outs, r_asm, g_idx, False),
                 ):
                     if asm is None:
                         continue
-                    out_rows, totals = _read_waves(outs, fs)
+                    out_rows, totals = _read_waves(outs, fs, with_store)
+                    if outs is s_outs:
+                        s_rows = out_rows
                     tx.add(out_rows)
                     (status[idx], r_limit[idx], remaining[idx],
                      reset_time[idx]) = _demux_lanes(out_rows, asm[3])
@@ -3501,6 +3554,10 @@ class MeshEngine(EngineBase):
             self.metrics.busy_exit()  # entered in _execute_waves
         dev_s = time.perf_counter() - t_dev
         with tracing.stage("flush.post", fs, fs.ids):
+            if store is not None:
+                self._store_columns_postwork(
+                    fs, wb_entries, s_rows, wave_rows_host, events
+                )
             dur = time.perf_counter() - t_start
             flush_trace_id = tracing.trace_id_of(fspan)
             em = self.metrics
@@ -3511,7 +3568,8 @@ class MeshEngine(EngineBase):
                 "columnar", n, waves_total, dur, dev_s,
                 flush_trace_id if cfg.exemplars else "",
                 collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
-                launches=fs.launches, calls=fs.calls,
+                launches=fs.launches, programs=fs.programs,
+                crossings=fs.crossings, calls=fs.calls,
             )
             em.observe_stage("assemble", t_dev - t_start)
             em.observe_stage("device_sync", dev_s)
@@ -3939,6 +3997,7 @@ class MeshEngine(EngineBase):
         # the pre-reset snapshot via a late batched on_change), and a
         # RESET followed by a new hit must end as the new snapshot.
         ops: Dict[str, Optional[ItemSnapshot]] = {}
+        skipped = []
         for i, (key, w, lane, hi, lo) in enumerate(entries):
             # Only a token-bucket RESET_REMAINING free deletes the
             # persisted entry (reference algorithms.go:78-90); the
@@ -3948,8 +4007,12 @@ class MeshEngine(EngineBase):
                 ops[key] = None
                 continue
             if not v["used"][i] or v["key_hi"][i] != hi or v["key_lo"][i] != lo:
-                # Shouldn't happen with per-wave gathers; skip defensively
-                # without touching the persisted entry.
+                # The gathered row is not the one this lane's decide
+                # wrote: nothing is known of the bucket, so the
+                # persisted entry is left as it is, and the lane is
+                # counted (gubernator_store_rows_skipped: 0 wherever
+                # the gather reads the decide's own slots).
+                skipped.append(i)
                 continue
             ops[key] = ItemSnapshot(
                 key=key,
@@ -3963,6 +4026,8 @@ class MeshEngine(EngineBase):
                 invalid_at=v["invalid_at"][i],
                 burst=v["burst"][i],
             )
+        if skipped:
+            self._note_skipped_rows(entries, skipped, v)
         changes = [s for s in ops.values() if s is not None]
         # Store failures here must NEVER propagate: write-behind runs
         # AFTER the table commit, and the columnar edge's caller treats a
@@ -3987,6 +4052,27 @@ class MeshEngine(EngineBase):
             logging.getLogger(__name__).exception(
                 "store write-behind failed (%d changes dropped)", len(changes)
             )
+
+    def _note_skipped_rows(self, entries, skipped, v) -> None:
+        """Lanes of a flush whose acknowledged change did not reach the
+        Store because their gathered row was unused or held another key:
+        counted, and named in one log line a minute at most."""
+        self.metrics.store_rows_skipped.inc(len(skipped))
+        now = time.monotonic()
+        if now - self._skipped_logged < 60.0:
+            return
+        self._skipped_logged = now
+        import logging
+
+        i = skipped[0]
+        key, w, lane, hi, lo = entries[i]
+        logging.getLogger(__name__).warning(
+            "store write-behind skipped %d of %d changes of a flush: wave "
+            "%d lane %d decided key %r (%d, %d) but its gathered row "
+            "holds used=%s (%d, %d); the Store keeps its old entry",
+            len(skipped), len(entries), w, lane, key, hi, lo,
+            v["used"][i], v["key_hi"][i], v["key_lo"][i],
+        )
 
     def _maybe_prune_key_strings(self) -> None:
         """Bound host memory: under key churn the hash->string dict keeps

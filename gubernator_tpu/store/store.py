@@ -218,7 +218,17 @@ def load_engine(engine, loader: Loader) -> int:
 
 
 def attach_store(engine, store: Store) -> None:
-    """Enable read-through + write-behind on a DeviceEngine.
+    """Enable read-through + write-behind on an engine: a DeviceEngine
+    on one chip, or the mesh engines (MeshEngine, IciEngine) over an
+    owner-sharded table. The contract is the same on both: every
+    acknowledged change of an ordinary key's bucket is handed to the
+    Store before the next flush's, by the sequence's row gather, which
+    on a mesh reads each lane's row at the chip that owns it; a key the
+    table evicted is read back into its owner's shard. GLOBAL buckets of
+    an IciEngine's replica tier are not persisted
+    (docs/architecture.md "A Store on the sharded table").
+    gubernator_store_rows_skipped counts a lane whose row the gather
+    did not find, and stays 0.
 
     Read-through correctness is driven by the device-table residency
     probe and write-behind keys come from each request, so the host

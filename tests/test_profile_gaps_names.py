@@ -92,6 +92,32 @@ def test_every_name_the_rule_can_give_is_printed():
                                          "loop.lag"}
 
 
+def test_a_store_waves_gap_goes_to_its_stage_and_its_programs_are_named():
+    """ISSUE 41: inside `dispatch` a Store wave's `readthrough` and
+    `store_rows` take the gap first, and the sequence's device programs
+    are counted under the names a capture gives them, on one chip and
+    on a mesh, with the mesh programs' named phases beside them."""
+    spans = [
+        (0.0, 0.0, "rpc.begin", 1, 0), (1.0, 9.0, "call.engine", 1, 0),
+        (2.0, 8.0, "flush.dispatch", 1, 5),
+        (2.0, 4.0, "flush.readthrough", 1, 5),
+        (5.0, 7.0, "flush.store_rows", 1, 5),
+    ]
+    got = profile_gaps.attribute_plane([(8.0, 9.0)], spans, 2.0, 9.0)
+    assert got == pytest.approx({
+        "readthrough": 2.0, "store_rows": 2.0, "dispatch": 1.0 + 1.0})
+    named = [("jit_probe_exists_fn(1)", 2e-5), ("jit_decide_fn(2)", 2e-4),
+             ("jit_gather_rows_fn(3)", 3e-5), ("jit_gather_rows_fused(4)", 1e-5),
+             ("jit_sync_fn(5)", 1e-3)]
+    assert profile_gaps.store_programs(named) == {
+        "probe_exists": (1, 2e-5), "decide": (1, 2e-4),
+        "gather_rows": (2, pytest.approx(4e-5))}
+    assert profile_gaps.STORE_PHASES["gather_rows"] == (
+        "owner_mask", "store_rows_local", "psum_rows")
+    assert profile_gaps.STORE_PHASES["probe_exists"] == (
+        "owner_mask", "probe_local", "psum_probe")
+
+
 # ---- a capture of a serving daemon ------------------------------------------
 
 

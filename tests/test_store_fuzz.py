@@ -15,14 +15,36 @@ from gubernator_tpu.store import MemoryStore, attach_store
 NOW = 1_753_700_000_000
 
 
-@pytest.mark.parametrize("seed", [7, 8])
-def test_engine_with_store_matches_oracle(seed):
-    rng = random.Random(seed)
-    clock = {"now": NOW}
-    eng = DeviceEngine(
-        EngineConfig(num_groups=1 << 10, batch_size=32, batch_wait_s=0.001),
+def _engine(clock, chips):
+    """The one-chip engine, or with `chips` the four-chip daemon's: the
+    same Store sequence over an owner-sharded table (ISSUE 41)."""
+    if not chips:
+        return DeviceEngine(
+            EngineConfig(num_groups=1 << 10, batch_size=32, batch_wait_s=0.001),
+            now_fn=lambda: clock["now"],
+        )
+    import jax
+
+    from gubernator_tpu.runtime.ici_engine import IciEngine, IciEngineConfig
+
+    return IciEngine(
+        IciEngineConfig(
+            devices=jax.devices()[:chips], num_groups=1 << 10,
+            num_slots=1 << 11, batch_size=32, batch_wait_s=0.001,
+            sync_wait_s=3600,
+        ),
         now_fn=lambda: clock["now"],
     )
+
+
+@pytest.mark.parametrize("seed,chips", [
+    pytest.param(7, 0, id="7"), pytest.param(8, 0, id="8"),
+    pytest.param(7, 4, id="7-four-devices"),
+])
+def test_engine_with_store_matches_oracle(seed, chips):
+    rng = random.Random(seed)
+    clock = {"now": NOW}
+    eng = _engine(clock, chips)
     store = MemoryStore()
     attach_store(eng, store)
     oracle = OracleEngine()
@@ -57,10 +79,7 @@ def test_engine_with_store_matches_oracle(seed):
         # Restart: a fresh engine over the SAME store must continue each
         # key exactly where the oracle's state says (read-through).
         eng.close()
-        eng2 = DeviceEngine(
-            EngineConfig(num_groups=1 << 10, batch_size=32, batch_wait_s=0.001),
-            now_fn=lambda: clock["now"],
-        )
+        eng2 = _engine(clock, chips)
         attach_store(eng2, store)
         try:
             for key in keys:

@@ -248,14 +248,11 @@ def test_gather_fed_from_the_decide_output_on_the_device(kind, wide):
     np.testing.assert_array_equal(fed, sliced)
     rows = wide_rows(fed)
     assert_same_struct(rows, rows_reference(image(ld.K, table), slots))
-    if kind.startswith("mesh"):
-        # A sharded decide's slot column is the psum of shard-local
-        # indices (every shard adds its local N for a lane it does not
-        # own), not a slot of the whole table: a Store on a sharded
-        # table is not a supported attachment (PERF.md §7). The program
-        # still gathers what the column says, from the device.
-        return
-    # the lanes' own rows, as the decide wrote them; padding reads zeros
+    # the lanes' own rows, as the decide wrote them; padding reads zeros.
+    # On a sharded table too: the slot column names rows of the whole
+    # table (each owner rebases its own before the psum), and the gather
+    # reads each from the shard that owns it.
+    assert (slots[:lanes] < N).all() and (slots[lanes:] == N).all()
     assert rows.used[:lanes].all() and not rows.used[lanes:].any()
     np.testing.assert_array_equal(rows.key_hi, batch.key_hi)
     np.testing.assert_array_equal(rows.key_lo, batch.key_lo)

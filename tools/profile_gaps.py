@@ -21,7 +21,9 @@ answer. The rule here:
   leader of a merged columnar flush waiting for its turn: the flush
   before it had not launched yet), `hash`, `waves`, `keydict`,
   `lock_wait` and `dispatch`, and, before `rpc.begin`, "call not yet in
-  the server";
+  the server"; with a Store the two stages inside `dispatch` come first:
+  `readthrough` (the probe's launch and read, `Store.get`, the inject)
+  and `store_rows` (the row gather's launch and the wave's two reads);
 - what none of these covers goes to another flush's `readback` or
   `post` where one was open (a pipelined pump waits for the flush before
   last to be read before it launches the next);
@@ -35,7 +37,13 @@ answer. The rule here:
   launch, a wait no span times, a gap that ends with the capture).
 
 Prints seconds per name and per device plane; the names add up to the
-plane's idle time. Reads the file with jax.profiler.ProfileData on the
+plane's idle time. For the device programs of a Store's per-wave
+sequence it also prints, per plane, their executions and time, and the
+`jax.named_scope` phases inside each (STORE_PHASES: on a mesh
+`owner_mask`, `probe_local`, `psum_probe`; `owner_mask`, `decide`,
+`global_slot`, `psum_merge`; `owner_mask`, `store_rows_local`,
+`psum_rows`), which `tools/trace_phases.py --program <name> --phases
+<list>` splits a capture by. Reads the file with jax.profiler.ProfileData on the
 CPU backend and never touches a chip.
 """
 
@@ -54,8 +62,8 @@ OTHER = {"flush.readback": "another flush: readback",
          "flush.post": "another flush: post"}
 # Innermost first: where two of a call's intervals overlap (a pump
 # flush beside its call's own thread) the gap goes to the earlier name.
-STAGES = ("dispatch", "lock_wait", "keydict", "waves", "hash", "join",
-          "queue", "route", "parse", "executor_wait")
+STAGES = ("readthrough", "store_rows", "dispatch", "lock_wait", "keydict",
+          "waves", "hash", "join", "queue", "route", "parse", "executor_wait")
 # What no call owns, under its span's own name, in the order a gap
 # goes to them once the launching flush's call and the other flushes
 # have had theirs.
@@ -69,8 +77,19 @@ PREFIXES = ("rpc.", "call.", "flush.", "tick.", "complete.", "loop.",
 RANK = {name: i for i, name in enumerate(
     STAGES + (NOT_YET,) + tuple(OTHER.values()) + GLOBAL)}
 ORDER = (NOT_YET, "executor_wait", "parse", "route", "queue", "join",
-         "hash", "waves", "keydict", "lock_wait", "dispatch", *OTHER.values(),
-         *GLOBAL, UNATTRIBUTED)
+         "hash", "waves", "keydict", "lock_wait", "dispatch", "readthrough",
+         "store_rows", *OTHER.values(), *GLOBAL, UNATTRIBUTED)
+# The device programs of a Store's per-wave sequence, by a part of their
+# name in a capture (one chip: jit_probe_exists_fused, jit_decide_fused,
+# jit_gather_rows_fused; a mesh: jit_probe_exists_fn, jit_decide_fn,
+# jit_gather_rows_fn), and the named phases inside each on a mesh
+# (parallel/mesh.py).
+STORE_PHASES = {
+    "probe_exists": ("owner_mask", "probe_local", "psum_probe"),
+    "decide": ("owner_mask", "decide", "global_slot", "psum_merge"),
+    "gather_rows": ("owner_mask", "store_rows_local", "psum_rows"),
+    "inject": (),
+}
 
 
 def find_trace(path: str) -> str:
@@ -166,8 +185,23 @@ def attribute_plane(programs: list, spans: list, t_lo: float, t_hi: float) -> di
     return totals
 
 
-def read_trace(path: str) -> tuple:
-    """({device plane: [(start, end)] of its programs}, host spans)."""
+def store_programs(named: list) -> dict:
+    """{program of STORE_PHASES: (executions, seconds)} over one plane's
+    [(program name, seconds)]."""
+    out: dict = {}
+    for name, secs in named:
+        for part in STORE_PHASES:
+            if part in name:
+                n, s = out.get(part, (0, 0.0))
+                out[part] = (n + 1, s + secs)
+                break
+    return out
+
+
+def read_trace(path: str, names: dict = None) -> tuple:
+    """({device plane: [(start, end)] of its programs}, host spans);
+    `names`, if given, is filled with {device plane: [(program name,
+    seconds)]}."""
     from jax.profiler import ProfileData
 
     planes: dict = {}
@@ -181,6 +215,9 @@ def read_trace(path: str) -> tuple:
                 planes[plane.name] = [
                     (e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9)
                     for e in line.events if e.duration_ns > 0]
+                if names is not None and line.name == MODULES_LINE:
+                    names[plane.name] = [(e.name, e.duration_ns * 1e-9)
+                                         for e in line.events]
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 for e in line.events:
@@ -200,7 +237,8 @@ def main(argv: list) -> int:
     if len(argv) != 2:
         print(__doc__.split("\n\n")[1], file=sys.stderr)
         return 2
-    planes, spans = read_trace(find_trace(argv[1]))
+    programs: dict = {}
+    planes, spans = read_trace(find_trace(argv[1]), programs)
     names = sorted({n for _, _, n, _, _ in spans})
     print(f"host spans: {len(spans)} of {len(names)} names: {' '.join(names)}")
     if not planes:
@@ -218,6 +256,10 @@ def main(argv: list) -> int:
             if stage in totals:
                 print(f"  {stage:<28} {totals[stage]:>10.6f} s "
                       f"{100 * totals[stage] / idle:>6.2f} % of idle")
+        for part, (n, secs) in sorted(
+                store_programs(programs.get(name, [])).items()):
+            print(f"  program {part:<20} {n:>6} x {1e6 * secs / n:>8.1f} us"
+                  f"  phases: {' '.join(STORE_PHASES[part]) or '-'}")
     return 0
 
 
