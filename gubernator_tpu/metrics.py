@@ -713,12 +713,14 @@ def engine_store_wave_crossings() -> _BareCounter:
         "gubernator_engine_store_wave_crossings",
         "Arrays that crossed the host-device boundary under the engine "
         "lock in the Store's per-wave sequence, by direction: d2h the "
-        "probe's answer, the wave's output vector and its packed rows "
-        "(three a wave), and the two key columns an inject displaced; "
-        "h2d the fields of an inject's operand (only a wave with a miss "
-        "the Store answered uploads anything: the probe and the row "
-        "gather read what is on the device). The operand's own upload, "
-        "before the lock, is gubernator_engine_wave_transfers'. 0 "
+        "probe's answer (one a wave), the two key columns an inject "
+        "displaced, and an earlier wave's packed rows where a key was "
+        "displaced between its own waves; h2d the fields of an inject's "
+        "operand (only a wave with a miss the Store answered uploads "
+        "anything: the probe and the row gather read what is on the "
+        "device). The wave's output vector and its packed rows are "
+        "read after the lock is released, and the operand is uploaded "
+        "before it: both are gubernator_engine_wave_transfers'. 0 "
         "without a Store.",
         ["direction"],
     )
@@ -765,6 +767,15 @@ def engine_store_counters() -> dict:
             "to the Store because the row gathered for them was unused "
             "or held another key. 0 wherever the row gather reads the "
             "slots the decide wrote, on one device and on a mesh.",
+        ),
+        "store_handover_waits": _BareCounter(
+            "gubernator_store_handover_waits",
+            "Flushes that had to wait for the flush before them to "
+            "finish its hand-over to the Store (the reads of its waves' "
+            "outputs and rows and its write-behind, after the engine "
+            "lock) before they began their own, or before a Store.get "
+            "under the engine lock. The wait is under the engine lock: "
+            "0 while a hand-over is shorter than the next flush's hold.",
         ),
     }
 

@@ -494,10 +494,9 @@ class _FlushTicket:
     __slots__ = (
         "items",        # [(req, future-like)] — the flush's intake
         "placements",   # per-item routing (engine-specific)
-        "outs",         # per-launch (output, waves): device arrays; host with a store
+        "outs",         # per-launch (output, waves): device arrays
         "r_outs",       # ici replica-tier (output vector, 1) per wave
-        "rows",         # store path: materialized per-wave gathered rows
-        "events",       # store path: ('d'|'i', key) displacement events
+        "store_waves",  # store path: the flush's _StoreWaves (None without)
         "served",       # items answered by this flush (excludes carry)
         "carry_n",      # items deferred to the next flush (wave cap)
         "waves",        # wave count
@@ -526,20 +525,117 @@ def _read_waves(outs, fs: FlushStages, with_store: bool = False):
     (output, waves): one wave's vector, or the (depth, L) array of a
     stacked run whose first `waves` rows are its waves'. Returns ([rows
     (R, B) per wave, indexed by OUT_*], [hits, misses,
-    unexpired_evictions, over_limit] summed over the waves). A wave the
-    store path already read under the lock arrives as its host vector
-    and is not read again."""
+    unexpired_evictions, over_limit] summed over the waves). With a
+    Store the flush reads through _StoreWaves.read, which adds the
+    waves' packed rows to this."""
     rows = []
     totals = np.zeros(OUT_TOTALS, np.int64)
     for o, n in outs:
-        if not isinstance(o, np.ndarray):
-            o = np.asarray(o)  # guberlint: allow-host-sync -- completion-stage flush-boundary readback
-            fs.d2h += 1
+        o = np.asarray(o)  # guberlint: allow-host-sync -- completion-stage flush-boundary readback
+        fs.d2h += 1
         for vec in (o,) if o.ndim == 1 else o[:n]:
             r, t = split_output(vec, with_store)
             rows.append(r)
             totals += t
     return rows, totals.tolist()
+
+
+class _StoreWaves:
+    """What a flush with a Store carries out of the engine lock, and its
+    turn at the Store (docs/persistence.md "the per-wave sequence").
+
+    Under the lock a wave launches its programs, starts the host copies
+    of its output vector and of its packed rows (K.gather_rows) and
+    reads only the probe's answer: `rows[w]` is wave w's packed rows
+    still on the device and `pre[w]` the events its read-through made
+    (an inject's displacements and inserts). read(), in the flush's
+    `flush.readback` stage after the release, reads both arrays of
+    every wave and builds `events` in the order the waves ran. A later
+    wave that needs an earlier one's rows under the lock (a key
+    displaced between its own waves, _wave_readthrough's freshness rule
+    1) reads them then, through host_rows.
+
+    The hand-over: `_lock` is the engine's hand-over lock. take() is
+    called under the engine lock, as the last thing before its release
+    (or before the flush's first Store.get under the lock), and
+    release() after the flush's write-behind, so the Store is handed
+    the flushes in the order they held the engine lock and a Store.get
+    under the lock sees every earlier flush's hand-over. Whoever holds
+    it needs no engine lock to finish, so the two cannot deadlock.
+    release() may be called again: every way out of a flush calls it."""
+
+    __slots__ = (
+        "lane_reqs", "rows", "pre", "events", "nbytes", "_lock", "_waits",
+        "_held",
+    )
+
+    def __init__(self, lane_reqs, lock, waits):
+        self.lane_reqs = lane_reqs
+        self.rows: List[object] = []
+        self.pre: List[list] = []
+        self.events: List[Tuple[str, Tuple[int, int]]] = []  # ('d'|'i', key)
+        self.nbytes = 0  # of the packed rows read so far
+        self._lock = lock
+        self._waits = waits
+        self._held = False
+
+    def take(self) -> None:
+        if self._held:
+            return
+        if not self._lock.acquire(blocking=False):
+            # the flush before this one is still reading or writing
+            # behind (gubernator_store_handover_waits)
+            self._waits.inc()
+            self._lock.acquire()
+        self._held = True
+
+    def release(self) -> None:
+        if self._held:
+            self._held = False
+            self._lock.release()
+
+    def host_rows(self, w: int) -> Tuple[SlotTable, bool]:
+        """(wave w's gathered rows as the wide struct on the host,
+        whether this call was the one that read them)."""
+        r = self.rows[w]
+        if isinstance(r, SlotTable):
+            return r, False
+        packed = np.asarray(r)  # guberlint: allow-host-sync -- store path: a wave's packed rows, one read; in flush.readback, or under the lock only for a key displaced between its own waves
+        self.nbytes += packed.nbytes
+        r = self.rows[w] = wide_rows(packed)
+        return r, True
+
+    def read(self, outs, fs: FlushStages):
+        """_read_waves for a flush with a Store, after the release: the
+        output vector and the packed rows of every wave (their copies
+        were started under the lock), then `events` per wave as the
+        sequence made them: the inject's displacements and inserts,
+        the decide's evictions, the keys served
+        (_drop_displaced_strings acts on a key's LAST event). The table
+        has committed by now: a failed read raises TableCommittedError,
+        so that nobody retries the flush through another path, and
+        gives up the flush's turn at the Store."""
+        try:
+            out_rows, totals = _read_waves(outs, fs, True)
+            events = self.events
+            for w, o_rows in enumerate(out_rows):
+                self.host_rows(w)
+                events += self.pre[w]
+                ehi = o_rows[OUT_EVICTED_HI]
+                elo = o_rows[OUT_EVICTED_LO]
+                gone = (ehi != 0) | (elo != 0)
+                events += [
+                    ("d", k)
+                    for k in zip(ehi[gone].tolist(), elo[gone].tolist())
+                ]
+                for entry in self.lane_reqs[w].values():
+                    events.append(("i", (entry[1], entry[2])))
+        except BaseException as e:
+            self.release()
+            if isinstance(e, Exception):
+                raise TableCommittedError(str(e)) from e
+            raise
+        return out_rows, totals
 
 
 class _Joined:
@@ -801,6 +897,11 @@ class EngineBase:
         finally:
             tracing.end_span(t.span, error=err)
             t.span = None
+            sw = getattr(t, "store_waves", None)
+            if sw is not None:
+                # a Store flush's turn at the Store ends here at the
+                # latest, whatever _complete raised before its own release
+                sw.release()
 
     def _completion_loop(self) -> None:
         """Completion stage: sync each in-flight ticket in FIFO dispatch
@@ -1743,6 +1844,10 @@ class MeshEngine(EngineBase):
         self._lock = lockorder.make_lock("engine.table")  # guards table swap (load/restore)
         # guards the host key dictionaries (pump + executor threads)
         self._keys_lock = lockorder.make_lock("engine.keys")
+        # A flush's turn at the Store (_StoreWaves): taken under
+        # self._lock as the flush leaves it, held over its deferred
+        # reads and its write-behind, never the other way round.
+        self._handover = lockorder.make_lock("engine.handover")
         # Standby replication dirty-key harvest (parallel/standby.py):
         # key string -> hits dirtied since the last drain, fed by the
         # flush completion paths alongside the hotkey aggregation — no
@@ -2870,7 +2975,7 @@ class MeshEngine(EngineBase):
             with _telemetry.serving_scope(self.metrics), tracing.use_span_ctx(
                 fspan
             ):
-                outs, r_outs, wave_rows_host, events = self._execute_waves(
+                outs, r_outs, sw = self._execute_waves(
                     waves, ops, wave_lane_req, now, prefetched, fs,
                     r_ops=r_ops,
                 )
@@ -2879,8 +2984,7 @@ class MeshEngine(EngineBase):
             raise
         return carry, _FlushTicket(
             items=items, placements=placements, outs=outs,
-            r_outs=r_outs,
-            rows=wave_rows_host, events=events,
+            r_outs=r_outs, store_waves=sw,
             served=len(items) - len(carry), carry_n=len(carry),
             waves=n_waves,
             widths=widths,
@@ -2896,15 +3000,18 @@ class MeshEngine(EngineBase):
         resolve the futures — in FIFO dispatch order when pipelined."""
         cfg = self.cfg
         fs = t.stages
+        sw = t.store_waves
         t_c0 = time.perf_counter()
         # The np.asarray syncs live in _read_waves (the sanctioned
-        # completion-stage readback: one read a wave). Sharded ("s") and
-        # replica ("r") outputs materialize side by side; placements tag
-        # which list a lane demuxes from.
+        # completion-stage readback: one read a wave; with a Store the
+        # wave's packed rows too). Sharded ("s") and replica ("r")
+        # outputs materialize side by side; placements tag which list a
+        # lane demuxes from.
         try:
             with tracing.stage("flush.readback", fs, fs.ids):
-                s_rows, s_tot = _read_waves(
-                    t.outs, fs, self.store is not None
+                s_rows, s_tot = (
+                    _read_waves(t.outs, fs) if sw is None
+                    else sw.read(t.outs, fs)
                 )
                 r_rows, r_tot = _read_waves(t.r_outs, fs)
                 host = {"s": s_rows, "r": r_rows}
@@ -2916,12 +3023,11 @@ class MeshEngine(EngineBase):
             # Transfer ledger: the serve-path d2h readback. Duration is the
             # blocking sync (copy + any pending compute it waited on).
             _transfer.record(
-                self.metrics, "d2h", "serve", _transfer.nbytes(host),
+                self.metrics, "d2h", "serve",
+                _transfer.nbytes(host) + (sw.nbytes if sw is not None else 0),
                 t_sync - t_c0,
             )
 
-            if cfg.keep_key_strings:
-                self._drop_displaced_strings(t.events)
             tot = [a + b for a, b in zip(s_tot, r_tot)]
             dur = time.perf_counter() - t.t0
             em = self.metrics
@@ -2951,11 +3057,20 @@ class MeshEngine(EngineBase):
             # Write-behind BEFORE resolving futures, so a caller that observed
             # its response can rely on the store reflecting it (the reference's
             # OnChange runs within the request, algorithms.go:149-153).
-            if self.store is not None:
+            if sw is not None:
                 with tracing.stage("flush.write_behind", fs, fs.ids):
                     self._store_write_behind(
-                        t.items, t.placements, s_rows, t.rows
+                        t.items, t.placements, s_rows, sw.rows
                     )
+                # the next flush's turn at the Store (on a failure:
+                # _complete_ticket)
+                sw.release()
+                # Hygiene after the write-behind, as on the columnar
+                # path: a key that loses its string is prefetched from
+                # the Store by its next flush, outside both locks, and
+                # must find this flush's change there.
+                if cfg.keep_key_strings:
+                    self._drop_displaced_strings(sw.events)
 
             # GUBER_STAGE_METADATA: the flush-level stage times every served
             # item shares, built once; each response appends its own queue
@@ -3324,7 +3439,7 @@ class MeshEngine(EngineBase):
             "engine.flush", level="DEBUG", path="columnar", items=n, waves=W,
             layout=cfg.layout,
         ) as fspan:
-            outs, _r_outs, wave_rows_host, events = self._execute_waves(
+            outs, _r_outs, sw = self._execute_waves(
                 wave_slices, ops, lane_reqs, now, prefetched, fs,
                 req_resolver=resolver,
             )
@@ -3334,9 +3449,11 @@ class MeshEngine(EngineBase):
                 with tracing.stage(
                     "flush.readback", fs, fs.ids
                 ), _transfer.account(self.metrics, "d2h", "serve") as tx:
-                    out_rows, totals = _read_waves(
-                        outs, fs, store is not None
-                    )
+                    if sw is None:
+                        out_rows, totals = _read_waves(outs, fs)
+                    else:
+                        out_rows, totals = sw.read(outs, fs)
+                        tx.add(sw.nbytes)
                     tx.add(out_rows)
             finally:
                 self.metrics.busy_exit()  # entered in _execute_waves
@@ -3344,10 +3461,8 @@ class MeshEngine(EngineBase):
         flush_trace_id = tracing.trace_id_of(fspan)
 
         with tracing.stage("flush.post", fs, fs.ids):
-            if store is not None:
-                self._store_columns_postwork(
-                    fs, wb_entries, out_rows, wave_rows_host, events
-                )
+            if sw is not None:
+                self._store_columns_postwork(fs, wb_entries, out_rows, sw)
 
             tot_hits, tot_miss, tot_evic, tot_over = totals
             dur = time.perf_counter() - t_start
@@ -3448,16 +3563,22 @@ class MeshEngine(EngineBase):
             list(zip(strs, wave_l, lane_l, hi_l, lo_l)),
         )
 
-    def _store_columns_postwork(self, fs, wb_entries, out_rows, rows, events):
+    def _store_columns_postwork(self, fs, wb_entries, out_rows, sw):
         """What a columnar flush with a Store does after its read, before
         the call returns: write-behind from the per-wave gathered rows
-        (last-op-wins per key, request order), then key-dictionary
-        hygiene — same semantics as the object path's flush."""
-        if wb_entries:
-            with tracing.stage("flush.write_behind", fs, fs.ids):
-                self._store_write_behind_core(wb_entries, out_rows, rows)
+        (last-op-wins per key, request order), the end of its turn at
+        the Store, then key-dictionary hygiene — same semantics as the
+        object path's flush."""
+        try:
+            if wb_entries:
+                with tracing.stage("flush.write_behind", fs, fs.ids):
+                    self._store_write_behind_core(
+                        wb_entries, out_rows, sw.rows
+                    )
+        finally:
+            sw.release()
         if self.cfg.keep_key_strings:
-            self._drop_displaced_strings(events)
+            self._drop_displaced_strings(sw.events)
 
     def _check_columns_replica_split(
         self, cols, now, select, hashes, t_start, fs
@@ -3518,7 +3639,7 @@ class MeshEngine(EngineBase):
         ) as fspan:
             # _execute_waves supplies the lock, the collective guard,
             # page residency (paged mesh), and unified recovery.
-            s_outs, r_outs, wave_rows_host, events = self._execute_waves(
+            s_outs, r_outs, sw = self._execute_waves(
                 wave_slices, ops, lane_reqs, now, prefetched, fs,
                 req_resolver=resolver, r_ops=r_ops,
             )
@@ -3535,29 +3656,33 @@ class MeshEngine(EngineBase):
                 "flush.readback", fs, fs.ids
             ), _transfer.account(self.metrics, "d2h", "serve") as tx:
                 s_rows = None  # the sharded tier's, for the write-behind
-                for outs, asm, idx, with_store in (
-                    (s_outs, s_asm, ng_idx, store is not None),
-                    (r_outs, r_asm, g_idx, False),
+                for outs, asm, idx, tier_sw in (
+                    (s_outs, s_asm, ng_idx, sw), (r_outs, r_asm, g_idx, None),
                 ):
                     if asm is None:
                         continue
-                    out_rows, totals = _read_waves(outs, fs, with_store)
-                    if outs is s_outs:
+                    if tier_sw is None:
+                        out_rows, totals = _read_waves(outs, fs)
+                    else:
+                        out_rows, totals = tier_sw.read(outs, fs)
                         s_rows = out_rows
+                        tx.add(tier_sw.nbytes)
                     tx.add(out_rows)
                     (status[idx], r_limit[idx], remaining[idx],
                      reset_time[idx]) = _demux_lanes(out_rows, asm[3])
                     waves_total += asm[4]
                     for j, v in enumerate(totals):
                         tots[j] += v
+        except BaseException:
+            if sw is not None:
+                sw.release()  # the replica tier's read failed after sw's
+            raise
         finally:
             self.metrics.busy_exit()  # entered in _execute_waves
         dev_s = time.perf_counter() - t_dev
         with tracing.stage("flush.post", fs, fs.ids):
-            if store is not None:
-                self._store_columns_postwork(
-                    fs, wb_entries, s_rows, wave_rows_host, events
-                )
+            if sw is not None:
+                self._store_columns_postwork(fs, wb_entries, s_rows, sw)
             dur = time.perf_counter() - t_start
             flush_trace_id = tracing.trace_id_of(fspan)
             em = self.metrics
@@ -3671,9 +3796,12 @@ class MeshEngine(EngineBase):
         r_ops: the uploaded GLOBAL replica waves (replica topologies
         only; their per-lane home device rides the operand), decided
         against the replica tier after the sharded waves, wave by
-        wave. Returns (outs, r_outs, wave_rows_host, events): one
-        (output, waves) a launch for _read_waves, still on the device
-        unless a Store made the flush read it here.
+        wave. Returns (outs, r_outs, store_waves): one (output, waves)
+        a launch for _read_waves, still on the device, and with a Store
+        the flush's _StoreWaves (None without one): its waves' packed
+        rows, on the device too, and the hand-over lock, which the
+        caller releases after its write-behind (_StoreWaves.read in its
+        `flush.readback`, then _store_columns_postwork or _complete).
 
         `fs` (FlushStages) takes the two stages every path shares:
         `flush.lock_wait` (waiting for the engine lock and the
@@ -3700,9 +3828,12 @@ class MeshEngine(EngineBase):
         rt = self._rtier
         outs: List[object] = []
         r_outs: List[object] = []
-        wave_rows_host: List[object] = []  # materialized post-decide rows
         served: Dict[Tuple[int, int], Tuple[int, int]] = {}  # key->(w,lane)
-        events: List[Tuple[str, Tuple[int, int]]] = []  # ('d'|'i', key)
+        sw = None
+        if store is not None:
+            sw = _StoreWaves(
+                lane_reqs, self._handover, self.metrics.store_handover_waits
+            )
         if self.topo.n_dev > 1:
             # Shard-skew attribution (docs/monitoring.md "SLOs & burn
             # rates"): host-side bincount over the waves' group arrays
@@ -3748,49 +3879,32 @@ class MeshEngine(EngineBase):
                         with tracing.stage("flush.readthrough", fs, fs.ids):
                             table = self._wave_readthrough(
                                 table, op, wo.batch, lane_reqs[w], now,
-                                prefetched, served, wave_rows_host, events,
-                                fs, req_resolver=req_resolver,
+                                prefetched, served, sw, fs,
+                                req_resolver=req_resolver,
                             )
                     table, out = self.K.decide_packed(
                         table, op, cfg.ways, store is not None
                     )
                     if store is not None:
-                        # The store's sequence is synchronous per wave.
                         # The row gather (a program of its own,
                         # K.gather_rows) takes its slot column from the
                         # decide's output on the device, so the two are
-                        # launched back to back and only then read: the
-                        # wave's output vector and its packed rows, two
-                        # arrays, nothing uploaded. Both host copies are
-                        # started before the first blocking read, so the
-                        # second rides the first's wait (3,347 -> 3,925
-                        # decisions/s on store-1m.calls100, PERF.md §6
-                        # PR 37).
+                        # launched back to back, nothing uploaded. The
+                        # wave's output vector and its packed rows are
+                        # not read here: nothing under the lock needs
+                        # them but a later wave whose key was displaced
+                        # since (sw.host_rows). Their host copies start
+                        # now and the flush reads them after the
+                        # release (_StoreWaves.read).
                         fs.programs["decide"] += 1
                         fs.programs["gather_rows"] += 1
-                        with tracing.stage(
-                            "flush.store_rows", fs, fs.ids
-                        ), _transfer.account(
-                            self.metrics, "d2h", "serve"
-                        ) as tx:
+                        with tracing.stage("flush.store_rows", fs, fs.ids):
                             rows = self.K.gather_rows(table, out, True)
                             out.copy_to_host_async()
                             rows.copy_to_host_async()
-                            out = np.asarray(out)  # guberlint: allow-host-sync -- store path: the wave's own output vector, one read, synchronous by design
-                            rows = np.asarray(rows)  # guberlint: allow-host-sync -- store path: the wave's packed rows, one read; a later wave's read-through and the write-behind need them on the host
-                            fs.d2h += 1
-                            fs.crossings[1] += 2
-                            tx.add((out, rows))
-                        rows_h = wide_rows(rows)
-                        o_rows, _tot = split_output(out, True)
-                        ehi = o_rows[OUT_EVICTED_HI]
-                        elo = o_rows[OUT_EVICTED_LO]
-                        wave_rows_host.append(rows_h)
-                        for j in np.nonzero((ehi != 0) | (elo != 0))[0]:
-                            events.append(("d", (int(ehi[j]), int(elo[j]))))
+                        sw.rows.append(rows)
                         for lane, entry in lane_reqs[w].items():
                             served[(entry[1], entry[2])] = (w, lane)
-                            events.append(("i", (entry[1], entry[2])))
                     outs.append((out, n))
                 for _w, n, op in r_ops:
                     rstate, out = rt.decide(rstate, op)
@@ -3798,10 +3912,17 @@ class MeshEngine(EngineBase):
                 self.table = table
                 if rt is not None:
                     rt.state = rstate
+                if sw is not None:
+                    # the flush's turn at the Store, taken in the order
+                    # of the engine lock (waits only while the flush
+                    # before it is still handing over)
+                    sw.take()
             except Exception as e:
                 self.metrics.busy_exit()  # no readback will follow
                 if live is not None:
                     tracing.close_live(live)
+                if sw is not None:
+                    sw.release()  # taken for a Store.get, if at all
                 self.table = table
                 if rt is not None:
                     rt.state = rstate
@@ -3814,7 +3935,7 @@ class MeshEngine(EngineBase):
                 tracing.close_live(live)
         fs.add("lock_wait", t_wait, t_in)
         fs.add("dispatch", t_in, t_out)
-        return outs, r_outs, wave_rows_host, events
+        return outs, r_outs, sw
 
     def _drop_displaced_strings(self, events) -> None:
         """Key-dictionary hygiene (store path): a key whose LAST flush
@@ -3844,8 +3965,7 @@ class MeshEngine(EngineBase):
         now,
         prefetched: Dict,
         served: Dict,
-        wave_rows_host: List,
-        events: List,
+        sw: _StoreWaves,
         fs: FlushStages,
         req_resolver=None,
     ):
@@ -3860,10 +3980,16 @@ class MeshEngine(EngineBase):
         1. a row this SAME flush already decided (the key was displaced
            between its own waves — pre-flush store state would drop the
            earlier hits, and a RESET-freed row must stay gone because the
-           store.remove only lands at flush end);
+           store.remove only lands at flush end). That wave's packed
+           rows are still on the device: they are read here, under the
+           lock, the one case in which they are (a crossing);
         2. the pre-flush prefetch (keys never seen by this process);
         3. Store.Get under the lock (rare: displaced in a prior flush but
-           raced back before hygiene dropped its string).
+           raced back before hygiene dropped its string). The flush
+           takes its turn at the Store first (sw.take), so the answer
+           holds every earlier flush's write-behind.
+
+        The events of this wave's inject go to `sw.pre`, one list a wave.
 
         Runs under self._lock; store outages degrade to misses, never
         table-fatal."""
@@ -3873,6 +3999,8 @@ class MeshEngine(EngineBase):
         fs.programs["probe"] += 1
         exists = np.asarray(self.K.probe_exists(table, op, cfg.ways))  # guberlint: allow-host-sync -- store path: the probe's answer (a byte a lane), needed under the lock before Store.get and the inject
         fs.crossings[1] += 1
+        events: list = []
+        sw.pre.append(events)
         rows = []
         gets = hits = from_store = 0
         for lane, (req, hi, lo) in lane_req.items():
@@ -3887,7 +4015,9 @@ class MeshEngine(EngineBase):
             sv = served.get((hi, lo))
             if sv is not None:
                 pw, plane = sv
-                r = wave_rows_host[pw]
+                r, read_now = sw.host_rows(pw)
+                if read_now:
+                    fs.crossings[1] += 1
                 if (
                     bool(r.used[plane])
                     and int(r.key_hi[plane]) == hi
@@ -3899,6 +4029,7 @@ class MeshEngine(EngineBase):
             else:
                 snap = prefetched.get((hi, lo))
                 if snap is None:
+                    sw.take()
                     snap = self._store_get(req)
                     gets += 1
                     hits += snap is not None

@@ -351,6 +351,81 @@ def test_global_buckets_of_the_replica_tier_are_not_persisted(path):
             assert g == (int(w.status), w.limit, w.remaining, w.reset_time)
 
 
+def same_group_pair(num_groups):
+    from gubernator_tpu.api.keys import group_of
+
+    seen = {}
+    for i in range(10_000):
+        k = f"d{i}"
+        g = group_of(key_hash128("st4_" + k)[1], num_groups)
+        if g in seen:
+            return seen[g], k
+        seen[g] = k
+    raise AssertionError("no two keys share a group")
+
+
+@pytest.mark.parametrize("path", ["columnar", "object"])
+@pytest.mark.parametrize("reset", [False, True], ids=["displaced", "freed"])
+def test_a_key_displaced_between_its_own_waves_on_the_mesh(path, reset):
+    """(vi) test_store_columnar_eviction's case (e) on four devices, one
+    way a group: one flush [A, B, A] of two keys of one group. B's wave
+    displaces A, and A's second wave continues from the row A's first
+    wave left, which is still on the devices when it is asked for (the
+    waves' rows are read after the engine lock, ISSUE 42): that one is
+    read under the lock, a crossing. A RESET_REMAINING's freed row stays
+    absent, and the Store's stale row is not read."""
+    groups = 32  # x 1 way: four lines of eight slots, one a device
+    ka, kb = same_group_pair(groups)
+
+    def item(key, **kw):
+        return RateLimitReq(name="st4", unique_key=key, hits=kw.pop("hits", 1),
+                            limit=20, duration=3_600_000, **kw)
+
+    def read_under_the_lock(eng):
+        for ln in eng.metrics.store_wave_crossings.render_lines():
+            if 'direction="d2h"' in ln:
+                return float(ln.rpartition(" ")[2])
+        raise KeyError("d2h")
+
+    clock = {"now": NOW}
+    eng = IciEngine(
+        IciEngineConfig(
+            devices=jax.devices()[:CHIPS], num_groups=groups, ways=1,
+            num_slots=1 << 11, batch_size=128, batch_wait_s=0.001,
+            sync_wait_s=3600,
+        ),
+        now_fn=lambda: clock["now"],
+    )
+    store = CountingStore()
+    attach_store(eng, store)
+    try:
+        eng.check_batch([item(ka, hits=5)])
+        assert store.data[f"st4_{ka}"].remaining == 15
+        reqs = [item(ka, behavior=RESET) if reset else item(ka),
+                item(kb), item(ka)]
+        before, read0 = counts(eng), read_under_the_lock(eng)
+        asked0 = len(store.asked)
+        if path == "columnar":
+            got = eng.check_columns(
+                wire.parse_requests(to_proto_bytes(reqs)), now=NOW + 5)
+            remaining = got[2].tolist()
+        else:
+            remaining = [r.remaining for r in eng.check_batch(reqs)]
+        assert remaining == ([20, 19, 19] if reset else [14, 19, 13])
+        after = counts(eng)
+        # three probes' answers, the first wave's rows, and the two key
+        # columns of the inject where A's row is seated again
+        assert read_under_the_lock(eng) - read0 == 3 + 1 + (0 if reset else 2)
+        # only B, never seen, is looked up: not A's stale row
+        assert set(store.asked[asked0:]) == {f"st4_{kb}"}
+        assert after["hit"] == before["hit"]
+        assert after["skipped"] == 0
+        assert store.data[f"st4_{ka}"].remaining == (19 if reset else 13)
+        assert store.data[f"st4_{kb}"].remaining == 19
+    finally:
+        eng.close()
+
+
 @pytest.mark.parametrize("make", [mesh_engine, one_chip_engine],
                          ids=["four", "one"])
 def test_a_gather_that_reads_other_rows_is_counted_not_persisted(make):
