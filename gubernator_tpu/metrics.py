@@ -1212,6 +1212,20 @@ class Metrics:
             "sync cadence.",
             registry=r,
         )
+        self.global_merged_hits = counter(
+            "gubernator_global_merged_hits",
+            "Hits that replicas other than the owner took and the sync "
+            "tick applied to a bucket its owner held (adoptions of keys "
+            "the owner lacked are not counted).",
+        )
+        self.global_over_admitted_hits = counter(
+            "gubernator_global_over_admitted_hits",
+            "Of gubernator_global_merged_hits, the hits the owner's "
+            "bucket could no longer take when the tick applied them "
+            "(max(hits - remaining, 0), whole hits, token and leaky "
+            "alike): what the replicas together admitted beyond a limit "
+            "between two ticks.",
+        )
 
         # MULTI_REGION behavior (no reference analog — the reference's
         # RegionPicker ships unimplemented, region_picker.go:19-103;
@@ -1736,6 +1750,14 @@ class Metrics:
             "topologies.",
             registry=r,
         )
+        self.replica_decisions = counter(
+            "gubernator_replica_decisions",
+            "GLOBAL lanes the replica tier answered, by the home device "
+            "the host assigned (round-robin), columnar and object path "
+            "alike; the series sum to the replica lanes answered. "
+            "Absent on engines without a replica tier.",
+            ["device"],
+        )
         self.shard_decisions = counter(
             "gubernator_shard_decisions",
             "Lanes the owner-sharded decide answered on each shard of "
@@ -1958,10 +1980,16 @@ def engine_sync(engine):
                     m.shard_decisions.labels(shard).set(lanes)
                 if ss.get("imbalance_ratio") is not None:
                     m.shard_imbalance_ratio.set(ss["imbalance_ratio"])
+                for dev, lanes in enumerate(ss["replica_decisions"]):
+                    m.replica_decisions.labels(dev).set(lanes)
         if hasattr(engine, "overflow_keys"):  # ici-mode engines only
             m.global_overflow_keys.set(engine.overflow_keys)
             m.global_overflow_drops.set(engine.overflow_drops)
             m.global_sync_backlog.set(getattr(engine, "sync_backlog", 0))
+            m.global_merged_hits.set(getattr(engine, "merged_hits", 0))
+            m.global_over_admitted_hits.set(
+                getattr(engine, "over_admitted_hits", 0)
+            )
             m.ici_full_ticks.set(getattr(engine, "full_ticks", 0))
         if hasattr(engine, "device_memory"):
             # Host-side arithmetic over static geometry + one allocator

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from gubernator_tpu.api.types import Behavior
+from gubernator_tpu.api.types import Behavior, Status
 from gubernator_tpu.models.bucket import FIXED_SHIFT
 from gubernator_tpu.ops.fused import join, split
 from gubernator_tpu.ops.kernels import get_raw_kernels
@@ -148,10 +148,19 @@ def _replica_step(RK, ways, groups_per, num_slots, dev, tbl, pending,
     pending = pending.at[:, evict_idx].set(0, mode="drop")
 
     # Accumulate deltas for lanes I answered but do not own
-    # (reference globalManager.QueueHit, global.go:74-78).
+    # (reference globalManager.QueueHit, global.go:74-78). Only what
+    # this replica took is queued: the hits of an OVER_LIMIT answer
+    # consumed nothing here and must consume nothing at the owner (the
+    # reference queues them too, so a refused request drains its owner;
+    # the tick's over-admission count would read every refusal as an
+    # admission). A DRAIN_OVER_LIMIT request drained this copy, and is
+    # relayed to drain the owner's.
     owned = (batch.group.astype(I64) // groups_per) == dev
     is_global = (batch.behavior & int(Behavior.GLOBAL)) != 0
-    pend_mask = mine & ~owned & is_global & (batch.hits != 0)
+    took = (out.status == int(Status.UNDER_LIMIT)) | (
+        (batch.behavior & int(Behavior.DRAIN_OVER_LIMIT)) != 0
+    )
+    pend_mask = mine & ~owned & is_global & (batch.hits != 0) & took
     idx = jnp.where(pend_mask, out.slot, num_slots)
     # A 64-bit add on the two words: read, add, write back. A wave's
     # lanes lie in distinct groups, hence distinct slots (the table's
@@ -412,8 +421,9 @@ def make_sync_step(
         """The sync merge over a block of groups. `t` is a wide SlotTable
         whose leaves are (C*W,), `pending` (C*W,), `gids` (C,) original
         group ids (sentinel G for padding lanes, valid False). Returns
-        (new wide table, new pending, kept_total, dropped_total) for the
-        block; padded lanes produce empty rows."""
+        (new wide table, new pending, totals) for the block, `totals`
+        this device's [kept, dropped, merged hits, over-admitted hits];
+        padded lanes produce empty rows."""
         nslots = gids.shape[0] * W
         own = jnp.broadcast_to(
             ((gids // groups_per) == dev)[:, None], (gids.shape[0], W)
@@ -551,6 +561,15 @@ def make_sync_step(
         rem_tok = jnp.maximum(rem - inc, 0)
         rem_lky = jnp.maximum(rem - (inc << FIXED_SHIFT), 0)
         new_rem = jnp.where(base_used & (inc != 0), jnp.where(is_leaky, rem_lky, rem_tok), rem)
+        # What the deltas did to the buckets the owner held, counted by
+        # the owner: the hits other replicas took, and those of them its
+        # bucket could no longer take (whole hits, token and leaky
+        # alike): between two ticks the replicas together may admit more
+        # than is left, by no more than they admit in between.
+        mine_inc = jnp.where(own & use_mine, inc, 0)
+        room = jnp.where(is_leaky, rem >> FIXED_SHIFT, rem)
+        merged_hits = jnp.sum(mine_inc)
+        over_hits = jnp.sum(jnp.maximum(mine_inc - room, 0))
 
         # Rebroadcast: each device contributes only its owned region; the
         # psum IS the UpdatePeerGlobals fan-out.
@@ -636,7 +655,9 @@ def make_sync_step(
         # degraded regime the reference cannot surface.
         surv_total = jnp.sum(surv.astype(I64))
         kept_total = jnp.sum(kept.astype(I64))
-        return new_table, new_pending, kept_total, surv_total - kept_total
+        return new_table, new_pending, jnp.stack(
+            [kept_total, surv_total - kept_total, merged_hits, over_hits]
+        )
 
     def local(state: IciState, now):
         dev = jax.lax.axis_index(AXIS).astype(I64)
@@ -647,14 +668,16 @@ def make_sync_step(
         if not capped:
             gids = jnp.arange(G, dtype=I64)
             valid = jnp.ones(G, dtype=bool)
-            new_t, new_p, kept_total, dropped_total = merge_block(
+            new_t, new_p, totals = merge_block(
                 dev, RK.to_wide(native), join(words[0], words[1]),
                 gids, valid, now, psum,
             )
-            diag = jnp.stack(
-                [kept_total, dropped_total, jnp.zeros((), I64),
-                 jnp.full((), G, I64), jnp.full((), G, I64)]
-            )[None, :]
+            diag = jnp.concatenate([
+                totals[:2],
+                jnp.stack([jnp.zeros((), I64), jnp.full((), G, I64),
+                           jnp.full((), G, I64)]),
+                totals[2:],
+            ])[None, :]
             return (
                 IciState(
                     table=_unsqueeze(RK.from_wide(new_t)),
@@ -740,7 +763,7 @@ def make_sync_step(
 
                 native_c = RK.take_groups(native, gids, W)
                 held = jnp.take(words, slots, axis=1, mode="clip")
-                new_tc, new_pc, kept_c, dropped_c = merge_block(
+                new_tc, new_pc, totals = merge_block(
                     dev, RK.to_wide(native_c), join(held[0], held[1]),
                     gids, valid, now, psum,
                 )
@@ -750,9 +773,11 @@ def make_sync_step(
                     words.at[:, slots].set(
                         jnp.stack(split(new_pc)), mode="drop"
                     ),
-                    jnp.stack([
-                        kept_c, dropped_c, jnp.sum(valid.astype(I64)),
-                        jnp.full((), width, I64),
+                    jnp.concatenate([
+                        totals[:2],
+                        jnp.stack([jnp.sum(valid.astype(I64)),
+                                   jnp.full((), width, I64)]),
+                        totals[2:],
                     ]),
                 )
 
@@ -769,7 +794,7 @@ def make_sync_step(
         # v5e; none this way).
         merged_state = (
             native, words,
-            jax.lax.pcast(jnp.zeros(4, I64), AXIS, to="varying"),
+            jax.lax.pcast(jnp.zeros(6, I64), AXIS, to="varying"),
         )
         for k, w in enumerate(widths):
             merged_state = jax.lax.fori_loop(
@@ -777,17 +802,16 @@ def make_sync_step(
                 lambda _, s, merge=merge_at(w): merge(s[0], s[1]),
                 merged_state,
             )
-        new_native, new_words, (kept_c, dropped_c, merged, width) = (
-            merged_state
-        )
+        new_native, new_words, done = merged_state
+        merged = done[2]
 
         # kept/dropped counters from UNSELECTED overflow groups carry
         # over from the previous tick's table unchanged; the gauges
         # reflect blocks actually merged this tick, plus the backlog of
         # active groups the cap pushed to the next tick.
         backlog = jnp.sum(g_act.astype(I64)) - merged
-        diag = jnp.stack(
-            [kept_c, dropped_c, backlog, merged, width]
+        diag = jnp.concatenate(
+            [done[:2], backlog[None], done[2:]]
         )[None, :]
         return (
             IciState(
@@ -805,14 +829,17 @@ def make_sync_step(
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def sync_fn(state: IciState, now):
-        """Returns (new_state, diag) where diag is (n_dev, 5) int64:
+        """Returns (new_state, diag) where diag is (n_dev, 7) int64:
         diag[d] = [overflow entries kept replica-local on device d (among
                    groups merged this tick), overflow survivors dropped
                    on device d this tick, active groups beyond the cap
                    left for the next tick (identical on every device; 0
                    when unbounded), groups merged this tick (identical
                    on every device; G when unbounded), the width in
-                   groups of the block they were merged in (likewise)]."""
+                   groups of the block they were merged in (likewise),
+                   hits of other replicas applied this tick to buckets
+                   device d owns and held, those of them its buckets
+                   could no longer take]."""
         with jax.named_scope("ici.tick"):  # profile metadata only
             return sharded(state, jnp.asarray(now, I64))
 

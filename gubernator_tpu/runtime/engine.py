@@ -778,6 +778,13 @@ class EngineBase:
             if self.topo.n_dev > 1
             else None
         )
+        # The replica tier's twin: GLOBAL lanes answered, by the home
+        # device the host assigned them (same lock, same topologies).
+        self._replica_decisions = (
+            np.zeros(self.topo.n_dev, dtype=np.int64)
+            if self.topo.n_dev > 1
+            else None
+        )
         # Cumulative pump time spent in _dispatch (host encode + launch);
         # pump-thread-only writer, read by the completion stage for the
         # host/device overlap ratio.
@@ -1334,6 +1341,15 @@ class EngineBase:
         with self._shard_lock:
             self._shard_decisions += counts
 
+    def _note_replica_decisions(self, counts) -> None:
+        """Add a flush's GLOBAL lanes, counted by home device, to the
+        replica tier's totals: that a window's lanes (and a run's
+        probes) met every replica is a count, not an inference from the
+        round-robin. Host arithmetic on what the assembly already
+        holds."""
+        with self._shard_lock:
+            self._replica_decisions += counts
+
     def shard_stats(self) -> Optional[dict]:
         """Per-shard skew attribution for the mesh path: decisions (the
         ownership split of served lanes), occupancy (census heatmap
@@ -1348,6 +1364,7 @@ class EngineBase:
             return None
         with self._shard_lock:
             decisions = self._shard_decisions.tolist()
+            replica_decisions = self._replica_decisions.tolist()
 
         def imbalance(vals) -> Optional[float]:
             total = sum(vals)
@@ -1360,6 +1377,7 @@ class EngineBase:
             "n_shards": n_dev,
             "decisions": decisions,
             "decision_imbalance": imbalance(decisions),
+            "replica_decisions": replica_decisions,
         }
         census = self.cached_census()
         if census is not None:
@@ -2851,6 +2869,7 @@ class MeshEngine(EngineBase):
             # carry an "r" tag so _complete demuxes from the replica outputs.
             r_asm = _WaveAssembler(WaveOperand.zeros, B) if rt is not None else None
 
+            r_homes = [0] * self.topo.n_dev  # replica lanes placed, by home
             carry: List[Tuple[RateLimitReq, object]] = []
             new_strings: Dict[Tuple[int, int], str] = {}
             for i, (req, fut) in enumerate(items):
@@ -2878,6 +2897,7 @@ class MeshEngine(EngineBase):
                         continue
                     wb.home[lane] = home
                     r_asm.commit(w, (home, slot))
+                    r_homes[home] += 1
                     placements.append(("r", w, lane, hi, lo))
                     continue
                 grp = grp_l[i]
@@ -2956,6 +2976,8 @@ class MeshEngine(EngineBase):
             # completion thread re-attaches its context — see
             # _complete_ticket). Request spans link to it and back.
             r_waves = r_asm.waves if r_asm is not None else []
+            if r_waves:
+                self._note_replica_decisions(r_homes)
             n_waves = len(waves) + len(r_waves)
             ops = self._upload(waves, now, fs)
             r_ops = self._upload(r_waves, now, fs, stack=False)
@@ -3761,6 +3783,9 @@ class MeshEngine(EngineBase):
             r_wo, r_ix = r_asm[0], r_asm[3]
             r_wo.batch.group[r_ix] = slot.astype(np.int32)
             r_wo.home[r_ix] = homes
+            self._note_replica_decisions(
+                np.bincount(homes, minlength=self.topo.n_dev)
+            )
 
         wave_slices, r_slices = [], []
         if s_asm is not None:
@@ -4701,6 +4726,7 @@ raceguard.guarded_by(EngineBase, {
     "_admission_cache": "engine.admission",
     "_admission_ts": "engine.admission",
     "_shard_decisions": "w:engine.shards",
+    "_replica_decisions": "w:engine.shards",
     "_inflight": "w:engine.pipeline",
 })
 raceguard.guarded_by(MeshEngine, {

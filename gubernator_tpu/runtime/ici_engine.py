@@ -160,6 +160,11 @@ class IciEngine(MeshEngine):
         self.overflow_keys = 0
         self.overflow_drops = 0
         self.sync_backlog = 0
+        # What the ticks' deltas did to buckets their owners held
+        # (running totals): the hits other replicas took, and those of
+        # them the owner's bucket could no longer take.
+        self.merged_hits = 0
+        self.over_admitted_hits = 0
         # Backstop bookkeeping (gubernator_ici_full_ticks): host-side
         # capped-tick counter and a running total of forced full ticks.
         self.full_ticks = 0
@@ -253,6 +258,8 @@ class IciEngine(MeshEngine):
                 self.overflow_keys = int(d[:, 0].sum())
                 self.overflow_drops += int(d[:, 1].sum())
                 self.sync_backlog = int(d[:, 2].max())
+                self.merged_hits += int(d[:, 5].sum())
+                self.over_admitted_hits += int(d[:, 6].sum())
         finally:
             tracing.next_live(live)
         dur = time.perf_counter() - t0

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from gubernator_tpu.api.keys import group_of, key_hash128
-from gubernator_tpu.api.types import Algorithm, Behavior, RateLimitReq
+from gubernator_tpu.api.types import Algorithm, Behavior, RateLimitReq, Status
 from gubernator_tpu.models.oracle import OracleEngine
 from gubernator_tpu.ops.encode import encode_batch
 from gubernator_tpu.ops.layout import batch_entry
@@ -112,7 +112,12 @@ class IciModel:
         resp = ora.decide(dataclasses.replace(req, metadata={}), now)
         g = slot // self.ways
         owned = g // self.groups_per == home
-        if not owned and req.hits != 0:
+        # only what this replica took is queued for the owner (a
+        # DRAIN_OVER_LIMIT request drained this copy and is relayed)
+        took = resp.status == Status.UNDER_LIMIT or (
+            req.behavior & Behavior.DRAIN_OVER_LIMIT
+        )
+        if not owned and req.hits != 0 and took:
             self.pending[home][slot] = self.pending[home].get(slot, 0) + req.hits
         return resp
 
@@ -413,7 +418,7 @@ def _planted_groups_match(active, num_slots, ways, cap):
 
     state_b, diag = sync_cap(state_b, NOW)
     _kept, _dropped, backlog, merged, width = (
-        int(x) for x in np.asarray(diag)[0]
+        int(x) for x in np.asarray(diag)[0, :5]
     )
     assert merged == min(active, cap)
     assert backlog == active - merged
