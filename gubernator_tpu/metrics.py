@@ -729,6 +729,21 @@ def engine_store_wave_crossings() -> _BareCounter:
     return c
 
 
+def engine_flushes_over_max_waves() -> _BareCounter:
+    """The engine-owned counter of columnar flushes whose assembly made
+    more waves than one launch holds (max_waves): one key more often
+    than that in one call. Exposed after
+    gubernator_engine_store_wave_crossings."""
+    return _BareCounter(
+        "gubernator_engine_flushes_over_max_waves",
+        "Columnar flushes that ran more than max_waves waves, as "
+        "consecutive launches under one hold of the engine lock: a "
+        "call in which one key (or one slot group) comes more often "
+        "than max_waves times. Such a call used to leave the columnar "
+        "path and count under gubernator_edge_calls' reason waves.",
+    )
+
+
 def engine_store_counters() -> dict:
     """The engine-owned counters of what it asks of an attached Store
     (reference store.go:49-65), keyed as EngineMetrics holds them."""
@@ -2027,6 +2042,7 @@ def wire_engine_telemetry(metrics: "Metrics", engine) -> None:
         if h is getattr(em, "flush_launches", None):
             metrics.register_renderable(em.wave_programs)
             metrics.register_renderable(em.store_wave_crossings)
+            metrics.register_renderable(em.flushes_over_max_waves)
     for c in getattr(em, "store_counters", ()):
         metrics.register_renderable(c)
     metrics.add_sync(engine_sync(engine))
@@ -2043,5 +2059,6 @@ def catalog_names() -> set:
     names.add(engine_wave_transfers().name)
     names.add(engine_wave_programs().name)
     names.add(engine_store_wave_crossings().name)
+    names.add(engine_flushes_over_max_waves().name)
     names |= {c.name for c in engine_store_counters().values()}
     return names

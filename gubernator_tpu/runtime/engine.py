@@ -44,6 +44,7 @@ from gubernator_tpu.metrics import (
     ENGINE_STAGES,
     FLUSH_STAGES,
     STORE_WAVE_PROGRAMS,
+    engine_flushes_over_max_waves,
     engine_histograms,
     engine_store_counters,
     engine_store_wave_crossings,
@@ -54,12 +55,14 @@ from gubernator_tpu.api.keys import group_of, key_hash128, key_hash128_batch
 from gubernator_tpu.api.types import (
     Behavior,
     ERR_ENGINE_DRAINING,
+    MAX_BATCH_SIZE,
     RateLimitReq,
     RateLimitResp,
     validate_request,
 )
 from gubernator_tpu.ops.encode import EncodeError, encode_one, encode_rows
 from gubernator_tpu.ops.layout import (
+    OPERAND_ROWS,
     OUT_EVICTED_HI,
     OUT_EVICTED_LO,
     OUT_FREED,
@@ -250,6 +253,10 @@ class EngineMetrics:
         self._store_crossing = tuple(
             self.store_wave_crossings.labels(d) for d in ("h2d", "d2h")
         )
+        # Columnar flushes that ran more than max_waves waves (one key
+        # more often than that in one call): further launches of the
+        # same flush, not a refusal.
+        self.flushes_over_max_waves = engine_flushes_over_max_waves()
         stores = engine_store_counters()
         for attr, c in stores.items():
             setattr(self, attr, c)
@@ -2226,12 +2233,14 @@ class MeshEngine(EngineBase):
         )
         # In-flight decide outputs pinned by the continuous-batching
         # ring: depth x waves x batch lanes x ~8 int64 output columns.
-        ring_b = (
-            max(int(cfg.pipeline_depth), 1)
-            * cfg.max_waves
-            * cfg.batch_size
-            * 8
-            * 8
+        # A pump flush holds max_waves waves at most. A columnar flush
+        # holds its whole call, a wave for every time its hottest key
+        # comes (the API's cap is MAX_BATCH_SIZE items), operands and
+        # outputs at batch_size lanes with a Store or on a mesh: the
+        # most one flush can pin, beside the ring.
+        ring_b = cfg.batch_size * 8 * (
+            max(int(cfg.pipeline_depth), 1) * cfg.max_waves * 8
+            + MAX_BATCH_SIZE * (OPERAND_ROWS + 8)
         )
         # Admission output: one excess histogram plus a handful of int64
         # scalars (ops/admission.py AdmissionOutput).
@@ -2391,7 +2400,11 @@ class MeshEngine(EngineBase):
         Returns the flush's launches in order, [(first wave, waves,
         operand on the device)]. A run of consecutive waves of one
         width is ONE array, stacked to the least warm depth that holds
-        it, and one launch. What the engine observes decides: a run of
+        it, and one launch; a run longer than the deepest warm depth
+        (max_waves: a columnar call whose hot key comes more often) is
+        as many launches as it takes, all of this one flush and issued
+        under its one hold of the engine lock (_execute_waves). What
+        the engine observes decides: a run of
         one wave stays the single-wave operand; a Store or a pager keeps
         the per-wave sequence (read-through, the row gather and page
         promotion are defined per wave), as `stack=False` does for the
@@ -3203,8 +3216,13 @@ class MeshEngine(EngineBase):
         anywhere — hashing, wave/lane assignment, encoding, and response
         demux are all batch array ops. Returns (status, limit, remaining,
         reset_time) int arrays in request order, or None when this batch
-        needs the object path (wave/lane bounds are exceeded, or the
-        batch is empty). A Store does NOT force a fallback: the store
+        needs the object path (one wave would hold more lanes than
+        batch_size, or the batch is empty). A key that comes more than
+        max_waves times does not: its waves run as further launches of
+        the same flush, under one hold of the engine lock
+        (_assemble_column_waves, _upload), so the call is applied as one
+        unit and no other flush's hits of that key land between its
+        own. A Store does NOT force a fallback: the store
         path runs the object path's per-wave sequence here (probe ->
         read-through -> decide -> write-behind) with request objects
         built only for actual miss lanes.
@@ -3313,19 +3331,21 @@ class MeshEngine(EngineBase):
     def _lead(self, me: _Joined):
         """Serve the waiting batch that `me` heads, on me's thread: one
         _flush_columns over the merged columns, each member handed its
-        slice and woken. A batch of one is served as it stands. If the
-        merged assembly is refused (one key more than max_waves times
-        across the members) the members are served one after another
-        as lone flushes, each answered as it would have been alone; an
-        exception out of the merged flush reaches every member
-        (TableCommittedError must; any other sends each to the object
-        path, service/fastpath.py)."""
+        slice and woken. A batch of one is served as it stands. A
+        merged assembly is never refused: one key more than max_waves
+        times across the members is more launches of the one flush
+        (_assemble_column_waves), and a batch holds no more items than
+        the narrowest warm width has lanes (_join_budget), so no wave
+        outgrows batch_size. Were one refused all the same, nothing
+        would have committed, and every member would go to the object
+        path with its `out` still None. An exception out of the merged
+        flush reaches every member (TableCommittedError must; any other
+        sends each to the object path, service/fastpath.py)."""
         gate = self._gate
         fs = me.fs
         with gate.lock:
             batch, gate.waiting, gate.items = gate.waiting, [], 0
         fs.in_host_stage = True  # the turn it was handed with its promotion
-        woken = 1  # batch[:woken] need no waking any more: me, to begin with
         try:
             if len(batch) == 1:
                 return self._flush_columns(me.cols, me.now, None, None, fs)
@@ -3344,24 +3364,10 @@ class MeshEngine(EngineBase):
                     hi = lo + m.cols.n
                     m.out = tuple(a[lo:hi] for a in out)
                     lo = hi
-                return me.out
-            fs.calls = 1
-            for m in batch:
-                try:
-                    m.out = self._flush_columns(
-                        m.cols, m.now, None, None, m.fs
-                    )
-                except Exception as e:  # its own, as if it had come alone
-                    m.exc = e
-                if m is not me:
-                    m.latch.release()
-                    woken += 1
-            if me.exc is not None:
-                raise me.exc
             return me.out
         finally:
             self._left_host_stage(fs)
-            for m in batch[woken:]:
+            for m in batch[1:]:
                 m.latch.release()
 
     def _flush_columns(self, cols, now: int, select, hashes, fs: FlushStages):
@@ -3412,7 +3418,7 @@ class MeshEngine(EngineBase):
             fs.publish()  # the refused attempt's hash and waves
             return None
         n = cols.n
-        wo, wave, lane, ix, W, B = asm
+        wave_slices, wave, lane, ix, W, B = asm
 
         def key_str(j: int) -> str:
             return orig_cols.key_string(
@@ -3453,7 +3459,6 @@ class MeshEngine(EngineBase):
                     self._maybe_prune_key_strings()
 
         with tracing.stage("flush.waves", fs, fs.ids):
-            wave_slices = [wo.wave(w) for w in range(W)]
             ops = self._upload(wave_slices, now, fs)
         _telemetry.set_shape_hint(f"{cfg.layout}:columnar:{W}x{B}")
         t_dev = time.perf_counter()
@@ -3499,9 +3504,12 @@ class MeshEngine(EngineBase):
             )
             em.observe_stage("assemble", t_dev - t_start)
             em.observe_stage("device_sync", dev_s)
+            if W > cfg.max_waves:
+                em.flushes_over_max_waves.inc()
             em.recorder.record(
                 path="columnar", layout=cfg.layout, n=n, waves=W, carry=0,
-                widths=[B] * W, dur_us=int(dur * 1e6),
+                widths=[w.lanes for w in wave_slices],
+                launches=fs.launches, dur_us=int(dur * 1e6),
                 dev_us=int(dev_s * 1e6), trace_id=flush_trace_id,
                 ticket=fs.ids["flush"], call=fs.ids["call"],
                 calls=fs.calls, stages_us=fs.us,
@@ -3720,9 +3728,15 @@ class MeshEngine(EngineBase):
             )
             em.observe_stage("assemble", t_dev - t_start)
             em.observe_stage("device_sync", dev_s)
+            if any(
+                a is not None and a[4] > cfg.max_waves
+                for a in (s_asm, r_asm)
+            ):
+                em.flushes_over_max_waves.inc()
             em.recorder.record(
                 path="columnar", layout=cfg.layout, n=n, waves=waves_total,
                 carry=0, widths=[cfg.batch_size] * waves_total,
+                launches=fs.launches,
                 dur_us=int(dur * 1e6), dev_us=int(dev_s * 1e6),
                 trace_id=flush_trace_id, ticket=fs.ids["flush"],
                 call=fs.ids["call"], calls=fs.calls, stages_us=fs.us,
@@ -3771,27 +3785,21 @@ class MeshEngine(EngineBase):
                 self._home_rr += len(g_idx)
             homes = (rr0 + np.arange(len(g_idx))) % self.topo.n_dev
             # Wave conflicts are per (home, slot) PAIR (the object path's
-            # place key): encode the pair as the assembly "group", then
-            # overwrite the batch's group column with the real slot.
+            # place key): the pair is the assembly's conflict key, the
+            # slot the batch's group column.
             pair = homes * np.int64(rt.num_rgroups) + slot
             r_asm = _assemble_column_waves(
                 r_cols, hi[g_idx], r_lo, pair, now,
-                cfg.batch_size, cfg.max_waves,
+                cfg.batch_size, cfg.max_waves, group=slot, home=homes,
             )
             if r_asm is None:
                 return None
-            r_wo, r_ix = r_asm[0], r_asm[3]
-            r_wo.batch.group[r_ix] = slot.astype(np.int32)
-            r_wo.home[r_ix] = homes
             self._note_replica_decisions(
                 np.bincount(homes, minlength=self.topo.n_dev)
             )
 
-        wave_slices, r_slices = [], []
-        if s_asm is not None:
-            wave_slices = [s_asm[0].wave(w) for w in range(s_asm[4])]
-        if r_asm is not None:
-            r_slices = [r_asm[0].wave(w) for w in range(r_asm[4])]
+        wave_slices = s_asm[0] if s_asm is not None else []
+        r_slices = r_asm[0] if r_asm is not None else []
         return (cols, hi, lo, s_asm, r_asm, ng_idx, g_idx, wave_slices,
                 self._upload(wave_slices, now, fs),
                 self._upload(r_slices, now, fs, stack=False))
@@ -3886,6 +3894,7 @@ class MeshEngine(EngineBase):
                 )
             table = self.table
             rstate = rt.state if rt is not None else None
+            unread = 0  # launches since the last one that was waited for
             try:
                 for w, n, op in ops:
                     wo = waves[w]
@@ -3931,9 +3940,11 @@ class MeshEngine(EngineBase):
                         for lane, entry in lane_reqs[w].items():
                             served[(entry[1], entry[2])] = (w, lane)
                     outs.append((out, n))
+                    unread = self._bound_in_flight(out, unread)
                 for _w, n, op in r_ops:
                     rstate, out = rt.decide(rstate, op)
                     r_outs.append((out, n))
+                    unread = self._bound_in_flight(out, unread)
                 self.table = table
                 if rt is not None:
                     rt.state = rstate
@@ -3961,6 +3972,22 @@ class MeshEngine(EngineBase):
         fs.add("lock_wait", t_wait, t_in)
         fs.add("dispatch", t_in, t_out)
         return outs, r_outs, sw
+
+    def _bound_in_flight(self, out, unread: int) -> int:
+        """Under the engine lock, after a launch whose output is `out`:
+        no more than max_waves launches of one flush are in flight
+        unread, the bound the pump's wave cap has always kept. A
+        columnar call whose key comes more often than max_waves times
+        may make more (a launch a wave on the replica tier, with a pager
+        or where no stacked shape is warm), and a backend that holds a
+        device's queue to a fixed depth (the CPU client: 32) then blocks
+        the enqueue for one device of a mesh while the others wait for
+        it in the collective. Returns the launches now unread."""
+        unread += 1
+        if unread < self.cfg.max_waves:
+            return unread
+        jax.block_until_ready(out)  # guberlint: allow-host-sync -- once in max_waves launches of one flush: bounds the device queue (see above)
+        return 0
 
     def _drop_displaced_strings(self, events) -> None:
         """Key-dictionary hygiene (store path): a key whose LAST flush
@@ -4531,22 +4558,35 @@ class DeviceEngine(MeshEngine):
 
 def _assemble_column_waves(
     cols, hi, lo, grp, now, batch_size: int, max_waves: int,
-    width_candidates=(), stacked_widths=(),
+    width_candidates=(), stacked_widths=(), group=None, home=None,
 ):
     """Vectorized wave assembly shared by the engines' columnar paths:
     wave = occurrence rank within the group (stable sort keeps arrival
     order, preserving per-key sequencing); lane = arrival rank within
-    the wave. Returns (wo, wave, lane, ix, W, B) with `wo` the W stacked
-    waves' WaveOperand (its `batch` fields are (W, B) views of the one
-    buffer), or None when the batch exceeds the wave/lane bounds (caller
-    falls back to the object path).
+    the wave. `grp` is what two lanes of one wave may not share; `group`
+    is the operand's group column where that differs (the replica tier:
+    `grp` is the (home, slot) pair, `group` the slot) and `home` its
+    home row. Returns (waves, wave, lane, ix, W, B): `waves` the W
+    waves' WaveOperands in order, views of one buffer a width, and B the
+    first wave's width; or None when a wave would hold more than
+    batch_size lanes (the caller falls back to the object path).
+
+    The wave count is not bounded: a key that comes more often than
+    `max_waves` times makes more waves than one launch holds, and
+    _upload cuts them into consecutive launches of one flush.
 
     `width_candidates` optionally narrows the device batch width to the
     actual occupancy — the kernel's cost is per-LANE — using only
     already-compiled widths. A call of several waves narrows only to a
     width among `stacked_widths`, those whose stacked launch is warm: a
     wider wave costs microseconds of device time, a launch a wave costs
-    a millisecond each under the engine lock."""
+    a millisecond each under the engine lock. So an assembly that one
+    launch holds (W <= max_waves) runs at one width, its first wave's.
+    One that needs several launches anyway gives every wave the
+    narrowest such width that holds it: wave w holds the groups that
+    come more than w times, so its waves never widen, and the long tail
+    that one hot key makes is uploaded and read back at the narrowest
+    width, not at its first wave's."""
     from gubernator_tpu.models.bucket import MAX_COUNT, MAX_DURATION_MS
 
     n = cols.n
@@ -4555,22 +4595,24 @@ def _assemble_column_waves(
     wave_sorted = np.arange(n) - np.searchsorted(sg, sg, side="left")
     wave = np.empty(n, np.int64)
     wave[order] = wave_sorted
-    num_waves = int(wave.max()) + 1
-    if num_waves > max_waves:
+    W = int(wave.max()) + 1
+    fill = np.bincount(wave)  # lanes a wave: never more than the wave before
+    if fill[0] > batch_size:
         return None
     order2 = np.argsort(wave, kind="stable")
     sw = wave[order2]
-    lane_sorted = np.arange(n) - np.searchsorted(sw, sw, side="left")
-    max_lane = int(lane_sorted.max())
-    if max_lane >= batch_size:
-        return None
     lane = np.empty(n, np.int64)
-    lane[order2] = lane_sorted
+    lane[order2] = np.arange(n) - np.searchsorted(sw, sw, side="left")
 
-    B = batch_size
-    for s in width_candidates:  # immutable snapshot; warmer swaps atomically
-        if s > max_lane and s < B and (num_waves == 1 or s in stacked_widths):
-            B = s
+    fits = sorted(  # of an immutable snapshot; the warmer swaps atomically
+        s for s in width_candidates
+        if s < batch_size and (W == 1 or s in stacked_widths)
+    ) + [batch_size]
+    if W <= max_waves:
+        fill[:] = fill[0]  # one launch holds it: one width, its first wave's
+    widths = np.array(fits)[np.searchsorted(fits, fill)]
+    firsts = [0, *(np.nonzero(np.diff(widths))[0] + 1).tolist(), W]
+    widths = widths.tolist()
 
     # Encode columns (the encode_one clamps, vectorized).
     hits = np.clip(cols.hits, -MAX_COUNT, MAX_COUNT)
@@ -4586,36 +4628,45 @@ def _assemble_column_waves(
         cols.created_at,
         np.int64(now),
     )
+    fields = dict(
+        key_hi=hi, key_lo=lo, group=grp if group is None else group,
+        algo=cols.algo.astype(np.int8),
+        behavior=cols.behavior.astype(np.int32),
+        hits=hits, limit=limit, duration=duration, rate_num=duration,
+        eff_duration=duration, burst=burst, created_at=created,
+    )
 
-    W = num_waves
-    wo = WaveOperand.zeros(B, W)
-    wb = wo.batch
-    ix = (wave, lane)
-    wb.key_hi[ix] = hi
-    wb.key_lo[ix] = lo
-    wb.group[ix] = grp
-    wb.algo[ix] = cols.algo.astype(np.int8)
-    wb.behavior[ix] = cols.behavior.astype(np.int32)
-    wb.hits[ix] = hits
-    wb.limit[ix] = limit
-    wb.duration[ix] = duration
-    wb.rate_num[ix] = duration
-    wb.eff_duration[ix] = duration
-    wb.burst[ix] = burst
-    wb.created_at[ix] = created
-    wb.active[ix] = True
-    return wo, wave, lane, ix, W, B
+    waves = []
+    for first, end in zip(firsts, firsts[1:]):
+        wo = WaveOperand.zeros(widths[first], end - first)
+        if end - first == W:
+            at, own = (wave, lane), slice(None)
+        else:
+            own = (wave >= first) & (wave < end)
+            at = (wave[own] - first, lane[own])
+        wb = wo.batch
+        for f, col in fields.items():
+            getattr(wb, f)[at] = col[own]
+        wb.active[at] = True
+        if home is not None:
+            wo.home[at] = home[own]
+        waves += [wo.wave(w) for w in range(end - first)]
+    return waves, wave, lane, (wave, lane), W, waves[0].lanes
 
 
 def _demux_lanes(out_rows, ix):
     """(status, limit, remaining, reset_time) in request order from the
-    output rows of equally wide waves (_read_waves): `ix` is each
-    request's (wave, lane) — the demux shared by the engines' columnar
-    paths."""
+    waves' output rows (_read_waves), whatever each wave's width: `ix`
+    is each request's (wave, lane) — the demux shared by the engines'
+    columnar paths."""
     wave, lane = ix
-    stacked = np.stack(out_rows)  # (W, R, B)
+    widths = np.fromiter(
+        (r.shape[1] for r in out_rows), np.int64, len(out_rows)
+    )
+    at = (np.cumsum(widths) - widths)[wave] + lane
+    lanes = np.concatenate(out_rows, axis=1)  # (R, every wave's lanes)
     return tuple(
-        stacked[wave, r, lane]
+        lanes[r, at]
         for r in (OUT_STATUS, OUT_LIMIT, OUT_REMAINING, OUT_RESET_TIME)
     )
 

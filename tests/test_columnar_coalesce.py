@@ -13,8 +13,8 @@ lock launches the first, whose launch hands the turn to the batch.
 - K waiting calls are one flush;
 - what may not join goes straight through: a call too large to meet a
   peer, NO_BATCHING, `select`;
-- a merged assembly refused above `max_waves` serves every member as a
-  lone flush;
+- a merged assembly above `max_waves` is one flush of several launches
+  (ISSUE 44; until then it was refused and every member served alone);
 - TableCommittedError reaches every member; any other error sends every
   member to the object path;
 - consumption is exact under 32 threads on ten keys.
@@ -95,6 +95,10 @@ def flush_calls(eng):
     """(columnar and pump flushes observed, calls they served)."""
     s = eng.metrics.flush_calls.summary()
     return s["count"], int(s["sum"])
+
+
+def over_max_waves(eng) -> int:
+    return int(eng.metrics.flushes_over_max_waves.labels().get())
 
 
 def joins(eng) -> int:
@@ -371,10 +375,13 @@ def test_the_budget_is_the_narrowest_warm_width_and_no_more_than_the_ladders():
 # ---- failure ----------------------------------------------------------------
 
 
-def test_a_merged_refusal_above_max_waves_serves_every_member_columnar():
-    """Three members hit one key twice each: six waves merged, over
-    max_waves 4, so each is served as the lone flush it would have been;
-    a fourth, itself over max_waves, is refused alone as it would be."""
+def test_a_merged_batch_above_max_waves_is_one_flush_of_several_launches():
+    """Three members hit one key twice each, and a fourth one of its own
+    five times: six waves merged, over max_waves 4, so the one merged
+    flush runs them as two launches and every member is answered as the
+    same calls served one after another are. (Until ISSUE 44 the merged
+    assembly was refused and each member served alone, the fourth by the
+    object path.)"""
     merged, serial = device_engine(max_waves=4), device_engine(max_waves=4)
     calls = [[mk("hot", limit=5), mk("hot", limit=5)] for _ in range(3)]
     calls.append([mk("own", limit=9)] * 5)
@@ -388,14 +395,17 @@ def test_a_merged_refusal_above_max_waves_serves_every_member_columnar():
             serial.check_columns(columns(reqs), now=NOW + 1 + i)
             for i, reqs in enumerate(calls)
         ]
-        assert want[3] is None
-        for (kind, got), exp in zip(h.results[1:4], want[:3]):
+        for (kind, got), exp in zip(h.results[1:], want):
             assert kind == "ok" and answers(got) == answers(exp)
-        assert h.results[4] == ("ok", None)  # to the object path, as alone
         assert [answers(g)[0] for _k, g in h.results[1:4]] == [
             [0, 0], [0, 0], [0, 1]]
-        # the blocker's flush and three lone ones; the refused observe none
-        assert flush_calls(merged) == (before[0] + 4, before[1] + 4)
+        assert answers(h.results[4][1])[2] == [8, 7, 6, 5, 4]
+        # the blocker's flush and the merged one, which served four calls
+        assert flush_calls(merged) == (before[0] + 2, before[1] + 5)
+        rec = merged.metrics.recorder.last()
+        assert (rec["calls"], rec["waves"], rec["launches"]) == (4, 6, 2)
+        # the merged flush; alone, only the fourth call is over max_waves
+        assert over_max_waves(merged) == over_max_waves(serial) == 1
     finally:
         merged.close()
         serial.close()

@@ -930,3 +930,54 @@ def test_mixed_gregorian_fuzz(seed):
     finally:
         eng_a.close()
         eng_b.close()
+
+
+@pytest.mark.parametrize("seed", [44, 45])
+def test_thousand_item_zipf_calls_are_served_columnar(seed):
+    """The fuzz at the API's cap: calls of 1,000 items drawn Zipf(0.99)
+    from 4,000 keys hold their hottest key some 80 times, more than
+    max_waves (32). Through fastpath.try_serve each is served columnar
+    (until ISSUE 44: refused, reason `waves`) and its bytes equal the
+    object path's answer serialized, over three calls in which the hot
+    keys pass their limits."""
+    from gubernator_tpu.service import fastpath
+    from gubernator_tpu.utils import tracing
+
+    rng = np.random.default_rng(seed)
+    w = np.arange(1, 4_001, dtype=np.float64) ** -0.99
+    cdf = np.cumsum(w) / w.sum()
+    clock = {"now": NOW}
+    mk = lambda: DeviceEngine(  # noqa: E731
+        EngineConfig(num_groups=1 << 12, batch_size=1024, batch_wait_s=0.001),
+        now_fn=lambda: clock["now"],
+    )
+    eng_a, eng_b = mk(), mk()
+    svc = _mk_fast_svc(eng_a)
+    try:
+        for step in range(3):
+            clock["now"] += 10
+            ranks = np.minimum(np.searchsorted(cdf, rng.random(1000)), 3_999)
+            batch = [
+                RateLimitReq(
+                    name="fp", unique_key=f"z{k}", duration=60_000,
+                    limit=100, hits=int(h),
+                    algorithm=(Algorithm.LEAKY_BUCKET if k % 2
+                               else Algorithm.TOKEN_BUCKET),
+                )
+                for k, h in zip(ranks.tolist(), rng.integers(0, 3, 1000))
+            ]
+            assert max(np.bincount(ranks)) > eng_a.cfg.max_waves
+            call = tracing.CallRecord({})
+            raw = fastpath.try_serve(svc, to_proto_bytes(batch), False, call)
+            assert (call.path, call.reason) == ("columnar", "")
+            want = pb.pb.GetRateLimitsResp()
+            for r in eng_b.check_batch(
+                [dataclasses.replace(r) for r in batch]
+            ):
+                want.responses.append(pb.resp_to_pb(r))
+            assert raw == want.SerializeToString(), f"seed {seed} step {step}"
+            assert eng_a.metrics.recorder.last()["waves"] > eng_a.cfg.max_waves
+        assert any(r.status for r in want.responses)  # some OVER_LIMIT
+    finally:
+        eng_a.close()
+        eng_b.close()

@@ -126,10 +126,11 @@ class Landed:
     def _flush(self, *a, **kw):
         out = self.flush(*a, **kw)
         r_asm = self.seen.asm[4]  # every item is GLOBAL: the replica waves
-        operand, lanes = r_asm[0], r_asm[3]
-        home = np.asarray(operand.home[lanes])
+        waves, at = r_asm[0], list(zip(*r_asm[3]))  # each item's (wave, lane)
+        home = np.array([waves[w].home[lane] for w, lane in at])
         groups_per = self.eng.num_rgroups // N_DEV
-        owner = np.asarray(operand.batch.group[lanes]) // groups_per
+        owner = np.array(
+            [waves[w].batch.group[lane] for w, lane in at]) // groups_per
         took = np.asarray(out[0]) == UNDER_LIMIT
         with self.lock:
             self.accepted_off_owner += int(np.sum(took & (home != owner)))

@@ -121,20 +121,24 @@ def test_a_store_waves_gap_goes_to_its_stage_and_its_programs_are_named():
 # ---- a capture of a serving daemon ------------------------------------------
 
 
-def body(keys) -> bytes:
+def body(keys, slow=False) -> bytes:
+    """`slow`: an item carries metadata, so the call needs the object
+    path (fastpath's reason `slow_item`)."""
     msg = pb.pb.GetRateLimitsReq()
     for k in keys:
         r = msg.requests.add()
         r.name, r.unique_key = "gaps", k
         r.hits, r.limit, r.duration = 1, 1_000_000, 60_000
+    if slow:
+        msg.requests[-1].metadata["tenant"] = "t"
     return msg.SerializeToString()
 
 
 @pytest.fixture(scope="module")
 def capture(loop_thread, tmp_path_factory):
     """The host spans (profile_gaps.read_trace) of a 3 s capture taken
-    while calls are served: most columnar, one in five refused at
-    max_waves and served by the object path through the pump."""
+    while calls are served: most columnar, one in five with an item
+    that carries metadata, served by the object path through the pump."""
     root = str(tmp_path_factory.mktemp("profiles"))
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(profiler, "trace_root", lambda: root)
@@ -160,8 +164,8 @@ def capture(loop_thread, tmp_path_factory):
                         break
                     call(body(["warm"]), timeout=30)
                 for i in range(40):
-                    call(body(["dup"] * 40 if i % 5 == 4
-                              else [f"a{i}", f"b{i}"]), timeout=30)
+                    call(body([f"a{i}", f"b{i}"], slow=i % 5 == 4),
+                         timeout=30)
             t.join(60)
             assert reply.get("trace_dir"), reply
             _, spans = profile_gaps.read_trace(

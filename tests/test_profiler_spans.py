@@ -35,19 +35,23 @@ def daemon(loop_thread, tmp_path_factory):
         loop_thread.run(d.close())
 
 
-def body(keys) -> bytes:
+def body(keys, slow=False) -> bytes:
+    """`slow`: an item carries metadata, so the call needs the object
+    path (fastpath's reason `slow_item`)."""
     msg = pb.pb.GetRateLimitsReq()
     for k in keys:
         r = msg.requests.add()
         r.name, r.unique_key = "prof", k
         r.hits, r.limit, r.duration = 1, 1_000_000, 60_000
+    if slow:
+        msg.requests[-1].metadata["tenant"] = "t"
     return msg.SerializeToString()
 
 
 def capture_while_serving(daemon, query: str) -> tuple:
     """(/debug/profile's reply, host spans) of a capture taken while
-    CALLS calls are served: most columnar, one in ten refused at
-    max_waves and served by the object path."""
+    CALLS calls are served: most columnar, one in ten with an item
+    that carries metadata, served by the object path."""
     started = threading.Event()
     reply = {}
 
@@ -72,8 +76,7 @@ def capture_while_serving(daemon, query: str) -> tuple:
                 break
             call(body(["warm"]), timeout=30)
         for i in range(CALLS):
-            call(body(["dup"] * 40 if i % 10 == 9 else [f"a{i}", f"b{i}"]),
-                 timeout=30)
+            call(body([f"a{i}", f"b{i}"], slow=i % 10 == 9), timeout=30)
     t.join(60)
     assert not t.is_alive() and "trace_dir" in reply, reply
     return reply, read_host_plane(reply["trace_dir"])

@@ -8,6 +8,10 @@ holds the reference's last state of every key touched; (c) the Store
 path's counters (gubernator_store_*, gubernator_engine_wave_programs)
 agree with what the Store saw and with each other.
 
+Every call is served columnar: a 1,000-item call holds its hot key more
+than `max_waves` times and is one flush of that many waves, each through
+the Store's per-wave sequence.
+
 The small twin of the benchmark's `store-1m.calls100` (PERF.md §4).
 """
 
@@ -129,27 +133,25 @@ def run(request):
             now = clock["now"]
             before = store_counts(eng.metrics)
             cols = wire.parse_requests(to_proto_bytes(reqs))
-            got = eng.check_columns(cols, now=now)
-            columnar = got is not None
-            if columnar:
-                got = list(zip(*(a.tolist() for a in got)))
-            else:  # over max_waves: the object path, same Store sequence
-                got = [(int(r.status), r.limit, r.remaining, r.reset_time)
-                       for r in eng.check_batch(
-                           [dataclasses.replace(r) for r in reqs])]
+            # columnar whatever its size: a 1,000-item call holds its
+            # hot key over max_waves times and runs the Store's per-wave
+            # sequence for every one of its waves, in one flush
+            got = list(zip(*(
+                a.tolist() for a in eng.check_columns(cols, now=now))))
             want = [oracle.decide(dataclasses.replace(r), now) for r in reqs]
             want = [(int(w.status), w.limit, w.remaining, w.reset_time)
                     for w in want]
             after = store_counts(eng.metrics)
             out["answers"].append((len(reqs), got, want))
-            if columnar:
-                out["columnar"].append((
-                    len(reqs), len({r.hash_key() for r in reqs}),
-                    {k: after[k] - before[k] for k in after}))
+            out["columnar"].append((
+                len(reqs), len({r.hash_key() for r in reqs}),
+                {k: after[k] - before[k] for k in after}))
             out["touched"].update(r.hash_key() for r in reqs)
         em = eng.metrics
         out["counts"] = store_counts(em)
         out["waves"] = em.waves
+        out["over_max_waves"] = int(
+            em.flushes_over_max_waves.labels().get())
         out["evictions"] = em.unexpired_evictions
     finally:
         eng.close()
@@ -161,11 +163,10 @@ def test_answers_equal_the_capacity_free_reference(run):
     assert run["evictions"] > 100 and run["counts"]["hit"] > 100
     for n, (size, got, want) in enumerate(run["answers"]):
         assert got == want, f"call {n} of {size} items"
-    sizes = [size for size, _, _ in run["columnar"]]
-    # the 2- and 100-item calls stay columnar; a 1,000-item call holds
-    # its hot key over max_waves times and takes the object path
-    assert sizes.count(2) == SIZES.count(2)
-    assert sizes.count(100) == SIZES.count(100)
+    # every call was served columnar; the 1,000-item ones as flushes of
+    # more than max_waves waves
+    assert sorted(size for size, _, _ in run["columnar"]) == sorted(SIZES)
+    assert run["over_max_waves"] == SIZES.count(1_000)
 
 
 def test_store_holds_the_references_last_states(run):
@@ -205,9 +206,7 @@ def test_store_counters_add_up(run):
         # every Store hit is injected before its wave's decide, and
         # nothing else counts as a row read through
         assert d["hit"] == d["injected"], size
-    # the object path may prefetch a key whose item the wave cap carries
-    # to the next flush, which asks the Store again: a hit more than rows
-    assert 0 < c["injected"] <= c["hit"]
+    assert 0 < c["injected"] == c["hit"]
     # one probe, one decide and one row gather a wave; an inject only
     # where a wave had a row to seat
     assert c["decide"] == c["probe"] == c["gather_rows"] == run["waves"]

@@ -111,14 +111,19 @@ def test_store_flush_counts_one_each_for_its_decide(engine, flush):
 
 
 @needs_wire
-def test_refused_columnar_attempt_counts_nothing(engine):
-    """33 of one key is over max_waves: the attempt is refused before
-    anything is uploaded."""
-    before = transfers(engine)
-    assert engine.check_columns(
+def test_columnar_call_over_max_waves_counts_its_launches(engine):
+    """33 of one key is over max_waves: two launches of one flush (32
+    waves and 1), one operand in and one read out each. Until ISSUE 44
+    the attempt was refused and counted nothing."""
+    h0, d0, w0 = transfers(engine)
+    out = engine.check_columns(
         columns([mk("over") for _ in range(33)]), now=NOW
-    ) is None
-    assert transfers(engine) == before
+    )
+    assert out[2].tolist() == list(range(999, 966, -1))
+    h1, d1, w1 = transfers(engine)
+    assert (w1 - w0, h1 - h0, d1 - d0) == (33, 2, 2)
+    rec = engine.metrics.recorder.last()
+    assert (rec["waves"], rec["launches"]) == (33, 2)
 
 
 def test_counter_is_exported_per_direction(engine):
