@@ -679,6 +679,10 @@ def engine_wave_transfers() -> _BareCounter:
 STORE_WAVE_PROGRAMS = ("probe", "inject", "decide", "gather_rows")
 
 
+# How the waves of a flush with a Store ran under the engine lock.
+STORE_SEQUENCES = ("stacked", "per_wave")
+
+
 def engine_wave_programs() -> _BareCounter:
     """The engine-owned counter of the device programs the Store's
     per-wave sequence launches, added where the engine observes
@@ -757,6 +761,20 @@ def engine_store_counters() -> dict:
     )
     for result in ("hit", "miss"):
         gets.labels(result).inc(0)
+    flushes = _BareCounter(
+        "gubernator_engine_store_flushes",
+        "Flushes that ran with a Store attached, by the sequence their "
+        "waves ran under the engine lock: stacked (every run of waves "
+        "was probed once, decided once and gathered once: one launch "
+        "of each program and one read a run, after the probe found "
+        "every lane live) or per_wave (probe, read-through, decide and "
+        "row gather wave by wave: a flush of one wave, one that reads "
+        "through, one with a RESET_REMAINING lane, a paged table, or a "
+        "stacked shape that is not warm).",
+        ["sequence"],
+    )
+    for sequence in STORE_SEQUENCES:
+        flushes.labels(sequence).inc(0)
     return {
         "store_gets": gets,
         "store_injected_rows": _BareCounter(
@@ -791,6 +809,16 @@ def engine_store_counters() -> dict:
             "lock) before they began their own, or before a Store.get "
             "under the engine lock. The wait is under the engine lock: "
             "0 while a hand-over is shorter than the next flush's hold.",
+        ),
+        "store_flushes": flushes,
+        "store_stacked_surprises": _BareCounter(
+            "gubernator_engine_store_stacked_surprises",
+            "Stacked launches of a Store flush whose output, read after "
+            "the engine lock, shows what the stacked sequence rules "
+            "out: a lane that missed, a key displaced, a row freed. "
+            "Must read 0: a run is stacked only after its one probe "
+            "found every lane live and no lane asks for "
+            "RESET_REMAINING.",
         ),
     }
 

@@ -28,7 +28,7 @@ from gubernator_tpu.ops.layout import (
     SlotTable,
     gathered_rows,
     packed_cols,
-    unpack_operand,
+    probed_waves,
     vary_like,
 )
 
@@ -509,9 +509,12 @@ def probe_exists(table: SlotTable, operand, ways: int = 8):
     (algorithms.go:45-51), and the table, not host bookkeeping, is what
     defines a miss. `operand` is the wave's own uploaded operand, the one
     its decide takes next (ops/layout.py unpack_operand): the probe
-    brings nothing across the boundary but its answer."""
-    batch, _home, now = unpack_operand(operand)
-    return _probe_exists_impl(table, batch, now, ways)
+    brings nothing across the boundary but its answer. A stacked run's
+    operand is probed whole, (W, B) (ops/layout.py probed_waves)."""
+    return probed_waves(
+        lambda batch, now: _probe_exists_impl(table, batch, now, ways),
+        operand,
+    )
 
 
 def _gather_cols(table: SlotTable, safe):

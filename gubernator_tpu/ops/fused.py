@@ -89,7 +89,7 @@ from gubernator_tpu.ops.layout import (
     gathered_rows,
     pack_meta as _pack_meta,
     packed_cols,
-    unpack_operand,
+    probed_waves,
     wide_rows as _wide,
 )
 
@@ -659,9 +659,12 @@ def _probe_exists_fused_impl(table: FusedTable, batch, now, ways: int):
 @functools.partial(jax.jit, static_argnames=("ways",))
 def probe_exists_fused(table: FusedTable, operand, ways: int = 8):
     """Residency probe (store read-through seam), fused layout, of the
-    wave's own uploaded operand (ops/layout.py unpack_operand)."""
-    batch, _home, now = unpack_operand(operand)
-    return _probe_exists_fused_impl(table, batch, now, ways)
+    wave's own uploaded operand, or of a stacked run's, whole
+    (ops/layout.py probed_waves)."""
+    return probed_waves(
+        lambda batch, now: _probe_exists_fused_impl(table, batch, now, ways),
+        operand,
+    )
 
 
 def _gather_cols(table: FusedTable, safe):

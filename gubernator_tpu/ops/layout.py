@@ -232,8 +232,18 @@ def wide_rows(cols) -> SlotTable:
 
 def output_slots(vec):
     """Row OUT_SLOT of the vector a `with_store` decide produced, inside
-    a jit: the slot each lane's row was written to."""
-    return vec[:-OUT_TOTALS].reshape(OUT_STORE_ROWS, -1)[OUT_SLOT]
+    a jit: the slot each lane's row was written to, (B,); of a stacked
+    run's (W, L) output, every wave's, (W, B)."""
+    rows = vec[..., :-OUT_TOTALS].reshape(vec.shape[:-1] + (OUT_STORE_ROWS, -1))
+    return rows[..., OUT_SLOT, :]
+
+
+def rows_of_slots(gather, slots):
+    """`gather(flat slots) -> (NCOLS, lanes)` over `slots` (B,) or a
+    run's (W, B) as ONE gather of every lane: (NCOLS, B), or a run's
+    (W, NCOLS, B), one packed array a wave."""
+    rows = gather(slots.reshape(-1))
+    return jnp.moveaxis(rows.reshape((NCOLS,) + slots.shape), 0, -2)
 
 
 def gathered_rows(gather, slots, num_slots: int, from_output: bool):
@@ -243,11 +253,17 @@ def gathered_rows(gather, slots, num_slots: int, from_output: bool):
     slot column never leaves the device. `gather(safe)` gives the
     (NCOLS, B) columns of in-range slots; a slot past the table (a
     padding lane's) reads zeros. Returns the packed rows, (NCOLS, B)
-    int64."""
+    int64. A stacked run's (W, L) output gives (W, NCOLS, B): every
+    wave's lanes in the one gather, read from the table as the run's
+    last wave left it (a padding wave's rows are never looked at)."""
     if from_output:
         slots = output_slots(slots)
-    rows = gather(jnp.clip(slots, 0, num_slots - 1))
-    return jnp.where((slots < num_slots)[None, :], rows, 0)
+
+    def safe_rows(flat):
+        rows = gather(jnp.clip(flat, 0, num_slots - 1))
+        return jnp.where((flat < num_slots)[None, :], rows, 0)
+
+    return rows_of_slots(safe_rows, slots)
 
 
 class WaveOperand:
@@ -320,6 +336,11 @@ class WaveOperand:
         """The first `lanes` lanes as an operand of its own."""
         return WaveOperand(np.ascontiguousarray(self.buf[..., :lanes]))
 
+    def asks(self, behavior: int) -> bool:
+        """Whether any lane carries one of the `behavior` bits (read
+        off the shared word: no field view is built)."""
+        return bool((self.buf[..., OP_GROUP_BEHAVIOR, :] & (behavior << 32)).any())
+
     def stamp(self, now: int) -> "WaveOperand":
         self.buf[..., OP_NOW, 0] = now
         return self
@@ -339,6 +360,31 @@ def unpack_operand(operand):
         **{f: operand[i] for i, f in enumerate(_OP_I64)},
     )
     return batch, operand[OP_HOME], operand[OP_NOW, 0]
+
+
+def probed_waves(probe, operand):
+    """THE body of every layout's probe_exists, inside its jit:
+    `probe(batch, now) -> bool[lanes]` over one wave's uploaded operand,
+    (B,), or over a stacked run's (W, OPERAND_ROWS, B) as ONE batch of
+    W * B lanes, (W, B): the table is only read, so the waves are
+    independent, and a flush stamps one `now` into all of them."""
+    if operand.ndim == 2:
+        batch, _home, now = unpack_operand(operand)
+        return probe(batch, now)
+    depth, rows, lanes = operand.shape
+    batch, _home, now = unpack_operand(
+        jnp.swapaxes(operand, 0, 1).reshape(rows, depth * lanes)
+    )
+    return probe(batch, now).reshape(depth, lanes)
+
+
+@jax.jit
+def operand_waves(operand):
+    """The waves of a stacked operand that is on the device, as
+    operands of their own and placed as it is: one small program and no
+    upload, for the run whose stacked probe found a lane not live
+    (runtime/engine.py _execute_waves)."""
+    return tuple(operand[w] for w in range(operand.shape[0]))
 
 
 def vary_like(values, refs):

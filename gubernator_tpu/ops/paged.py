@@ -61,7 +61,7 @@ from gubernator_tpu.ops.kernels import (
 from gubernator_tpu.ops.layout import (
     SlotTable,
     packed_waves,
-    unpack_operand,
+    probed_waves,
     wide_rows,
 )
 
@@ -256,9 +256,11 @@ def make_paged_kernels(
 
     @jax.jit
     def _probe_exists(pt, operand):
-        batch, _home, now = unpack_operand(operand)
-        b = batch._replace(group=_xlate(pt.page_map, batch.group))
-        return raw.probe_exists(pt.data, b, now, ways)
+        def probe(batch, now):
+            b = batch._replace(group=_xlate(pt.page_map, batch.group))
+            return raw.probe_exists(pt.data, b, now, ways)
+
+        return probed_waves(probe, operand)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def _bind_page(pt, lp, pp):

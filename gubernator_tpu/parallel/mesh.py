@@ -33,7 +33,8 @@ from gubernator_tpu.ops.layout import (
     SlotTable,
     output_slots,
     packed_waves,
-    unpack_operand,
+    probed_waves,
+    rows_of_slots,
     wide_rows,
 )
 from gubernator_tpu.utils import lockorder, transfer
@@ -185,7 +186,9 @@ def _sharded_gather_rows(mesh: Mesh, slots_per: int, gather_cols):
     shard reads the lanes whose slot lies in its slice out of that
     slice, `gather_cols(slice, local slots)`, and gives zeros for the
     rest; one psum hands every device every lane's row. A slot past the
-    table (a padding lane's) is in nobody's slice and reads zeros."""
+    table (a padding lane's) is in nobody's slice and reads zeros. A
+    stacked run's (W, L) output gives (W, NCOLS, B): every wave's lanes
+    in the one gather and the one psum (ops/layout.py rows_of_slots)."""
 
     def local(data, slots):
         with jax.named_scope("owner_mask"):
@@ -203,7 +206,10 @@ def _sharded_gather_rows(mesh: Mesh, slots_per: int, gather_cols):
 
     @functools.partial(jax.jit, static_argnames=("from_output",))
     def gather_rows_fn(table, slots, from_output=False):
-        return sharded(table, output_slots(slots) if from_output else slots)
+        return rows_of_slots(
+            functools.partial(sharded, table),
+            output_slots(slots) if from_output else slots,
+        )
 
     return gather_rows_fn
 
@@ -216,16 +222,22 @@ def _sharded_probe_exists(
     replicated. Each shard probes the lanes it owns against its slice
     (`probe(slice, batch, now, ways)`, the decide's ownership mask, the
     paged `xlate` before it) and answers False for the rest; one psum of
-    the (B,) answers gives every device every lane's."""
+    the (B,) answers gives every device every lane's. A stacked run's
+    operand is probed whole, (W, B), in the one probe and the one psum
+    (ops/layout.py probed_waves)."""
 
     def local(data, operand, *page_map):
-        batch, _home, now = unpack_operand(operand)
-        with jax.named_scope("owner_mask"):
-            if xlate is not None:
-                batch = batch._replace(group=xlate(page_map[0], batch.group))
-            mine = _mask_to_local(groups_per, batch)
-        with jax.named_scope("probe_local"):
-            found = probe(data, mine, now, ways)
+        def probe_owned(batch, now):
+            with jax.named_scope("owner_mask"):
+                if xlate is not None:
+                    batch = batch._replace(
+                        group=xlate(page_map[0], batch.group)
+                    )
+                mine = _mask_to_local(groups_per, batch)
+            with jax.named_scope("probe_local"):
+                return probe(data, mine, now, ways)
+
+        found = probed_waves(probe_owned, operand)
         with jax.named_scope("psum_probe"):
             return jax.lax.psum(found.astype(jnp.int32), AXIS) != 0
 

@@ -27,6 +27,7 @@ granularity also guarantees scatter-disjointness inside each wave.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import itertools
 import queue
@@ -43,6 +44,7 @@ from gubernator_tpu.utils import raceguard
 from gubernator_tpu.metrics import (
     ENGINE_STAGES,
     FLUSH_STAGES,
+    STORE_SEQUENCES,
     STORE_WAVE_PROGRAMS,
     engine_flushes_over_max_waves,
     engine_histograms,
@@ -73,6 +75,7 @@ from gubernator_tpu.ops.layout import (
     OUT_TOTALS,
     SlotTable,
     WaveOperand,
+    operand_waves,
     split_output,
     wide_rows,
 )
@@ -263,6 +266,9 @@ class EngineMetrics:
         self.store_counters = tuple(stores.values())
         self._store_hit = self.store_gets.labels("hit")
         self._store_miss = self.store_gets.labels("miss")
+        self._store_flush = {
+            q: self.store_flushes.labels(q) for q in STORE_SEQUENCES
+        }
         # Pre-resolved stage children (labels() lookups are per-flush
         # hot-path cost).
         self._stage = {
@@ -374,7 +380,8 @@ class EngineMetrics:
                       dev: float, trace_id: str = "",
                       collective: bool = False, transfers=(0, 0),
                       launches: int = 0, programs=None,
-                      crossings=None, calls: int = 1) -> None:
+                      crossings=None, calls: int = 1,
+                      sequence: Optional[str] = None) -> None:
         """One flush's distribution samples (per FLUSH, not per
         request). A non-empty trace_id attaches an OpenMetrics exemplar
         to the latency buckets this flush lands in, so a p99 spike in
@@ -387,10 +394,11 @@ class EngineMetrics:
         `launches` the decide programs it launched (a run of equally
         wide waves is one operand, one launch, one output), counted
         beside the waves themselves so a scrape sees all or none, as
-        are `programs`, the launches of the Store's per-wave sequence
-        by STORE_WAVE_PROGRAMS name, and `crossings`, the [uploaded,
-        read] arrays that sequence moved under the engine lock (both
-        None without a Store). `calls` is the calls the flush served:
+        are `programs`, the launches of the Store's sequence by
+        STORE_WAVE_PROGRAMS name, `crossings`, the [uploaded, read]
+        arrays that sequence moved under the engine lock, and
+        `sequence`, how the flush's waves ran it (STORE_SEQUENCES; all
+        three None without a Store). `calls` is the calls the flush served:
         the members of a columnar flush (check_columns' group commit),
         the distinct calls a pump flush coalesced."""
         self.flush_duration.labels(path).observe(dur, trace_id)
@@ -406,6 +414,7 @@ class EngineMetrics:
                 self._wave_program[name].inc(n)
             for child, n in zip(self._store_crossing, crossings):
                 child.inc(n)
+            self._store_flush[sequence].inc()
         if collective:
             self.collective_tick.observe(dev)
 
@@ -418,7 +427,7 @@ class FlushStages:
 
     __slots__ = (
         "em", "ids", "us", "_rows", "h2d", "d2h", "launches", "programs",
-        "crossings", "calls", "in_host_stage",
+        "crossings", "sequence", "calls", "in_host_stage",
     )
 
     def __init__(self, em: EngineMetrics, flush: int, call: int):
@@ -435,12 +444,14 @@ class FlushStages:
         self.h2d = 0
         self.d2h = 0
         self.launches = 0
-        # with a Store: the launches of its per-wave sequence by
+        # with a Store: the launches of its sequence by
         # STORE_WAVE_PROGRAMS name (_execute_waves)
         self.programs: Optional[Dict[str, int]] = None
-        # and the arrays that sequence moved across the host-device
+        # the arrays that sequence moved across the host-device
         # boundary under the engine lock, [uploaded, read]
         self.crossings: Optional[List[int]] = None
+        # and how the flush's waves ran it (STORE_SEQUENCES)
+        self.sequence: Optional[str] = None
         # every key from the start: the record shares this dict, and a
         # /debug/engine dump may walk it while publish() fills it in
         self.us: Dict[str, int] = dict.fromkeys(FLUSH_STAGES, 0)
@@ -549,18 +560,20 @@ def _read_waves(outs, fs: FlushStages, with_store: bool = False):
 
 class _StoreWaves:
     """What a flush with a Store carries out of the engine lock, and its
-    turn at the Store (docs/persistence.md "the per-wave sequence").
+    turn at the Store (docs/persistence.md "What a Store costs").
 
-    Under the lock a wave launches its programs, starts the host copies
-    of its output vector and of its packed rows (K.gather_rows) and
-    reads only the probe's answer: `rows[w]` is wave w's packed rows
-    still on the device and `pre[w]` the events its read-through made
-    (an inject's displacements and inserts). read(), in the flush's
-    `flush.readback` stage after the release, reads both arrays of
-    every wave and builds `events` in the order the waves ran. A later
-    wave that needs an earlier one's rows under the lock (a key
-    displaced between its own waves, _wave_readthrough's freshness rule
-    1) reads them then, through host_rows.
+    Under the lock a launch (a wave, or a run of waves that ran
+    stacked) starts the host copies of its output and of its packed
+    rows (K.gather_rows) and reads only the probe's answer: `runs`
+    holds a launch's (first wave, waves, packed rows still on the
+    device), `pre[w]` the events wave w's read-through made (an
+    inject's displacements and inserts; none where the run was
+    stacked). read(), in the flush's `flush.readback` stage after the
+    release, reads both arrays of every launch and builds `events` in
+    the order the waves ran. A later wave that needs an earlier one's
+    rows under the lock (a key displaced between its own waves,
+    _wave_readthrough's freshness rule 1) reads them then, through
+    host_rows.
 
     The hand-over: `_lock` is the engine's hand-over lock. take() is
     called under the engine lock, as the last thing before its release
@@ -572,18 +585,20 @@ class _StoreWaves:
     release() may be called again: every way out of a flush calls it."""
 
     __slots__ = (
-        "lane_reqs", "rows", "pre", "events", "nbytes", "_lock", "_waits",
-        "_held",
+        "lane_reqs", "runs", "rows", "pre", "events", "nbytes", "_lock",
+        "_em", "_held",
     )
 
-    def __init__(self, lane_reqs, lock, waits):
+    def __init__(self, lane_reqs, lock, em: EngineMetrics):
         self.lane_reqs = lane_reqs
-        self.rows: List[object] = []
+        self.runs: List[Tuple[int, int, object]] = []
+        # wave w's rows as the wide struct, once its launch's are read
+        self.rows: List[Optional[SlotTable]] = []
         self.pre: List[list] = []
         self.events: List[Tuple[str, Tuple[int, int]]] = []  # ('d'|'i', key)
         self.nbytes = 0  # of the packed rows read so far
         self._lock = lock
-        self._waits = waits
+        self._em = em
         self._held = False
 
     def take(self) -> None:
@@ -592,7 +607,7 @@ class _StoreWaves:
         if not self._lock.acquire(blocking=False):
             # the flush before this one is still reading or writing
             # behind (gubernator_store_handover_waits)
-            self._waits.inc()
+            self._em.store_handover_waits.inc()
             self._lock.acquire()
         self._held = True
 
@@ -601,29 +616,54 @@ class _StoreWaves:
             self._held = False
             self._lock.release()
 
+    def add(self, first: int, n: int, rows) -> None:
+        """A launch's packed rows, still on the device: (NCOLS, B) of
+        wave `first`, or (depth, NCOLS, B) of the `n` waves from it
+        that ran stacked."""
+        self.runs.append((first, n, rows))
+        self.rows += [None] * n
+
     def host_rows(self, w: int) -> Tuple[SlotTable, bool]:
         """(wave w's gathered rows as the wide struct on the host,
-        whether this call was the one that read them)."""
+        whether this call was the one that read its launch's)."""
         r = self.rows[w]
-        if isinstance(r, SlotTable):
+        if r is not None:
             return r, False
-        packed = np.asarray(r)  # guberlint: allow-host-sync -- store path: a wave's packed rows, one read; in flush.readback, or under the lock only for a key displaced between its own waves
+        first, n, dev = next(
+            run for run in self.runs if run[0] <= w < run[0] + run[1]
+        )
+        packed = np.asarray(dev)  # guberlint: allow-host-sync -- store path: a launch's packed rows, one read; in flush.readback, or under the lock only for a key displaced between its own waves
         self.nbytes += packed.nbytes
-        r = self.rows[w] = wide_rows(packed)
-        return r, True
+        if packed.ndim == 2:
+            self.rows[first] = wide_rows(packed)
+        else:
+            self.rows[first:first + n] = [wide_rows(p) for p in packed[:n]]
+        return self.rows[w], True
 
     def read(self, outs, fs: FlushStages):
         """_read_waves for a flush with a Store, after the release: the
-        output vector and the packed rows of every wave (their copies
-        were started under the lock), then `events` per wave as the
-        sequence made them: the inject's displacements and inserts,
-        the decide's evictions, the keys served
-        (_drop_displaced_strings acts on a key's LAST event). The table
-        has committed by now: a failed read raises TableCommittedError,
-        so that nobody retries the flush through another path, and
-        gives up the flush's turn at the Store."""
+        output and the packed rows of every launch (their copies were
+        started under the lock), then `events` per wave as the sequence
+        made them: the inject's displacements and inserts, the decide's
+        evictions, the keys served (_drop_displaced_strings acts on a
+        key's LAST event). A launch that ran stacked is held to what
+        its probe promised (gubernator_engine_store_stacked_surprises).
+        The table has committed by now: a failed read raises
+        TableCommittedError, so that nobody retries the flush through
+        another path, and gives up the flush's turn at the Store."""
         try:
-            out_rows, totals = _read_waves(outs, fs, True)
+            out_rows: list = []
+            totals = [0] * OUT_TOTALS
+            for out, (_first, n, _dev) in zip(outs, self.runs):
+                o_rows, tot = _read_waves([out], fs, True)
+                out_rows += o_rows
+                totals = [a + b for a, b in zip(totals, tot)]
+                # a stacked run: no miss (tot[1]), and rows
+                # OUT_EVICTED_HI, OUT_EVICTED_LO, OUT_FREED all zero
+                if n > 1 and (tot[1] or any(
+                    r[OUT_EVICTED_HI:OUT_FREED + 1].any() for r in o_rows
+                )):
+                    self._em.store_stacked_surprises.inc()
             events = self.events
             for w, o_rows in enumerate(out_rows):
                 self.host_rows(w)
@@ -1933,6 +1973,9 @@ class MeshEngine(EngineBase):
         # The stacked launch's warm (depth, width) shapes, published as
         # _warm_shapes is: _warmup's at batch_size, the ladder's after.
         self._warm_stacks: tuple = ()
+        # The same for a Store's stacked sequence (probe, `with_store`
+        # decide and row gather of a run): warm_store_path's.
+        self._warm_store_stacks: tuple = ()
         # Set when the ladder's stacked shapes are wanted (_warm_buckets).
         self._stack_wanted = threading.Event()
         # Group commit at check_columns' entry.
@@ -2405,18 +2448,23 @@ class MeshEngine(EngineBase):
         as many launches as it takes, all of this one flush and issued
         under its one hold of the engine lock (_execute_waves). What
         the engine observes decides: a run of
-        one wave stays the single-wave operand; a Store or a pager keeps
-        the per-wave sequence (read-through, the row gather and page
-        promotion are defined per wave), as `stack=False` does for the
-        replica tier's waves; a stacked shape that is not warm is not
+        one wave stays the single-wave operand; a pager keeps the
+        per-wave sequence (page promotion is defined per wave), as
+        `stack=False` does for the replica tier's waves and for a Store
+        flush that already knows it will read through; with a Store a
+        lane that can free its row (RESET_REMAINING, the one way `used`
+        goes false: ops/decide.py _token_paths) keeps it too, and the
+        stacked shapes are the Store sequence's own
+        (warm_store_path); a stacked shape that is not warm is not
         used (no compile on the serving path)."""
         if not waves:
             return []
-        stack = (
-            stack and len(waves) > 1
-            and self.store is None and self._pager is None
-        )
+        stack = stack and len(waves) > 1 and self._pager is None
         warm = self._warm_stacks  # immutable snapshot
+        if stack and self.store is not None:
+            warm = self._warm_store_stacks
+            reset = int(Behavior.RESET_REMAINING)
+            stack = not any(w.asks(reset) for w in waves)
         runs, bufs = [], []
         w = 0
         while w < len(waves):
@@ -2447,20 +2495,34 @@ class MeshEngine(EngineBase):
     def warm_store_path(self) -> None:
         """Compile the store-path kernels (the with_store decide variant,
         probe_exists, gather_rows) at serving shapes so the first flush
-        doesn't cold-compile under the serving lock. Called by
-        attach_store — at daemon init, before traffic, so briefly holding
-        the lock here is free."""
+        doesn't cold-compile under the serving lock: a wave's, and a
+        stacked run's at every depth the serving path may pick (with
+        the program that hands a run's waves back one by one where its
+        probe finds a lane not live). Called by attach_store — at
+        daemon init, before traffic, so briefly holding the lock here
+        is free."""
         cfg = self.cfg
-        op = self._warm_operand(cfg.batch_size, self.now_fn())
+        now = self.now_fn()
+        depths = () if self._pager is not None else self._wave_depths()
+        ops = [
+            self._warm_operand(cfg.batch_size, now, depth)
+            for depth in (None,) + depths
+        ]
         with self._lock, self.topo.dispatch_guard(), _transfer.account(
             self.metrics, "d2h", "warmup"
         ) as tx:
-            # an empty wave through the serving sequence, shape for shape
-            tx.add(np.asarray(self.K.probe_exists(self.table, op, cfg.ways)))
-            table, out = self.K.decide_packed(self.table, op, cfg.ways, True)
-            self.table = table
-            tx.add(np.asarray(self.K.gather_rows(table, out, True)))
-            tx.add(np.asarray(out))
+            # an empty wave, then an empty run, through the serving
+            # sequence, shape for shape
+            table = self.table
+            for op in ops:
+                tx.add(np.asarray(self.K.probe_exists(table, op, cfg.ways)))
+                table, out = self.K.decide_packed(table, op, cfg.ways, True)
+                self.table = table
+                tx.add(np.asarray(self.K.gather_rows(table, out, True)))
+                tx.add(np.asarray(out))
+                if op.ndim == 3:
+                    jax.block_until_ready(operand_waves(op))  # guberlint: allow-host-sync allow-blocking-under-lock -- warm-up at attach time, before traffic: the compile must end before the shape is published
+        self._warm_store_stacks = tuple((d, cfg.batch_size) for d in depths)
 
     # ---- introspection -----------------------------------------------------
 
@@ -2839,6 +2901,7 @@ class MeshEngine(EngineBase):
         # residency) are prefetched HERE; the per-wave probe catches the
         # rare remainder (displaced keys) with a direct fetch.
         prefetched: Dict[Tuple[int, int], object] = {}
+        need: list = []  # never-seen keys: the flush knows it reads through
         with tracing.stage("flush.keydict", fs, fs.ids):
             if self.store is not None and cfg.keep_key_strings:
                 # the replica tier's GLOBAL buckets are never persisted,
@@ -2847,7 +2910,6 @@ class MeshEngine(EngineBase):
                     int(Behavior.GLOBAL) if self._rtier is not None else 0
                 )
                 with self._keys_lock:
-                    need = []
                     seen = set()
                     for i, (req, _) in enumerate(items):
                         if req.behavior & replica:
@@ -2992,7 +3054,7 @@ class MeshEngine(EngineBase):
             if r_waves:
                 self._note_replica_decisions(r_homes)
             n_waves = len(waves) + len(r_waves)
-            ops = self._upload(waves, now, fs)
+            ops = self._upload(waves, now, fs, stack=not need)
             r_ops = self._upload(r_waves, now, fs, stack=False)
         fspan = self._start_flush_span(
             items, seq, path="object", layout=cfg.layout,
@@ -3073,6 +3135,7 @@ class MeshEngine(EngineBase):
                 collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
                 launches=fs.launches, programs=fs.programs,
                 crossings=fs.crossings, calls=fs.calls,
+                sequence=fs.sequence,
             )
             em.observe_stage("assemble", t.t_dev - t.t0)
             # `dispatch` (the launches under the lock) and `lock_wait` were
@@ -3087,6 +3150,7 @@ class MeshEngine(EngineBase):
                 dur_us=int(dur * 1e6), dev_us=int(dev_s * 1e6),
                 ticket=t.seq, trace_id=t.trace_id or "",
                 call=fs.ids["call"], calls=fs.calls, stages_us=fs.us,
+                sequence=fs.sequence,
             )
 
             # Write-behind BEFORE resolving futures, so a caller that observed
@@ -3430,9 +3494,10 @@ class MeshEngine(EngineBase):
         prefetched: Dict[Tuple[int, int], object] = {}
         lane_reqs: List[Dict[int, tuple]] = [{} for _ in range(W)]
         resolver = wb_entries = None
+        reads_through = False
         with tracing.stage("flush.keydict", fs, fs.ids):
             if store is not None:
-                prefetched, lane_reqs, resolver, wb_entries = (
+                prefetched, lane_reqs, resolver, wb_entries, reads_through = (
                     self._store_columns_prework(
                         orig_cols, sel_map, hi, lo, wave, lane, W
                     )
@@ -3459,7 +3524,7 @@ class MeshEngine(EngineBase):
                     self._maybe_prune_key_strings()
 
         with tracing.stage("flush.waves", fs, fs.ids):
-            ops = self._upload(wave_slices, now, fs)
+            ops = self._upload(wave_slices, now, fs, stack=not reads_through)
         _telemetry.set_shape_hint(f"{cfg.layout}:columnar:{W}x{B}")
         t_dev = time.perf_counter()
         with _telemetry.serving_scope(self.metrics), tracing.span(
@@ -3501,6 +3566,7 @@ class MeshEngine(EngineBase):
                 collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
                 launches=fs.launches, programs=fs.programs,
                 crossings=fs.crossings, calls=fs.calls,
+                sequence=fs.sequence,
             )
             em.observe_stage("assemble", t_dev - t_start)
             em.observe_stage("device_sync", dev_s)
@@ -3513,6 +3579,7 @@ class MeshEngine(EngineBase):
                 dev_us=int(dev_s * 1e6), trace_id=flush_trace_id,
                 ticket=fs.ids["flush"], call=fs.ids["call"],
                 calls=fs.calls, stages_us=fs.us,
+                sequence=fs.sequence,
             )
             st_req, r_limit, remaining, reset_time = _demux_lanes(
                 out_rows, ix
@@ -3541,7 +3608,9 @@ class MeshEngine(EngineBase):
         through the original columns). Returns (prefetched, per-wave
         {lane: (item, hi, lo)}, the item -> request resolver, the
         write-behind's (key, wave, lane, hi, lo) entries in request
-        order)."""
+        order, whether the flush already knows it reads through: a key
+        this process has never seen, in the Store or not, is not in
+        the table)."""
         from gubernator_tpu import wire as _wire
 
         cfg = self.cfg
@@ -3562,13 +3631,13 @@ class MeshEngine(EngineBase):
         wave_l, lane_l = wave.tolist(), lane.tolist()
         keys_l = list(zip(hi_l, lo_l))
         prefetched: Dict[Tuple[int, int], object] = {}
+        need: list = []
         if cfg.keep_key_strings:
             # Prefetch never-seen keys OUTSIDE the lock (the dict is
             # a superset of table residency, as in _process). Without
             # the dictionary there is no never-seen predicate: rely
             # on the in-lock per-wave probe alone rather than issuing
             # a blocking store.get for every key of every flush.
-            need = []
             seen = set()
             with self._keys_lock:
                 for j, k in enumerate(keys_l):
@@ -3590,7 +3659,7 @@ class MeshEngine(EngineBase):
             lane_reqs[w_][lane_l[j]] = (j, hi_l[j], lo_l[j])
         return (
             prefetched, lane_reqs, req_of,
-            list(zip(strs, wave_l, lane_l, hi_l, lo_l)),
+            list(zip(strs, wave_l, lane_l, hi_l, lo_l)), bool(need),
         )
 
     def _store_columns_postwork(self, fs, wb_entries, out_rows, sw):
@@ -3636,28 +3705,32 @@ class MeshEngine(EngineBase):
         orig_cols = cols
         with tracing.stage("flush.waves", fs, fs.ids):
             asm = self._assemble_replica_split(
-                cols, now, select, hi, lo, grp, fs
+                cols, now, select, hi, lo, grp
             )
         if asm is None:
             fs.publish()
             return None
-        (cols, hi, lo, s_asm, r_asm, ng_idx, g_idx, wave_slices, ops,
-         r_ops) = asm
+        (cols, hi, lo, s_asm, r_asm, ng_idx, g_idx, wave_slices,
+         r_slices) = asm
         n = cols.n
         prefetched: Dict[Tuple[int, int], object] = {}
         lane_reqs: List[Dict[int, tuple]] = [{} for _ in wave_slices]
         resolver = wb_entries = None
+        reads_through = False
         if store is not None and s_asm is not None:
             with tracing.stage("flush.keydict", fs, fs.ids):
                 idx_map = ng_idx if select is None else select[ng_idx]
                 if select is None and len(g_idx) == 0:
                     idx_map = None  # the call's own columns, in order
-                prefetched, lane_reqs, resolver, wb_entries = (
+                prefetched, lane_reqs, resolver, wb_entries, reads_through = (
                     self._store_columns_prework(
                         orig_cols, idx_map, hi[ng_idx], lo[ng_idx],
                         s_asm[1], s_asm[2], s_asm[4],
                     )
                 )
+        with tracing.stage("flush.waves", fs, fs.ids):
+            ops = self._upload(wave_slices, now, fs, stack=not reads_through)
+            r_ops = self._upload(r_slices, now, fs, stack=False)
 
         _telemetry.set_shape_hint(
             f"{cfg.layout}:mesh-columnar:B{cfg.batch_size}"
@@ -3725,6 +3798,7 @@ class MeshEngine(EngineBase):
                 collective=self.topo.n_dev > 1, transfers=(fs.h2d, fs.d2h),
                 launches=fs.launches, programs=fs.programs,
                 crossings=fs.crossings, calls=fs.calls,
+                sequence=fs.sequence,
             )
             em.observe_stage("assemble", t_dev - t_start)
             em.observe_stage("device_sync", dev_s)
@@ -3740,17 +3814,17 @@ class MeshEngine(EngineBase):
                 dur_us=int(dur * 1e6), dev_us=int(dev_s * 1e6),
                 trace_id=flush_trace_id, ticket=fs.ids["flush"],
                 call=fs.ids["call"], calls=fs.calls, stages_us=fs.us,
+                sequence=fs.sequence,
             )
             if em.hotkeys.k > 0:
                 _note_hotkeys_columnar(em.hotkeys, hi, lo, cols.hits, status)
         fs.publish()
         return (status, r_limit, remaining, reset_time)
 
-    def _assemble_replica_split(self, cols, now, select, hi, lo, grp, fs):
+    def _assemble_replica_split(self, cols, now, select, hi, lo, grp):
         """The host assembly of _check_columns_replica_split: the
-        sharded and the replica waves, their per-wave slices and their
-        uploaded operands, or None where the batch needs the object
-        path."""
+        sharded and the replica waves and their per-wave slices, or
+        None where the batch needs the object path."""
         cfg = self.cfg
         rt = self._rtier
         if select is not None:
@@ -3801,15 +3875,14 @@ class MeshEngine(EngineBase):
         wave_slices = s_asm[0] if s_asm is not None else []
         r_slices = r_asm[0] if r_asm is not None else []
         return (cols, hi, lo, s_asm, r_asm, ng_idx, g_idx, wave_slices,
-                self._upload(wave_slices, now, fs),
-                self._upload(r_slices, now, fs, stack=False))
+                r_slices)
 
     def _execute_waves(
         self, waves, ops, lane_reqs, now, prefetched, fs, req_resolver=None,
         r_ops=(),
     ):
         """Run decide over scatter-disjoint waves under the device lock,
-        with the store's per-wave sequence when a Store is attached:
+        with the store's sequence when a Store is attached, wave by wave:
         probe (cache lookup) -> Store.Get for misses -> insert -> decide
         -> gather touched rows (reference algorithms.go:45-51, 149-153 —
         the gathered rows let write-behind persist the value the caller
@@ -3822,8 +3895,15 @@ class MeshEngine(EngineBase):
         a launch beside the device-resident table, so nothing crosses to
         the device under the lock. A run of several waves is applied in
         order inside its one program and commits whole or not at all;
-        with a Store or a pager every run is one wave (_upload), so
-        their per-wave sequence below is the whole loop. lane_reqs: per-wave
+        with a pager every run is one wave (_upload), so its per-wave
+        sequence below is the whole loop. **With a Store a run is
+        probed once, decided once and gathered once** (_probe_run)
+        where the one probe finds every lane live: no wave of such a
+        run inserts, so nothing is displaced, no later wave can miss
+        and no inject can be due, which is all the per-wave sequence is
+        there for; a run with a lane that is not live is handed back
+        wave by wave, on the device (ops/layout.py operand_waves), and
+        runs the per-wave sequence. lane_reqs: per-wave
         {lane: (req_or_index, key_hi, key_lo)}; with req_resolver set,
         the first element is an index resolved lazily (columnar path).
         r_ops: the uploaded GLOBAL replica waves (replica topologies
@@ -3831,7 +3911,7 @@ class MeshEngine(EngineBase):
         against the replica tier after the sharded waves, wave by
         wave. Returns (outs, r_outs, store_waves): one (output, waves)
         a launch for _read_waves, still on the device, and with a Store
-        the flush's _StoreWaves (None without one): its waves' packed
+        the flush's _StoreWaves (None without one): its launches' packed
         rows, on the device too, and the hand-over lock, which the
         caller releases after its write-behind (_StoreWaves.read in its
         `flush.readback`, then _store_columns_postwork or _complete).
@@ -3864,9 +3944,7 @@ class MeshEngine(EngineBase):
         served: Dict[Tuple[int, int], Tuple[int, int]] = {}  # key->(w,lane)
         sw = None
         if store is not None:
-            sw = _StoreWaves(
-                lane_reqs, self._handover, self.metrics.store_handover_waits
-            )
+            sw = _StoreWaves(lane_reqs, self._handover, self.metrics)
         if self.topo.n_dev > 1:
             # Shard-skew attribution (docs/monitoring.md "SLOs & burn
             # rates"): host-side bincount over the waves' group arrays
@@ -3895,8 +3973,10 @@ class MeshEngine(EngineBase):
             table = self.table
             rstate = rt.state if rt is not None else None
             unread = 0  # launches since the last one that was waited for
+            todo = collections.deque(ops)
             try:
-                for w, n, op in ops:
+                while todo:
+                    w, n, op = todo.popleft()
                     wo = waves[w]
                     if self._pager is not None:
                         # Promote every page this wave touches BEFORE
@@ -3909,7 +3989,20 @@ class MeshEngine(EngineBase):
                             table,
                             self._pager.touched_pages(wb.group, wb.active),
                         )
-                    if store is not None:
+                    if store is not None and n > 1:
+                        with tracing.stage("flush.readthrough", fs, fs.ids):
+                            if not self._probe_run(
+                                table, op, lane_reqs[w:w + n], fs
+                            ):
+                                # a lane to read through: the run's
+                                # waves one by one, from here on
+                                fs.launches += n - 1
+                                todo.extendleft(reversed([
+                                    (w + i, 1, o) for i, o in
+                                    enumerate(operand_waves(op)[:n])
+                                ]))
+                                continue
+                    elif store is not None:
                         with tracing.stage("flush.readthrough", fs, fs.ids):
                             table = self._wave_readthrough(
                                 table, op, wo.batch, lane_reqs[w], now,
@@ -3924,21 +4017,29 @@ class MeshEngine(EngineBase):
                         # K.gather_rows) takes its slot column from the
                         # decide's output on the device, so the two are
                         # launched back to back, nothing uploaded. The
-                        # wave's output vector and its packed rows are
+                        # launch's output and its packed rows are
                         # not read here: nothing under the lock needs
                         # them but a later wave whose key was displaced
                         # since (sw.host_rows). Their host copies start
                         # now and the flush reads them after the
-                        # release (_StoreWaves.read).
+                        # release (_StoreWaves.read). A stacked run's
+                        # gather comes after its last wave and shows
+                        # each key's final row, which is what the
+                        # write-behind persists (last op per key); with
+                        # nothing displaced it is the row after the
+                        # key's last wave.
                         fs.programs["decide"] += 1
                         fs.programs["gather_rows"] += 1
                         with tracing.stage("flush.store_rows", fs, fs.ids):
                             rows = self.K.gather_rows(table, out, True)
                             out.copy_to_host_async()
                             rows.copy_to_host_async()
-                        sw.rows.append(rows)
-                        for lane, entry in lane_reqs[w].items():
-                            served[(entry[1], entry[2])] = (w, lane)
+                        sw.add(w, n, rows)
+                        if n > 1:
+                            sw.pre += [[] for _ in range(n)]
+                        for k in range(w, w + n):
+                            for lane, entry in lane_reqs[k].items():
+                                served[(entry[1], entry[2])] = (k, lane)
                     outs.append((out, n))
                     unread = self._bound_in_flight(out, unread)
                 for _w, n, op in r_ops:
@@ -3949,6 +4050,11 @@ class MeshEngine(EngineBase):
                 if rt is not None:
                     rt.state = rstate
                 if sw is not None:
+                    fs.sequence = (
+                        "stacked"
+                        if sw.runs and all(n > 1 for _w, n, _r in sw.runs)
+                        else "per_wave"
+                    )
                     # the flush's turn at the Store, taken in the order
                     # of the engine lock (waits only while the flush
                     # before it is still handing over)
@@ -3972,6 +4078,20 @@ class MeshEngine(EngineBase):
         fs.add("lock_wait", t_wait, t_in)
         fs.add("dispatch", t_in, t_out)
         return outs, r_outs, sw
+
+    def _probe_run(self, table, op, lane_reqs, fs: FlushStages) -> bool:
+        """Under the engine lock, a Store flush's stacked run: ONE
+        probe over the run's operand (the same program at a (depth,
+        rows, B) shape: the table is only read, so the waves are
+        independent) and the one blocking read, a byte a lane. Whether
+        every lane of the run is live in the table at the flush's
+        `now`: `lane_reqs` are the run's waves' and hold every active
+        lane, and the probe answers False for a padding lane, so the
+        live lanes are all of them exactly when they are as many."""
+        fs.programs["probe"] += 1
+        found = np.asarray(self.K.probe_exists(table, op, self.cfg.ways))  # guberlint: allow-host-sync -- store path: the run's one probe answer (a byte a lane), needed under the lock to choose the sequence
+        fs.crossings[1] += 1
+        return int(found.sum()) == sum(map(len, lane_reqs))
 
     def _bound_in_flight(self, out, unread: int) -> int:
         """Under the engine lock, after a launch whose output is `out`:

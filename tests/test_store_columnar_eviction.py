@@ -202,14 +202,19 @@ def test_store_counters_add_up(run):
     # the items answered
     for size, distinct, d in run["columnar"]:
         assert d["changed"] + d["removed"] == distinct, size
-        assert d["probe"] == d["decide"] == d["gather_rows"] >= 1
+        # a run that ran stacked is one of each; one whose stacked probe
+        # found a lane to read through is probed again wave by wave
+        assert d["probe"] >= d["decide"] == d["gather_rows"] >= 1
         # every Store hit is injected before its wave's decide, and
         # nothing else counts as a row read through
         assert d["hit"] == d["injected"], size
     assert 0 < c["injected"] == c["hit"]
-    # one probe, one decide and one row gather a wave; an inject only
-    # where a wave had a row to seat
-    assert c["decide"] == c["probe"] == c["gather_rows"] == run["waves"]
+    # one probe, one decide and one row gather a wave, or one of each a
+    # run where every key of a call was resident (the few calls here
+    # that read nothing through: 4,000 keys through 512 slots); an
+    # inject only where a wave had a row to seat
+    assert c["probe"] >= c["decide"] == c["gather_rows"]
+    assert 0.9 * run["waves"] < c["decide"] <= run["waves"]
     assert 0 < c["inject"] <= run["waves"]
 
 
@@ -246,9 +251,12 @@ def test_a_wave_without_a_miss_uploads_nothing_and_reads_one(monkeypatch):
     through: under the engine lock nothing goes to the device (the
     probe reads the operand _upload left there, the row gather the
     decide's own output), which jax's transfer guard enforces on the
-    launches themselves, and one array a wave comes back: the probe's
+    launches themselves, and one array a launch comes back: the probe's
     answer. The output vector and the packed rows are read after the
-    release (ISSUE 42), one output read a wave as before."""
+    release (ISSUE 42). The first call's keys were never seen, so it
+    knows it reads through and runs wave by wave; the second call's
+    are resident and its four waves run stacked: one probe, one decide,
+    one gather, one read (ISSUE 45)."""
     import jax
 
     from gubernator_tpu.runtime.engine import MeshEngine
@@ -277,10 +285,11 @@ def test_a_wave_without_a_miss_uploads_nothing_and_reads_one(monkeypatch):
             d = {k: after[k] - before[k] for k in after}
             waves = eng.metrics.waves - waves0
             assert waves == 4
-            assert d["probe"] == d["decide"] == d["gather_rows"] == waves
+            launches = (waves, 1)[n]
+            assert d["probe"] == d["decide"] == d["gather_rows"] == launches
             assert d["inject"] == 0
             assert d["h2d"] == 0
-            assert d["d2h"] == waves
+            assert d["d2h"] == launches
         assert store.data["ev_dup"].remaining == 20 - 8
         assert eng.metrics.cold_compiles == 0
     finally:
@@ -361,6 +370,9 @@ def test_a_key_displaced_between_its_own_waves(path, reset):
         assert crossings(eng.metrics)["d2h"] - read0 == 3 + 1 + (
             0 if reset else 2)
         assert d["decide"] - programs0["decide"] == 3  # a wave an item
+        # never stacked (ISSUE 45 d): B was never seen, the flush knows
+        # it reads through and every wave reads its own rows
+        assert eng.metrics.recorder.last()["sequence"] == "per_wave"
         # A's second wave re-seats its own earlier row (displaced), or
         # finds nothing to seat (freed): the Store is not asked again
         assert d["inject"] - programs0["inject"] == (0 if reset else 1)

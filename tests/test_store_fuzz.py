@@ -75,6 +75,7 @@ def test_engine_with_store_matches_oracle(seed, chips):
             assert (got.status, got.remaining, got.reset_time) == (
                 int(want.status), want.remaining, want.reset_time
             ), f"seed {seed} step {step}: {req}"
+        assert _surprises(eng) == 0
 
         # Restart: a fresh engine over the SAME store must continue each
         # key exactly where the oracle's state says (read-through).
@@ -100,6 +101,83 @@ def test_engine_with_store_matches_oracle(seed, chips):
             eng.close()
         except Exception:
             pass
+
+
+def _surprises(eng) -> float:
+    return eng.metrics.store_stacked_surprises.labels().get()
+
+
+def _sequences(eng) -> dict:
+    return {q: eng.metrics.store_flushes.labels(q).get()
+            for q in ("stacked", "per_wave")}
+
+
+@pytest.mark.parametrize("seed,chips", [
+    pytest.param(21, 0, id="21"), pytest.param(22, 0, id="22"),
+    pytest.param(23, 0, id="23"), pytest.param(21, 4, id="21-four-devices"),
+])
+def test_calls_of_repeated_keys_match_the_oracle_stacked_and_per_wave(
+        seed, chips):
+    """ISSUE 45 (e): calls of 2-24 items over twelve keys, so keys
+    repeat inside a call and its waves make runs; every behaviour, both
+    algorithms, changes of limit and duration, clock jumps past a
+    bucket's life. The engine (stacked runs where it may) and its twin
+    with no stacked Store shape warm (every flush wave by wave) both
+    answer as the oracle does and end with the same Store, and
+    gubernator_engine_store_stacked_surprises reads 0."""
+    rng = random.Random(seed)
+    clock = {"now": NOW}
+    engines = [_engine(clock, chips), _engine(clock, chips)]
+    stores = [MemoryStore(), MemoryStore()]
+    for eng, store in zip(engines, stores):
+        attach_store(eng, store)
+    engines[1]._warm_store_stacks = ()
+    oracle = OracleEngine()
+    keys = [f"sf{i}" for i in range(12)]
+    try:
+        for step in range(60):
+            if rng.random() < 0.3:
+                clock["now"] += rng.choice([5, 500, 70_000])
+            calm = rng.random() < 0.6  # a call with no RESET lane at all
+            reqs = []
+            for _ in range(rng.randint(2, 24)):
+                behavior = 0
+                if not calm and rng.random() < 0.1:
+                    behavior |= Behavior.RESET_REMAINING
+                if rng.random() < 0.15:
+                    behavior |= Behavior.DRAIN_OVER_LIMIT
+                key = rng.choice(keys)
+                reqs.append(RateLimitReq(
+                    name="sf", unique_key=key,
+                    # a key keeps its algorithm most of the time
+                    algorithm=(
+                        rng.choice([Algorithm.TOKEN_BUCKET,
+                                    Algorithm.LEAKY_BUCKET])
+                        if rng.random() < 0.05 else
+                        Algorithm(int(key[2:]) % 2)
+                    ),
+                    behavior=behavior,
+                    duration=rng.choice([100, 60_000, 60_000, 60_000]),
+                    limit=rng.choice([3, 10, 50, 50]),
+                    hits=rng.choice([-1, 0, 1, 1, 2, 5, 60]),
+                ))
+            want = [oracle.decide(dataclasses.replace(r), clock["now"])
+                    for r in reqs]
+            for name, eng in zip(("stacked", "per-wave"), engines):
+                got = eng.check_batch([dataclasses.replace(r) for r in reqs])
+                assert [(g.status, g.remaining, g.reset_time) for g in got] == [
+                    (int(w.status), w.remaining, w.reset_time) for w in want
+                ], f"seed {seed} step {step} ({name})"
+        assert stores[0].data == stores[1].data
+        seq = _sequences(engines[0])
+        assert seq["stacked"] >= 10 and seq["per_wave"] >= 10, seq
+        assert _sequences(engines[1])["stacked"] == 0
+        for eng in engines:
+            assert _surprises(eng) == 0
+            assert eng.metrics.cold_compiles == 0
+    finally:
+        for eng in engines:
+            eng.close()
 
 
 def _colliding_keys(num_groups: int, n: int, prefix: str = "ev"):

@@ -111,7 +111,9 @@ class HeldReader:
         self.go.set()
 
 
-@pytest.mark.parametrize("second", ["get_under_the_lock", "end_of_its_hold"])
+@pytest.mark.parametrize("second", [
+    "get_under_the_lock", "end_of_its_hold", "stacked_end_of_its_hold",
+])
 def test_the_store_sees_the_flushes_in_the_order_of_the_engine_lock(
         monkeypatch, second):
     """Two flushes from two threads, the first one's deferred read held
@@ -122,11 +124,16 @@ def test_the_store_sees_the_flushes_in_the_order_of_the_engine_lock(
     one's key, which the table has lost meanwhile, so it reads through
     with Store.get under the lock: it waits first, and the answer
     continues from the first flush's change, not from the Store's row
-    of before it."""
+    of before it. `stacked_end_of_its_hold`: both flushes are stacked
+    runs of resident keys (ISSUE 45), the second another key's: the
+    same wait and the same order."""
     eng, store = engine()
     try:
         first = eng.check_columns(columns([req("a", hits=3)]), now=NOW)
         assert first[2].tolist() == [17]
+        if second == "stacked_end_of_its_hold":
+            seen = eng.check_columns(columns([req("b")]), now=NOW)
+            assert seen[2].tolist() == [19]
         assert handover_waits(eng) == 0.0
         held = HeldReader(monkeypatch)
         got = {}
@@ -134,7 +141,9 @@ def test_the_store_sees_the_flushes_in_the_order_of_the_engine_lock(
         def flush(name, reqs):
             got[name] = eng.check_columns(columns(reqs), now=NOW + 1)
 
-        t1 = threading.Thread(target=flush, args=("first", [req("a")]))
+        stacked = second == "stacked_end_of_its_hold"
+        reqs1 = [req("a", hits=0), req("a")] if stacked else [req("a")]
+        t1 = threading.Thread(target=flush, args=("first", reqs1))
         t1.start()
         assert held.entered.wait(30.0)  # launched, released, not read
         assert not eng._lock.locked() and eng._handover.locked()
@@ -143,6 +152,8 @@ def test_the_store_sees_the_flushes_in_the_order_of_the_engine_lock(
             with eng._lock:
                 eng.table = eng.K.create(eng.cfg.num_groups, eng.cfg.ways)
             reqs2 = [req("a")]
+        elif stacked:
+            reqs2 = [req("b", hits=0), req("b")]  # resident: a stacked run
         else:
             reqs2 = [req("b")]  # never seen: prefetched before the lock
         n_log = len(store.log)
@@ -158,13 +169,18 @@ def test_the_store_sees_the_flushes_in_the_order_of_the_engine_lock(
         t1.join(30.0)
         t2.join(30.0)
         assert not t1.is_alive() and not t2.is_alive()
-        assert got["first"][2].tolist() == [16]
+        assert got["first"][2].tolist()[-1] == 16
         tail = [e for e in store.log[n_log:] if e[0] == "change"
                 or e[1] == "ho_a"]
         if second == "get_under_the_lock":
             assert tail == [("change", "ho_a", 16), ("get", "ho_a", 16),
                             ("change", "ho_a", 15)]
             assert got["second"][2].tolist() == [15]
+        elif stacked:
+            assert tail == [("change", "ho_a", 16), ("change", "ho_b", 18)]
+            assert got["second"][2].tolist() == [19, 18]
+            rec = eng.metrics.recorder.last()
+            assert (rec["sequence"], rec["waves"]) == ("stacked", 2)
         else:
             assert tail == [("change", "ho_a", 16), ("change", "ho_b", 19)]
             assert got["second"][2].tolist() == [19]
@@ -221,21 +237,27 @@ def test_a_read_that_fails_after_the_release_is_a_committed_flush(
         eng.close()
 
 
-def test_many_threads_hand_one_key_over_in_the_order_of_their_flushes():
+def store_flushes(eng, sequence: str) -> float:
+    return eng.metrics.store_flushes.labels(sequence).get()
+
+
+@pytest.mark.parametrize("waves", [1, 3], ids=["a-wave", "stacked-run"])
+def test_many_threads_hand_one_key_over_in_the_order_of_their_flushes(waves):
     """More threads than cores, each a flush of its own on one key, the
     interpreter switching often: whatever order the flushes took the
     engine lock in, the Store is handed the key's changes in that order,
     so what it holds of the key only ever goes down and ends at the
-    table's own row."""
+    table's own row. With the key three times a call every flush after
+    the first is a stacked run (ISSUE 45): the hand-over is the same."""
     import os
     import sys
 
     eng, store = engine()
     threads, calls = 2 * (os.cpu_count() or 4), 12
-    limit = threads * calls + 5
+    limit = threads * calls * waves + 5
     one = RateLimitReq(name="ho", unique_key="hot", limit=limit,
                        duration=3_600_000, hits=1)
-    cols = columns([one])
+    cols = columns([one] * waves)
     errors = []
 
     def caller():
@@ -262,5 +284,12 @@ def test_many_threads_hand_one_key_over_in_the_order_of_their_flushes():
     # change of the key is the one handed over: steps of one or more)
     handed = [e[2] for e in store.log if e[0] == "change"]
     assert all(a > b for a, b in zip(handed, handed[1:])), handed
-    assert handed[-1] == limit - threads * calls
+    assert handed[-1] == limit - threads * calls * waves
     assert not eng._handover.locked()
+    stacked, per_wave = (store_flushes(eng, q) for q in ("stacked", "per_wave"))
+    assert stacked + per_wave == len(handed)
+    if waves > 1:  # all but the flushes that met the key before it was seen
+        assert stacked > per_wave >= 1
+    # (one item a call: the calls that met at the gate and shared a
+    # flush made a run of the key's waves, stacked too)
+    assert eng.metrics.store_stacked_surprises.labels().get() == 0

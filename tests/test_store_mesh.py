@@ -116,6 +116,9 @@ def counts(eng) -> dict:
         "changed": one(em.store_on_change_items),
         "removed": one(em.store_removes),
         "skipped": one(em.store_rows_skipped),
+        "stacked": em.store_flushes.labels("stacked").get(),
+        "per_wave": em.store_flushes.labels("per_wave").get(),
+        "surprises": em.store_stacked_surprises.labels().get(),
     }
 
 
@@ -177,13 +180,16 @@ def one_chip_engine(clock, num_groups=1 << 10):
     )
 
 
-def drive(eng, calls, path: str) -> dict:
+def drive(eng, calls, path: str, stacked: bool = True) -> dict:
     """The calls through `path` ("columnar": check_columns on the wire
     columns; "object": the pump) with a counting Store attached; every
     answer, the Store, and each call's handed-over count beside its
-    distinct ordinary keys."""
+    distinct ordinary keys. Without `stacked` no stacked Store shape is
+    warm and every flush runs wave by wave."""
     store = CountingStore()
     attach_store(eng, store)
+    if not stacked:
+        eng._warm_store_stacks = ()
     clock = eng._test_clock
     out = {"store": store, "answers": [], "handed": []}
     for reqs in calls:
@@ -222,12 +228,12 @@ def reference(calls):
     return ref, answers
 
 
-def run_on(make, calls, path):
+def run_on(make, calls, path, stacked=True):
     clock = {"now": NOW}
     eng = make(clock)
     eng._test_clock = clock
     try:
-        return drive(eng, calls, path)
+        return drive(eng, calls, path, stacked)
     finally:
         eng.close()
 
@@ -349,6 +355,76 @@ def test_global_buckets_of_the_replica_tier_are_not_persisted(path):
                 continue
             w = oracle.decide(dataclasses.replace(r), now)
             assert g == (int(w.status), w.limit, w.remaining, w.reset_time)
+
+
+def preload(keys: int, items: int = 75):
+    """Calls that hit every ordinary key of calls100's keyspace once,
+    as the benchmark's preload does: resident and persisted after."""
+    reqs = [
+        RateLimitReq(
+            name="st4", unique_key=f"k{k:05d}", hits=1, limit=20,
+            duration=3_600_000,
+            algorithm=(Algorithm.TOKEN_BUCKET if k % 2 == 0
+                       else Algorithm.LEAKY_BUCKET),
+        )
+        for k in range(keys)
+    ]
+    return [reqs[i:i + items] for i in range(0, keys, items)]
+
+
+@pytest.mark.parametrize("path", ["columnar", "object"])
+def test_the_sharded_tier_runs_resident_calls_stacked(path):
+    """(ISSUE 45 f) 100-item calls over 150 keys, a fifth of the items
+    GLOBAL: once a call's ordinary keys are all resident its sharded
+    waves run stacked (one SPMD probe, decide and gather a run); the
+    answers and the Store equal the per-wave twin's and the
+    reference's, no row is skipped, no GLOBAL bucket is persisted and
+    the guard reads 0."""
+    calls = preload(150) + calls100(
+        53, 6, keys=150, reset_share=0.0, global_share=0.2)
+    ordinary = [[r for r in reqs if not r.behavior & GLOBAL] for reqs in calls]
+    ref, _ = reference(ordinary)
+    four = run_on(mesh_engine, calls, path)
+    c = four["counts"]
+    if path == "columnar":  # a call a flush: the preload's, then six runs
+        assert (c["stacked"], c["per_wave"]) == (6, 2)
+    else:  # the pump may split a call over flushes
+        assert c["stacked"] >= 6
+    assert c["surprises"] == c["skipped"] == 0
+    assert_store_is_the_references(four["store"], ref)
+    assert not any(k.startswith("st4_g") for k in four["store"].data)
+    assert not any(k.startswith("st4_g") for k in four["store"].asked)
+    twin = run_on(mesh_engine, calls, path, stacked=False)
+    assert twin["counts"]["stacked"] == 0
+    assert ({k: entry_of(s) for k, s in twin["store"].data.items()}
+            == {k: entry_of(s) for k, s in four["store"].data.items()})
+    # the ordinary items' answers are the per-wave twin's and the
+    # oracle's (a GLOBAL item's is its replica's and is not held here)
+    now = NOW
+    oracle = OracleEngine()
+    for reqs, got, tw in zip(calls, four["answers"], twin["answers"]):
+        now += 10
+        for r, g, t in zip(reqs, got, tw):
+            if r.behavior & GLOBAL:
+                continue
+            w = oracle.decide(dataclasses.replace(r), now)
+            assert g == t == (
+                int(w.status), w.limit, w.remaining, w.reset_time)
+
+
+def test_a_reset_lane_keeps_the_mesh_flush_per_wave():
+    """(ISSUE 45 c) on the mesh as on one chip: with a RESET_REMAINING
+    lane in it a flush of resident keys runs wave by wave."""
+    calls = preload(40) + calls100(59, 4, keys=40, reset_share=0.0)
+    calls += calls100(61, 2, keys=40, reset_share=0.2)
+    assert all(any(r.behavior & RESET for r in reqs) for reqs in calls[5:])
+    ref, want = reference(calls)
+    four = run_on(mesh_engine, calls, "columnar")
+    assert four["answers"] == want
+    c = four["counts"]
+    assert (c["stacked"], c["per_wave"]) == (4, 1 + 2)
+    assert c["surprises"] == c["skipped"] == 0 and c["removed"] > 0
+    assert_store_is_the_references(four["store"], ref)
 
 
 def same_group_pair(num_groups):

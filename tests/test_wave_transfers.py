@@ -95,18 +95,25 @@ def test_one_operand_in_one_read_out_a_wave(engine, flush):
     assert (h1 - h0, d1 - d0) == (launches, launches)
 
 
+# With a Store a flush whose keys this process has never seen knows it
+# reads through and runs wave by wave: an operand in and a read out a
+# wave. The 40 of one key are a flush of 32 waves (never seen: 32) and
+# the carry's 8, whose key is resident by then: one stacked run, 1.
+STORE_TRANSFERS = {flush_columnar: 4, flush_pump: 2, flush_32_waves: 32 + 1}
+
+
 @pytest.mark.parametrize("flush", FLUSHES.values(), ids=FLUSHES.keys())
 def test_store_flush_counts_one_each_for_its_decide(engine, flush):
-    """With a Store the wave's one read happens under the lock (its
-    slot column drives the row gather, a program of its own with its own
-    transfers); the count a wave stays one each way."""
+    """With a Store the count stays one each way a launch: a wave's
+    where the flush runs the per-wave sequence, a run's where it runs
+    stacked (tests/test_store_stacked.py has the stacked counts)."""
     store = MemoryStore()
     attach_store(engine, store)
     h0, d0, w0 = transfers(engine)
     waves, _launches = flush(engine)
     h1, d1, w1 = transfers(engine)
     assert w1 - w0 == waves
-    assert (h1 - h0, d1 - d0) == (waves, waves)
+    assert (h1 - h0, d1 - d0) == (STORE_TRANSFERS[flush],) * 2
     assert store.data  # write-behind read the packed store columns
 
 
@@ -176,7 +183,7 @@ def test_guard_catches_a_host_operand(engine, guarded, monkeypatch):
     itself is refused."""
     monkeypatch.setattr(
         engine, "_upload",
-        lambda waves, now, fs: [
+        lambda waves, now, fs, stack=True: [
             (i, 1, w.stamp(now).buf) for i, w in enumerate(waves)
         ],
     )

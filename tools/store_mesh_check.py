@@ -2,7 +2,7 @@
 """A Store on the owner-sharded table against the plain reference, at the
 configuration's own size, in one process (ISSUE 41; not a benchmark cell).
 
-    python tools/store_mesh_check.py --seed <n> [--calls 2000]
+    python tools/store_mesh_check.py --seed <n> [--calls 2000] [--passes 2]
         [--config store-4] [--path columnar|object] [--keys <n> --rehearsal]
 
 Builds the configuration's engine from its `env` exactly as the daemon
@@ -20,10 +20,16 @@ over `max_waves` take), and compares:
   per key the reference's bucket after the key's last request (a token
   bucket's RESET_REMAINING removes the entry);
 - `on_change_items + removes` of each call with its distinct keys;
-- `gubernator_store_rows_skipped`, which has to stay 0.
+- `gubernator_store_rows_skipped` and
+  `gubernator_engine_store_stacked_surprises`, which have to stay 0.
 
-Prints one JSON object: entries, mismatches, skipped rows, the device it
-ran on. Exit code 0 only if everything agrees. `--rehearsal` applies the
+The first pass over the calls meets its keys for the first time, so
+nearly every flush knows it reads through and runs the per-wave
+sequence; `--passes 2` sends the same calls again, their keys resident
+now, so that the flushes run stacked (docs/persistence.md "When the
+waves run stacked"; ISSUE 45). Prints one JSON object: entries,
+mismatches, skipped rows, the flushes by sequence, the device it ran
+on. Exit code 0 only if everything agrees. `--rehearsal` applies the
 configuration's `rehearsal_env` (a small table) for a run on the CPU.
 """
 
@@ -68,6 +74,7 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--calls", type=int, default=2000)
+    ap.add_argument("--passes", type=int, default=1)
     ap.add_argument("--config", default="store-4")
     ap.add_argument("--traffic", default="calls100")
     ap.add_argument("--path", choices=("columnar", "object"), default="columnar")
@@ -127,7 +134,7 @@ def main() -> int:
     first = None
     t0 = time.monotonic()
     try:
-        for i in range(n_calls):
+        for i in list(range(n_calls)) * args.passes:
             now = eng.now_fn()
             before = handed()
             cols = wire.parse_requests(plan.blobs[i])
@@ -155,6 +162,11 @@ def main() -> int:
         run_s = time.monotonic() - t0
         counter = getattr(em, "store_rows_skipped", None)  # PR 41's
         skipped = None if counter is None else total(counter)
+        sequences = surprises = None
+        if hasattr(em, "store_flushes"):  # PR 45's
+            sequences = {q: em.store_flushes.labels(q).get()
+                         for q in ("stacked", "per_wave")}
+            surprises = total(em.store_stacked_surprises)
         got = {k: store_entry(s) for k, s in store.data.items()}
         want = {k: reference_entry(v) for k, v in ref.cache.items()}
         mismatches = sum(got.get(k) != w for k, w in want.items())
@@ -165,10 +177,12 @@ def main() -> int:
         dev = jax.devices()[0]
         result = {
             "config": args.config, "seed": args.seed, "path": args.path,
-            "calls": n_calls,
-            "items": int(sum(len(plan.keys[i]) for i in range(n_calls))),
+            "calls": n_calls, "passes": args.passes,
+            "items": args.passes * int(
+                sum(len(plan.keys[i]) for i in range(n_calls))),
             "entries": len(got), "reference_entries": len(want),
             "mismatches": mismatches, "skipped_rows": skipped,
+            "store_flushes": sequences, "stacked_surprises": surprises,
             "wrong_answers": wrong_answers,
             "calls_not_handing_their_distinct_keys": wrong_handed,
             "first": first, "start_s": start_s, "run_s": run_s,
@@ -178,7 +192,8 @@ def main() -> int:
     finally:
         eng.close()
     print(json.dumps(result))
-    ok = not (mismatches or skipped or wrong_answers or wrong_handed)
+    ok = not (mismatches or skipped or surprises or wrong_answers
+              or wrong_handed)
     return 0 if ok and len(got) > 0 else 1
 
 
