@@ -248,8 +248,8 @@ class Checker:
 
 def load_requests(seed: int, n: int, t0: int, behavior: int = 0,
                   prefix: str = "k", limit_lo: int = 1) -> list:
-    """`n` distinct keys from `seed`; every fourth is a leaky bucket (the
-    bench.py BASELINE-3 mix). Small limits, so some first hits are
+    """`n` distinct keys from `seed`; every fourth is a leaky bucket
+    (BASELINE.json config 3's mix). Small limits, so some first hits are
     already over the limit. created_at is pinned: answers do not depend
     on the daemon's wall clock."""
     rng = random.Random(seed)

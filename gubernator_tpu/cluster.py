@@ -41,8 +41,7 @@ class Cluster:
         """Daemon i's table lives on ``jax.devices()[i % n]``: on a
         four-chip host four daemons hold four chips. Extra keyword args
         pass through to every DaemonConfig — e.g. ``overload=True,
-        intake_limit=64`` arms the overload control plane mesh-wide
-        (tools/jobs/45_overload_soak.py)."""
+        intake_limit=64`` arms the overload control plane mesh-wide."""
         c = cls()
         dcs = list(datacenters) if datacenters else [DATACENTER_NONE] * count
         devices = jax.devices()

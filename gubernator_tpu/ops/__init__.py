@@ -10,13 +10,9 @@ import jax
 jax.config.update("jax_enable_x64", True)
 
 from gubernator_tpu.ops.layout import SlotTable, RequestBatch, DecideOutput  # noqa: E402
-from gubernator_tpu.ops.decide import decide, decide_scan, make_decide  # noqa: E402
 
 __all__ = [
     "SlotTable",
     "RequestBatch",
     "DecideOutput",
-    "decide",
-    "decide_scan",
-    "make_decide",
 ]

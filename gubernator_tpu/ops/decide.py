@@ -1,13 +1,15 @@
 """The decide kernel: one vectorized step replacing the reference hot loop.
 
-decide(table, batch, now) -> (table', DecideOutput)
+_decide_impl(table, batch, now) -> (table', DecideOutput)
 
-This single jitted function subsumes the reference's entire L3 execution
+This single traced function subsumes the reference's entire L3 execution
 engine — WorkerPool dispatch (reference workers.go:261-324), LRU cache
 get/add/evict (reference lrucache.go:88-161), and every branch of
 tokenBucket/leakyBucket (reference algorithms.go:37-493) — as masked int64
-vector ops over a W-way set-associative HBM slot table. The table buffers
-are donated, so the update is in-place on device.
+vector ops over a W-way set-associative HBM slot table. It is jitted in
+one place, as the body of the packed launch (ops/kernels.py
+decide_packed, ops/layout.py packed_waves): the table buffers are
+donated there, so the update is in-place on device.
 
 Branch semantics are bit-for-bit identical to models/oracle.py (the spec),
 which is fuzz-verified in tests/test_kernel_fuzz.py.
@@ -471,17 +473,6 @@ def _decide_impl(table: SlotTable, batch: RequestBatch, now, *, ways: int):
     return new_table, out
 
 
-@functools.partial(jax.jit, static_argnames=("ways",), donate_argnums=(0,))
-def decide(table: SlotTable, batch: RequestBatch, now, ways: int = 8):
-    """Jitted decide step with donated table buffers (in-place on device)."""
-    return _decide_impl(table, batch, now, ways=ways)
-
-
-def make_decide(ways: int = 8):
-    """Returns a decide fn closed over `ways` (for engines/benchmarks)."""
-    return functools.partial(decide, ways=ways)
-
-
 def _probe_exists_impl(table: SlotTable, batch, now, ways: int):
     grp_base = batch.group.astype(I64) * ways
     way_ix = grp_base[:, None] + jnp.arange(ways, dtype=I64)[None, :]
@@ -531,21 +522,3 @@ def gather_rows(table: SlotTable, slots, from_output: bool = False):
         functools.partial(_gather_cols, table),
         slots, table.num_slots, from_output,
     )
-
-
-@functools.partial(jax.jit, static_argnames=("ways",), donate_argnums=(0,))
-def decide_scan(table: SlotTable, batches: RequestBatch, nows, ways: int = 8):
-    """Run a time-sequence of batches through decide in ONE dispatch.
-
-    `batches` fields are stacked (T, B); `nows` is (T,). Used by tests (to
-    fuzz long sequences without per-step dispatch overhead) and by the
-    benchmark's steady-state loop. Compiler-friendly sequential control
-    flow via lax.scan — no Python loop under jit.
-    """
-
-    def step(tbl, xs):
-        b, now = xs
-        tbl, out = _decide_impl(tbl, b, now, ways=ways)
-        return tbl, out
-
-    return jax.lax.scan(step, table, (batches, nows))

@@ -624,21 +624,6 @@ def _decide_fused_impl(table: FusedTable, batch: RequestBatch, now, *, ways: int
     return FusedTable(data=new_data), out
 
 
-@functools.partial(jax.jit, static_argnames=("ways",), donate_argnums=(0,))
-def decide_fused(table: FusedTable, batch: RequestBatch, now, ways: int = 8):
-    return _decide_fused_impl(table, batch, now, ways=ways)
-
-
-@functools.partial(jax.jit, static_argnames=("ways",), donate_argnums=(0,))
-def decide_scan_fused(table: FusedTable, batches: RequestBatch, nows, ways: int = 8):
-    def step(tbl, xs):
-        b, now = xs
-        tbl, out = _decide_fused_impl(tbl, b, now, ways=ways)
-        return tbl, out
-
-    return jax.lax.scan(step, table, (batches, nows))
-
-
 def _probe_exists_fused_impl(table: FusedTable, batch, now, ways: int):
     rows = _gather_groups(table.data, batch.group, ways)
     w_meta = rows[..., META]

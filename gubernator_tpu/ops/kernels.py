@@ -20,8 +20,7 @@ import jax
 import jax.numpy as jnp
 
 # The registry every layout-selection surface validates against
-# (EngineConfig.layout, IciEngineConfig.layout, bench.py --layout, the
-# kernel fuzz suite).
+# (EngineConfig.layout, IciEngineConfig.layout, the kernel fuzz suite).
 LAYOUTS = ("wide", "fused")
 
 # Resident bytes per table slot, by layout (engine table-size gates,
@@ -30,8 +29,6 @@ LAYOUTS = ("wide", "fused")
 BYTES_PER_SLOT = {"wide": 83, "fused": 80}
 
 from gubernator_tpu.ops.decide import (
-    decide as _wd,
-    decide_scan as _wds,
     gather_rows as _wgr,
     probe_exists as _wpe,
 )
@@ -42,8 +39,6 @@ from gubernator_tpu.ops.layout import SlotTable, packed_waves
 class Kernels(NamedTuple):
     layout: str
     create: object  # (num_groups, ways) -> table
-    decide: object  # (table, batch, now, ways, with_store) -> (table, out)
-    decide_scan: object  # (table, batches, nows, ways, with_store)
     inject: object  # (table, items, now, ways) -> (table, ehi, elo)
     # The Store's two other programs exchange packed device arrays as the
     # decide does. probe_exists reads the wave's uploaded operand;
@@ -63,19 +58,9 @@ class Kernels(NamedTuple):
     decide_packed: object = None
 
 
-def _wide_decide(table, batch, now, ways, with_store=False):
-    return _wd(table, batch, now, ways=ways)
-
-
-def _wide_scan(table, batches, nows, ways, with_store=False):
-    return _wds(table, batches, nows, ways=ways)
-
-
 _WIDE = Kernels(
     layout="wide",
     create=SlotTable.create,
-    decide=_wide_decide,
-    decide_scan=_wide_scan,
     inject=lambda table, items, now, ways: _wi(table, items, now, ways=ways),
     probe_exists=lambda table, operand, ways: _wpe(table, operand, ways=ways),
     gather_rows=_wgr,
@@ -91,12 +76,6 @@ def _fused():
     return Kernels(
         layout="fused",
         create=_f.FusedTable.create,
-        decide=lambda table, batch, now, ways, with_store=False: _f.decide_fused(
-            table, batch, now, ways=ways
-        ),
-        decide_scan=lambda table, batches, nows, ways, with_store=False: (
-            _f.decide_scan_fused(table, batches, nows, ways=ways)
-        ),
         inject=lambda table, items, now, ways: _f.inject_fused(
             table, items, now, ways=ways
         ),

@@ -82,7 +82,9 @@ class SlotTable(NamedTuple):
 
 
 class RequestBatch(NamedTuple):
-    """Device operands for one decide() call, padded to a fixed batch size.
+    """One wave's operands as the decide program sees them inside its jit
+    (unpack_operand below), padded to a fixed batch size; on the host,
+    views of the wave's one WaveOperand buffer.
 
     Host-resolved fields (the kernel is calendar/string-free):
     - key_hi/key_lo: 128-bit key hash (api/keys.py)
@@ -503,16 +505,42 @@ class DecideOutput(NamedTuple):
     over_limit: jnp.ndarray
 
 
-def batch_entry(packed):
+def _step_operand(batch, *home_now) -> WaveOperand:
+    *home, now = home_now
+    return WaveOperand.of(batch, int(now), home[0] if home else None)
+
+
+def batch_entry(packed, with_store: bool = False):
     """A packed program under the RequestBatch signature, for tests and
     tools: entry(state, batch, now) or entry(state, batch, home, now)
     packs the host batch into one WaveOperand, launches `packed(state,
     operand)` and returns (state, DecideOutput of host arrays)."""
 
     def entry(state, batch, *home_now):
-        *home, now = home_now
-        op = WaveOperand.of(batch, int(now), home[0] if home else None)
-        state, vec = packed(state, op.buf)
-        return state, output_struct(vec)
+        state, vec = packed(state, _step_operand(batch, *home_now).buf)
+        return state, output_struct(vec, with_store)
+
+    return entry
+
+
+def run_entry(packed, with_store: bool = False):
+    """batch_entry's twin for a run of waves, as an engine stacks them:
+    entry(state, steps, depth=None), `steps` a list of (batch, now) or
+    (batch, home, now) of one width, is ONE launch of `packed` over the
+    steps' operands stacked to `depth` waves (no fewer than the steps;
+    the waves past the last are empty). Returns (state, one DecideOutput
+    of host arrays a step, the whole (depth, L) output on the host)."""
+
+    def entry(state, steps, depth=None):
+        run = WaveOperand.stacked(
+            [_step_operand(*step) for step in steps], depth or len(steps)
+        )
+        state, vecs = packed(state, run.buf)
+        vecs = np.asarray(vecs)  # guberlint: allow-host-sync -- tests/tools helper, never on the serving path
+        return (
+            state,
+            [output_struct(vecs[i], with_store) for i in range(len(steps))],
+            vecs,
+        )
 
     return entry

@@ -109,11 +109,9 @@ class PagedKernels(NamedTuple):
 
     layout: str
     create: object  # () -> PagedTable (empty map, zeroed physical table)
-    decide: object  # (pt, batch, now, ways, with_store) -> (pt, out)
     # (pt, operand, ways, with_store) -> (pt, output vector): what an
     # engine launches (ops/kernels.py Kernels.decide_packed)
     decide_packed: object
-    decide_scan: object  # (pt, batches, nows, ways, with_store)
     inject: object  # (pt, items, now, ways) -> (pt, ehi, elo)
     probe_exists: object  # (pt, operand, ways) -> bool[B]
     # (pt, PHYSICAL slots, from_output=False) -> packed rows (the paged
@@ -227,21 +225,6 @@ def make_paged_kernels(
         data, out = raw.decide(pt.data, b, now, ways)
         return PagedTable(data, pt.page_map), out
 
-    _decide = jax.jit(_raw_decide, donate_argnums=(0,))
-
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def _decide_scan(pt, batches, nows):
-        pm = pt.page_map
-
-        def step(data, xs):
-            b, now = xs
-            b = b._replace(group=_xlate(pm, b.group))
-            data, out = raw.decide(data, b, now, ways)
-            return data, out
-
-        data, outs = jax.lax.scan(step, pt.data, (batches, nows))
-        return PagedTable(data, pm), outs
-
     @functools.partial(
         jax.jit, static_argnames=("with_store",), donate_argnums=(0,)
     )
@@ -300,14 +283,8 @@ def make_paged_kernels(
     return PagedKernels(
         layout=layout,
         create=_create,
-        decide=lambda t, b, now, ways_=ways, with_store=False: _decide(
-            t, b, now
-        ),
         decide_packed=lambda t, op, ways_=ways, with_store=False: (
             _decide_packed(t, op, with_store=bool(with_store))
-        ),
-        decide_scan=lambda t, bs, ns, ways_=ways, with_store=False: (
-            _decide_scan(t, bs, ns)
         ),
         inject=lambda t, i, now, ways_=ways: _inject(t, i, now),
         probe_exists=lambda t, operand, ways_=ways: _probe_exists(t, operand),

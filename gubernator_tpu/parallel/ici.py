@@ -130,7 +130,7 @@ def _unsqueeze(tree):
 def _replica_step(RK, ways, groups_per, num_slots, dev, tbl, pending,
                   batch, home, now):
     """One device-local replica decide: answer my lanes, maintain pending
-    deltas. Shared by the single-step and scan factories."""
+    deltas."""
     mine = batch.active & (home == dev)
     local_batch = batch._replace(active=mine)
 
@@ -213,59 +213,6 @@ def make_replica_decide(
         return sharded(state, operand)
 
     return decide_fn
-
-
-def make_replica_decide_scan(
-    mesh: Mesh, num_slots: int, ways: int = 1, layout: str = DEFAULT_LAYOUT
-):
-    """Scan variant: decide(state, operands) where `operands` is S
-    stacked wave operands (S, OPERAND_ROWS, B) — S replica decide steps
-    in ONE dispatch, answered by (S, ...) stacked output vectors.
-    Benchmarks need this to keep per-dispatch host overhead out of the
-    device step time the same way decide_scan does for the single-chip
-    kernel (bench.py kernel mode)."""
-    n_dev = mesh.devices.size
-    num_groups = num_slots // ways
-    groups_per = num_groups // n_dev
-    RK = get_raw_kernels(layout)
-
-    def local(state: IciState, operands):
-        dev = jax.lax.axis_index(AXIS).astype(I64)
-
-        def step(carry, operand):
-            tbl, pending = carry
-            b, home, now = unpack_operand(operand)
-            tbl, pending, out = _replica_step(
-                RK, ways, groups_per, num_slots, dev,
-                tbl, pending, b, home, now,
-            )
-            return (tbl, pending), pack_output(out, False)
-
-        (tbl, pending), outs = jax.lax.scan(
-            step, (_squeeze(state.table), state.pending[0]), operands
-        )
-        # One collective on the stacked (S, ...) vectors, instead of one
-        # per scan step.
-        return (
-            IciState(
-                table=_unsqueeze(tbl), pending=pending[None],
-                tick=state.tick,
-            ),
-            jax.lax.psum(outs, AXIS),
-        )
-
-    sharded = jax.shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(P(AXIS), P()),
-        out_specs=(P(AXIS), P()),
-    )
-
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def scan_fn(state: IciState, operands):
-        return sharded(state, operands)
-
-    return scan_fn
 
 
 def make_inject_replicas(

@@ -304,20 +304,6 @@ def make_sharded_inject(
     return inject_fn
 
 
-def _no_scan(*_a, **_k):
-    raise NotImplementedError(
-        "the mesh tier serves wave-at-a-time SPMD programs; there is no "
-        "decide_scan path (bench the single-chip engine for scan shapes)"
-    )
-
-
-def _packed_only(*_a, **_k):
-    raise NotImplementedError(
-        "the mesh tier launches the packed entry only (decide_packed: one "
-        "uploaded operand in, one array out)"
-    )
-
-
 def make_mesh_kernels(
     mesh: Mesh,
     layout: str,
@@ -380,11 +366,9 @@ def make_mesh_kernels(
         return Kernels(
             layout=layout,
             create=_create,
-            decide=_packed_only,
             decide_packed=lambda t, op, ways_=ways, with_store=False: (
                 decide_fn(t, op, with_store=bool(with_store))
             ),
-            decide_scan=_no_scan,
             inject=lambda t, i, now, ways_=ways: inject_fn(t, i, now),
             probe_exists=lambda t, operand, ways_=ways: probe_fn(t, operand),
             gather_rows=gather_fn,
@@ -528,11 +512,9 @@ def _make_mesh_paged_kernels(
     return PagedKernels(
         layout=layout,
         create=_create,
-        decide=_packed_only,
         decide_packed=lambda t, op, ways_=ways, with_store=False: (
             _decide_packed(t, op, with_store=bool(with_store))
         ),
-        decide_scan=_no_scan,
         inject=lambda t, i, now, ways_=ways: _inject(t, i, now),
         probe_exists=lambda t, operand, ways_=ways: _probe_exists(t, operand),
         gather_rows=lambda t, slots, from_output=False: _gather_rows(

@@ -4,12 +4,12 @@ table, ONE core parameterized by mesh shape (runtime/topology.py).
 This is the TPU-native replacement for the reference's entire execution
 engine (reference workers.go:54-626): instead of sharding the key space
 across single-threaded goroutine workers with channel hops, requests
-accumulate into fixed-shape device batches and one jitted decide() call
-updates the HBM slot table in place. At mesh shape ``(1,)`` that table
-lives on one chip (DeviceEngine); at ``(chips,)`` it shards across the
-mesh under shard_map with psum-merged outputs, plus a per-device GLOBAL
-replica tier (IciEngine, runtime/ici_engine.py) — same core, same wave
-assembler, same pipeline, different strategy object.
+accumulate into fixed-shape device batches and one launch of the packed
+decide program (ops/kernels.py decide_packed) updates the HBM slot table in
+place. At mesh shape ``(1,)`` that table lives on one chip (DeviceEngine);
+at ``(chips,)`` it shards across the mesh under shard_map with psum-merged
+outputs, plus a per-device GLOBAL replica tier (IciEngine,
+runtime/ici_engine.py) — same core, same assembler, different strategy.
 
 The micro-batching policy transfers directly from the reference's peer
 batching (reference peer_client.go:284-337; config.go:126-128): flush at
@@ -21,8 +21,8 @@ same-key requests through one worker, so in-batch duplicates see each
 other's effects in request order, and an over-limit rejection does NOT
 consume. The assembler reproduces this with *waves*: within one flush,
 requests whose slot-group is already taken by an earlier request go to the
-next wave; waves execute as sequential decide() calls. Group (not key)
-granularity also guarantees scatter-disjointness inside each wave.
+next wave; waves apply in order, a launch each or a run stacked into one
+(ops/layout.py packed_waves). Groups keep a wave's scatters disjoint.
 """
 
 from __future__ import annotations

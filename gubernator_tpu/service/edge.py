@@ -1,9 +1,9 @@
 """Disaggregated serving edge: framed RPC between edge processes and
 the device daemon.
 
-TPU-native scale-out of the serving tier (SURVEY.md §2.3 sharding row;
-docs/benchmarks.md round-2/3 edge analysis): the chip — and the one
-process owning its HBM slot table — is the scarce resource, while gRPC
+TPU-native scale-out of the serving tier (SURVEY.md §2.3 sharding
+row): the chip — and the one process
+owning its HBM slot table — is the scarce resource, while gRPC
 / HTTP2 / TLS termination and the native wire parse are horizontally
 scalable host work. N `gubernator-tpu-edge` processes terminate client
 gRPC and relay each call over a length-prefixed stream (unix socket or

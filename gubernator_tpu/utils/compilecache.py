@@ -18,7 +18,7 @@ machine-feature lists and can refuse across heterogeneous hosts, and
 CPU compiles are seconds.
 
 Called from every entry point that touches a device: the daemon
-(cmd/daemon.py), the cluster runner, bench.py and the graft entry.
+(cmd/daemon.py), the cluster runner and the graft entry.
 """
 
 from __future__ import annotations

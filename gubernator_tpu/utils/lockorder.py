@@ -103,14 +103,13 @@ class LockOrderGraph:
         attempt is reported even if the acquire then blocks forever
         (or times out in a test)."""
         held = self._held()
-        site = _site()
         if not reentrant and any(lid == lock_id for _, lid in held):
             with self._mu:
                 self.violations.append({
                     "kind": "double-acquire",
                     "lock": name,
                     "thread": threading.current_thread().name,
-                    "site": site,
+                    "site": _site(),
                 })
             return
         if reentrant and any(lid == lock_id for _, lid in held):
@@ -122,6 +121,15 @@ class LockOrderGraph:
         if not held_names:
             return
         with self._mu:
+            # The witness site is a stack walk (traceback reads source
+            # lines): taken only for an ordering not seen before, so an
+            # acquire on a hot path costs a dict probe.
+            held_names = [
+                p for p in held_names if name not in self.edges.get(p, ())
+            ]
+            if not held_names:
+                return
+            site = _site()
             for prior in held_names:
                 # Inversion check BEFORE inserting prior->name: a path
                 # name ->* prior means some execution acquired these in

@@ -4,7 +4,7 @@ Tests run on CPU with 8 virtual devices so multi-chip sharding
 (jax.sharding.Mesh) is exercised without TPU hardware, mirroring how the
 reference tests spin up an in-process multi-node cluster without a real
 cluster (reference cluster/cluster.go:123-189). Runs on a TPU happen via
-chip_smoke.py and bench.py, not pytest. JAX_PLATFORMS is set here, before
+chip_smoke.py and benchmarks/run.py, not pytest. JAX_PLATFORMS is set here, before
 anything imports jax, so subprocesses a test starts inherit the pin too.
 """
 

@@ -20,6 +20,7 @@ from gubernator_tpu.api.types import (
 from gubernator_tpu.models.oracle import OracleEngine
 from gubernator_tpu.ops.encode import encode_batch
 from gubernator_tpu.ops.kernels import get_kernels
+from gubernator_tpu.ops.layout import batch_entry, run_entry
 from gubernator_tpu.utils.gregorian import GREGORIAN_MINUTES
 
 NOW = 1_753_700_000_000
@@ -34,10 +35,46 @@ from gubernator_tpu.ops.kernels import LAYOUTS  # noqa: E402
 
 LAYOUTS = list(LAYOUTS)
 WAYS = [8, 4]
+# How a sequence reaches the program, both as an engine launches it: one
+# launch a step, or the steps stacked into one run (ops/layout.py
+# packed_waves: the loop over waves, its early stop, the packed output).
+RUNS = ["per_wave", "stacked"]
+
+
+def packed(K, ways, with_store=False):
+    """`K`'s launched entry (Kernels or PagedKernels) as (table, operand)."""
+    return lambda t, op: K.decide_packed(t, op, ways, with_store)
+
+
+def run_depth(steps: int) -> int:
+    """A stacked depth that holds `steps` waves with room to spare (a
+    power of two, at least 16): the sequences of this file compile a few
+    depths between them, and every run ends in empty waves, as a run an
+    engine pads to a compiled depth does."""
+    return max(16, 1 << steps.bit_length())
+
+
+def decide_seq(entry, table, steps, run="stacked", with_store=False):
+    """`steps` [(batch, now)] through `entry` (table, operand), one
+    launch a step or as one stacked run: (table, [DecideOutput])."""
+    if run == "per_wave":
+        step, outs = batch_entry(entry, with_store), []
+        for batch, now in steps:
+            table, out = step(table, batch, now)
+            outs.append(out)
+        return table, outs
+    assert run == "stacked", run
+    table, outs, vecs = run_entry(entry, with_store)(
+        table, steps, run_depth(len(steps))
+    )
+    # the loop stopped at the last wave with a lane: the padding's rows
+    # were never written
+    assert not vecs[len(steps):].any()
+    return table, outs
 
 
 class KernelHarness:
-    """Single-request-per-call harness around the jitted kernel."""
+    """Single-request-per-call harness around the launched entry."""
 
     def __init__(self, num_groups=NUM_GROUPS, ways=8, batch=1, layout="wide"):
         self.K = get_kernels(layout)
@@ -45,13 +82,14 @@ class KernelHarness:
         self.num_groups = num_groups
         self.ways = ways
         self.batch = batch
+        self.step = batch_entry(packed(self.K, ways))
 
     def decide_one(self, r: RateLimitReq, now_ms: int):
         import copy
 
         rc = copy.replace(r) if hasattr(copy, "replace") else r
         b = encode_batch([rc], now_ms, self.num_groups, self.batch)
-        self.table, out = self.K.decide(self.table, b, now_ms, self.ways, False)
+        self.table, out = self.step(self.table, b, now_ms)
         return (
             int(out.status[0]),
             int(out.limit[0]),
@@ -60,16 +98,24 @@ class KernelHarness:
         )
 
 
-def check_seq(seq, num_groups=NUM_GROUPS, layout="wide", ways=8):
-    """Run (req, now) pairs through oracle and kernel; compare each step.
-
-    The kernel side runs the whole sequence in ONE dispatch via decide_scan
-    (stacked (T, 1) batches), so long fuzz sequences don't pay per-step
-    dispatch overhead.
-    """
+def encode_steps(seq, num_groups=NUM_GROUPS):
+    """(req, now) pairs as one-lane steps [(RequestBatch, now)]."""
     import dataclasses
 
-    import jax
+    return [
+        (encode_batch([dataclasses.replace(r)], now, num_groups, 1), now)
+        for r, now in seq
+    ]
+
+
+def check_seq(seq, num_groups=NUM_GROUPS, layout="wide", ways=8, run="stacked"):
+    """Run (req, now) pairs through oracle and kernel; compare each step.
+
+    The kernel side is the entry every engine launches (decide_packed),
+    one launch a step (`run="per_wave"`) or the whole sequence as ONE
+    stacked (T, OPERAND_ROWS, 1) run (`run="stacked"`).
+    """
+    import dataclasses
 
     K = get_kernels(layout)
 
@@ -81,20 +127,17 @@ def check_seq(seq, num_groups=NUM_GROUPS, layout="wide", ways=8):
             (int(want.status), int(want.limit), int(want.remaining), int(want.reset_time))
         )
 
-    batches = [
-        encode_batch([dataclasses.replace(r)], now, num_groups, 1) for r, now in seq
-    ]
-    stacked = jax.tree.map(lambda *xs: np.stack(xs), *batches)
-    nows = np.array([now for _, now in seq], dtype=np.int64)
-    table = K.create(num_groups, ways)
-    _, outs = K.decide_scan(table, stacked, nows, ways, False)
+    _, outs = decide_seq(
+        packed(K, ways), K.create(num_groups, ways),
+        encode_steps(seq, num_groups), run,
+    )
 
     for i, (r, _) in enumerate(seq):
         got = (
-            int(outs.status[i, 0]),
-            int(outs.limit[i, 0]),
-            int(outs.remaining[i, 0]),
-            int(outs.reset_time[i, 0]),
+            int(outs[i].status[0]),
+            int(outs[i].limit[0]),
+            int(outs[i].remaining[0]),
+            int(outs[i].reset_time[0]),
         )
         assert got == wants[i], f"step {i}: {r} got={got} want={wants[i]}"
 
@@ -206,11 +249,12 @@ def _fuzz_seq(seed):
 GREGORIAN_HOURS_SAFE = 1  # GREGORIAN_HOURS
 
 
+@pytest.mark.parametrize("run", RUNS)
 @pytest.mark.parametrize("ways", WAYS)
 @pytest.mark.parametrize("layout", LAYOUTS)
 @pytest.mark.parametrize("seed", [1, 2, 3, 4, 5, 6])
-def test_kernel_fuzz(seed, layout, ways):
-    check_seq(_fuzz_seq(seed), layout=layout, ways=ways)
+def test_kernel_fuzz(seed, layout, ways, run):
+    check_seq(_fuzz_seq(seed), layout=layout, ways=ways, run=run)
 
 
 # (groups, ways) whose slot count is no multiple of 8: a fused line then
@@ -296,7 +340,7 @@ def test_kernel_batch_parallel_lanes(layout, ways):
     import dataclasses
 
     b = encode_batch([dataclasses.replace(r) for r in reqs], NOW, NUM_GROUPS, 16)
-    kern.table, out = kern.K.decide(kern.table, b, NOW, ways, False)
+    kern.table, out = kern.step(kern.table, b, NOW)
     for i, r in enumerate(reqs):
         want = oracle.decide(dataclasses.replace(r), NOW)
         got = (int(out.status[i]), int(out.limit[i]), int(out.remaining[i]), int(out.reset_time[i]))
@@ -365,10 +409,6 @@ def _assert_outs_equal(of, op, i, layout):
 def test_paged_bitexact_all_resident(seed, layout, ways, gpp):
     """Full fuzz sequence, every page resident but SCRAMBLED across the
     physical table: logical->physical translation must be invisible."""
-    import dataclasses
-
-    import jax
-
     from gubernator_tpu.ops.kernels import get_paged_kernels
 
     K = get_kernels(layout)
@@ -379,17 +419,12 @@ def test_paged_bitexact_all_resident(seed, layout, ways, gpp):
     for lp, pp in enumerate(perm):
         pt = PK.bind_page(pt, np.int32(lp), np.int32(pp))
 
-    seq = _fuzz_reqs(seed)
-    batches = [
-        encode_batch([dataclasses.replace(r)], now, NUM_GROUPS, 1)
-        for r, now in seq
-    ]
-    stacked = jax.tree.map(lambda *xs: np.stack(xs), *batches)
-    nows = np.array([now for _, now in seq], dtype=np.int64)
+    steps = encode_steps(_fuzz_reqs(seed))
     flat = K.create(NUM_GROUPS, ways)
-    _, of = K.decide_scan(flat, stacked, nows, ways, False)
-    _, op = PK.decide_scan(pt, stacked, nows, ways, False)
-    _assert_outs_equal(of, op, "scan", layout)
+    _, ofs = decide_seq(packed(K, ways, True), flat, steps, with_store=True)
+    _, ops = decide_seq(packed(PK, ways, True), pt, steps, with_store=True)
+    for i, (of, op) in enumerate(zip(ofs, ops)):
+        _assert_outs_equal(of, op, i, layout)
 
 
 @PAGED
@@ -410,6 +445,8 @@ def test_paged_bitexact_under_churn(layout, ways, gpp):
     PK = get_paged_kernels(layout, NUM_GROUPS, ways, gpp, 4)
     pt = PK.create()
     flat = K.create(NUM_GROUPS, ways)
+    flat_step = batch_entry(packed(K, ways, True), True)
+    paged_step = batch_entry(packed(PK, ways, True), True)
 
     host_tier = {}  # logical page -> wide rows (numpy)
     resident = {}  # logical page -> physical page
@@ -439,8 +476,8 @@ def test_paged_bitexact_under_churn(layout, ways, gpp):
                 pt = PK.bind_page(pt, np.int32(lp), np.int32(pp))
             resident[lp] = pp
         lru[lp] = i
-        flat, of = K.decide(flat, b, now, ways, False)
-        pt, op = PK.decide(pt, b, now, ways, False)
+        flat, of = flat_step(flat, b, now)
+        pt, op = paged_step(pt, b, now)
         _assert_outs_equal(of, op, i, layout)
     assert host_tier or len(resident) == PK.num_phys_pages
 
@@ -456,6 +493,7 @@ def test_paged_nonresident_probe_safe(layout, ways, gpp):
     PK = get_paged_kernels(layout, NUM_GROUPS, ways, gpp, 2)
     pt = PK.create()
     pt = PK.bind_page(pt, np.int32(0), np.int32(0))
+    step = batch_entry(packed(PK, ways))
 
     import dataclasses
 
@@ -479,10 +517,10 @@ def test_paged_nonresident_probe_safe(layout, ways, gpp):
             break
     rr, rb = resident_req
     dr, db = demoted_req
-    pt, _ = PK.decide(pt, rb, NOW, ways, False)
+    pt, _ = step(pt, rb, NOW)
     before = np.asarray(PK.to_wide(pt).remaining).copy()
     # Hammer the demoted page: decide + probe must be inert.
-    pt, out = PK.decide(pt, db, NOW + 1, ways, False)
+    pt, out = step(pt, db, NOW + 1)
     exists = PK.probe_exists(
         pt, jnp.asarray(WaveOperand.of(db, NOW + 2).buf), ways
     )
@@ -490,7 +528,7 @@ def test_paged_nonresident_probe_safe(layout, ways, gpp):
     after = np.asarray(PK.to_wide(pt).remaining)
     assert (before == after).all(), "non-resident decide mutated the table"
     # The resident key is still served with its counter intact.
-    pt, out = PK.decide(pt, rb, NOW + 3, ways, False)
+    pt, out = step(pt, rb, NOW + 3)
     assert int(out.remaining[0]) == 8
 
 
@@ -522,20 +560,10 @@ def _admission_assert(out, want, ctx):
 
 def _fuzz_table(layout, seed, ways):
     """Final table state after a fuzz sequence, plus the last `now`."""
-    import dataclasses
-
-    import jax
-
     K = get_kernels(layout)
-    seq = _fuzz_reqs(seed)
-    batches = [
-        encode_batch([dataclasses.replace(r)], now, NUM_GROUPS, 1)
-        for r, now in seq
-    ]
-    stacked = jax.tree.map(lambda *xs: np.stack(xs), *batches)
-    nows = np.array([now for _, now in seq], dtype=np.int64)
-    table, _ = K.decide_scan(K.create(NUM_GROUPS, ways), stacked, nows, ways, False)
-    return table, int(nows[-1])
+    steps = encode_steps(_fuzz_reqs(seed))
+    table, _ = decide_seq(packed(K, ways), K.create(NUM_GROUPS, ways), steps)
+    return table, steps[-1][1]
 
 
 @pytest.mark.parametrize("ways", WAYS)
@@ -609,6 +637,8 @@ def test_admission_paged_tiers_bitexact(layout, ways, gpp):
     PK = get_paged_kernels(layout, NUM_GROUPS, ways, gpp, 4)
     pt = PK.create()
     flat = K.create(NUM_GROUPS, ways)
+    flat_step = batch_entry(packed(K, ways))
+    paged_step = batch_entry(packed(PK, ways))
 
     host_tier = {}
     resident = {}
@@ -650,8 +680,8 @@ def test_admission_paged_tiers_bitexact(layout, ways, gpp):
                 pt = PK.bind_page(pt, np.int32(lp), np.int32(pp))
             resident[lp] = pp
         lru[lp] = i
-        flat, _ = K.decide(flat, b, now, ways, False)
-        pt, _ = PK.decide(pt, b, now, ways, False)
+        flat, _ = flat_step(flat, b, now)
+        pt, _ = paged_step(pt, b, now)
     last = seq[-1][1]
     assert host_tier, "churn never demoted a page; shrink the frame count"
 
@@ -715,17 +745,3 @@ def test_kernel_eviction_lru(layout, ways):
     # k1 was evicted: fresh bucket
     s, lim, rem, _ = kern.decide_one(mk(keys[1]), now + 2)
     assert rem == 9
-
-
-# GL014 kernel-parity registry: every decide* entry point wired through
-# ops/kernels.py / ops/paged.py must name its oracle-comparison test
-# here. guberlint parses this dict from disk and fails the build when a
-# new entry point lands without a parity case (or maps to a test that
-# does not exist in this file).
-KERNEL_PARITY_CASES = {
-    # wide, the reference, and fused, what serves: oracle fuzz over both
-    "decide": "test_kernel_fuzz",
-    "decide_scan": "test_kernel_fuzz",
-    "decide_fused": "test_kernel_fuzz",
-    "decide_scan_fused": "test_kernel_fuzz",
-}

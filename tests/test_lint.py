@@ -66,7 +66,7 @@ def test_registry_complete():
     assert codes == {
         "GL000", "GL001", "GL002", "GL003", "GL004", "GL005", "GL006",
         "GL007", "GL008", "GL009", "GL010", "GL011", "GL012", "GL013",
-        "GL014", "GL015", "GL016", "GL017", "GL018", "GL019",
+        "GL015", "GL017", "GL018", "GL019",
     }
 
 
@@ -178,15 +178,6 @@ _CASES = [
             # dunders, non-core names, module-level defs don't fire
     ),
     (
-        "GL014",
-        fixture("ops", "gl014_kernel_parity.py"),
-        {"'decide_turbo'", "'decide_scan_turbo'",
-         "requires a non-empty reason"},
-        3,  # 2 uncovered entry points + 1 reason-less pragma; names
-            # covered by the real parity map (decide, decide_fused) and
-            # the reasoned-pragma reference stay quiet
-    ),
-    (
         "GL015",
         fixture("service", "gl015_slo_parity.py"),
         {"'turbo-freshness'", "requires a non-empty reason"},
@@ -222,18 +213,6 @@ _CASES = [
         5,  # 4 unbounded constructions + 1 reason-less pragma; bounded
             # (literal/positional/computed) and reasoned-pragma sites
             # stay quiet
-    ),
-    (
-        "GL016",
-        os.path.relpath(
-            os.path.join(
-                HERE, "lint_fixtures", "tools", "jobs", "99_ghostmode.py"
-            ),
-            REPO,
-        ),
-        {"'99_ghostmode'", "_MODE_FROM_JOB", "tools/jobs/README.md"},
-        2,  # no ledger mode + no README row; the ghost direction
-            # (README row with no job file) only fires on full scans
     ),
 ]
 
@@ -340,24 +319,6 @@ def test_linter_is_stdlib_only():
     )
     assert p.returncode == 0, p.stdout + p.stderr
     assert "scanned-ok" in p.stdout
-
-
-def test_gl014_repo_baseline_zero_and_map_valid():
-    # The shipping registry surface must be FULLY covered — GL014's
-    # repo baseline is pinned at zero (unlike the grandfathered rules),
-    # and every parity-map entry must point at a real test function.
-    res = run_lint(
-        paths=["gubernator_tpu/ops/kernels.py", "gubernator_tpu/ops/paged.py"],
-        rule_codes=["GL014"],
-    )
-    assert [f.render() for f in res.new] == []
-
-    from tools.lint.rules import kernel_parity_cases
-
-    cases, funcs = kernel_parity_cases()
-    assert cases, "KERNEL_PARITY_CASES must exist in tests/test_kernel_fuzz.py"
-    dangling = {k: v for k, v in cases.items() if v not in funcs}
-    assert dangling == {}
 
 
 def test_gl015_repo_baseline_zero_and_doc_table_valid():
@@ -563,26 +524,3 @@ def test_full_repo_lint_is_fast_enough():
     run_lint()
     dt = _time.perf_counter() - t0
     assert dt < 10.0, f"full repo lint took {dt:.1f}s"
-
-
-def test_gl016_repo_baseline_zero_and_readme_valid():
-    # Every shipping job must key to a ledger mode AND have a README
-    # row, and every README row must name a live job — GL016's repo
-    # baseline is pinned at zero in both directions.
-    import glob
-
-    jobs = sorted(
-        os.path.relpath(p, REPO)
-        for p in glob.glob(os.path.join(REPO, "tools", "jobs", "*.py"))
-    )
-    assert jobs, "tools/jobs must contain runnable jobs"
-    res = run_lint(paths=jobs, rule_codes=["GL016"])
-    assert [f.render() for f in res.new] == []
-
-    from tools.lint import Context, REGISTRY
-    from tools.lint.rules import jobs_readme_stems
-
-    assert jobs_readme_stems(), "tools/jobs/README.md must carry a job table"
-    gl016 = next(r for r in REGISTRY if r.code == "GL016")
-    ghosts = gl016.check_repo(Context([], full_repo=True))
-    assert [f.render() for f in ghosts] == []

@@ -1,8 +1,9 @@
 """The decide program's packed interface (ops/layout.py): one uploaded
-operand in, one output vector out, bit-identical to the RequestBatch
-entry it wraps — for both layouts at 4 and 8 ways, with and without
-the store columns, through the paged kernels, and the mesh and replica
-programs on faked devices."""
+operand in, one output vector out, the one entry of every kernel set.
+The serving layout's program is held to the reference layout's (`wide`)
+at 4 and 8 ways, with and without the store columns, a launch a wave
+and as one stacked run; the paged kernels, and the mesh and replica
+programs on faked devices, are held to the flat program."""
 
 import dataclasses
 import random
@@ -24,10 +25,12 @@ from gubernator_tpu.ops.layout import (
     DecideOutput,
     RequestBatch,
     WaveOperand,
+    batch_entry,
     output_struct,
     unpack_operand,
 )
 from gubernator_tpu.utils.gregorian import GREGORIAN_MINUTES
+from tests.test_kernel_fuzz import RUNS, decide_seq, packed
 
 NOW = 1_753_700_000_000
 NUM_GROUPS = 64  # tiny: full groups evict, so slot/evicted/freed carry values
@@ -88,8 +91,14 @@ def assert_same(got: DecideOutput, want: DecideOutput, with_store, where):
         )
 
 
-def assert_same_table(K, a, b):
-    wa, wb = K.to_wide(a), K.to_wide(b)
+def flat_entry(layout, ways, with_store=False):
+    """The flat single-table program of `layout` under the RequestBatch
+    signature: what every other program here is held to."""
+    return batch_entry(packed(get_kernels(layout), ways, with_store), with_store)
+
+
+def assert_same_table(K, a, b, Kb=None):
+    wa, wb = K.to_wide(a), (Kb or K).to_wide(b)
     for f in wa._fields:
         np.testing.assert_array_equal(
             np.asarray(getattr(wa, f)), np.asarray(getattr(wb, f)),
@@ -131,43 +140,53 @@ def test_operand_round_trip():
 
 @pytest.mark.parametrize("ways", [4, 8])
 @pytest.mark.parametrize("with_store", [False, True])
-@pytest.mark.parametrize("layout", LAYOUTS)
-def test_packed_entry_is_the_batch_entry(layout, with_store, ways):
-    K = get_kernels(layout)
+@pytest.mark.parametrize("run", RUNS)
+def test_fused_program_is_the_wide_program(run, with_store, ways):
+    """The serving layout's launch, a wave at a time or the corpus as one
+    stacked run, answers lane for lane (store columns and totals too) as
+    the reference layout's does, and leaves the same table."""
+    W, F = get_kernels("wide"), get_kernels("fused")
     groups = NUM_GROUPS * WAYS // ways  # as many slots, so groups still fill
-    ta, tb = K.create(groups, ways), K.create(groups, ways)
-    evicted = 0
-    for i, (batch, now) in enumerate(corpus(7, num_groups=groups)):
-        ta, want = K.decide(ta, batch, now, ways, with_store)
-        tb, vec = K.decide_packed(
-            tb, WaveOperand.of(batch, now).buf, ways, with_store
-        )
-        vec = np.asarray(vec)
+    steps = corpus(7, num_groups=groups)
+    wide_step = flat_entry("wide", ways, with_store)
+    tw, wants = W.create(groups, ways), []
+    for batch, now in steps:
+        tw, want = wide_step(tw, batch, now)
+        wants.append(want)
+
+    def fused(t, op):
+        t, vec = F.decide_packed(t, op, ways, with_store)
         assert vec.dtype == np.int64
-        assert vec.shape == ((8 if with_store else 4) * B + 4,)
-        assert_same(output_struct(vec, with_store), want, with_store,
-                    f"{layout} {ways} ways step {i}")
-        evicted += int(np.count_nonzero(np.asarray(want.evicted_hi)))
-    assert evicted > 0  # the store columns carried values
-    assert_same_table(K, ta, tb)
+        assert vec.shape[-1] == (8 if with_store else 4) * B + 4
+        return t, vec
+
+    tf, gots = decide_seq(fused, F.create(groups, ways), steps, run, with_store)
+    evicted = 0
+    for i, (got, want) in enumerate(zip(gots, wants)):
+        assert_same(got, want, with_store, f"{run} {ways} ways step {i}")
+        if with_store:
+            evicted += int(np.count_nonzero(want.evicted_hi))
+    assert evicted > 0 or not with_store  # the store columns carried values
+    assert_same_table(W, tw, tf, F)
 
 
 @pytest.mark.parametrize("gpp", [8, 4])
-def test_paged_packed_entry_is_the_batch_entry(gpp):
+def test_paged_program_is_the_flat_program(gpp):
     layout, pages = "fused", NUM_GROUPS // gpp
+    K = get_kernels(layout)
     PK = get_paged_kernels(layout, NUM_GROUPS, WAYS, gpp, pages)
-    pa, pb = PK.create(), PK.create()
-    for lp in range(pages):  # every logical page resident
-        z = np.int32(lp)
-        pa, pb = PK.bind_page(pa, z, z), PK.bind_page(pb, z, z)
+    flat, pt = K.create(NUM_GROUPS, WAYS), PK.create()
+    for lp in range(pages):  # every logical page resident, in place
+        pt = PK.bind_page(pt, np.int32(lp), np.int32(lp))
+    flat_step = flat_entry(layout, WAYS, True)
     for i, (batch, now) in enumerate(corpus(11)):
-        pa, want = PK.decide(pa, batch, now, WAYS, True)
-        pb, vec = PK.decide_packed(
-            pb, WaveOperand.of(batch, now).buf, WAYS, True
+        flat, want = flat_step(flat, batch, now)
+        pt, vec = PK.decide_packed(
+            pt, WaveOperand.of(batch, now).buf, WAYS, True
         )
         assert_same(output_struct(vec, True), want, True,
                     f"paged {gpp} groups a page step {i}")
-    assert_same_table(PK, pa, pb)
+    assert_same_table(K, flat, pt, PK)
 
 
 NDEV = 8
@@ -175,10 +194,10 @@ NDEV = 8
 
 @pytest.mark.parametrize("n_dev", [NDEV, 4])
 @pytest.mark.parametrize("layout", ["fused", "wide"])
-def test_mesh_program_is_the_batch_entry(layout, n_dev):
+def test_mesh_program_is_the_flat_program(layout, n_dev):
     """The owner-sharded packed program over 8 (and 4) faked devices
-    answers as the single-table RequestBatch entry does: every lane has
-    one owner, so the psum of the packed vectors is that owner's answer."""
+    answers as the single-table program does: every lane has one owner,
+    so the psum of the packed vectors is that owner's answer."""
     from gubernator_tpu.parallel import mesh as pmesh
 
     groups = 8 * NDEV
@@ -186,16 +205,16 @@ def test_mesh_program_is_the_batch_entry(layout, n_dev):
     table = pmesh.create_sharded_table(mesh, groups, ways=WAYS, layout=layout)
     decide = pmesh.make_sharded_decide(mesh, groups, ways=WAYS, layout=layout)
     K = get_kernels(layout)
-    flat = K.create(groups, WAYS)
+    flat, flat_step = K.create(groups, WAYS), flat_entry(layout, WAYS)
     for i, (batch, now) in enumerate(corpus(17, num_groups=groups)):
-        flat, want = K.decide(flat, batch, now, WAYS, False)
+        flat, want = flat_step(flat, batch, now)
         table, vec = decide(table, WaveOperand.of(batch, now).buf)
         assert_same(output_struct(vec), want, False,
                     f"mesh {layout} x{n_dev} step {i}")
     assert_same_table(K, flat, table)
 
 
-def test_replica_program_is_the_batch_entry():
+def test_replica_program_is_the_flat_program():
     """The replica tier's packed program (the `home` row rides the
     operand): lane i is answered by device home[i]'s replica alone, so
     the answers are those of one flat table per home device deciding
@@ -209,6 +228,7 @@ def test_replica_program_is_the_batch_entry():
     decide = ici.make_replica_decide(mesh, groups * WAYS, WAYS, layout=layout)
     K = get_kernels(layout)
     flats = [K.create(groups, WAYS) for _ in range(NDEV)]
+    flat_step = flat_entry(layout, WAYS)
     rng = np.random.default_rng(19)
     for i, (batch, now) in enumerate(
         corpus(19, num_groups=groups, steps=25, global_=True)
@@ -219,7 +239,7 @@ def test_replica_program_is_the_batch_entry():
         want = {f: 0 for f in LANE_FIELDS + TOTALS}
         for d in range(NDEV):
             mine = batch._replace(active=batch.active & (home == d))
-            flats[d], o = K.decide(flats[d], mine, now, WAYS, False)
+            flats[d], o = flat_step(flats[d], mine, now)
             for f in want:
                 want[f] = want[f] + np.asarray(getattr(o, f)).astype(np.int64)
         for f in want:

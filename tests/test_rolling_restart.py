@@ -1,5 +1,4 @@
-"""Rolling-restart elasticity (fast subset of
-tools/jobs/41_rolling_restart.py, chaos marker — tier-1 covers it):
+"""Rolling-restart elasticity (chaos marker — tier-1 covers it):
 restart a 3-daemon cluster one node at a time and assert ZERO counter
 loss — every hit applied before and between restarts is still reflected
 in each key's remaining afterwards.

@@ -1,8 +1,7 @@
 """Crash-tolerant ownership (parallel/standby.py, docs/robustness.md
 "Standby replication & crash recovery"): wire codec + version skew,
 receiver shadow semantics, promotion/echo idempotence, drain retire,
-fault-injected repair, and the GUBER_STANDBY=0 bit-exact pin. The
-acceptance soak is tools/jobs/44_crash_soak.py."""
+fault-injected repair, and the GUBER_STANDBY=0 bit-exact pin."""
 
 import asyncio
 import threading

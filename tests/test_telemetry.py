@@ -9,7 +9,7 @@ import os
 import pytest
 
 from gubernator_tpu.api.types import Behavior, RateLimitReq
-from gubernator_tpu.ops.layout import RequestBatch
+from gubernator_tpu.ops.layout import WaveOperand
 from gubernator_tpu.runtime import telemetry
 from gubernator_tpu.runtime.engine import DeviceEngine, EngineConfig
 from gubernator_tpu.runtime.telemetry import FlightRecorder
@@ -132,12 +132,12 @@ def test_deliberate_cold_dispatch_is_detected(engine):
     0 above is not a dead sensor)."""
     scratch = engine.K.create(32, 4)  # geometry the engine never warmed
     with telemetry.serving_scope(engine.metrics):
-        engine.K.decide(scratch, RequestBatch.zeros(8), NOW, 4, False)
+        engine.K.decide_packed(scratch, WaveOperand.zeros(8).stamp(NOW).buf, 4)
     assert engine.metrics.cold_compiles > 0
     # and the same dispatch OUTSIDE a serving scope is not counted
     before = engine.metrics.cold_compiles
     scratch2 = engine.K.create(16, 4)
-    engine.K.decide(scratch2, RequestBatch.zeros(4), NOW, 4, False)
+    engine.K.decide_packed(scratch2, WaveOperand.zeros(4).stamp(NOW).buf, 4)
     assert engine.metrics.cold_compiles == before
 
 
@@ -163,7 +163,9 @@ def test_completion_thread_compile_is_counted(monkeypatch):
         def cold_then_real(*a, **kw):
             if fired["n"] == 0:
                 fired["n"] = 1
-                eng.K.decide(scratch, RequestBatch.zeros(12), NOW, 4, False)
+                eng.K.decide_packed(
+                    scratch, WaveOperand.zeros(12).stamp(NOW).buf, 4
+                )
             return real(*a, **kw)
 
         monkeypatch.setattr(engine_mod, "_read_waves", cold_then_real)

@@ -13,8 +13,22 @@ from gubernator_tpu.cluster import Cluster
 LIMIT = 1_000_000
 
 
+def start_warm(loop_thread, n):
+    """An n-daemon cluster whose engines have every program compiled.
+    The daemons, their warm-up threads and the herd's clients share this
+    one interpreter: a herd that starts while three ladders of widths
+    still compile in the background waits behind them, and a forward
+    then misses the peer call's deadline (batch_timeout_s, 0.5 s) and is
+    retried after the owner has applied it. A deployment closes the same
+    gap with GUBER_PREWARM_BUCKETS; no deadline is changed here."""
+    c = loop_thread.run(Cluster.start(n, cache_size=4096), timeout=120)
+    for d in c.daemons:
+        assert d.engine.wait_warm(120)
+    return c
+
+
 def test_thundering_herd_exact_consumption(loop_thread):
-    c = loop_thread.run(Cluster.start(3, cache_size=4096), timeout=120)
+    c = start_warm(loop_thread, 3)
 
     async def run():
         clients = [GubernatorClient(d.grpc_address) for d in c.daemons]
@@ -69,7 +83,7 @@ def test_thundering_herd_global_exact_replication(loop_thread):
 
     from gubernator_tpu.api.types import Behavior
 
-    c = loop_thread.run(Cluster.start(3, cache_size=4096), timeout=120)
+    c = start_warm(loop_thread, 3)
 
     async def run():
         clients = [GubernatorClient(d.grpc_address) for d in c.daemons]

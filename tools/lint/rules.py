@@ -1,4 +1,4 @@
-"""guberlint rule set GL000-GL016.
+"""guberlint rule set GL000-GL019.
 
 Each rule pins one serving-path invariant; docs/linting.md is the
 operator-facing catalog. Rules are deliberately heuristic — static
@@ -1151,128 +1151,6 @@ class GL013EngineCoreDrift(Rule):
         return out
 
 
-# Files that register decide entry points into the kernel registry
-# surface (GL014): the layout registry itself and the paged facade
-# that composes over it.
-_KERNEL_REGISTRY_FILES = (
-    "gubernator_tpu/ops/kernels.py",
-    "gubernator_tpu/ops/paged.py",
-    # fixture twin — only ever scanned when passed explicitly
-    "gubernator_tpu/ops/gl014_kernel_parity.py",
-)
-_PARITY_TEST_FILE = "tests/test_kernel_fuzz.py"
-_PARITY_MAP_NAME = "KERNEL_PARITY_CASES"
-_DECIDE_NAME_RE = re.compile(r"^_?decide\w*$")
-
-_parity_cases_cache: Optional[Tuple[Dict[str, str], Set[str]]] = None
-
-
-def _normalize_decide_name(name: str) -> str:
-    """Registry spelling -> parity-map key: `_decide_fused_impl` and
-    `decide_fused` are the same entry point."""
-    name = name.lstrip("_")
-    if name.endswith("_impl"):
-        name = name[: -len("_impl")]
-    return name
-
-
-def kernel_parity_cases() -> Tuple[Dict[str, str], Set[str]]:
-    """(KERNEL_PARITY_CASES map, defined test-function names) parsed
-    from tests/test_kernel_fuzz.py on disk — from disk so the rule
-    works on partial scans (fixtures); cached per process."""
-    global _parity_cases_cache
-    if _parity_cases_cache is None:
-        cases: Dict[str, str] = {}
-        funcs: Set[str] = set()
-        path = os.path.join(REPO_ROOT, _PARITY_TEST_FILE)
-        try:
-            with open(path, encoding="utf-8") as f:
-                tree = ast.parse(f.read())
-        except (OSError, SyntaxError):
-            tree = ast.Module(body=[], type_ignores=[])
-        for node in ast.walk(tree):
-            if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-                funcs.add(node.name)
-            if (
-                isinstance(node, ast.Assign)
-                and len(node.targets) == 1
-                and isinstance(node.targets[0], ast.Name)
-                and node.targets[0].id == _PARITY_MAP_NAME
-                and isinstance(node.value, ast.Dict)
-            ):
-                for k, v in zip(node.value.keys, node.value.values):
-                    if isinstance(k, ast.Constant) and isinstance(
-                        v, ast.Constant
-                    ):
-                        cases[str(k.value)] = str(v.value)
-        _parity_cases_cache = (cases, funcs)
-    return _parity_cases_cache
-
-
-class GL014KernelParity(Rule):
-    code = "GL014"
-    name = "kernel-parity"
-    requires_reason = True
-    description = (
-        "every decide* entry point the kernel registry surface "
-        "(ops/kernels.py, ops/paged.py) wires up must be claimed by an "
-        "oracle-comparison case in tests/test_kernel_fuzz.py's "
-        "KERNEL_PARITY_CASES map (key = normalized entry-point name, "
-        "value = the covering test function) — a decide variant without "
-        "a differential test is an unfuzzed fork of the policy "
-        "arithmetic"
-    )
-
-    def check_module(self, mod: Module) -> List[Finding]:
-        if scan_path(mod.relpath) not in _KERNEL_REGISTRY_FILES:
-            return []
-        cases, funcs = kernel_parity_cases()
-        # Entry points this module wires: attribute reads off layout /
-        # backend modules plus from-imports of decide impls. Keyword
-        # names (decide=..., the facade FIELD) are not entry points.
-        referenced: Dict[str, int] = {}
-        for node in mod.nodes():
-            if isinstance(node, ast.Attribute) and _DECIDE_NAME_RE.match(
-                node.attr
-            ):
-                key = _normalize_decide_name(node.attr)
-                referenced.setdefault(key, node.lineno)
-            elif isinstance(node, ast.ImportFrom):
-                for alias in node.names:
-                    if _DECIDE_NAME_RE.match(alias.name):
-                        key = _normalize_decide_name(alias.name)
-                        referenced.setdefault(key, node.lineno)
-        out = []
-        for key in sorted(referenced):
-            line = referenced[key]
-            if key not in cases:
-                out.append(
-                    self.finding(
-                        mod.relpath,
-                        line,
-                        f"decide entry point '{key}' has no "
-                        f"KERNEL_PARITY_CASES entry in "
-                        f"{_PARITY_TEST_FILE} — add an oracle-"
-                        f"comparison case (or an allow-kernel-parity "
-                        f"pragma)",
-                        f"parity:{key}",
-                    )
-                )
-            elif cases[key] not in funcs:
-                out.append(
-                    self.finding(
-                        mod.relpath,
-                        line,
-                        f"KERNEL_PARITY_CASES['{key}'] names "
-                        f"'{cases[key]}', which is not a test function "
-                        f"in {_PARITY_TEST_FILE} — the parity claim is "
-                        f"dangling",
-                        f"parity-dangling:{key}",
-                    )
-                )
-        return out
-
-
 # Files that define SloSpec catalog entries (GL015): the observatory's
 # default catalog and the fixture twin.
 _SLO_CATALOG_FILES = (
@@ -1375,124 +1253,6 @@ class GL015SloCatalogParity(Rule):
                         f"'{sid}' but service/slo.py constructs no such "
                         f"SloSpec — the documented alert is a ghost",
                         f"slo-catalog-ghost:{sid}",
-                    )
-                )
-        return out
-
-
-# ---------------------------------------------------------------------------
-# GL016 — tools/jobs <-> ledger mode map <-> jobs README parity.
-
-_JOBS_DIR = "tools/jobs"
-_JOBS_README = "tools/jobs/README.md"
-# A runnable device job: NN_name.py (helpers like README.md don't match).
-_JOB_PATH_RE = re.compile(r"^tools/jobs/(\d+_[a-z0-9_]+)\.py$")
-# Job stems mentioned in a README table row cell.
-_JOB_STEM_RE = re.compile(r"\b(\d+_[a-z0-9_]+)\b")
-
-_jobs_readme_cache: Optional[Dict[str, int]] = None
-
-
-def jobs_readme_stems() -> Dict[str, int]:
-    """Job stems named in tools/jobs/README.md table rows -> line number.
-    Parsed from disk (so fixture scans see the real catalog); cached per
-    process. Scoped to table rows so prose mentioning an old job name
-    never counts as its catalog entry."""
-    global _jobs_readme_cache
-    if _jobs_readme_cache is None:
-        stems: Dict[str, int] = {}
-        path = os.path.join(REPO_ROOT, _JOBS_README)
-        try:
-            with open(path, encoding="utf-8") as f:
-                lines = f.read().splitlines()
-        except OSError:
-            lines = []
-        for i, line in enumerate(lines, 1):
-            if not line.lstrip().startswith("|"):
-                continue
-            for stem in _JOB_STEM_RE.findall(line):
-                stems.setdefault(stem, i)
-        _jobs_readme_cache = stems
-    return _jobs_readme_cache
-
-
-def _ledger_mode_re() -> "re.Pattern[str]":
-    """The ONE job-name -> ledger mode regex (utils/ledger.py). Imported,
-    not re-parsed: the rule must agree with what archiving actually does."""
-    if REPO_ROOT not in sys.path:
-        sys.path.insert(0, REPO_ROOT)
-    from gubernator_tpu.utils import ledger
-
-    return ledger._MODE_FROM_JOB
-
-
-class GL016JobLedgerParity(Rule):
-    code = "GL016"
-    name = "job-ledger-parity"
-    requires_reason = True
-    description = (
-        "every tools/jobs/NN_name.py must key to a ledger mode "
-        "(utils/ledger.py _MODE_FROM_JOB) and have a row in "
-        "tools/jobs/README.md — a job whose RESULT ledgers with mode='' "
-        "silently falls out of gate() regression baselines, and a README "
-        "row naming a deleted job is a ghost runbook entry"
-    )
-
-    def check_module(self, mod: Module) -> List[Finding]:
-        m = _JOB_PATH_RE.match(scan_path(mod.relpath))
-        if not m:
-            return []
-        stem = m.group(1)
-        out = []
-        if _ledger_mode_re().search(stem) is None:
-            out.append(
-                self.finding(
-                    mod.relpath,
-                    1,
-                    f"job '{stem}' matches no mode in utils/ledger.py "
-                    f"_MODE_FROM_JOB — its RESULT rows would ledger with "
-                    f"mode='' and never gate; extend the mode alternation "
-                    f"(or add an allow-job-ledger-parity pragma)",
-                    f"ledger-mode:{stem}",
-                )
-            )
-        if stem not in jobs_readme_stems():
-            out.append(
-                self.finding(
-                    mod.relpath,
-                    1,
-                    f"job '{stem}' has no row in {_JOBS_README} — add it "
-                    f"to the catalog table (or add an "
-                    f"allow-job-ledger-parity pragma)",
-                    f"readme-row:{stem}",
-                )
-            )
-        return out
-
-    def check_repo(self, ctx: Context) -> List[Finding]:
-        # Ghost direction (README row naming a job file that no longer
-        # exists) only makes sense against the real full tree.
-        if not ctx.full_repo:
-            return []
-        try:
-            present = {
-                fn[: -len(".py")]
-                for fn in os.listdir(os.path.join(REPO_ROOT, _JOBS_DIR))
-                if _JOB_PATH_RE.match(f"{_JOBS_DIR}/{fn}")
-            }
-        except OSError:
-            return []
-        out = []
-        for stem, line in sorted(jobs_readme_stems().items()):
-            if stem not in present:
-                out.append(
-                    self.finding(
-                        _JOBS_README,
-                        line,
-                        f"README row names job '{stem}' but "
-                        f"{_JOBS_DIR}/{stem}.py does not exist — the "
-                        f"catalog entry is a ghost",
-                        f"readme-ghost:{stem}",
                     )
                 )
         return out
