@@ -5,21 +5,45 @@ Three stages, all on what the timed path's own server answered:
 
 1. before the window: sequential calls of the cell's own mix with a
    pinned clock, every answer equal to the reference's (``Sequential``);
-2. in the window: every response kept; with one hit per item the
-   order-free invariants of a token bucket are exact (``check_window``):
-   inside one generation of a key (one ``reset_time``) the accepted hits
-   carry the remaining values start-1, start-2, ... start-n, each once;
-   an OVER_LIMIT answer carries 0 and only occurs in a generation that is
-   used up; every ``limit`` echoes the request;
+2. in the window: every response kept; the order-free invariants of a
+   token bucket are exact (``check_window``), whatever an item's hits h
+   and whether it carries DRAIN_OVER_LIMIT or RESET_REMAINING: inside one
+   generation of a key (one ``reset_time``) the accepted items, each the
+   stretch (remaining, remaining + h], tile (low, start] with no gap and
+   no overlap (with one hit each: start-1, start-2, ... start-n, each
+   once); an OVER_LIMIT answer carries a remaining under its h that the
+   generation passed through (0 once it was drained) and consumes
+   nothing; a refusal that carried DRAIN_OVER_LIMIT shows 0 and leaves 0;
+   an item that carried RESET_REMAINING is answered the full limit with
+   ``reset_time`` 0 (it found a bucket, took nothing and removed it) or
+   as the first of a generation (it found none); every ``limit`` echoes
+   the request;
 3. after the window: ``hits=0`` probes equal what was left minus the
-   accepted hits (``check_probes``).
+   accepted hits, 0 where the generation was drained (``check_probes``).
+
+What the rows model is behaviour 0, DRAIN_OVER_LIMIT, RESET_REMAINING and
+their union at any hits >= 1 (``modelled``); GLOBAL items have rows of
+their own (below) and DURATION_IS_GREGORIAN is outside the reference.
+Leaky keys are held to their range, the echo of ``limit``, a refusal's
+remaining under its hits, and a RESET_REMAINING answer to burst less its
+hits.
 
 The reference has no capacity, the table has: it is set-associative and
 evicts inside a group. A key that shows a new generation while its old
 one had not expired was evicted. That is counted, and held to three times
 what the table's geometry lets one expect among the keys a run looks at
 (``eviction_allowance``); inside every generation the
-count stays exact.
+count stays exact. A RESET_REMAINING answer that removed the key's bucket
+pays for one generation made while the old one lived: a key counts as
+evicted only where the window shows more such generations than removals.
+The check keeps no order, so it cannot say which removal came before which
+generation: what that excuses is a bucket the table forgot of a key that
+was also reset in the window and whose removal no new generation followed.
+One call stamps one time on its items, so a key reset and hit again twice
+in one millisecond shows two generations under one ``reset_time``: the
+accepted items of such a group have to split into that many tilings, each
+from the full limit (``_chains``), and each further one is a generation
+made while the old one lived.
 
 A configuration whose guarantee is eventual (consistency.py) answers
 from copies that are reconciled in the background. Its items (those whose
@@ -39,19 +63,25 @@ from __future__ import annotations
 
 import copy
 import math
+from collections import Counter
 from dataclasses import dataclass, field
 
 import numpy as np
 
 from benchmarks.reference.oracle import (
+    DRAIN_OVER_LIMIT,
     GLOBAL,
     OVER_LIMIT,
+    RESET_REMAINING,
     TOKEN_BUCKET,
     UNDER_LIMIT,
     Reference,
 )
 
-PLAIN_BEHAVIORS = (0,)  # what the order-free invariants model
+
+def modelled(behavior: np.ndarray) -> np.ndarray:
+    """Items whose flags the order-free invariants model."""
+    return (behavior & ~(DRAIN_OVER_LIMIT | RESET_REMAINING)) == 0
 
 
 def is_global(behavior: np.ndarray) -> np.ndarray:
@@ -231,6 +261,11 @@ class Items:
     reset_time: np.ndarray
     valid: np.ndarray  # the call returned and the item carries no error
     behavior: np.ndarray
+    hits: np.ndarray = None  # what each item asked for; one where not given
+
+    def __post_init__(self):
+        if self.hits is None:
+            self.hits = np.ones(len(self.key), dtype=np.int64)
 
 
 def _first(mask: np.ndarray, items: Items, what: str) -> str:
@@ -255,6 +290,24 @@ class Carried:
                    np.zeros(n, bool))
 
 
+def _chains(rem: list, hits: list, start: int):
+    """Splits accepted items (``remaining``, hits) into tilings that each
+    begin at `start` and step down with no gap: the lowest value of each,
+    or None where they do not split so. An item begins a tiling where its
+    stretch ends at `start` and otherwise continues the one that stands
+    where its stretch ends; tilings that stand at one value are alike, so
+    taking the items from the top down decides it."""
+    stands = Counter()
+    for r, h in sorted(zip(rem, hits), key=lambda x: -(x[0] + x[1])):
+        top = r + h
+        if top != start:
+            if not stands[top]:
+                return None
+            stands[top] -= 1
+        stands[r] += 1
+    return sorted(stands.elements())
+
+
 class WindowCheck:
     """Order-free invariants over the window's items, then the probes."""
 
@@ -262,12 +315,21 @@ class WindowCheck:
         self.ks = keyspace
         self.carried = carried
         self.uncertain = uncertain  # bool over keys: in a call that failed
-        # generations seen in the window, sorted by (key, reset_time)
+        # generations seen in the window, sorted by (key, reset_time): what
+        # each took (the sum of its accepted hits), its OVER_LIMIT answers
+        # that showed 0, and whether a refusal that drains is among them
         self.g_key = np.zeros(0, np.int64)
         self.g_reset = np.zeros(0, np.int64)
-        self.g_accepted = np.zeros(0, np.int64)
-        self.g_over = np.zeros(0, bool)
+        self.g_taken = np.zeros(0, np.int64)
+        self.g_over_at_zero = np.zeros(0, np.int64)
+        self.g_drain = np.zeros(0, bool)
+        self.split = {}  # (key, reset_time) -> the lows of its several tilings
+        # per key: RESET_REMAINING answers that removed its bucket, and
+        # generations made while the old one lived
+        self.removed = np.zeros(keyspace.n, np.int64)
+        self.early = {}
         self.evicted = set()
+        self.counted = {}  # what the window held, for the run's last line
 
     def _initial(self, key: np.ndarray, reset: np.ndarray) -> np.ndarray:
         c = self.carried
@@ -277,80 +339,162 @@ class WindowCheck:
     def check_window(self, it: Items, v: Verdict) -> None:
         ks = self.ks
         ok = it.valid
-        plain = np.isin(it.behavior, PLAIN_BEHAVIORS)
+        plain = modelled(it.behavior)
         tok = ks.is_token(it.key)
+        over = it.status == OVER_LIMIT
+        drains = plain & ((it.behavior & DRAIN_OVER_LIMIT) != 0)
+        resets = plain & ((it.behavior & RESET_REMAINING) != 0)
         v.add("window.limit_not_echoed", np.sum(ok & (it.limit != ks.limit)), 0,
               _first(ok & (it.limit != ks.limit), it, "limit"))
         bad_range = ok & ((it.remaining < 0) | (it.remaining > ks.limit)
                           | ~np.isin(it.status, (UNDER_LIMIT, OVER_LIMIT)))
         v.add("window.out_of_range", np.sum(bad_range), 0,
               _first(bad_range, it, "range"))
-        over_nonzero = ok & plain & (it.status == OVER_LIMIT) & (it.remaining != 0)
-        v.add("window.over_limit_with_remaining", np.sum(over_nonzero), 0,
-              _first(over_nonzero, it, "over"))
-        sel = ok & plain & tok
+        # a refusal shows what was left, and that is less than it asked for
+        over_enough = ok & plain & over & ((it.remaining < 0)
+                                           | (it.remaining >= it.hits))
+        v.add("window.over_limit_with_remaining", np.sum(over_enough), 0,
+              _first(over_enough, it, "over"))
+        # RESET_REMAINING: the bucket removed (the full limit, no reset_time),
+        # or none found and the item the first of a generation; a leaky
+        # bucket is refilled to its burst and then takes the hits
+        removal = (resets & tok & ~over & (it.remaining == ks.limit)
+                   & (it.reset_time == 0))
+        first = ~over & (it.remaining == ks.limit - it.hits)
+        not_fresh = ok & resets & ~removal & ~(first & (~tok | (it.reset_time != 0)))
+        self.removed = np.bincount(it.key[ok & removal], minlength=ks.n)
+        drain_left = ok & drains & over & (it.remaining != 0)
+        self.counted = {
+            "items": int(np.sum(ok)),
+            "items_hits_over_1": int(np.sum(ok & (it.hits > 1))),
+            "items_drain": int(np.sum(ok & drains)),
+            "items_reset": int(np.sum(ok & resets)),
+            "refused": int(np.sum(ok & plain & over)),
+            "refused_with_remainder": int(np.sum(ok & plain & over & (it.remaining > 0))),
+            "reset_removed_bucket": int(np.sum(ok & removal)),
+        }
+        sel = ok & plain & tok & ~removal
         key, rst = it.key[sel], it.reset_time[sel]
         rem, st = it.remaining[sel], it.status[sel]
+        hit, drn = it.hits[sel], drains[sel]
+
+        def flag_rows(stood=0, example=""):
+            v.add("window.drain_left_remaining", np.sum(drain_left) + stood, 0,
+                  _first(drain_left, it, "drain") if drain_left.any() else example)
+            v.add("window.reset_not_fresh", np.sum(not_fresh), 0,
+                  _first(not_fresh, it, "reset"))
+
         if not len(key):
             v.add("window.token_generations_not_exact", 0, 0)
             v.add("window.over_limit_before_used_up", 0, 0)
+            flag_rows()
             return
         order, gid, starts = generations(key, rst, then=(rem,))
         key, rst, rem, st = key[order], rst[order], rem[order], st[order]
+        hit, drn = hit[order], drn[order]
         g_key, g_reset = key[starts], rst[starts]
         n_gen = len(starts)
         acc = st == UNDER_LIMIT
         g_acc = np.bincount(gid[acc], minlength=n_gen)
-        g_over = np.bincount(gid[~acc], minlength=n_gen) > 0
+        g_taken = np.bincount(gid[acc], weights=hit[acc],
+                              minlength=n_gen).astype(np.int64)
         initial = self._initial(g_key, g_reset)
         certain = ~self.uncertain[g_key]
-        # accepted remaining values, ascending inside each generation
-        a_gid, a_rem = gid[acc], rem[acc]
-        lo = np.full(n_gen, 0, np.int64)
-        hi = np.full(n_gen, -1, np.int64)
+        # accepted items, ascending by remaining inside each generation: the
+        # stretch (remaining, remaining + hits] each took
+        a_gid, a_rem, a_top = gid[acc], rem[acc], rem[acc] + hit[acc]
+        lo = initial.copy()  # where the generation stands at the end
+        top = np.full(n_gen, -1, np.int64)
         steps_off = np.zeros(n_gen, np.int64)
+        a_first = np.zeros(n_gen, np.int64)
         if len(a_gid):
             a_new = np.ones(len(a_gid), dtype=bool)
             a_new[1:] = a_gid[1:] != a_gid[:-1]
             a_starts = np.nonzero(a_new)[0]
             a_ends = np.append(a_starts[1:], len(a_gid)) - 1
+            a_first[a_gid[a_starts]] = a_starts
             lo[a_gid[a_starts]] = a_rem[a_starts]
-            hi[a_gid[a_starts]] = a_rem[a_ends]
-            d = np.diff(a_rem)
+            top[a_gid[a_starts]] = a_top[a_ends]
+            gap = a_rem[1:] - a_top[:-1]
             inside = ~a_new[1:]
-            # certain keys: steps of exactly 1; keys of a failed call: no repeat
-            wrong = inside & np.where(certain[a_gid[1:]], d != 1, d == 0)
+            # certain keys: each stretch ends where the next begins; keys of
+            # a failed call: no overlap
+            wrong = inside & np.where(certain[a_gid[1:]], gap != 0, gap < 0)
             steps_off = np.bincount(a_gid[1:][wrong], minlength=n_gen)
         has = g_acc > 0
         bad_seq = has & (
             (steps_off > 0)
-            | np.where(certain,
-                       (lo != initial - g_acc) | (hi != initial - 1),
-                       (lo < 0) | (hi > initial - 1))
+            | np.where(certain, top != initial, (lo < 0) | (top > initial))
         )
-        bad_over = g_over & certain & (initial - g_acc != 0)
+        # several generations under one reset_time: made in one millisecond,
+        # a RESET_REMAINING between them
+        extra = np.zeros(n_gen, np.int64)
+        made_here = self.carried.reset_time[g_key] != g_reset
+        for g in np.nonzero(bad_seq & certain & made_here
+                            & (self.removed[g_key] > 0))[0].tolist():
+            a, b = int(a_first[g]), int(a_first[g]) + int(g_acc[g])
+            lows = _chains(a_rem[a:b].tolist(), (a_top[a:b] - a_rem[a:b]).tolist(),
+                           ks.limit)
+            if lows is not None:
+                bad_seq[g] = False
+                extra[g] = len(lows) - 1
+                lo[g] = lows[0]
+                self.split[(int(g_key[g]), int(g_reset[g]))] = lows
+        # a refusal shows a value its generation passed through: where it
+        # began or what an accepted item left; 0 only where the accepted hits
+        # add up to the start (with one hit each: as many as the start) or a
+        # refusal drained it
+        o_gid, o_rem, o_hit, o_drn = gid[~acc], rem[~acc], hit[~acc], drn[~acc]
+        g_drain = np.bincount(o_gid[o_drn], minlength=n_gen) > 0
+        emptied = np.where(extra > 0, lo == 0, g_taken == initial) | g_drain
+        span = ks.limit + 2
+        passed = np.isin(o_gid * span + np.clip(o_rem, -1, ks.limit),
+                         a_gid * span + np.clip(a_rem, -1, ks.limit))
+        passed = np.where(o_rem == 0, emptied[o_gid],
+                          passed | (o_rem == initial[o_gid]))
+        bad_over = np.bincount(o_gid[~passed & certain[o_gid]], minlength=n_gen) > 0
+        # a refusal that drains left nothing, and the one that emptied the
+        # generation asked for more than it had fallen to
+        most = np.zeros(n_gen, np.int64)
+        np.maximum.at(most, o_gid[o_drn], o_hit[o_drn])
+        g_drained = g_drain & (lo > 0)
+        stood = g_drained & certain & (most <= lo)
 
         def gen(i):
             return (f"key={int(g_key[i])} reset_time={int(g_reset[i])} "
                     f"start={int(initial[i])} accepted={int(g_acc[i])} "
-                    f"lowest={int(lo[i])} highest={int(hi[i])}")
+                    f"took={int(g_taken[i])} lowest={int(lo[i])} "
+                    f"highest_stretch_ends={int(top[i])}")
 
         v.add("window.token_generations_not_exact", np.sum(bad_seq), 0,
               gen(int(np.argmax(bad_seq))))
         v.add("window.over_limit_before_used_up", np.sum(bad_over), 0,
               gen(int(np.argmax(bad_over))))
+        flag_rows(np.sum(stood), gen(int(np.argmax(stood))))
         self.g_key, self.g_reset = g_key, g_reset
-        self.g_accepted, self.g_over = g_acc, g_over
-        self._note_evictions(g_key, g_reset)
+        self.g_taken, self.g_drain = g_taken, g_drained
+        self.g_over_at_zero = np.bincount(o_gid[o_rem == 0], minlength=n_gen)
+        self._note_evictions(g_key, g_reset, extra=extra)
+        self.counted.update(
+            generations=int(n_gen + extra.sum()),
+            generations_after_reset=int(sum(
+                min(n, int(self.removed[k])) for k, n in self.early.items())),
+            generations_drained=int(np.sum(g_drained)),
+            generations_under_one_reset_time=int(extra.sum()),
+        )
 
     def _note_evictions(self, g_key: np.ndarray, g_reset: np.ndarray,
-                        holds_carried: np.ndarray = None, slack_ms: int = 0) -> None:
+                        holds_carried: np.ndarray = None, slack_ms: int = 0,
+                        extra: np.ndarray = None) -> None:
         """Evictions among buckets sorted by (key, reset_time): one made
-        while the previous one, or the carried one, was alive. Where several
-        generations count as one bucket, `g_reset` is the earliest of them
-        (the copies may have kept that one) and `holds_carried` says the
-        carried generation is among them. `slack_ms` before its time is up
-        a bucket may be gone already (``check_window_eventual``)."""
+        while the previous one, or the carried one, was alive, beyond those
+        that a RESET_REMAINING answer's removal paid for (``removed``).
+        Where several generations count as one bucket, `g_reset` is the
+        earliest of them (the copies may have kept that one) and
+        `holds_carried` says the carried generation is among them.
+        `slack_ms` before its time is up a bucket may be gone already
+        (``check_window_eventual``). `extra`: further generations a row
+        stands for, made in its own millisecond."""
         dur = self.ks.duration_ms - slack_ms
         same = g_key[1:] == g_key[:-1]
         early = same & (g_reset[1:] - dur <= g_reset[:-1])
@@ -361,8 +505,12 @@ class WindowCheck:
             holds_carried = g_reset == c_reset
         early_first = (first & (c_reset >= 0) & ~holds_carried
                        & (g_reset - dur <= c_reset))
-        self.evicted.update(g_key[1:][early].tolist())
-        self.evicted.update(g_key[early_first].tolist())
+        made = [g_key[1:][early], g_key[early_first]]
+        if extra is not None:
+            made.append(np.repeat(g_key, extra))
+        keys, counts = np.unique(np.concatenate(made), return_counts=True)
+        self.early = dict(zip(keys.tolist(), counts.tolist()))
+        self.evicted.update(keys[counts > self.removed[keys]].tolist())
 
     def check_probes(self, it: Items, v: Verdict) -> None:
         """hits=0 probes sent after the window."""
@@ -371,9 +519,10 @@ class WindowCheck:
         c = self.carried
         probed = np.isin(self.g_key, it.key)
         gens = {
-            (int(k), int(r)): (int(a), bool(o))
-            for k, r, a, o in zip(self.g_key[probed], self.g_reset[probed],
-                                  self.g_accepted[probed], self.g_over[probed])
+            (int(k), int(r)): (int(t), int(z), bool(d))
+            for k, r, t, z, d in zip(
+                self.g_key[probed], self.g_reset[probed], self.g_taken[probed],
+                self.g_over_at_zero[probed], self.g_drain[probed])
         }
         last = {}
         for (k, r) in gens:
@@ -393,18 +542,31 @@ class WindowCheck:
                 continue
             else:
                 r = got[3]
-                if (k, r) in gens or c_reset == r:
-                    n_acc, over = gens.get((k, r), (0, False))
+                if (k, r) in self.split:
+                    # generations of one millisecond: the probe met the last,
+                    # and no order says which that was
+                    lows = set(self.split[(k, r)])
+                    if gens[(k, r)][2]:
+                        lows.add(0)
+                    want = f"limit echoed, remaining one of {sorted(lows)}"
+                    want_ok = (got[1] == ks.limit and got[2] in lows)
+                elif (k, r) in gens or c_reset == r:
+                    taken, at_zero, drained = gens.get((k, r), (0, 0, False))
                     start = c_rem if c_reset == r else ks.limit
-                    sticky = over or (c_reset == r and bool(c.sticky_over[k]))
+                    # the status sticks once a request met an empty bucket;
+                    # the refusal that emptied it met what it drained
+                    sticky = (at_zero - drained > 0
+                              or (c_reset == r and bool(c.sticky_over[k])))
                     want = (OVER_LIMIT if sticky else UNDER_LIMIT, ks.limit,
-                            start - n_acc, r)
+                            0 if drained else start - taken, r)
+                    want_ok = got == want
                 else:  # a bucket the probe itself made
                     prev = max(last.get(k, -1), c_reset)
-                    if prev >= 0 and r - ks.duration_ms <= prev:
+                    if (prev >= 0 and r - ks.duration_ms <= prev
+                            and self.early.get(k, 0) >= self.removed[k]):
                         self.evicted.add(k)
                     want = (UNDER_LIMIT, ks.limit, ks.limit, r)
-                want_ok = got == want
+                    want_ok = got == want
             if not want_ok:
                 bad += 1
                 example = example or f"key={k}: got {got} want {want}"

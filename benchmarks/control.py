@@ -16,6 +16,9 @@ generators and the server during a ``--control <kind>`` run. It passes
     forget         the call is answered from buckets made anew (its keys
                    reach the server under another name): live buckets are
                    forgotten outside any over-full group
+    strip_flags    the call reaches the server with RESET_REMAINING and
+                   DRAIN_OVER_LIMIT cleared from every item: a bucket that
+                   was to be removed or emptied is not
 
 The benchmark's own runs never start it. The parent's own calls
 (preload, set-up check, probes) go to the server directly, so the fault
@@ -35,7 +38,8 @@ if ROOT not in sys.path:
 from benchmarks import wire  # noqa: E402
 
 EVERY = 20
-KINDS = ("double_apply", "stale_answer", "forget")
+KINDS = ("double_apply", "stale_answer", "forget", "strip_flags")
+STRIPPED = wire.BEHAVIOR["RESET_REMAINING"] | wire.BEHAVIOR["DRAIN_OVER_LIMIT"]
 
 
 async def main_async(kind: str, port: int, target: str) -> None:
@@ -50,10 +54,13 @@ async def main_async(kind: str, port: int, target: str) -> None:
     async def relay(request: bytes, context) -> bytes:
         state["n"] += 1
         broken = state["n"] % EVERY == 0
-        if broken and kind == "forget":
+        if broken and kind in ("forget", "strip_flags"):
             msg = wire.GetReq.FromString(request)
             for r in msg.requests:
-                r.unique_key += "~forgotten"
+                if kind == "forget":
+                    r.unique_key += "~forgotten"
+                else:
+                    r.behavior &= ~STRIPPED
             request = msg.SerializeToString()
         answer = await upstream(request, timeout=30)
         if broken and kind == "double_apply":

@@ -133,11 +133,11 @@ def preload(client: Client, ks, spec: dict, t_pin: int, blobs: list) -> None:
 
 def setup_check(seq: check.Sequential, ks, plan, t_chk: int,
                 n_calls: int, max_wall_s: float, settle=None) -> int:
-    """Stage 1: calls of the cell's own mix, one at a time, pinned clock
-    advancing 10 ms a call, each answer equal to the reference's. Stops
-    early if wall time nears the shortest bucket's life (the server's
-    expiry runs on its own clock). With `settle` (a guarantee that is
-    eventual: it blocks until every copy holds what was sent) the first
+    """Stage 1: calls of the cell's own mix (its keys, hits and flags), one
+    at a time, pinned clock advancing 10 ms a call, each answer equal to the
+    reference's. Stops early if wall time nears the shortest bucket's life
+    (the server's expiry runs on its own clock). With `settle` (a guarantee
+    that is eventual: it blocks until every copy holds what was sent) the first
     `n_calls / 2` calls whose keys differ are each sent twice, the list
     once and then again, and a call that names a key sent since the copies
     last met waits for `settle` first: the second answer has to show the
@@ -154,12 +154,12 @@ def setup_check(seq: check.Sequential, ks, plan, t_chk: int,
         if made == n_calls or time.monotonic() - t_start > max_wall_s:
             break
         now = t_chk + 10 * (n + 1)
-        ids, behs = plan.keys[i], plan.behaviors[i]
+        ids, behs, hits = plan.keys[i], plan.behaviors[i], plan.hits[i]
         if settle and unsettled & set(ids.tolist()):
             settle()
             unsettled.clear()
-        reqs = [ks.request(k, 1, created_at=now, behavior=int(b))
-                for k, b in zip(ids, behs)]
+        reqs = [ks.request(k, int(h), created_at=now, behavior=int(b))
+                for k, h, b in zip(ids, hits, behs)]
         seq.call(f"setup-check call {i}", ids, reqs, now)
         unsettled.update(ids.tolist())
         made += 1
@@ -199,6 +199,7 @@ def send_probes(client: Client, ks, ids: np.ndarray) -> check.Items:
         key=ids, status=p[:, 0], limit=p[:, 1], remaining=p[:, 2], reset_time=p[:, 3],
         valid=np.asarray([g[4] == "" for g in got], dtype=bool),
         behavior=np.full(len(ids), ks.behavior),
+        hits=np.zeros(len(ids), dtype=np.int64),
     )
 
 
@@ -283,7 +284,7 @@ def gather(workers_n: int, work: str, plan) -> dict:
     # a call that returned fewer answers than it asked is a failed call
     out["ok"] = out["ok"] & (got == sizes)
     out["sizes"] = sizes
-    key, beh, valid = [], [], []
+    key, beh, hits, valid = [], [], [], []
     cols = {k: [] for k in ("status", "limit", "remaining", "reset_time")}
     n = 0
     for p in parts:
@@ -297,6 +298,7 @@ def gather(workers_n: int, work: str, plan) -> dict:
             a, b = int(offs[j]), int(offs[j + 1])
             key.append(plan.keys[i])
             beh.append(plan.behaviors[i])
+            hits.append(plan.hits[i])
             valid.append(~p["item_error"][a:b])
             for c in cols:
                 cols[c].append(p[c][a:b])
@@ -315,6 +317,7 @@ def gather(workers_n: int, work: str, plan) -> dict:
         remaining=cat(cols["remaining"], np.int64),
         reset_time=cat(cols["reset_time"], np.int64),
         valid=cat(valid, bool), behavior=cat(beh, np.int64),
+        hits=cat(hits, np.int64),
     )
     out["first_error"] = first_error
     return out
@@ -560,8 +563,9 @@ def measure(args, m, cell, conf, traf, seconds, chips, work,
         carried.reset_time[tok] = t_pin + ks.duration_ms
     for k in seq.known:
         st = seq.token_state(k, ks)
-        if st is not None:
-            carried.remaining[k], carried.reset_time[k], carried.sticky_over[k] = st
+        if st is None:  # a leaky key, or one whose bucket the check's last call removed
+            st = (0, -1, False)
+        carried.remaining[k], carried.reset_time[k], carried.sticky_over[k] = st
 
     workers_ready(load_workers)
     before = scrape(daemon.http_addr)
@@ -629,9 +633,12 @@ def measure(args, m, cell, conf, traf, seconds, chips, work,
     wc.check_window(items, verdict)
     if eventual:
         # every hit the window sent to a key, failed calls included
-        sent = np.bincount(np.concatenate(
-            [plan.keys[int(i)] for i in res["call"]] or [np.zeros(0, np.int64)]),
-            minlength=ks.n) * int(traf.get("hits", 1))
+        calls = [int(i) for i in res["call"]]
+        none = [np.zeros(0, np.int64)]
+        sent = np.bincount(
+            np.concatenate([plan.keys[i] for i in calls] or none),
+            weights=np.concatenate([plan.hits[i] for i in calls] or none),
+            minlength=ks.n).astype(np.int64)
         wc.check_window_eventual(items, sent, born, eventual.join_ms, verdict)
         wc.check_probes_eventual(probes, verdict)
         say(f"eventual: {wc.joined} generations joined to a bucket made a moment "
@@ -647,6 +654,7 @@ def measure(args, m, cell, conf, traf, seconds, chips, work,
     verdict.add("window.cold_compiles", cold, 0)
     for line in verdict.lines():
         say(line)
+    say("counted: " + " ".join(f"{k}={n}" for k, n in wc.counted.items()))
     t_checked = time.monotonic()
     stopped.result()  # a BenchFailure of the stop is the run's
     stopping.shutdown()
@@ -697,7 +705,7 @@ def measure(args, m, cell, conf, traf, seconds, chips, work,
         shutil.rmtree(os.path.join(work, "tmp"), ignore_errors=True)  # tens of MB
         say(f"trace: planes={trace['plane_names']} device_planes="
             f"{len(trace['devices'])} busy_s={trace['busy_s']} "
-            f"window_s={trace['window_s']}")
+            f"window_s={trace['window_s']} stop_trace_s={trace_out.get('stop_s')}")
 
     device = device_object(dev_after)
     wanted = manifest.metrics_of(m, cell["name"],
@@ -749,6 +757,8 @@ def measure(args, m, cell, conf, traf, seconds, chips, work,
     }
     if args.trace and trace and trace["devices"] and args.platform != "cpu":
         result["breakdown"] = trace["breakdown"]
+    # what the window held for the exact rows to work on (no limit: counts)
+    result["counted"] = wc.counted
     # every number compared beside its limit, where a record of a run that
     # came out not correct keeps them: the end of stderr and of the result line
     result["checks"] = verdict.as_dict()

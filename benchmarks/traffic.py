@@ -10,6 +10,8 @@ A traffic mix is data (``benchmarks/traffic/<name>.json``):
     arrivals        open loop: {"kind": "poisson"} or
                     {"kind": "bursts", "calls": 50, "every_ms": 100}
     items_per_call  a number, or {"2": 0.7, "100": 0.25, "1000": 0.05}
+    hits            hits an item asks for: a number (1), or a share table over
+                    the items, {"1": 0.8, "2": 0.1, "5": 0.08, "20": 0.02}
     keys            {"distribution": "uniform"}
                     {"distribution": "zipf", "s": 0.99, "scrambled": true}
                     {"distribution": "hotset", "hot_keys": 100, "hot_share": 0.9}
@@ -34,6 +36,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from functools import cached_property
 
 import numpy as np
 
@@ -55,7 +58,10 @@ def rng_for(seed: int, stream: int) -> np.random.Generator:
 @dataclass
 class Keyspace:
     """The configuration's keys: ``n`` of them, named from the seed, with
-    the algorithm, limit and duration the configuration's file states."""
+    the algorithm, limit and duration the configuration's file states.
+    ``behavior`` is every key's; ``behavior_of_keys`` in the file, a list of
+    {"one_in": 4, "behavior": ["DRAIN_OVER_LIMIT"]}, adds flags that are part
+    of some limits' definition: every request of such a key carries them."""
 
     name: str
     n: int
@@ -64,6 +70,7 @@ class Keyspace:
     algorithm: str  # "token" | "leaky" | "even_token_odd_leaky"
     behavior: int
     salt: int
+    key_flags: tuple = ()  # ((one_in, bits), ...) from `behavior_of_keys`
 
     @classmethod
     def from_config(cls, conf: dict, seed: int) -> "Keyspace":
@@ -76,7 +83,20 @@ class Keyspace:
             algorithm=ks["algorithm"],
             behavior=behavior_bits(ks.get("behavior", [])),
             salt=int(rng_for(seed, 0).integers(0, 1 << 40)),
+            key_flags=tuple((int(r["one_in"]), behavior_bits(r["behavior"]))
+                            for r in ks.get("behavior_of_keys", ())),
         )
+
+    @cached_property
+    def flags(self) -> np.ndarray:
+        """Each key's own behaviour: a rule's flags sit on the keys whose id
+        mixes (``scramble``) to 0 modulo its `one_in`, whatever their rank
+        and algorithm."""
+        out = np.full(self.n, self.behavior, dtype=np.int64)
+        ids = np.arange(self.n, dtype=np.int64)
+        for one_in, bits in self.key_flags:
+            out[scramble(ids, one_in) == 0] |= bits
+        return out
 
     def unique_key(self, key_id: int) -> str:
         return f"k{key_id:08d}-{self.salt:010x}"
@@ -99,11 +119,13 @@ class Keyspace:
 
     def request(self, key_id: int, hits: int, created_at=None,
                 behavior=None) -> Request:
+        if behavior is None:
+            behavior = int(self.flags[key_id]) if self.key_flags else self.behavior
         return Request(
             name=self.name, unique_key=self.unique_key(int(key_id)),
             hits=hits, limit=self.limit, duration=self.duration_ms,
             algorithm=self.algorithm_of(int(key_id)),
-            behavior=self.behavior if behavior is None else behavior,
+            behavior=behavior,
             created_at=created_at,
         )
 
@@ -183,11 +205,19 @@ def apportion(shares: dict, count: int) -> list:
     return [(v, c) for v, c in out]
 
 
+def shared_out(shares: dict, count: int, rng: np.random.Generator) -> np.ndarray:
+    """`count` values in the exact proportions of `shares` (value -> weight,
+    the values whole numbers as a data file's keys or as numbers), the same
+    multiset for every seed, in an order the seed's stream gives."""
+    parts = apportion({int(v): float(w) for v, w in shares.items()}, count)
+    out = np.concatenate([np.full(c, v, dtype=np.int64) for v, c in parts])
+    rng.shuffle(out)
+    return out
+
+
 def call_sizes(spec, count: int, rng: np.random.Generator) -> np.ndarray:
     if isinstance(spec, dict):
-        parts = apportion({int(k): float(w) for k, w in spec.items()}, count)
-        sizes = np.concatenate([np.full(c, v, dtype=np.int64) for v, c in parts])
-        rng.shuffle(sizes)
+        sizes = shared_out(spec, count, rng)
     else:
         sizes = np.full(count, int(spec), dtype=np.int64)
     if sizes.min() < 1 or sizes.max() > wire.MAX_ITEMS_PER_CALL:
@@ -224,11 +254,13 @@ def due_times(spec: dict, rate: float, seconds: float,
 class Plan:
     """Calls made before the window opens. ``keys[i]`` holds call i's key
     ids, ``blobs[i]`` its encoded request. Closed loop: ``caller_of[i]`` is
-    the caller whose pool holds call i. Open loop: ``due[i]`` seconds."""
+    the caller whose pool holds call i. Open loop: ``due[i]`` seconds.
+    ``behaviors[i]`` and ``hits[i]`` are its items' flags and hits."""
 
     loop: str
     keys: list
     behaviors: list
+    hits: list
     blobs: list
     caller_of: np.ndarray
     due: np.ndarray
@@ -256,25 +288,31 @@ def build_plan(traffic: dict, keyspace: Keyspace, seed: int,
     keys = np.split(flat, np.cumsum(sizes)[:-1])
     shares = traffic.get("behavior_shares")
     if shares:
-        parts = apportion(
+        beh_flat = shared_out(
             {behavior_bits(s["behavior"]) | keyspace.behavior: s["share"]
-             for s in shares}, len(flat))
-        beh_flat = np.concatenate(
-            [np.full(c, v, dtype=np.int64) for v, c in parts])
-        rng_for(seed, 4).shuffle(beh_flat)
+             for s in shares}, len(flat), rng_for(seed, 4))
     else:
         beh_flat = np.full(len(flat), keyspace.behavior, dtype=np.int64)
+    if keyspace.key_flags:
+        beh_flat = beh_flat | keyspace.flags[flat]
     behaviors = np.split(beh_flat, np.cumsum(sizes)[:-1])
-    hits = int(traffic.get("hits", 1))
+    spec = traffic.get("hits", 1)
+    if isinstance(spec, dict):
+        hits_flat = shared_out(spec, len(flat), rng_for(seed, 5))
+        if hits_flat.min() < 1:
+            raise ValueError("hits under 1: a window's item takes something")
+    else:
+        hits_flat = np.full(len(flat), int(spec), dtype=np.int64)
+    hits = np.split(hits_flat, np.cumsum(sizes)[:-1])
     blobs = [
         wire.encode_call([
-            keyspace.request(k, hits, behavior=int(b))
-            for k, b in zip(ks, bs)
+            keyspace.request(k, int(h), behavior=int(b))
+            for k, h, b in zip(ks, hs, bs)
         ])
-        for ks, bs in zip(keys, behaviors)
+        for ks, hs, bs in zip(keys, hits, behaviors)
     ]
     caller_of = (
         np.arange(n_calls) % callers if loop == "closed"
         else np.zeros(n_calls, dtype=np.int64)
     )
-    return Plan(loop, keys, behaviors, blobs, caller_of, due, callers)
+    return Plan(loop, keys, behaviors, hits, blobs, caller_of, due, callers)

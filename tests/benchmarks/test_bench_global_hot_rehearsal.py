@@ -56,7 +56,7 @@ def test_rehearsal_global_hot_4_herd_zipf_uses_a_key_up_and_counts_what_went_ove
     sound(r.returncode, result, log)
     assert result["device"]["count"] == 4
     # the exact rows stay (no plain item: all 0), the probes' row gives way
-    assert rows_printed(r.stdout) == (EXACT_ROWS[:7] + GLOBAL_ROWS + EXACT_ROWS[-2:]), log
+    assert rows_printed(r.stdout) == (EXACT_ROWS[:9] + GLOBAL_ROWS + EXACT_ROWS[-2:]), log
     assert "the keys live in tier 'replica'" in log
     assert "check_calls=8 check_items=16" in log
     assert "(after a preload, the rest inside check_s)" in log

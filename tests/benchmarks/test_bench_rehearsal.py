@@ -64,11 +64,15 @@ def test_rehearsal_batching_10k_herd(herd_run):
 
 
 # what a run of an exact configuration printed before the harness learned a
-# guarantee that is eventual (PR 25's tree), in this order
+# guarantee that is eventual (PR 25's tree), in this order; the two rows of
+# DRAIN_OVER_LIMIT and RESET_REMAINING (PR 47) follow the window's other rows
+# and read 0 where no item carries a flag
+FLAG_ROWS = ["window.drain_left_remaining", "window.reset_not_fresh"]
 EXACT_ROWS = [
     "setup.mismatches", "setup.calls_short", "window.limit_not_echoed",
     "window.out_of_range", "window.over_limit_with_remaining",
     "window.token_generations_not_exact", "window.over_limit_before_used_up",
+    *FLAG_ROWS,
     "probe.failed", "probe.mismatches", "evicted_keys", "window.cold_compiles"]
 
 
@@ -100,7 +104,7 @@ def test_rehearsal_global_4_herd_on_four_forced_devices_at_a_tiny_size():
     assert result["device"]["count"] == 4
     assert set(result["metrics"]) == {"decisions_per_s", "setup_s"}
     # the exact rows stay (no plain item: all 0), the probes' row gives way
-    assert rows_printed(log) == (EXACT_ROWS[:7] + GLOBAL_ROWS + EXACT_ROWS[-2:]), log
+    assert rows_printed(log) == (EXACT_ROWS[:9] + GLOBAL_ROWS + EXACT_ROWS[-2:]), log
     assert list(result["checks"]) == rows_printed(log)
     assert "the keys live in tier 'replica'" in log
     assert "table: groups=4096 ways=4 " in log
@@ -214,19 +218,31 @@ def test_a_freeze_longer_than_issue_23s_deadline_fails_no_call():
     assert "failed calls by gRPC status: {}" in text
 
 
-@pytest.fixture(scope="module")
-def tree(tmp_path_factory):
-    """A checkout with additions only: new files under the benchmark's
-    directories and new entries in BENCHMARK.json; no file that was there
-    is edited."""
+def checkout(tmp_path_factory):
+    """A copy of the benchmark's files beside the program, and the manifest
+    as it stands, for a test to add files and entries to."""
     root = tmp_path_factory.mktemp("checkout")
     shutil.copytree(os.path.join(ROOT, "benchmarks"), root / "benchmarks",
                     ignore=shutil.ignore_patterns("__pycache__"))
     for name in ("gubernator_tpu", "native"):
         os.symlink(os.path.join(ROOT, name), root / name)
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
-        m = json.load(f)
-    before = json.dumps(m, sort_keys=True)
+        return root, json.load(f)
+
+
+def only_added_to(old: dict, m: dict) -> bool:
+    """Nothing that was there changed: the old manifest is a subset of the new."""
+    return (all(w in m["workloads"] for w in old["workloads"])
+            and all(c in m["configs"] for c in old["configs"]))
+
+
+@pytest.fixture(scope="module")
+def tree(tmp_path_factory):
+    """A checkout with additions only: new files under the benchmark's
+    directories and new entries in BENCHMARK.json; no file that was there
+    is edited."""
+    root, m = checkout(tmp_path_factory)
+    old = json.loads(json.dumps(m))
 
     conf = json.load(open(root / "benchmarks/configs/batching-10k.json"))
     conf["name"] = "throwaway"
@@ -263,10 +279,7 @@ def tree(tmp_path_factory):
                                "moves": "call_p50_ms",
                                "workloads": ["throwaway.trickle"]})
     json.dump(m, open(root / "BENCHMARK.json", "w"))
-    # nothing that was there changed: the old manifest is a subset of the new
-    old = json.loads(before)
-    assert all(w in m["workloads"] for w in old["workloads"])
-    assert all(c in m["configs"] for c in old["configs"])
+    assert only_added_to(old, m)
     return str(root)
 
 
@@ -281,3 +294,87 @@ def test_a_configuration_traffic_cell_and_metric_are_files_plus_one_entry(tree):
     assert {"throwaway_ratio", "throwaway_reader"} <= set(result["metrics"])
     # bursts of 4 calls every 100 ms over 3 s, sizes 1 and 3 in equal shares
     assert "calls=120" in log and result["attempted"] == 240
+
+
+# ---- hits above one, DRAIN_OVER_LIMIT and RESET_REMAINING through the whole harness ----
+# BASELINE's fifth configuration (mixed token+leaky with both flags, Zipfian)
+# is no cell yet: no public source bears its shares (PERF.md section 7). What
+# the harness learned for it is held here by a test's own files, in the shape
+# a later PR brings it in: DRAIN_OVER_LIMIT a part of some limits' definition
+# (`behavior_of_keys`), RESET_REMAINING an event, hits a share table.
+
+MIXED = "mixed.calls100-mixed"
+
+
+@pytest.fixture(scope="module")
+def mixed_tree(tmp_path_factory):
+    root, m = checkout(tmp_path_factory)
+    old = json.loads(json.dumps(m))
+    conf = json.load(open(root / "benchmarks/configs/zipf-1m.json"))
+    conf["name"] = "mixed"
+    conf["keyspace"].update(
+        algorithm="even_token_odd_leaky",
+        behavior_of_keys=[{"one_in": 3, "behavior": ["DRAIN_OVER_LIMIT"]}])
+    json.dump(conf, open(root / "benchmarks/configs/mixed.json", "w"))
+    traf = json.load(open(root / "benchmarks/traffic/calls100.json"))
+    traf["hits"] = {"1": 0.80, "2": 0.10, "5": 0.08, "20": 0.02}
+    traf["behavior_shares"] = [{"share": 0.98, "behavior": []},
+                               {"share": 0.02, "behavior": ["RESET_REMAINING"]}]
+    json.dump(traf, open(root / "benchmarks/traffic/calls100-mixed.json", "w"))
+    m["configs"].append({"name": "mixed", "source": "none: a test's own",
+                         "file": "benchmarks/configs/mixed.json", "reduced": [],
+                         "why": "token and leaky keys, a third of them DRAIN_OVER_LIMIT"})
+    m["workloads"].append({"name": MIXED, "config": "mixed", "traffic": "calls100-mixed",
+                           "chips": 1, "why": "hits of 1 to 20 and RESET_REMAINING events"})
+    for e in m["end_to_end"] + m["per_layer"]:
+        if e["name"] in ("decisions_per_s", "waves_per_flush", "columnar_call_share"):
+            e["workloads"].append(MIXED)
+    json.dump(m, open(root / "BENCHMARK.json", "w"))
+    assert only_added_to(old, m)
+    return str(root)
+
+
+@pytest.mark.deadline(120)
+def test_rehearsal_of_a_mixed_cell_gives_the_flag_rows_work(mixed_tree):
+    """Token and leaky keys, hits of 1 to 20, DRAIN_OVER_LIMIT by key and
+    RESET_REMAINING by item, added as files and entries only. The counts are
+    the check's own (the program has no counter of RESET or DRAIN lanes) and
+    are the same on a CPU."""
+    rc, result, log = run_cell(mixed_tree, MIXED, "--trace", "0", "--platform", "cpu",
+                               "--keys", "20000", seconds=4)
+    sound(rc, result, log)
+    assert set(result["metrics"]) == {"decisions_per_s", "setup_s"}
+    assert rows_printed(log) == EXACT_ROWS and list(result)[-2:] == ["counted", "checks"]
+    assert all(v == 0 for n, (v, _) in result["checks"].items() if n != "evicted_keys"), log
+    assert "table: groups=4096 ways=8 slots=32768 " in log
+    assert "check_calls=8 check_items=800" in log  # its hits and flags, one by one
+    c = result["counted"]
+    assert c["items"] == result["attempted"] and f"counted: items={c['items']} " in log
+    # hits and RESET_REMAINING in the traffic file's shares, exactly over the
+    # plan and nearly over a window; DRAIN_OVER_LIMIT on the keys the rule names
+    assert 0.15 < c["items_hits_over_1"] / c["items"] < 0.25
+    assert 0.01 < c["items_reset"] / c["items"] < 0.03
+    assert 0.10 < c["items_drain"] / c["items"] < 0.60
+    assert c["reset_removed_bucket"] > 50 and c["generations_after_reset"] > 50, log
+    assert c["refused_with_remainder"] > 20 and c["generations_drained"] > 0, log
+
+
+@pytest.mark.deadline(120)
+@pytest.mark.parametrize("kind,rows", [
+    ("double_apply", {"window.token_generations_not_exact"}),
+    ("stale_answer", {"window.token_generations_not_exact"}),
+    ("forget", {"evicted_keys"}),
+    ("strip_flags", {"window.reset_not_fresh", "window.drain_left_remaining"}),
+])
+def test_a_mixed_cell_broken_underneath_comes_out_not_correct(mixed_tree, kind, rows):
+    """Each control under the mixed cell's timed path; `strip_flags` (the relay
+    clears RESET_REMAINING and DRAIN_OVER_LIMIT on every 20th call) is seen by
+    the rows that hold those flags. 8,000 keys on 4,096 groups of 8: next to
+    no eviction of the table's own, so the buckets `forget` makes anew stand
+    out, and a RESET_REMAINING's removal excuses none of them."""
+    rc, result, log = run_cell(mixed_tree, MIXED, "--trace", "0", "--platform", "cpu",
+                               "--keys", "8000", "--control", kind, seconds=4)
+    assert rc == 0 and result is not None, log
+    assert result["correct"] is False, log
+    failed = {n for n, (v, lim) in result["checks"].items() if v > lim}
+    assert failed & rows, log

@@ -40,8 +40,10 @@ def test_rehearsal_store_4_calls100_hands_every_change_to_the_store():
     """20,000 keys through the 32,768 sharded slots of four forced devices:
     the twin without a Store excuses ~2,000 evicted keys; here each is read
     back into its owner's shard, and the write-behind skips no row."""
+    # six seconds: the two scrapes lie 1.5 s apart (0.5 s at four), so a flush
+    # ends between them on four forced devices and a loaded host too
     rc, result, log = run_cell(ROOT, STORE4, "--trace", "1", "--platform", "cpu",
-                               "--keys", "20000", seconds=4, timeout=190)
+                               "--keys", "20000", seconds=6, timeout=190)
     sound(rc, result, log)
     assert result["device"]["count"] == 4
     assert rows_printed(log) == EXACT_ROWS and "quiesce" not in log
@@ -55,12 +57,13 @@ def test_rehearsal_store_4_calls100_hands_every_change_to_the_store():
     printed = {ln.split()[1].rstrip(":"): ln.split()[2] for ln in log.splitlines()
                if ln.startswith("per_layer ")}
     assert set(NEW) <= set(printed), log
-    # counts are the same on a CPU: nothing skipped, 3 programs a wave and now
-    # and then a fourth, every call columnar, a launch a wave
+    # counts are the same on a CPU: nothing skipped, every call columnar; since
+    # PR 45 a flush of resident keys is one stacked probe, decide and gather:
+    # fewer launches than waves, under the per-wave sequence's three programs
     assert float(printed["sharded_store_skipped_rows_per_flush"]) == 0.0, log
-    assert 3.0 <= float(printed["sharded_store_programs_per_wave"]) <= 4.0, log
+    assert 0.0 < float(printed["sharded_store_programs_per_wave"]) < 3.0, log
     assert float(printed["columnar_call_share"]) == 100.0
-    assert float(printed["launches_per_flush"]) == float(printed["waves_per_flush"])
+    assert 1.0 <= float(printed["launches_per_flush"]) < float(printed["waves_per_flush"])
     assert float(printed["shard_imbalance"]) >= 1.0
     for name in NEW[1:4]:
         assert float(printed[name]) > 0.0, name
@@ -92,16 +95,21 @@ def test_rehearsal_batching_10k_burst_fifty_calls_at_one_instant():
     assert result["attempted"] == 400
 
 
-def test_manifest_holds_ten_cells_three_of_them_on_four_chips():
+def test_manifest_holds_the_two_cells_by_rules_that_the_next_cell_keeps():
+    """Rules, not counts and last names: every configuration has a cell, at
+    most half the cells (rounded down) ask for four chips, and the two cells
+    of PR 41 report what they came for; a cell appended later breaks none."""
     m = manifest.load(ROOT)
     manifest.check(m, ROOT)
-    assert len(m["configs"]) == 6 and len(m["workloads"]) == 10
-    assert [w["name"] for w in m["workloads"] if w["chips"] == 4] == [
-        "global-4.herd", "sharded-4.calls100", STORE4]
-    assert [w["name"] for w in m["workloads"]][-2:] == [STORE4, BURST]
+    cells = {w["name"]: w for w in m["workloads"]}
+    assert {w["config"] for w in m["workloads"]} == {c["name"] for c in m["configs"]}
+    four = [n for n, w in cells.items() if w["chips"] == 4]
+    assert STORE4 in four and len(four) <= len(cells) // 2
+    assert cells[BURST]["chips"] == 1
     e2e = {e["name"]: e for e in m["end_to_end"]}
-    assert e2e["decisions_per_s"]["workloads"][-1] == STORE4
-    assert e2e["call_p50_ms"]["workloads"][-1] == BURST
+    assert STORE4 in e2e["decisions_per_s"]["workloads"]
+    assert BURST in e2e["call_p50_ms"]["workloads"]
+    assert all(0 < e["bound"] <= 0.25 for e in m["end_to_end"])
     for name in NEW:
         entry = next(x for x in m["per_layer"] if x["name"] == name)
         assert entry["workloads"] == [STORE4] and entry["moves"] == "decisions_per_s"

@@ -44,13 +44,15 @@ def test_rehearsal_store_1m_calls100_reads_evicted_keys_back():
     assert result["checks"]["evicted_keys"][1] > 1000  # the table's allowance stays
     listed = {x["name"] for x in manifest.metrics_of(manifest.load(ROOT), STORE, "per_layer")}
     assert set(result["metrics"]) <= listed
-    # counts are the same on a CPU: 3 programs a wave and now and then a
-    # fourth, every call columnar, a launch a wave
+    # counts are the same on a CPU: every call columnar; since PR 45 a flush
+    # whose keys are all resident runs its waves as one stacked probe, decide
+    # and row gather, so a flush is fewer launches than waves and a wave fewer
+    # than the per-wave sequence's three programs
     printed = {ln.split()[1].rstrip(":"): ln.split()[2] for ln in log.splitlines()
                if ln.startswith("per_layer ")}
-    assert 3.0 <= float(printed["store_programs_per_wave"]) <= 4.0, log
+    assert 0.0 < float(printed["store_programs_per_wave"]) < 3.0, log
     assert float(printed["columnar_call_share"]) == 100.0
-    assert float(printed["launches_per_flush"]) == float(printed["waves_per_flush"])
+    assert 1.0 <= float(printed["launches_per_flush"]) < float(printed["waves_per_flush"])
     assert float(printed["store_get_share"]) >= 0.0  # a number: the short span may hold no miss
     for name in ("store_readthrough_us_per_wave", "store_rows_us_per_wave",
                  "store_write_behind_us_per_flush"):

@@ -141,6 +141,108 @@ def test_plan_is_a_function_of_the_seed_and_takes_large_seeds():
     assert np.sum(flat == 8) == 4 and np.sum(flat == 0) == 36
 
 
+def test_hits_a_calls_items_differ_in_are_shared_out_exactly_whatever_the_seed():
+    conf = {"keyspace": {"keys": 500, "limit": 100, "duration_ms": 5000,
+                         "algorithm": "even_token_odd_leaky"}}
+    traf = {"loop": "closed", "callers": 4, "pool_calls": 25, "items_per_call": 10,
+            "hits": {"1": 0.80, "2": 0.10, "5": 0.08, "20": 0.02},
+            "keys": {"distribution": "uniform"}}
+    seen = []
+    for seed in (3, 2**31 + 77):
+        ks = traffic.Keyspace.from_config(conf, seed)
+        p = traffic.build_plan(traf, ks, seed, 5.0)
+        flat = np.concatenate(p.hits)
+        vals, counts = np.unique(flat, return_counts=True)
+        assert dict(zip(vals.tolist(), counts.tolist())) == {1: 800, 2: 100, 5: 80, 20: 20}
+        assert [len(h) for h in p.hits] == [len(k) for k in p.keys]
+        # the encoded call carries them, item for item
+        sent = wire.GetReq.FromString(p.blobs[7]).requests
+        assert [r.hits for r in sent] == p.hits[7].tolist()
+        seen.append(flat)
+    assert not np.array_equal(seen[0], seen[1])  # the same multiset, another order
+    # a plain number is every item's, and a stream of its own moves nothing else
+    one = traffic.build_plan(dict(traf, hits=3), ks, seed, 5.0)
+    assert set(np.concatenate(one.hits).tolist()) == {3}
+    assert [k.tolist() for k in one.keys] == [k.tolist() for k in p.keys]
+    with pytest.raises(ValueError):
+        traffic.build_plan(dict(traf, hits={"0": 0.5, "1": 0.5}), ks, seed, 5.0)
+
+
+def test_a_flag_that_is_part_of_a_limits_definition_rides_on_every_request_of_its_key():
+    conf = {"keyspace": {"keys": 4000, "limit": 100, "duration_ms": 5000,
+                         "algorithm": "even_token_odd_leaky", "behavior": [],
+                         "behavior_of_keys": [
+                             {"one_in": 4, "behavior": ["DRAIN_OVER_LIMIT"]}]}}
+    traf = {"loop": "closed", "callers": 4, "pool_calls": 25, "items_per_call": 10,
+            "keys": {"distribution": "zipf", "s": 0.99, "scrambled": True},
+            "behavior_shares": [{"share": 0.9, "behavior": []},
+                                {"share": 0.1, "behavior": ["RESET_REMAINING"]}]}
+    drain, reset = wire.BEHAVIOR["DRAIN_OVER_LIMIT"], wire.BEHAVIOR["RESET_REMAINING"]
+    a, b = (traffic.Keyspace.from_config(conf, seed) for seed in (3, 2**31 + 77))
+    assert np.array_equal(a.flags, b.flags)  # the rule is of the key, not of the seed
+    flagged = a.flags == drain
+    assert set(a.flags.tolist()) == {0, drain} and 900 < flagged.sum() < 1100
+    ids = np.arange(a.n)
+    assert 400 < (flagged & a.is_token(ids)).sum() < 600  # token and leaky alike
+    k = int(np.nonzero(flagged)[0][0])
+    assert a.request(k, 1).behavior == drain and a.request(k, 0).behavior == drain
+    assert a.request(k, 1, behavior=0).behavior == 0  # what a caller states stands
+    # an item's flags are its key's and its own event's
+    p = traffic.build_plan(traf, a, 3, 5.0)
+    keys, behs = np.concatenate(p.keys), np.concatenate(p.behaviors)
+    assert np.array_equal(behs & drain, a.flags[keys])
+    assert np.sum((behs & reset) != 0) == 100
+    sent = wire.GetReq.FromString(p.blobs[7]).requests
+    assert [r.behavior for r in sent] == p.behaviors[7].tolist()
+    # a file without the rule: every key carries the keyspace's behaviour and no more
+    del conf["keyspace"]["behavior_of_keys"]
+    plain = traffic.Keyspace.from_config(conf, 3)
+    assert plain.key_flags == () and plain.request(k, 1).behavior == 0
+    assert not np.any(np.concatenate(traffic.build_plan(traf, plain, 3, 5.0).behaviors) & drain)
+
+
+# sha-256 (first 16 hex digits) over the encoded calls of each cell's plan as
+# the generator made them before it learnt per-item hits (the parent of PR 47),
+# seed 2147483999, the keyspace cut to 20,000 keys, the file's `rehearsal`
+# overrides, 3 s: a file whose `hits` is a plain number makes the same plan
+PLANS_BEFORE = {
+    "batching-10k.herd": (20000, "750cce508370daed"),
+    "zipf-1m.saturate": (12, "2893a6a227dff4b1"),
+    "batching-10k.steady": (192, "2f491c28e785fbf2"),
+    "global-4.herd": (20000, "3e421de95cc43e5d"),
+    "zipf-1m.calls100": (32, "b67f49bdb8d5ed9c"),
+    "sharded-4.calls100": (32, "b67f49bdb8d5ed9c"),
+    "store-1m.calls100": (32, "b67f49bdb8d5ed9c"),
+    "zipf-1m.steady": (158, "bc56a58377687676"),
+    "store-4.calls100": (32, "b67f49bdb8d5ed9c"),
+    "batching-10k.burst": (150, "e061e07270d34089"),
+    "global-hot-4.herd-zipf": (1600, "e1cc288e944262ae"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PLANS_BEFORE))
+def test_the_plan_of_every_cell_that_was_there_is_byte_for_byte_what_it_was(cell):
+    import hashlib
+
+    m = manifest.load(ROOT)
+    w = next(x for x in m["workloads"] if x["name"] == cell)
+    entry = next(c for c in m["configs"] if c["name"] == w["config"])
+    with open(os.path.join(ROOT, entry["file"]), encoding="utf-8") as f:
+        conf = json.load(f)
+    with open(manifest.traffic_path(ROOT, manifest.bench_dir(m), w["traffic"]),
+              encoding="utf-8") as f:
+        traf = json.load(f)
+    conf["keyspace"]["keys"] = min(conf["keyspace"]["keys"], 20000)
+    traf.update(traf.get("rehearsal", {}))
+    seed = 2147483999
+    p = traffic.build_plan(traf, traffic.Keyspace.from_config(conf, seed), seed, 3.0)
+    digest = hashlib.sha256()
+    for blob in p.blobs:
+        digest.update(blob)
+    assert (len(p.blobs), digest.hexdigest()[:16]) == PLANS_BEFORE[cell]
+    assert set(np.concatenate(p.hits).tolist()) == {int(traf.get("hits", 1))}
+
+
 def test_open_plan_holds_rate_times_seconds_calls():
     conf = {"keyspace": {"keys": 500, "limit": 10, "duration_ms": 5000, "algorithm": "token"}}
     traf = {"loop": "open", "rate_calls_per_s": 120.0, "items_per_call": 2}
@@ -202,19 +304,22 @@ def test_reference_refuses_what_it_does_not_model():
 # ---- the comparison ------------------------------------------------------------------
 
 
-def items_of(rows, behavior=0):
-    """rows: (key, status, remaining, reset_time)"""
-    a = np.asarray(rows, dtype=np.int64).reshape(-1, 4)
-    return check.Items(key=a[:, 0], status=a[:, 1], limit=np.full(len(a), KS.limit),
+def items_of(rows, behavior=0, ks=KS):
+    """rows: (key, status, remaining, reset_time), then optionally the hits
+    the item asked for (1) and the flags it carried (`behavior`)"""
+    rows = [tuple(r) + (1, behavior)[len(r) - 4:] for r in rows]
+    a = np.asarray(rows, dtype=np.int64).reshape(-1, 6)
+    return check.Items(key=a[:, 0], status=a[:, 1], limit=np.full(len(a), ks.limit),
                        remaining=a[:, 2], reset_time=a[:, 3],
-                       valid=np.ones(len(a), bool), behavior=np.full(len(a), behavior))
+                       valid=np.ones(len(a), bool), behavior=a[:, 5], hits=a[:, 4])
 
 
-def window(rows, carried=None):
-    wc = check.WindowCheck(KS, carried or check.Carried.empty(KS.n),
-                           np.zeros(KS.n, bool))
+def window(rows, carried=None, uncertain=(), ks=KS):
+    unc = np.zeros(ks.n, bool)
+    unc[list(uncertain)] = True
+    wc = check.WindowCheck(ks, carried or check.Carried.empty(ks.n), unc)
     v = check.Verdict()
-    wc.check_window(items_of(rows), v)
+    wc.check_window(items_of(rows, ks=ks), v)
     return wc, v
 
 
@@ -328,6 +433,309 @@ def test_sequential_compares_and_adopts_an_eviction():
     served.cache[r1.hash_key()].value.remaining = 4  # a wrong count is no eviction
     seq.call("d", [3], [KS.request(3, 1, created_at=now)], now)
     assert seq.mismatches == 1
+
+
+# ---- hits above one, DRAIN_OVER_LIMIT, RESET_REMAINING (PR 47) -----------------------
+# rows: (key, status, remaining, reset_time, hits, flags)
+
+DRAIN, RESET = wire.BEHAVIOR["DRAIN_OVER_LIMIT"], wire.BEHAVIOR["RESET_REMAINING"]
+MIXED = traffic.Keyspace(name="t", n=1000, limit=10, duration_ms=5000,
+                         algorithm="even_token_odd_leaky", behavior=0, salt=7)
+
+
+def carried_at(key, remaining, reset_time=R, ks=KS):
+    c = check.Carried.empty(ks.n)
+    c.remaining[key], c.reset_time[key] = remaining, reset_time
+    return c
+
+
+def test_accepted_items_of_mixed_hits_tile_the_generation_in_any_order():
+    rows = [(5, 0, 0, R, 2), (5, 0, 8, R, 2), (5, 0, 2, R, 1), (5, 0, 3, R, 5)]
+    wc, v = window(rows)
+    assert v.correct, v.lines()
+    assert wc.counted["items_hits_over_1"] == 3 and wc.counted["generations"] == 1
+    assert window(rows[::-1])[1].correct
+
+
+@pytest.mark.parametrize("rows", [
+    [(5, 0, 8, R, 2), (5, 0, 2, R, 3), (5, 0, 1, R, 1)],  # the hit of 3 applied twice
+    [(5, 0, 8, R, 2), (5, 0, 8, R, 2)],                   # a hit of 2 not counted
+    [(5, 0, 7, R, 2)],                                    # began under the start
+    [(5, 0, 7, R, 3), (5, 1, 7, R, 8), (5, 0, 2, R, 1)],  # the refusal consumed
+], ids=["applied-twice", "not-counted", "began-low", "refusal-consumed"])
+def test_a_gap_or_an_overlap_in_the_tiling_is_not_exact(rows):
+    _, v = window(rows)
+    assert row(v, "window.token_generations_not_exact") == 1 and not v.correct
+
+
+def test_a_refusal_shows_a_remainder_under_its_hits_that_the_generation_passed():
+    # 7 left: 20 and 8 are refused whole and take nothing; 20 of a full new bucket too
+    rows = [(5, 0, 7, R, 3), (5, 1, 7, R, 20), (5, 1, 7, R, 8), (5, 0, 3, R, 4),
+            (6, 1, 10, R + 1, 20)]
+    wc, v = window(rows)
+    assert v.correct, v.lines()
+    assert wc.counted["refused"] == 3 and wc.counted["refused_with_remainder"] == 3
+
+
+@pytest.mark.parametrize("rows,name", [
+    ([(5, 0, 7, R, 3), (5, 1, 7, R, 5)], "window.over_limit_with_remaining"),  # 7 >= 5
+    ([(5, 0, 7, R, 3), (5, 1, 4, R, 5)], "window.over_limit_before_used_up"),  # never at 4
+    ([(5, 1, 0, R, 5)], "window.over_limit_before_used_up"),                   # never at 0
+    # 0 shown after nine accepted hits of one: a hit counted twice emptied it
+    ([(5, 0, r, R) for r in (9, 7, 6, 5, 4, 3, 2, 1, 0)] + [(5, 1, 0, R)],
+     "window.over_limit_before_used_up"),
+], ids=["enough-was-left", "a-value-never-passed", "zero-never-passed",
+        "emptied-by-a-hit-counted-twice"])
+def test_a_refusal_with_enough_left_or_a_value_never_passed_is_caught(rows, name):
+    _, v = window(rows)
+    assert row(v, name) == 1 and not v.correct, v.lines()
+
+
+def test_a_refusal_that_drains_leaves_nothing_and_the_probe_sees_nothing():
+    rows = [(5, 0, 3, R, 7), (5, 1, 0, R, 5, DRAIN), (5, 1, 0, R, 1), (5, 1, 0, R, 2, DRAIN),
+            (6, 0, 3, R, 7), (6, 1, 0, R, 5, DRAIN),
+            (8, 0, 0, R, 10), (8, 1, 0, R, 5, DRAIN)]  # used up, then a drain that met 0
+    wc, v = window(rows)
+    assert v.correct, v.lines()
+    assert wc.counted["generations_drained"] == 2 and wc.counted["items_drain"] == 4
+    # the status sticks where a request met the empty bucket: not for the
+    # refusal that emptied it
+    wc.check_probes(items_of([(5, 1, 0, R), (6, 0, 0, R), (8, 1, 0, R)]), v)
+    assert v.correct, v.lines()
+    v2 = check.Verdict()
+    wc.check_probes(items_of([(5, 1, 3, R), (6, 1, 0, R)]), v2)  # not emptied; stuck early
+    assert row(v2, "probe.mismatches") == 2
+
+
+@pytest.mark.parametrize("rows", [
+    [(5, 0, 3, R, 7), (5, 1, 3, R, 5, DRAIN)],  # refused and left 3: the flag was dropped
+    [(5, 0, 6, R, 4), (5, 1, 0, R, 5, DRAIN)],  # refused 5 though the bucket never fell under 6
+], ids=["left-remaining", "never-fell-that-low"])
+def test_a_drain_that_left_something_is_caught(rows):
+    _, v = window(rows)
+    assert row(v, "window.drain_left_remaining") == 1 and not v.correct, v.lines()
+
+
+def test_a_reset_removes_the_bucket_or_is_the_first_of_a_generation():
+    later = R + 100
+    rows = [(5, 0, 5, R, 1), (5, 0, 10, 0, 3, RESET), (5, 0, 8, later, 2),
+            (6, 0, 7, later, 3, RESET)]  # key 6 had no bucket
+    wc, v = window(rows, carried_at(5, 6))
+    assert v.correct, v.lines()
+    assert wc.removed[5] == 1 and wc.counted["reset_removed_bucket"] == 1
+    assert wc.counted["generations_after_reset"] == 1
+    assert wc.evicted == set()  # the removal paid for the generation made early
+
+
+@pytest.mark.parametrize("answer,name", [
+    ((5, 0, 4, R, 2, RESET), "window.reset_not_fresh"),   # applied as a plain hit
+    ((5, 1, 0, R, 2, RESET), "window.reset_not_fresh"),   # refused
+    ((5, 0, 10, R, 2, RESET), "window.reset_not_fresh"),  # full, but the bucket stayed
+    ((5, 0, 8, R, 2, RESET), "window.token_generations_not_exact"),  # "first" of a live one
+], ids=["plain-hit", "refused", "bucket-stayed", "first-of-a-live-generation"])
+def test_a_reset_answered_otherwise_is_caught(answer, name):
+    _, v = window([answer], carried_at(5, 6))
+    assert row(v, name) == 1 and not v.correct, v.lines()
+
+
+def test_a_leaky_key_is_held_to_its_range_its_refusals_and_its_resets():
+    rows = [(7, 0, 8, R, 2, RESET), (7, 0, 3, R + 9, 5), (7, 1, 1, R + 9, 2),
+            (7, 1, 0, R + 9, 5, DRAIN)]
+    assert window(rows, ks=MIXED)[1].correct
+    _, v = window([(7, 0, 5, R, 2, RESET)], ks=MIXED)  # not refilled to burst less 2
+    assert row(v, "window.reset_not_fresh") == 1
+    _, v = window([(7, 1, 3, R, 2)], ks=MIXED)  # refused 2 with 3 left
+    assert row(v, "window.over_limit_with_remaining") == 1
+    _, v = window([(7, 1, 1, R, 2, DRAIN)], ks=MIXED)  # drained, and 1 is left
+    assert row(v, "window.drain_left_remaining") == 1
+
+
+@pytest.mark.parametrize("rows,evicted", [
+    ([(5, 0, 9, R), (5, 0, 10, 0, 1, RESET), (5, 0, 9, R + 100)], set()),
+    ([(5, 0, 9, R), (5, 0, 9, R + 100)], {5}),
+    ([(5, 0, 9, R), (5, 0, 10, 0, 1, RESET), (5, 0, 9, R + 100), (5, 0, 9, R + 200)], {5}),
+    ([(5, 0, 9, R), (5, 0, 10, 0, 1, RESET), (5, 0, 9, R + 100),
+      (5, 0, 10, 0, 2, RESET), (5, 0, 8, R + 200, 2)], set()),
+], ids=["one-removal-one-generation", "no-removal", "one-removal-two-generations",
+        "two-and-two"])
+def test_a_removal_pays_for_one_generation_made_while_the_old_one_lived(rows, evicted):
+    wc, v = window(rows)
+    assert v.correct and wc.evicted == evicted, v.lines()
+
+
+def test_two_generations_of_one_millisecond_split_into_two_tilings():
+    at = R + 100
+    rows = [(5, 0, 9, at, 1), (5, 0, 10, 0, 1, RESET), (5, 0, 7, at, 3), (5, 0, 6, at, 1)]
+    wc, v = window(rows)
+    assert v.correct and wc.evicted == set(), v.lines()
+    assert wc.split == {(5, at): [6, 9]}
+    assert wc.counted["generations_under_one_reset_time"] == 1
+    assert wc.counted["generations"] == 2
+    # no order says which of the two the probe met
+    for left in (6, 9):
+        v = check.Verdict()
+        wc.check_probes(items_of([(5, 0, left, at)]), v)
+        assert v.correct, v.lines()
+    wc.check_probes(items_of([(5, 0, 8, at)]), v)
+    assert not v.correct
+    # without the removal's answer the same items are a hit that was not counted
+    _, v = window([r for r in rows if r[3]])
+    assert row(v, "window.token_generations_not_exact") == 1
+    # three stretches that end at the top and one removal: one bucket too many
+    wc, v = window(rows + [(5, 0, 8, at, 2)])
+    assert v.correct and wc.evicted == {5}
+
+
+def test_chains_split_from_the_top_down():
+    assert check._chains([9, 7, 6], [1, 3, 1], 10) == [6, 9]
+    assert check._chains([8, 8, 3, 6], [2, 2, 5, 2], 10) == [3, 6]
+    assert check._chains([8, 3], [2, 4], 10) is None  # a gap
+    assert check._chains([8, 6, 6], [2, 2, 2], 10) is None  # two from one place
+
+
+def test_a_failed_calls_keys_are_held_to_no_overlap_with_hits_above_one():
+    rows = [(5, 0, 8, R, 2), (5, 0, 2, R, 3)]  # a gap: the lost call's hits
+    assert window(rows, uncertain=[5])[1].correct
+    assert not window(rows)[1].correct
+    _, v = window([(5, 0, 8, R, 2), (5, 0, 7, R, 3)], uncertain=[5])  # 8..10 taken twice
+    assert row(v, "window.token_generations_not_exact") == 1
+    # what a lost call may have moved is not held against a refusal or a drain
+    _, v = window([(5, 1, 4, R, 5), (5, 1, 0, R, 5, DRAIN)], uncertain=[5])
+    assert v.correct, v.lines()
+
+
+def test_probes_equal_the_start_less_the_sum_of_the_accepted_hits():
+    wc, v = window([(5, 0, 8, R, 2), (5, 0, 3, R, 5)])
+    wc.check_probes(items_of([(5, 0, 3, R)]), v)
+    assert v.correct, v.lines()
+    v2 = check.Verdict()
+    wc.check_probes(items_of([(5, 0, 8, R)]), v2)  # the start less the count of them
+    assert row(v2, "probe.mismatches") == 1
+
+
+@pytest.mark.parametrize("removal,evicted", [(True, set()), (False, {5})])
+def test_a_probe_that_makes_a_bucket_after_a_removal_saw_no_eviction(removal, evicted):
+    rows = [(5, 0, 9, R)] + ([(5, 0, 10, 0, 1, RESET)] if removal else [])
+    wc, v = window(rows)
+    wc.check_probes(items_of([(5, 0, 10, R + 300)]), v)  # a bucket of its own making
+    assert v.correct and wc.evicted == evicted, v.lines()
+
+
+def mixed_window(fault=None, calls=400, seed=2147483747):
+    """The plain reference put in the server's place under a mixed plan at a
+    small size (`calls100`'s calls; hits a share table, DRAIN_OVER_LIMIT a
+    part of one limit in four, RESET_REMAINING on 2 % of the items), callers
+    in turn, one time a call; every 20th call broken as control.py breaks it."""
+    with open(os.path.join(ROOT, "benchmarks/traffic/calls100.json"),
+              encoding="utf-8") as f:
+        traf = dict(json.load(f), callers=8, pool_calls=12,
+                    hits={"1": 0.80, "2": 0.10, "5": 0.08, "20": 0.02},
+                    behavior_shares=[{"share": 0.98, "behavior": []},
+                                     {"share": 0.02, "behavior": ["RESET_REMAINING"]}])
+    ks = traffic.Keyspace(name="t", n=4000, limit=100, duration_ms=3_600_000,
+                          algorithm="even_token_odd_leaky", behavior=0, salt=9,
+                          key_flags=((4, DRAIN),))
+    plan = traffic.build_plan(traf, ks, seed, 5.0)
+    server, t_pin = ref.Reference(), 1_700_000_000_000
+    for k in range(ks.n):  # the preload: one hit on every key
+        server.get_rate_limits([ks.request(k, 1, created_at=t_pin)], t_pin)
+    pools = [np.nonzero(plan.caller_of == c)[0] for c in range(plan.callers)]
+    rows, now, last = [], t_pin + 60_000, None
+    for n in range(calls):
+        i = int(pools[n % 8][(n // 8) % len(pools[n % 8])])
+        now += 6
+        broken = fault and (n + 1) % 20 == 0
+        flags = [int(b) & ~(DRAIN | RESET) if broken and fault == "strip_flags" else int(b)
+                 for b in plan.behaviors[i]]
+        names = {}
+        if broken and fault == "forget":
+            names = {"name": f"forgotten{n}"}
+
+        def ask():
+            reqs = [ks.request(k, int(h), created_at=now, behavior=b)
+                    for k, h, b in zip(plan.keys[i], plan.hits[i], flags)]
+            for r in reqs:
+                r.name = names.get("name", r.name)
+            return [a.as_tuple()[:4] for a in server.get_rate_limits(reqs, now)]
+
+        got = ask()
+        if broken and fault == "double_apply":
+            got = ask()
+        if fault == "stale_answer":
+            got, last = (last if broken and last else got), got
+        rows += [(k,) + a + (h, b) for k, a, h, b in
+                 zip(plan.keys[i], got, plan.hits[i], plan.behaviors[i])]
+    a = np.asarray(rows, dtype=np.int64)
+    it = check.Items(key=a[:, 0], status=a[:, 1], limit=a[:, 2], remaining=a[:, 3],
+                     reset_time=a[:, 4], valid=np.ones(len(a), bool), behavior=a[:, 6],
+                     hits=a[:, 5])
+    carried = check.Carried.empty(ks.n)
+    tok = ks.is_token(np.arange(ks.n))
+    carried.remaining[tok], carried.reset_time[tok] = ks.limit - 1, t_pin + ks.duration_ms
+    wc = check.WindowCheck(ks, carried, np.zeros(ks.n, bool))
+    v = check.Verdict()
+    wc.check_window(it, v)
+    ids = np.unique(a[:, 0])
+    now += 1000
+    p = np.asarray([server.get_rate_limits([ks.request(k, 0, created_at=now)], now)[0]
+                    .as_tuple()[:4] for k in ids.tolist()], dtype=np.int64)
+    wc.check_probes(check.Items(
+        key=ids, status=p[:, 0], limit=p[:, 1], remaining=p[:, 2], reset_time=p[:, 3],
+        valid=np.ones(len(ids), bool), behavior=np.zeros(len(ids), np.int64),
+        hits=np.zeros(len(ids), np.int64)), v)
+    wc.check_evictions(len(ids), 1 << 16, 8, v)  # a table in which nothing is evicted
+    return wc, v
+
+
+def test_the_reference_in_the_servers_place_reads_every_row_nought():
+    wc, v = mixed_window()
+    assert v.correct and wc.evicted == set(), v.lines()
+    assert all(x == 0 for _, x, _ in v.rows), v.lines()
+    c = wc.counted
+    # the rows had work: hits above one, refusals with a remainder, drains,
+    # removals and the generations made after them
+    assert c["items"] == 40_000 and c["items_hits_over_1"] > 0.15 * c["items"]
+    assert c["refused_with_remainder"] > 20 and c["generations_drained"] > 3
+    assert c["reset_removed_bucket"] > 200 and c["generations_after_reset"] > 150
+
+
+@pytest.mark.parametrize("fault,rows", [
+    ("double_apply", {"window.token_generations_not_exact"}),
+    ("stale_answer", {"window.token_generations_not_exact"}),
+    ("forget", {"evicted_keys"}),
+    ("strip_flags", {"window.reset_not_fresh", "window.drain_left_remaining"}),
+])
+def test_the_reference_broken_as_the_controls_break_the_server_is_not_correct(fault, rows):
+    # a stripped DRAIN_OVER_LIMIT shows only where it met a remainder: a longer window
+    _, v = mixed_window(fault, calls=1200 if fault == "strip_flags" else 400)
+    failed = {n for n, x, lim in v.rows if x > lim}
+    assert not v.correct and failed & rows, v.lines()
+    if fault == "strip_flags":  # each of the two new rows sees it
+        assert rows <= failed, v.lines()
+
+
+def test_sequential_follows_hits_and_flags_and_a_removed_bucket_carries_nothing():
+    served = ref.Reference()
+    now = 5_000
+
+    def send(reqs):
+        return [r.as_tuple() for r in served.get_rate_limits(copy.deepcopy(reqs), now)]
+
+    seq = check.Sequential(send)
+    steps = [(4, 0), (20, 0), (20, DRAIN), (1, 0), (3, RESET), (2, 0)]
+    got = [seq.call("c", [3], [KS.request(3, h, created_at=now, behavior=b)], now)[0][:3]
+           for h, b in steps]
+    assert got == [(0, 10, 6), (1, 10, 6), (1, 10, 0), (1, 10, 0), (0, 10, 10), (0, 10, 8)]
+    assert seq.mismatches == 0 and seq.token_state(3, KS) == (8, now + 5000, False)
+    seq.call("c", [3], [KS.request(3, 1, created_at=now, behavior=RESET)], now)
+    assert seq.token_state(3, KS) is None  # run.py then carries no bucket for the key
+
+
+def test_the_control_that_strips_the_flags_is_one_of_the_kinds():
+    from benchmarks import control
+
+    assert control.KINDS == ("double_apply", "stale_answer", "forget", "strip_flags")
+    assert control.STRIPPED == DRAIN | RESET == 40
 
 
 # ---- a guarantee that is eventual -----------------------------------------------------
@@ -570,6 +978,7 @@ def test_a_probe_that_finds_a_live_bucket_gone_counts_an_eviction():
 class FakePlan:
     keys = [np.array(k) for k in ([1, 2], [3, 3], [4, 5], [2, 6], [7, 8], [9, 10])]
     behaviors = [np.array([GLOBAL, GLOBAL])] * 6
+    hits = [np.array([1, 1])] * 6
 
 
 def test_the_set_up_check_of_an_eventual_configuration_sends_each_call_twice():
@@ -679,7 +1088,9 @@ def test_metrics_of_a_cell():
     e2e = {x["name"] for x in manifest.metrics_of(m, "batching-10k.steady", "end_to_end")}
     assert e2e == {"call_p50_ms", "setup_s"}
     herd = {x["name"] for x in manifest.metrics_of(m, "batching-10k.herd", "per_layer")}
-    assert "fast_path_share" in herd and "gen_late_p99_ms" not in herd
+    assert "columnar_call_share" in herd and "gen_late_p99_ms" not in herd
+    # retired in PR 47: it read flushes over calls, about 100 / calls_per_flush
+    assert "fast_path_share" not in {p["name"] for p in m["per_layer"]}
     assert "compile_s" in herd  # no `workloads` key: every cell that reports setup_s
 
 
