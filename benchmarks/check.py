@@ -1,7 +1,7 @@
 """The comparison that decides ``correct``: the served path held to the
 plain reference (reference/oracle.py), by integer equality throughout.
 
-Three stages, all on what the timed path's own server answered:
+Four stages, all on what the timed path's own server answered or saved:
 
 1. before the window: sequential calls of the cell's own mix with a
    pinned clock, every answer equal to the reference's (``Sequential``);
@@ -19,7 +19,22 @@ Three stages, all on what the timed path's own server answered:
    as the first of a generation (it found none); every ``limit`` echoes
    the request;
 3. after the window: ``hits=0`` probes equal what was left minus the
-   accepted hits, 0 where the generation was drained (``check_probes``).
+   accepted hits, 0 where the generation was drained (``check_probes``);
+4. after the exit, for a configuration with ``"shutdown": {"saved":
+   "checked"}``: the snapshot the server's Loader wrote (snapshot.py) holds
+   no key that is not of the keyspace (``save.keys_unknown``), every probed
+   token key (``save.keys_missing``) and for each the ``limit``,
+   ``duration``, ``remaining`` and expiry its probe answered
+   (``save.rows_differ``), which stage 3 has held to the reference: every
+   acknowledged hit is in the checkpoint (``check_saved``).
+
+A configuration preloaded by a snapshot (``"preload": {"via": "snapshot"}``)
+has a row before stage 1, ``load.keys_not_resident``: its keys less the
+slots ``/debug/table`` counts in use after the Load, against what the
+geometry lets one expect (``resident_allowance``). Stage 1 then holds the
+Load as it holds a gRPC preload: its history is the same request at the
+same pinned clock, so a key the Load dropped or changed answers unlike the
+reference.
 
 What the rows model is behaviour 0, DRAIN_OVER_LIMIT, RESET_REMAINING and
 their union at any hits >= 1 (``modelled``); GLOBAL items have rows of
@@ -218,6 +233,26 @@ def lost_share(keys: int, groups: int, ways: int) -> float:
     touches it."""
     m = keys / groups
     return sum((k - ways) * p for k, p in _poisson(m, ways + 200) if k > ways) / m
+
+
+def resident_allowance(keys: int, groups: int, ways: int) -> int:
+    """Keys that may find no slot when all `keys` are loaded into an empty
+    table: those over `ways` in their group, `keys * lost_share` expected,
+    plus six standard deviations of that sum over the groups, plus 2 (ten
+    seeds on the chip spread by 1.4 of the model's deviation, PERF.md §2)."""
+    m = keys / groups
+    over = [(k - ways, p) for k, p in _poisson(m, ways + 200) if k > ways]
+    mean = sum(o * p for o, p in over)
+    var = sum(o * o * p for o, p in over) - mean * mean
+    return int(math.ceil(groups * mean + 6.0 * math.sqrt(groups * var))) + 2
+
+
+def check_load(table: dict, keys: int, v: Verdict) -> None:
+    """A table filled by a Load, as ``/debug/table`` (or the tier of it that
+    holds the keys) reports it once the server is healthy."""
+    v.add("load.keys_not_resident", max(keys - int(table["live"]), 0),
+          resident_allowance(keys, table["groups"], table["ways"]),
+          f"{table['live']} slots in use for {keys} keys")
 
 
 def eviction_allowance(observed_keys: int, keys: int, groups: int, ways: int) -> int:
@@ -698,6 +733,63 @@ class WindowCheck:
         v.add("probe.failed", sum(int(np.sum(~it.valid)) for it in repeats), 0)
         v.add("probe.global_mismatches", bad, 0, example)
         v.add("probe.global_answers_disagree", differ, 0, example_d)
+
+    def check_saved(self, it: Items, saved, hash_keys: list, also_known,
+                    v: Verdict) -> None:
+        """Stage 4: the snapshot the server saved at shutdown, `saved` =
+        (keys, columns) as snapshot.read gives them or the reason why it
+        could not be read, against the probes `it` sent just before.
+        `hash_keys` are the keyspace's by id; `also_known` the harness's
+        own keys (its workers' warm-up calls). A probed key with a sign of
+        eviction, or in a call that failed, is excused, as stage 3 excuses
+        it; one whose bucket a RESET_REMAINING removed may be missing."""
+        ks = self.ks
+        unreadable = isinstance(saved, str)
+        v.add("save.file_unreadable", int(unreadable), 0, saved if unreadable else "")
+        if unreadable:
+            for row in ("save.keys_unknown", "save.keys_missing", "save.rows_differ"):
+                v.add(row, 0, 0)
+            return
+        keys, cols = saved
+        id_of = {h: k for k, h in enumerate(hash_keys)}
+        also_known = set(also_known)
+        ids = np.fromiter((id_of.get(h, -1) for h in keys), np.int64, len(keys))
+        row_of = np.full(ks.n, -1, np.int64)
+        rows = np.nonzero(ids >= 0)[0]
+        row_of[ids[rows]] = rows
+        # not of the keyspace, or a key that came before
+        strange = [keys[i] for i in np.nonzero(ids < 0)[0].tolist()
+                   if keys[i] not in also_known]
+        twice = len(rows) - int(np.sum(row_of >= 0))
+        v.add("save.keys_unknown", len(strange) + twice, 0,
+              f"first {strange[:3]}; {twice} rows of a key saved before")
+        evicted = np.zeros(ks.n, dtype=bool)
+        evicted[sorted(self.evicted)] = True
+        held = (it.valid & ks.is_token(it.key) & ~self.uncertain[it.key]
+                & ~evicted[it.key])
+        row = row_of[it.key]
+        missing = held & (row < 0) & (self.removed[it.key] == 0)
+        v.add("save.keys_missing", np.sum(missing), 0,
+              f"first key ids {it.key[missing][:5].tolist()} of {len(keys)} rows")
+        there = held & (row >= 0)
+        key, row = it.key[there], row[there]
+        differ = ((cols["limit"][row] != it.limit[there])
+                  | (cols["duration"][row] != ks.duration_ms)
+                  | (cols["remaining"][row] != it.remaining[there])
+                  | (cols["expire_at"][row] != it.reset_time[there]))
+        example = ""
+        if differ.any():
+            i = int(np.argmax(differ))
+            r = int(row[i])
+            example = (f"key={int(key[i])}: saved limit={int(cols['limit'][r])} "
+                       f"duration={int(cols['duration'][r])} "
+                       f"remaining={int(cols['remaining'][r])} "
+                       f"expire_at={int(cols['expire_at'][r])}; probe "
+                       f"limit={int(it.limit[there][i])} "
+                       f"remaining={int(it.remaining[there][i])} "
+                       f"reset_time={int(it.reset_time[there][i])}")
+        v.add("save.rows_differ", np.sum(differ), 0, example)
+        self.counted.update(saved_rows=len(keys), saved_probed=int(np.sum(there)))
 
     def check_evictions(self, observed_keys: int, groups: int, ways: int,
                         v: Verdict) -> None:

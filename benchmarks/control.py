@@ -23,6 +23,16 @@ generators and the server during a ``--control <kind>`` run. It passes
 The benchmark's own runs never start it. The parent's own calls
 (preload, set-up check, probes) go to the server directly, so the fault
 sits under the timed path alone.
+
+Two more kinds need no relay: they break what a Loader-attached daemon is
+handed at start or hands back at shutdown (run.py calls them on the
+snapshot files, snapshot.py):
+
+    stale_snapshot the checkpoint the server loads holds ``remaining`` one
+                   too high for one key in 1,000: the Load gives back a
+                   hit that was acknowledged
+    drop_saved     one row in 1,000 of the checkpoint the server saved is
+                   gone before the check reads it: a Save that lacks keys
 """
 
 from __future__ import annotations
@@ -40,6 +50,23 @@ from benchmarks import wire  # noqa: E402
 EVERY = 20
 KINDS = ("double_apply", "stale_answer", "forget", "strip_flags")
 STRIPPED = wire.BEHAVIOR["RESET_REMAINING"] | wire.BEHAVIOR["DRAIN_OVER_LIMIT"]
+SNAPSHOT_KINDS = ("stale_snapshot", "drop_saved")
+ONE_IN = 1000
+
+
+def stale_snapshot(cols: dict) -> None:
+    """In place: every `ONE_IN`-th row of a snapshot's columns gets back one
+    hit it had taken."""
+    cols["remaining"][::ONE_IN] += 1
+
+
+def drop_saved(keys: list, cols: dict) -> tuple:
+    """The snapshot without every `ONE_IN`-th row."""
+    import numpy as np
+
+    keep = np.arange(len(keys)) % ONE_IN != 0
+    return ([k for k, kept in zip(keys, keep.tolist()) if kept],
+            {c: v[keep] for c, v in cols.items()})
 
 
 async def main_async(kind: str, port: int, target: str) -> None:

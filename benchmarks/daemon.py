@@ -20,6 +20,9 @@ import urllib.error
 import urllib.request
 
 START_TIMEOUT_S = 1100  # exec to healthy, cold compile included
+# SIGTERM to exit: a Loader's Save of 1M rows took 61 s where the program that
+# reads the table back compiled, 12-14 s after (my chip runs, PR 48)
+STOP_TIMEOUT_S = 300
 BAD_LOG_LINES = (
     "Traceback",
     "native library",
@@ -130,7 +133,7 @@ class Daemon:
     configuration's environment."""
 
     def __init__(self, label: str, conf: dict, platform: str, chips: int,
-                 root: str, work_dir: str):
+                 root: str, work_dir: str, extra_env: dict = None):
         self.grpc_addr = f"127.0.0.1:{free_port()}"
         self.http_addr = f"127.0.0.1:{free_port()}"
         tmp = os.path.join(work_dir, "tmp")
@@ -141,6 +144,7 @@ class Daemon:
             # profiler captures land under TMPDIR: keep them in the checkout
             "TMPDIR": tmp,
             **{k: str(v) for k, v in conf.get("env", {}).items()},
+            **(extra_env or {}),  # the harness's own, e.g. BENCH_SNAPSHOT_IN
         }
         if platform != "cpu":
             # only the first run of a cell in a checkout compiles
@@ -166,9 +170,13 @@ class Daemon:
                    START_TIMEOUT_S, poll_s=0.1)
         return time.monotonic() - self.child.t_exec
 
-    def stop(self) -> None:
-        rc = self.child.terminate()
+    def stop(self) -> float:
+        """SIGTERM, the drain, the exit; returns the seconds that took."""
+        t_term = time.monotonic()
+        rc = self.child.terminate(STOP_TIMEOUT_S)
+        stop_s = time.monotonic() - t_term
         require(rc == 0, f"{self.child.label} exited rc={rc} after SIGTERM")
         require("drain complete" in self.child.log_text(),
                 f"{self.child.label} log never reached 'drain complete'")
         self.child.require_clean_log()
+        return stop_s

@@ -1,5 +1,20 @@
 """``BENCHMARK.json`` and the files it names, checked before anything
-starts: a manifest that breaks the contract fails here, not on the chip."""
+starts: a manifest that breaks the contract fails here, not on the chip.
+
+Of a configuration's own file two optional keys are checked
+(``check_config``), both off by default:
+
+    "preload":  {"hits": 1, "via": "grpc" | "snapshot"}
+    "shutdown": {"saved": "checked"}
+
+``via`` says how the keys get into the table before the window: ``grpc``
+(the default) is run.py's own loop of 1,000-key calls; ``snapshot`` is a
+checkpoint file of the reference's state (snapshot.py) that the
+configuration's launcher loads at start, refused for a keyspace with leaky
+keys (the file carries upstream's fields, a leaky remainder is the
+program's own fixed-point form). With ``shutdown.saved`` the run reads back
+what the server's Loader saved at shutdown (check.py, stage 4); without
+the key nothing is read after the exit."""
 
 from __future__ import annotations
 
@@ -66,6 +81,8 @@ def check(m: dict, root: str) -> None:
               f"config file {c['file']} outside paths")
         _need(os.path.isfile(os.path.join(root, c["file"])),
               f"config file {c['file']} missing")
+        with open(os.path.join(root, c["file"]), encoding="utf-8") as f:
+            check_config(json.load(f), c["name"])
         _need(len(c["reduced"]) <= 16 and all(NAME.match(k) for k in c["reduced"]),
               "reduced: at most 16 names")
         configs[c["name"]] = c
@@ -125,6 +142,23 @@ def check(m: dict, root: str) -> None:
     for w in cells:
         _need(any(w in cells_of(p, e2e, names) for p in m["per_layer"]),
               f"cell {w} reports no per-layer metric")
+
+
+def check_config(conf: dict, name: str = "config") -> None:
+    """The two optional keys of a configuration's file (the module's doc)."""
+    pre = conf.get("preload")
+    if pre is not None:
+        _need(isinstance(pre, dict) and set(pre) <= {"hits", "via"},
+              f"{name}: preload holds `hits` and `via` alone")
+        via = pre.get("via", "grpc")
+        _need(via in ("grpc", "snapshot"),
+              f"{name}: preload.via is \"grpc\" or \"snapshot\"")
+        _need(via == "grpc" or conf.get("keyspace", {}).get("algorithm") == "token",
+              f"{name}: preload.via snapshot needs a keyspace of token keys")
+    _need(conf.get("shutdown", {"saved": "checked"}) == {"saved": "checked"},
+          f"{name}: shutdown is {{\"saved\": \"checked\"}} or absent")
+    _need("shutdown" not in conf or "consistency" not in conf,
+          f"{name}: shutdown.saved is checked against probes that are exact")
 
 
 def cells_of(metric: dict, e2e: dict, all_cells: list) -> list:

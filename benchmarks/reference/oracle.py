@@ -202,6 +202,24 @@ class Reference:
             return self._leaky_bucket(r, now_ms, is_owner)
         return self._token_bucket(r, now_ms, is_owner)
 
+    def export(self) -> List[dict]:
+        """Every bucket held, in upstream's CacheItem terms (reference
+        store.go:29-43): what a Loader is handed at shutdown and hands back
+        at start. Token buckets only: a leaky bucket's remainder is a
+        fixed-point form of this port, not upstream's field."""
+        rows = []
+        for item in self.cache.values():
+            if item.algorithm != TOKEN_BUCKET:
+                raise ValueError(f"{item.key}: only token buckets are exported")
+            t: TokenBucketState = item.value
+            rows.append({
+                "key": item.key, "algorithm": item.algorithm,
+                "expire_at": item.expire_at, "status": t.status,
+                "limit": t.limit, "duration": t.duration,
+                "remaining": t.remaining, "created_at": t.created_at,
+            })
+        return rows
+
     # -- cache access with lazy expiry --------------------------------------
 
     def _get(self, r: Request, now_ms: int) -> Optional[CacheEntry]:

@@ -13,6 +13,18 @@ object as the last line of stdout. Everything that belongs to one
 configuration, traffic mix or per-layer metric is a file found by the
 name in ``BENCHMARK.json``.
 
+A configuration whose file says ``"preload": {"via": "snapshot"}`` is not
+preloaded over gRPC: the pinned clock is fixed and the reference's state
+after the preload's own request written as a checkpoint (snapshot.py:
+key, algorithm, expire-at, status, limit, duration, remaining, created-at)
+*before* the server starts, and the server's launcher is told where it is
+(``BENCH_SNAPSHOT_IN``). ``start_s`` then holds the Load and ``preload_s``
+the making and writing of the file. With ``"shutdown": {"saved":
+"checked"}`` the launcher is also told where to save at shutdown
+(``BENCH_SNAPSHOT_OUT``); after the probes the run waits for the exit,
+prints ``save_s`` (SIGTERM to exit) and holds the file to what the probes
+answered (check.py, stage 4). Both paths lie in the run's own directory.
+
 Without a TPU the run exits non-zero with no result line.
 ``--platform cpu`` is the rehearsal: its result carries ``correct`` and
 counts, and null for every time, rate and share.
@@ -41,7 +53,17 @@ if ROOT not in sys.path:
 
 import numpy as np  # noqa: E402
 
-from benchmarks import check, consistency, manifest, readers, stats, traffic, wire  # noqa: E402
+from benchmarks import (  # noqa: E402
+    check,
+    consistency,
+    control,
+    manifest,
+    readers,
+    snapshot,
+    stats,
+    traffic,
+    wire,
+)
 from benchmarks.reference.oracle import Request  # noqa: E402
 from benchmarks.daemon import (  # noqa: E402
     BenchFailure,
@@ -210,15 +232,20 @@ def call_deadline_s(traf: dict) -> float:
     return float(traf.get("call_deadline_s", CALL_DEADLINE_S))
 
 
+def warmup_request(w: int) -> Request:
+    """Worker w's first call, a look at a key of its own outside the
+    keyspace: its channel is up before the window."""
+    return Request(name="bench-warmup", unique_key=f"worker{w}", hits=0,
+                   limit=1, duration=60_000)
+
+
 def start_workers(plan, targets, n_workers: int, work: str,
                   deadline_s: float) -> list:
     workers = []
     for w in range(n_workers):
         job = {"loop": plan.loop, "targets": targets, "blobs": {},
                "deadline_s": deadline_s,
-               "warmup": wire.encode_call([Request(
-                   name="bench-warmup", unique_key=f"worker{w}", hits=0,
-                   limit=1, duration=60_000)])}
+               "warmup": wire.encode_call([warmup_request(w)])}
         if plan.loop == "closed":
             pools = {}
             for c in range(w, plan.callers, n_workers):
@@ -402,8 +429,9 @@ def main() -> int:
                     "implicitly and it reports no time, rate or share")
     ap.add_argument("--control", default=None,
                     help="not a benchmark run: put control.py's relay, which "
-                    "breaks one guarantee, under the timed path; `correct` "
-                    "has to come out false")
+                    "breaks one guarantee, under the timed path, or break a "
+                    "snapshot (stale_snapshot, drop_saved); `correct` has to "
+                    "come out false")
     ap.add_argument("--keys", type=int, default=None,
                     help="rehearsal only: a smaller keyspace")
     args = ap.parse_args()
@@ -457,15 +485,34 @@ def run(args) -> int:
 
 def measure(args, m, cell, conf, traf, seconds, chips, work,
             daemons, workers) -> int:
-    daemon = Daemon("daemon", conf, args.platform, chips, ROOT, work)
-    daemons.append(daemon)
-
-    # the traffic and the preload are made while the server starts
     ks = traffic.Keyspace.from_config(conf, args.seed)
     spec = conf.get("preload")
+    by_snapshot = bool(spec) and spec.get("via", "grpc") == "snapshot"
+    saved_path = (os.path.join(work, "snapshot_out.npz")
+                  if conf.get("shutdown") else None)
+    require(args.control != "stale_snapshot" or by_snapshot,
+            "--control stale_snapshot: the configuration loads no snapshot")
+    require(args.control != "drop_saved" or saved_path,
+            "--control drop_saved: the configuration checks no saved snapshot")
     t_pin = int(time.time() * 1000)
+    snapshot_env = {"BENCH_SNAPSHOT_OUT": saved_path} if saved_path else {}
+    preload_s = 0.0
+    t_preload = time.monotonic()
+    hash_keys = snapshot.hash_keys(ks) if by_snapshot or saved_path else None
+    if by_snapshot:  # the checkpoint is there before the server starts
+        rows = snapshot.preload_rows(ks, int(spec.get("hits", 1)), t_pin)
+        if args.control == "stale_snapshot":
+            control.stale_snapshot(rows)
+        snapshot_env["BENCH_SNAPSHOT_IN"] = os.path.join(work, "snapshot_in.npz")
+        snapshot.write(snapshot_env["BENCH_SNAPSHOT_IN"], hash_keys, rows)
+        preload_s = time.monotonic() - t_preload
+    daemon = Daemon("daemon", conf, args.platform, chips, ROOT, work, snapshot_env)
+    daemons.append(daemon)
+
+    # the traffic and a gRPC preload are made while the server starts
     blobs_made = ThreadPoolExecutor(max_workers=1)
-    blobs = blobs_made.submit(preload_blobs, ks, spec, t_pin) if spec else None
+    blobs = (blobs_made.submit(preload_blobs, ks, spec, t_pin)
+             if spec and not by_snapshot else None)
     plan = traffic.build_plan(traf, ks, args.seed, seconds)
     n_workers = int(traf.get("workers", 1))
     say(f"plan: loop={plan.loop} calls_made={len(plan.blobs)} "
@@ -505,7 +552,9 @@ def measure(args, m, cell, conf, traf, seconds, chips, work,
         f"evictable_share={check.evictable_share(*shape):.6f}")
 
     target = daemon.grpc_addr
-    if args.control:
+    if args.control in control.SNAPSHOT_KINDS:
+        say(f"CONTROL RUN: {args.control}; not a benchmark run")
+    elif args.control:
         port = free_port()
         relay = Child(
             "control",
@@ -522,11 +571,11 @@ def measure(args, m, cell, conf, traf, seconds, chips, work,
     load_workers = start_workers(plan, [target], n_workers, work, deadline_s)
     workers.extend(load_workers)
     client = Client(daemon.grpc_addr)
-    t_preload = time.monotonic()
-    if spec:
+    if blobs:
+        t_preload = time.monotonic()
         preload(client, ks, spec, t_pin, blobs.result())
+        preload_s = time.monotonic() - t_preload
     blobs_made.shutdown()
-    preload_s = time.monotonic() - t_preload
     hits0 = int(spec.get("hits", 1)) if spec else 0
     if eventual and spec:
         eventual.wait("after the preload")
@@ -626,6 +675,8 @@ def measure(args, m, cell, conf, traf, seconds, chips, work,
                      "evicted_in_setup": sorted(seq.evicted)}, f)
 
     verdict = check.Verdict()
+    if by_snapshot:
+        check.check_load(geom, ks.n, verdict)
     verdict.add("setup.mismatches", seq.mismatches, 0, " | ".join(seq.examples))
     verdict.add("setup.calls_short", max(min(50, asked) - made, 0), 0)
     wc = check.WindowCheck(ks, carried, uncertain)
@@ -652,6 +703,19 @@ def measure(args, m, cell, conf, traf, seconds, chips, work,
     cold = after.get("gubernator_engine_cold_compile_count", 0) - before.get(
         "gubernator_engine_cold_compile_count", 0)
     verdict.add("window.cold_compiles", cold, 0)
+    if saved_path:  # stage 4, once the server has gone: what its Loader saved
+        phases["save_s"] = stopped.result()
+        try:
+            saved = snapshot.read(saved_path)
+            if args.control == "drop_saved":
+                saved = control.drop_saved(*saved)
+        except ValueError as e:
+            saved = str(e)
+        wc.check_saved(probes, saved, hash_keys,
+                       [warmup_request(w).hash_key() for w in range(n_workers)],
+                       verdict)
+        say(f"shutdown: save_s={phases['save_s']:.3f} (SIGTERM to exit, the "
+            f"drain and the Save)")
     for line in verdict.lines():
         say(line)
     say("counted: " + " ".join(f"{k}={n}" for k, n in wc.counted.items()))
@@ -757,6 +821,9 @@ def measure(args, m, cell, conf, traf, seconds, chips, work,
     }
     if args.trace and trace and trace["devices"] and args.platform != "cpu":
         result["breakdown"] = trace["breakdown"]
+    # the parent's own clock over set-up and, where a Save is checked, shutdown
+    result["phases"] = {k: None if args.platform == "cpu" else v
+                        for k, v in phases.items()}
     # what the window held for the exact rows to work on (no limit: counts)
     result["counted"] = wc.counted
     # every number compared beside its limit, where a record of a run that

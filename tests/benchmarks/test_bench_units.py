@@ -217,6 +217,8 @@ PLANS_BEFORE = {
     "store-4.calls100": (32, "b67f49bdb8d5ed9c"),
     "batching-10k.burst": (150, "e061e07270d34089"),
     "global-hot-4.herd-zipf": (1600, "e1cc288e944262ae"),
+    # since PR 48, the calls100 family's fifth: the same file over the same keys
+    "loader-1m.calls100": (32, "b67f49bdb8d5ed9c"),
 }
 
 
